@@ -1,172 +1,129 @@
-"""Cross-version JAX API shims.
+"""The one import point for the JAX names whose public home has moved.
 
-The public homes of ``shard_map`` and ``export`` moved between jax releases:
-
-* ``shard_map``: ``jax.experimental.shard_map.shard_map`` (<= 0.4.x, kwarg
-  ``check_rep``) became ``jax.shard_map`` (>= 0.5, kwarg ``check_vma``).
-* ``export``: ``jax.experimental.export`` (<= 0.4.2x) became ``jax.export``
-  (a lazily-imported submodule — plain attribute access on ``jax`` raises
-  AttributeError until something imports it).
-
-Every in-repo and in-test use goes through this module so a jax upgrade is a
+``shard_map``, ``enable_x64``, ``lax.axis_size`` and ``jax.export`` all changed
+address between jax releases. Every in-repo and in-test use goes through this
+module (the ``compat-shim`` lint rule enforces it), so the next move is a
 one-file change (SURVEY §4: version-drift collection errors silently dropped
-three files from tier-1).
+three files from tier-1). It holds the spellings of the installed jax only; a
+branch for a jax that is not installed cannot be run and is not kept.
 """
 from __future__ import annotations
 
 import os
 
 import jax
+from jax import enable_x64, shard_map  # noqa: F401
+from jax.lax import axis_size  # noqa: F401  (NameError when the axis is unbound)
 
 __all__ = [
     "shard_map", "shard_map_check_kwargs", "jax_export", "axis_size",
-    "enable_persistent_compilation_cache",
+    "enable_x64", "enable_persistent_compilation_cache",
 ]
-
-try:  # jax >= 0.5: stable API, replication check renamed to check_vma
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
-try:  # jax >= 0.5: promoted out of experimental
-    from jax import enable_x64  # type: ignore[attr-defined]  # noqa: F401
-except ImportError:
-    from jax.experimental import enable_x64  # noqa: F401
-
-
-def shard_map(f, *args, **kwargs):
-    """``jax.shard_map`` resolved across versions; accepts either spelling of
-    the replication-check kwarg (``check_vma``/``check_rep``) and translates
-    to whatever this jax understands."""
-    for alias in ("check_vma", "check_rep"):
-        if alias in kwargs and alias != _CHECK_KW:
-            kwargs[_CHECK_KW] = kwargs.pop(alias)
-    return _shard_map(f, *args, **kwargs)
 
 
 def shard_map_check_kwargs(value=False):
-    """Kwargs dict disabling (or enabling) the replication check, spelled for
-    this jax version: ``{"check_vma": value}`` or ``{"check_rep": value}``."""
-    return {_CHECK_KW: value}
-
-
-def axis_size(axis: str) -> int:
-    """Size of a bound manual mesh axis; raises (NameError) when ``axis`` is
-    not bound. ``lax.axis_size`` only exists on newer jax — the classic
-    spelling is ``psum(1, axis)``, which constant-folds to the axis size
-    inside shard_map/pmap and raises outside one."""
-    from jax import lax
-
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis)
-    return lax.psum(1, axis)
+    """Kwargs dict disabling (or enabling) shard_map's replication check."""
+    return {"check_vma": value}
 
 
 def jax_export():
-    """The export module (``jax.export`` on >= 0.4.30, else
-    ``jax.experimental.export``). Importing it also binds the ``jax.export``
-    attribute, so legacy ``jax.export.deserialize`` call sites work after any
-    paddle_tpu import."""
-    try:
-        import jax.export as m  # submodule import works even when the lazy
-        return m  # attribute on `jax` hasn't been materialized
-    except ImportError:
-        from jax.experimental import export as m
+    """The ``jax.export`` module. It is a lazily imported submodule: plain
+    attribute access on ``jax`` raises AttributeError until something imports
+    it, so callers take it from here."""
+    import jax.export as m
 
-        return m
+    return m
+
+
+# <checkout>/.jax_cache: a FIXED path, because the directory is part of the
+# cache key (a cache that moves never hits), and inside the checkout, because
+# a sealed machine that copies the tree keeps nothing under $HOME.
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 
 
 def enable_persistent_compilation_cache():
-    """Point JAX's persistent compilation cache at a paddle_tpu-owned dir so
-    re-runs warm-start compiles (the flush-executable signatures are stable
-    across processes). Controlled by ``FLAGS_xla_persistent_cache`` (default
-    on) and ``FLAGS_xla_persistent_cache_dir``. Returns the dir or None."""
+    """Turn on JAX's persistent compilation cache so re-runs warm-start
+    compiles (the flush-executable signatures are stable across processes).
+
+    A directory that is already configured — ``JAX_COMPILATION_CACHE_DIR`` in
+    the environment, or ``jax.config.update`` before importing paddle_tpu —
+    is left exactly as it is: the cache is process-global and nothing here
+    may point it elsewhere. Otherwise the directory is
+    ``FLAGS_xla_persistent_cache_dir`` or ``<checkout>/.jax_cache``.
+    ``FLAGS_xla_persistent_cache=0`` disables the whole thing. Returns the
+    directory in force, or None."""
     from ..framework import flags as _flags
 
     if not _flags.flag("FLAGS_xla_persistent_cache", True):
         return None
-    # Respect a cache the host application already configured (env var or
-    # jax.config.update before importing paddle_tpu) — the compilation cache
-    # is process-global and hijacking it would cold-start their workloads.
-    existing = getattr(jax.config, "jax_compilation_cache_dir", None)
+    _atomic_cache_writes()
+    existing = jax.config.jax_compilation_cache_dir
     if existing:
         return existing
-    d = _flags.flag("FLAGS_xla_persistent_cache_dir") or os.path.join(
-        os.path.expanduser("~"), ".cache", "paddle_tpu", "xla"
-    )
+    d = _flags.flag("FLAGS_xla_persistent_cache_dir") or _DEFAULT_CACHE_DIR
     try:
         os.makedirs(d, exist_ok=True)
-        _atomic_cache_writes()
-        # jax's default threshold (1s) is tuned for serving-sized programs;
-        # a train step's flush executable compiles faster than that on CPU
-        # yet is exactly what a warm restart wants back. Set the threshold
-        # BEFORE the dir: if either option is missing on this jax, nothing
-        # is half-activated (a threshold without a dir is inert).
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(_flags.flag("FLAGS_xla_persistent_cache_min_compile_secs", 0.5)),
-        )
-        jax.config.update("jax_compilation_cache_dir", d)
-        return d
-    except Exception:
-        return None
+    except OSError:
+        return None  # read-only checkout: run without a persistent cache
+    # jax's default threshold (1s) is tuned for serving-sized programs; a
+    # train step's flush executable compiles faster than that on CPU yet is
+    # exactly what a warm restart wants back
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(_flags.flag("FLAGS_xla_persistent_cache_min_compile_secs", 0.5)),
+    )
+    jax.config.update("jax_compilation_cache_dir", d)
+    return d
 
 
 _atomic_writes_patched = False
 
 
 def _atomic_cache_writes():
-    """Make the persistent-cache entry write ATOMIC on jax versions whose
-    ``LRUCache.put`` uses a bare ``write_bytes`` (jax<=0.4.x): a process
-    killed mid-write (the common fate of driver-timed-out benches, SIGKILL)
-    leaves a truncated serialized executable, and every later process that
-    deserializes it crashes — observed as a deterministic segfault in a
-    single test until the cache dir is cleared. tmp-file + ``os.replace``
-    makes a torn entry impossible; readers either see nothing or a full
-    write. No-op when the jax version has no patchable LRUCache."""
+    """Make the persistent-cache entry write ATOMIC. jax 0.9.0's
+    ``LRUCache.put`` still writes the payload with a bare ``write_bytes``: a
+    process killed mid-write (the common fate of a run cut off at its time
+    limit, SIGKILL) leaves a truncated serialized executable, and every later
+    process that deserializes it crashes — observed as a deterministic
+    segfault in a single test until the cache dir is cleared. tmp-file +
+    ``os.replace`` makes a torn entry impossible; readers either see nothing
+    or a full write."""
     global _atomic_writes_patched
     if _atomic_writes_patched:
         return
-    try:
-        from jax._src import lru_cache as _lru
+    import time
 
-        orig_put = _lru.LRUCache.put
-        suffix = getattr(_lru, "_CACHE_SUFFIX", ".bin")
+    from jax._src import lru_cache as _lru
 
-        def atomic_put(self, key, val):
-            # Pre-write the payload file atomically; the original put then
-            # sees it existing and skips its own (torn-write-prone)
-            # write_bytes while still doing the lock/atime bookkeeping.
-            # Thread/process-safe: no global state, and a concurrent
-            # os.replace of the same entry just wins with identical bytes.
-            # (When LRU eviction is explicitly enabled, a pre-written entry
-            # escapes the eviction size accounting — acceptable: this repo
-            # runs the cache unbounded, and a slightly-over-budget cache
-            # beats a segfaulting one.)
-            if key:
-                try:
-                    import time as _time
+    orig_put = _lru.LRUCache.put
 
-                    path = self.path / f"{key}{suffix}"
-                    if not path.exists():
-                        # atime sidecar FIRST: orig_put early-returns on an
-                        # existing payload without writing it, and eviction
-                        # read_bytes()-es every entry's atime
-                        atime = self.path / f"{key}{getattr(_lru, '_ATIME_SUFFIX', '.atime')}"
-                        atime.write_bytes(_time.time_ns().to_bytes(8, "little"))
-                        tmp = path.with_name(f"{path.name}.tmp{os.getpid()}")
-                        tmp.write_bytes(val)
-                        os.replace(tmp, path)
-                except OSError:
-                    pass  # fall through: orig_put raises or handles it
-            return orig_put(self, key, val)
+    def atomic_put(self, key, val):
+        # Pre-write the payload file atomically; the original put then sees
+        # it existing and skips its own (torn-write-prone) write_bytes while
+        # still doing the lock bookkeeping. Thread/process-safe: no global
+        # state, and a concurrent os.replace of the same entry just wins with
+        # identical bytes. (When LRU eviction is explicitly enabled, a
+        # pre-written entry escapes the eviction size accounting —
+        # acceptable: this repo runs the cache unbounded, and a
+        # slightly-over-budget cache beats a segfaulting one.)
+        if key:
+            try:
+                path = self.path / f"{key}{_lru._CACHE_SUFFIX}"
+                if not path.exists():
+                    # atime sidecar FIRST: orig_put early-returns on an
+                    # existing payload without writing it, and eviction
+                    # read_bytes()-es every entry's atime
+                    atime = self.path / f"{key}{_lru._ATIME_SUFFIX}"
+                    atime.write_bytes(time.time_ns().to_bytes(8, "little"))
+                    tmp = path.with_name(f"{path.name}.tmp{os.getpid()}")
+                    tmp.write_bytes(val)
+                    os.replace(tmp, path)
+            except OSError:
+                pass  # fall through: orig_put raises or handles it
+        return orig_put(self, key, val)
 
-        _lru.LRUCache.put = atomic_put
-        _atomic_writes_patched = True
-    except Exception:
-        pass
+    _lru.LRUCache.put = atomic_put
+    _atomic_writes_patched = True

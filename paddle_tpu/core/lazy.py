@@ -1253,6 +1253,7 @@ def _fallback_execute(entry, leaves, replay, don, donated_dead, sp, prof):
                 # never eat an exhaustion into an unfused replay — it would
                 # OOM harder on a real device and silently un-fuse on CPU
                 raise
+            prof.counter_inc("lazy_eager_replay_fallbacks")
             if sp is not None:
                 sp.set(fallback="eager_replay")
             with _spans().span("execute", fallback="eager_replay"):
@@ -1262,6 +1263,7 @@ def _fallback_execute(entry, leaves, replay, don, donated_dead, sp, prof):
         raise
     else:
         # fallback: run un-jitted (still one pass, concrete ops)
+        prof.counter_inc("lazy_eager_replay_fallbacks")
         if sp is not None:
             sp.set(fallback="eager_replay")
         with _spans().span("execute", fallback="eager_replay"):

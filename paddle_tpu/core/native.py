@@ -31,7 +31,12 @@ HAS_EMBED = False
 
 
 def _build():
-    subprocess.run(["make", "-C", _RUNTIME_DIR], check=True, capture_output=True)
+    # the runtime library only: the default target also links the C inference
+    # API against python3-config, which a host that can run this need not have
+    subprocess.run(
+        ["make", "-C", _RUNTIME_DIR, os.path.basename(_SO)],
+        check=True, capture_output=True,
+    )
 
 
 def _stale() -> bool:

@@ -21,13 +21,20 @@ class Place:
 
     # -- PJRT handle ------------------------------------------------------
     def jax_device(self):
-        devs = [d for d in jax.devices() if d.platform == self.device_type]
-        if not devs:
-            # Fall back to the default backend (e.g. asking for tpu on a
-            # CPU-only test host): semantics match reference CPU fallback
-            # (paddle/fluid/framework/operator.cc:1187-1234 phi CPU fallback).
-            devs = jax.devices()
-        return devs[min(self.device_id, len(devs) - 1)]
+        """The ``jax.Device`` this place names. A place that is not there is
+        an error: running somewhere else would be a silent change of device."""
+        if self.device_type == "cpu":
+            # the host backend exists beside an accelerator default too
+            devs = jax.devices("cpu")
+        else:
+            devs = [d for d in jax.devices() if d.platform == self.device_type]
+        if not 0 <= self.device_id < len(devs):
+            have = sorted({d.platform for d in jax.devices()})
+            raise RuntimeError(
+                f"{self!r} is not available: this process has {len(devs)} "
+                f"{self.device_type} device(s) (default backend "
+                f"{jax.default_backend()!r}, platforms {have})")
+        return devs[self.device_id]
 
     def __eq__(self, other):
         return (

@@ -14,7 +14,40 @@ from typing import Dict, Optional
 
 import numpy as np
 
-__all__ = ["CostModel", "executable_memory"]
+__all__ = ["CostModel", "executable_memory", "device_peaks"]
+
+# Peaks by ``jax.Device.device_kind``, each with where it comes from. A device
+# that is not listed is an error, not a default: an estimate priced against
+# another chip's peaks looks exactly like a real one.
+_PEAKS = {
+    "TPU v5 lite": {
+        "flops": 197e12,      # bf16 FLOP/s
+        "hbm_bw": 819e9,      # bytes/s
+        "ici_bw": 200e9,      # bytes/s (1,600 Gbit/s chip-to-chip)
+        "overhead_ms": 2e-3,  # per grid program / collective launch; nominal
+        "source": 'Google Cloud documentation, "TPU v5e"',
+    },
+    "cpu": {
+        "flops": 1e11, "hbm_bw": 5e10, "ici_bw": 5e9, "overhead_ms": 2e-2,
+        "source": "nominal host figures: the CPU tier only ORDERS candidates "
+                  "with them, no time is reported from them",
+    },
+}
+
+
+def device_peaks() -> Dict[str, object]:
+    """The peaks row of the default device. Raises for a ``device_kind`` the
+    table does not hold."""
+    import jax
+
+    kind = jax.devices()[0].device_kind
+    try:
+        return _PEAKS[kind]
+    except KeyError:
+        raise RuntimeError(
+            f"cost_model: no peaks recorded for device_kind {kind!r} (known: "
+            f"{sorted(_PEAKS)}); add a row with its source to "
+            "cost_model._PEAKS") from None
 
 
 def executable_memory(compiled) -> Optional[Dict[str, int]]:
@@ -108,16 +141,9 @@ class CostModel:
         doesn't tile its axis. Relative order is all that matters; an
         unknown kernel scores 0.0 (neutral — stub kernels keep declared
         order)."""
-        import jax
-
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:
-            platform = "cpu"
-        # coarse per-platform peaks; only RATIOS matter for ordering
-        peak_flops = 180e12 if platform == "tpu" else 1e11
-        peak_bw = 7e11 if platform == "tpu" else 5e10
-        overhead_ms = 2e-3 if platform == "tpu" else 2e-2
+        peaks = device_peaks()
+        peak_flops, peak_bw = peaks["flops"], peaks["hbm_bw"]
+        overhead_ms = peaks["overhead_ms"]
 
         def pad(n, b):
             b = max(int(b), 1)
@@ -172,9 +198,8 @@ class CostModel:
             # engine uses this as the shed-ETA floor while its measured
             # decode EMA is still cold.
             wire_bytes, tp = key
-            ici_bw = 1e11 if platform == "tpu" else 5e9
             boundaries = max(int(tp) - 1, 1)
-            return (float(wire_bytes) / ici_bw) * 1e3 \
+            return (float(wire_bytes) / peaks["ici_bw"]) * 1e3 \
                 + boundaries * overhead_ms
         else:
             return 0.0

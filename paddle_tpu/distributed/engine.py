@@ -30,7 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import random as random_state
 from ..core.engine import no_grad
 from ..core.tensor import Tensor
-from .mesh import global_mesh
+from .mesh import global_mesh, partitioned_over
 
 
 def _sharding(mesh: Mesh, spec) -> NamedSharding:
@@ -156,9 +156,9 @@ class HybridParallelEngine:
                     for t, a in zip(params, p_arrays):
                         t._data = a
                     inputs = [Tensor(a, stop_gradient=True) for a in batch_arrays]
-                    with random_state.traced_keys(key):
-                        with no_grad():
-                            out = loss_fn(model, *inputs)
+                    with random_state.traced_keys(key), no_grad(), \
+                            partitioned_over(self.mesh):
+                        out = loss_fn(model, *inputs)
                     return out._data if isinstance(out, Tensor) else out
                 finally:
                     for t, a in saved:
@@ -355,18 +355,22 @@ class HybridParallelEngine:
         return opt_state
 
     @no_grad()
-    def lower_text(self, *batch) -> str:
-        """StableHLO of the train step (introspection/tests: sharding
-        constraints appear as @Sharding custom calls / sdy ops). Side-effect
-        free: the global RNG stream is restored so introspection never
-        perturbs subsequent training."""
+    def lower(self, *batch):
+        """The train step lowered for this batch (``jax.stages.Lowered``).
+        Side-effect free: the global RNG stream is restored so introspection
+        never perturbs subsequent training."""
         st = random_state._get()
         saved_key = st.key
         try:
             args = self._prepare(*batch)
-            return self._jit.lower(*args).as_text()
+            return self._jit.lower(*args)
         finally:
             st.key = saved_key
+
+    def lower_text(self, *batch) -> str:
+        """StableHLO of the train step (introspection/tests: sharding
+        constraints appear as @Sharding custom calls / sdy ops)."""
+        return self.lower(*batch).as_text()
 
     @no_grad()
     def train_step(self, *batch):

@@ -8,6 +8,8 @@ per-axis collectives onto ICI rings automatically.
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from typing import Optional, Sequence
 
 import numpy as np
@@ -39,10 +41,34 @@ def global_mesh() -> Mesh:
     return _global_mesh
 
 
+_partitioned = threading.local()
+
+
+@contextlib.contextmanager
+def partitioned_over(mesh: Mesh):
+    """Declare, for the duration of a trace, that the program being traced is
+    compiled with arguments sharded over ``mesh`` (the hybrid engine's GSPMD
+    step). Code that GSPMD cannot partition by itself — a Mosaic kernel —
+    reads it through :func:`partitioned_mesh` and maps itself over the mesh
+    by hand. A tracer carries no sharding, so the compiling side has to say."""
+    prev = getattr(_partitioned, "mesh", None)
+    _partitioned.mesh = mesh
+    try:
+        yield
+    finally:
+        _partitioned.mesh = prev
+
+
+def partitioned_mesh() -> Optional[Mesh]:
+    """The mesh of the enclosing :func:`partitioned_over`, or None. Unlike
+    :func:`global_mesh` it never invents one: a step compiled for one device
+    on a four-chip host must not be mapped over four."""
+    return getattr(_partitioned, "mesh", None)
+
+
 def shard_map_compat():
-    """(shard_map, check_kwargs) across jax versions — delegates to the
-    one-file shim in ``core/compat.py`` (the stable ``jax.shard_map`` takes
-    ``check_vma``; the older experimental API takes ``check_rep``)."""
+    """(shard_map, check_kwargs) — delegates to ``core/compat.py``, the one
+    import point for the jax names that moved between releases."""
     from ..core.compat import shard_map, shard_map_check_kwargs
 
     return shard_map, shard_map_check_kwargs(False)

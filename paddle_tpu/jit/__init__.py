@@ -308,7 +308,7 @@ class CompiledTrainStep:
         with _spans.span("train_step", kind="jit"):
             return self._call_impl(*batch)
 
-    def _call_impl(self, *batch):
+    def _prepare(self, *batch):
         if self._jit is None:
             self._build()
         batch_arrays = tuple(_conc(b._data) if isinstance(b, Tensor) else jnp.asarray(b) for b in batch)
@@ -316,6 +316,24 @@ class CompiledTrainStep:
         opt_state = self.optimizer._functional_state(self.params)
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         key = random_state.next_key()
+        return param_arrays, opt_state, batch_arrays, lr, key
+
+    def lower(self, *batch):
+        """The train step lowered for this batch (``jax.stages.Lowered``):
+        ``.as_text()`` is its StableHLO, ``.compile().as_text()`` the
+        optimized HLO — introspection of which kernels the step really
+        holds. Like ``HybridParallelEngine.lower``, the global RNG stream is
+        restored and nothing is donated."""
+        st = random_state._get()
+        saved_key = st.key
+        try:
+            args = self._prepare(*batch)
+            return self._jit.lower(*args)
+        finally:
+            st.key = saved_key
+
+    def _call_impl(self, *batch):
+        param_arrays, opt_state, batch_arrays, lr, key = self._prepare(*batch)
         loss, new_params, new_state = self._jit(param_arrays, opt_state, batch_arrays, lr, key)
         for p, a in zip(self.params, new_params):
             p._set_data(a)

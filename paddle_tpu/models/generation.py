@@ -785,9 +785,13 @@ def build_paged_decode(arch, B, block_size, max_blocks):
         # so the values are identical — but with gathers interleaved, every
         # scatter's operand has a later reader and XLA copy-on-writes the
         # whole pool per layer (CPU: ~L pool-sized temps per step); hoisted,
-        # only the first scatter pays one copy
-        ctx = [(kpool[li][tables].reshape(B, T_pad, KV, D),
-                vpool[li][tables].reshape(B, T_pad, KV, D))
+        # only the first scatter pays one copy. ONE gather per layer straight
+        # from the 5-D pool (``kpool[li, tables]``): sliced first
+        # (``kpool[li][tables]``) XLA:TPU materializes every layer's slice,
+        # a second pool's worth of temporaries, and a pool sized to fill the
+        # chip no longer compiles (v5e, 1.3B, 3400 blocks: 16.97 of 15.75 GB)
+        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                vpool[li, tables].reshape(B, T_pad, KV, D))
                for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_rows"](w, x, ctx[li][0], ctx[li][1],
@@ -811,7 +815,7 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     sampling — a drop-in the engine selects behind FLAGS_serve_paged_kernel.
 
     Differences from the gather builder, neither visible in the output:
-    - no ``kpool[li][tables]`` HBM materialization — the kernel DMAs each
+    - no ``kpool[li, tables]`` HBM materialization — the kernel DMAs each
       row's blocks straight out of the pool;
     - the fresh K/V is scattered into the pool BEFORE the kernel reads it
       (the gather path overwrites the gathered copy at ``pos`` in-context —
@@ -907,8 +911,8 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
         bids = jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
         # gathers hoisted above the scatter chain (see build_paged_decode):
         # avoids a whole-pool copy-on-write per layer
-        ctx = [(kpool[li][tables].reshape(B, T_pad, KV, D),
-                vpool[li][tables].reshape(B, T_pad, KV, D))
+        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                vpool[li, tables].reshape(B, T_pad, KV, D))
                for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
@@ -960,8 +964,8 @@ def build_paged_spec_decode(arch, B, k, block_size, max_blocks):
         offs = posm % block_size
         # gathers hoisted above the scatter chain (see build_paged_decode):
         # avoids a whole-pool copy-on-write per layer
-        ctx = [(kpool[li][tables].reshape(B, T_pad, KV, D),
-                vpool[li][tables].reshape(B, T_pad, KV, D))
+        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                vpool[li, tables].reshape(B, T_pad, KV, D))
                for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
@@ -1346,8 +1350,8 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
         else:
             live = jnp.arange(T_pad)[None, :] <= pos[:, None]
             # gathers hoisted above the scatter chain (see build_paged_decode)
-            ctx = [(kpool[li][tables].reshape(B, T_pad, KVl, D),
-                    vpool[li][tables].reshape(B, T_pad, KVl, D))
+            ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
+                    vpool[li, tables].reshape(B, T_pad, KVl, D))
                    for li in range(L)]
             for li in range(L):
                 x, k_new, v_new = arch["layer_rows"](
@@ -1454,8 +1458,8 @@ def build_tp_paged_tail_prefill(arch_key, B, T_bucket, block_size, max_blocks,
         bids = jnp.take_along_axis(
             tables, jnp.minimum(cols, max_blocks - 1), axis=1)
         bids = jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
-        ctx = [(kpool[li][tables].reshape(B, T_pad, KVl, D),
-                vpool[li][tables].reshape(B, T_pad, KVl, D))
+        ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
+                vpool[li, tables].reshape(B, T_pad, KVl, D))
                for li in range(L)]
         for li in range(L):
             x, k_new, v_new = arch["layer_tail"](

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 
 from ...core.dispatch import as_tensor, eager_call
+from ...ops.pallas import interpret_default
 
 
 def _flash_eligible(q, k, is_causal, attn_mask, dropout_p, training):
@@ -32,9 +33,46 @@ def _flash_eligible(q, k, is_causal, attn_mask, dropout_p, training):
     esize = 2 if q.dtype in ("bfloat16", jnp.bfloat16) else 4
     if k.shape[1] * d * esize > 4 * 1024 * 1024:
         return False
-    if jax.devices()[0].platform == "cpu":
-        return False  # interpret-mode pallas is orders slower; XLA exact wins
-    return True
+    # under the interpreter (the CPU tier) the kernel is orders slower than
+    # XLA's exact attention
+    return not interpret_default()
+
+
+def _flash(q, k, v):
+    """The causal Pallas flash call. GSPMD cannot partition a Mosaic custom
+    call ("Mosaic kernels cannot be automatically partitioned"), so under a
+    train step compiled over a device mesh (``distributed.mesh.partitioned_over``)
+    the call is a ``shard_map`` island, as ring attention is: batch over 'dp',
+    heads over 'mp', each device running the kernel on its own slice. An axis
+    the enclosing code already mapped by hand, or that does not divide its
+    dimension, is left out of the specs (the kernel then sees that dimension
+    whole)."""
+    from ...distributed.mesh import partitioned_mesh
+    from ...ops.pallas.flash_attention import flash_attention_array, flash_attention_tpu
+
+    mesh = partitioned_mesh()
+    if mesh is None or mesh.size == 1:
+        return flash_attention_tpu(q, k, v, causal=True)
+
+    from jax.sharding import PartitionSpec as P
+
+    from ...distributed.collective import _axis_bound
+    from ...distributed.mesh import shard_map_compat
+
+    def axis(name, dim):
+        n = mesh.shape.get(name, 1)
+        return name if n > 1 and dim % n == 0 and not _axis_bound(name) else None
+
+    dp, mp = axis("dp", q.shape[0]), axis("mp", q.shape[2])
+    if dp is None and mp is None:
+        return flash_attention_tpu(q, k, v, causal=True)
+    spec = P(dp, None, mp, None)
+    shard_map, check = shard_map_compat()
+    fn = shard_map(
+        lambda a, b, c: flash_attention_array(a, b, c, causal=True),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **check,
+    )
+    return eager_call("flash_attention_spmd", fn, [q, k, v])
 
 
 def scaled_dot_product_attention(
@@ -47,23 +85,18 @@ def scaled_dot_product_attention(
     (blockwise online softmax, no T×T materialization); everything else uses
     the XLA fused formulation. ``impl``: None (auto) | "exact" (never flash)
     | "flash" (force the Pallas kernel; raises if the call is ineligible).
+    A call the kernel was chosen for and then refuses is an error, not a
+    reason to run the exact path unseen.
     """
     q, k, v = as_tensor(query), as_tensor(key), as_tensor(value)
     if impl == "flash":
-        from ...ops.pallas.flash_attention import flash_attention_tpu
-
         if not is_causal or attn_mask is not None or (dropout_p and training):
             raise ValueError(
                 "impl='flash' requires is_causal=True, no attn_mask, no dropout"
             )
-        return flash_attention_tpu(q, k, v, causal=True)
+        return _flash(q, k, v)
     if impl is None and _flash_eligible(q, k, is_causal, attn_mask, dropout_p, training):
-        try:
-            from ...ops.pallas.flash_attention import flash_attention_tpu
-
-            return flash_attention_tpu(q, k, v, causal=True)
-        except Exception:
-            pass
+        return _flash(q, k, v)
     inputs = [q, k, v]
     has_mask = attn_mask is not None
     if has_mask:

@@ -25,7 +25,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ...core.compat import enable_x64
+from ..pallas import interpret_default, kernel_x64_off
 from .registry import register_kernel, resolve_config
 
 try:
@@ -37,12 +37,6 @@ except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
 __all__ = ["int8_matmul", "int8_matmul_key"]
-
-
-def _kernel_x64_off(interpret):
-    import contextlib
-
-    return contextlib.nullcontext() if interpret else enable_x64(False)
 
 
 def _pick_bn(limit: int, n: int) -> int:
@@ -70,7 +64,11 @@ def _int8_kernel(scale_ref, x_ref, w_ref, o_ref, *, transpose_w):
           * (scale_ref[0] / 127.0)).astype(x_ref.dtype)
     dims = ((((1,), (1,)), ((), ())) if transpose_w
             else (((1,), (0,)), ((), ())))
-    o_ref[...] = jax.lax.dot_general(x_ref[...], wd, dims).astype(o_ref.dtype)
+    # accumulate in f32 and round once: the MXU has no narrower accumulator
+    # (Mosaic: "Expected matmul acc to be 32-bit")
+    o_ref[...] = jax.lax.dot_general(
+        x_ref[...], wd, dims, preferred_element_type=jnp.float32,
+    ).astype(o_ref.dtype)
 
 
 def int8_matmul(x, qw, scale, transpose_w=True, config=None, interpret=None):
@@ -83,7 +81,7 @@ def int8_matmul(x, qw, scale, transpose_w=True, config=None, interpret=None):
     if not _HAS_PALLAS:
         raise RuntimeError("pallas unavailable")
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = interpret_default()
     lead = x.shape[:-1]
     K = x.shape[-1]
     x2 = x.reshape(-1, K)
@@ -95,7 +93,7 @@ def int8_matmul(x, qw, scale, transpose_w=True, config=None, interpret=None):
     bn = _pick_bn(int(config.get("block_n", 512)), N)
     wspec = (pl.BlockSpec((bn, K), lambda i: (i, 0)) if transpose_w
              else pl.BlockSpec((K, bn), lambda i: (0, i)))
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         out = pl.pallas_call(
             functools.partial(_int8_kernel, transpose_w=transpose_w),
             grid=(N // bn,),

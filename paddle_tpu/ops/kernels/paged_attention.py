@@ -50,7 +50,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ...core.compat import enable_x64
+from ..pallas import interpret_default, kernel_x64_off
 from .registry import register_kernel, resolve_config
 
 try:
@@ -62,14 +62,6 @@ except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
 __all__ = ["paged_attention_rows", "paged_attention_key"]
-
-
-def _kernel_x64_off(interpret):
-    # Mosaic has no i64/f64 lowering (see ops/pallas/flash_attention.py);
-    # interpret mode must keep the outer x64 state untouched
-    import contextlib
-
-    return contextlib.nullcontext() if interpret else enable_x64(False)
 
 
 def paged_attention_key(B, MB, BS, KV, rep, D, dtype) -> tuple:
@@ -144,7 +136,7 @@ def paged_attention_rows(q, kpool, vpool, tables, pos, config=None,
     if not _HAS_PALLAS:
         raise RuntimeError("pallas unavailable")
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = interpret_default()
     B, H, D = q.shape
     NB, BS, KV, _ = kpool.shape
     MB = tables.shape[1]
@@ -160,7 +152,7 @@ def paged_attention_rows(q, kpool, vpool, tables, pos, config=None,
     kern = functools.partial(
         _paged_kernel, KV=KV, rep=rep, D=D, BS=BS, MB=MB, R=R,
         score_mode=score_mode)
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         return pl.pallas_call(
             kern,
             grid=(B // R,),
@@ -169,8 +161,8 @@ def paged_attention_rows(q, kpool, vpool, tables, pos, config=None,
                              memory_space=pltpu.SMEM),
                 pl.BlockSpec((R,), lambda b: (b,), memory_space=pltpu.SMEM),
                 pl.BlockSpec((R, H * D), lambda b: (b, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),
-                pl.BlockSpec(memory_space=pltpu.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec((R, H * D), lambda b: (b, 0)),
             out_shape=jax.ShapeDtypeStruct((B, H * D), q.dtype),

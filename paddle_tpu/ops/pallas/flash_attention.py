@@ -18,7 +18,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from ...core.compat import enable_x64
+from . import interpret_default, kernel_x64_off
 
 try:
     from jax.experimental import pallas as pl
@@ -101,21 +101,9 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_sc, l_sc, acc_sc, *, bloc
         lse_ref[0, 0, :] = m_sc[:] + jnp.log(l_safe)
 
 
-def _kernel_x64_off(interpret):
-    """Mosaic has no i64/f64 lowering, so the real-kernel trace runs with x64
-    off. Interpret mode (CPU) handles 64-bit fine — and toggling x64 inside
-    an outer x64 trace (jit/shard_map around the model) makes the
-    interpreter's grid loops mix i32/i64 on jax<=0.4 — so leave it alone."""
-    import contextlib
-
-    return contextlib.nullcontext() if interpret else enable_x64(False)
-
-
 def _flash_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len):
-    # q: (BH, T, D). Traced with x64 disabled: the framework enables x64
-    # globally (paddle int64 semantics) but Mosaic has no i64/f64 lowering —
-    # index maps and weak python scalars must stay 32-bit inside the kernel.
-    with _kernel_x64_off(interpret):
+    # q: (BH, T, D)
+    with kernel_x64_off(interpret):
         return _flash_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len)
 
 
@@ -803,20 +791,20 @@ def _flash_hd_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpr
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_hd(q, k, v, causal, block_q, block_k, interpret, kv_len, d, hp):
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         out, _ = _flash_hd_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len, d, hp)
     return out
 
 
 def _flash_hd_vjp_fwd(q, k, v, causal, block_q, block_k, interpret, kv_len, d, hp):
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         out, lse = _flash_hd_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len, d, hp)
     return out, (q, k, v, out, lse)
 
 
 def _flash_hd_vjp_bwd(causal, block_q, block_k, interpret, kv_len, d, hp, res, do):
     q, k, v, out, lse = res
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         return _flash_hd_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret, kv_len, d, hp)
 
 
@@ -1217,7 +1205,7 @@ def _flash_vjp_bwd(causal, block_q, block_k, interpret, kv_len, res, do):
     # blocks per key block (causal lower bound skips fully-masked blocks).
     # No (BQ,T) score block or (n_q,BH,T,D) intermediate ever reaches HBM.
     q, k, v, out, lse = res
-    with _kernel_x64_off(interpret):
+    with kernel_x64_off(interpret):
         return _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret, kv_len)
 
 
@@ -1245,7 +1233,7 @@ def flash_attention_array(q, k, v, causal=False, block_q=None, block_k=None, int
     if not _HAS_PALLAS:
         raise RuntimeError("pallas unavailable")
     if interpret is None:
-        interpret = jax.devices()[0].platform == "cpu"
+        interpret = interpret_default()
     # mixed q/k/v dtypes (e.g. one operand silently upcast to f32 upstream)
     # would pair HIGHEST precision with bf16 operands inside the kernel,
     # which Mosaic rejects — unify on q's dtype

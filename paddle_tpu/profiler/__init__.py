@@ -77,7 +77,11 @@ def counters() -> Dict[str, int]:
     """Snapshot of engine counters.
 
     Lazy engine (always on): ``lazy_flushes``, ``lazy_cache_hits``,
-    ``lazy_donated_buffers``, ``lazy_donation_fallbacks``;
+    ``lazy_donated_buffers``, ``lazy_donation_fallbacks`` (a flush re-built
+    without donation after XLA refused it) and
+    ``lazy_eager_replay_fallbacks`` (a flush whose executable failed and was
+    replayed op by op, un-jitted — correct but unfused; zero on a healthy
+    run);
     ``dispatch_fastkey_hits`` is per-op and only counted while the profiler
     is running, to keep the dispatch hot path free of bookkeeping.
 
@@ -263,7 +267,8 @@ KNOWN_COUNTERS = frozenset({
     "lazy_bg_compiles", "lazy_bg_pickups", "lazy_bg_replays",
     "lazy_block_ns", "lazy_blocks", "lazy_cache_hits",
     "lazy_deferred_checks", "lazy_donated_buffers",
-    "lazy_donation_fallbacks", "lazy_flushes", "lazy_verify_passes",
+    "lazy_donation_fallbacks", "lazy_eager_replay_fallbacks",
+    "lazy_flushes", "lazy_verify_passes",
     "naninf_donation_suppressed", "naninf_trips",
     "preemption_drains", "retry_attempts",
     "serve_admitted", "serve_adoptions", "serve_backpressure",

@@ -629,18 +629,14 @@ class Engine:
         self._max_blocks = -(-(cfg.max_seq_len + self._spec_k)
                              // cfg.block_size)
         shape = (self._n_layers, cfg.num_blocks, cfg.block_size, kv, hd)
-        if self._tp:
-            # KV pool sharded on the kv-heads axis: every device owns
-            # heads/tp of EVERY block, so the replicated host-side block
-            # tables / PagePool bookkeeping index all shards identically
-            pool_s = G.tp_pool_sharding(self._tp_mesh)
-            self._kpool = jax.device_put(jnp.zeros(shape, self._dtype),
-                                         pool_s)
-            self._vpool = jax.device_put(jnp.zeros(shape, self._dtype),
-                                         pool_s)
-        else:
-            self._kpool = jnp.zeros(shape, self._dtype)
-            self._vpool = jnp.zeros(shape, self._dtype)
+        # under tp the KV pool is sharded on the kv-heads axis: every device
+        # owns heads/tp of EVERY block, so the replicated host-side block
+        # tables / PagePool bookkeeping index all shards identically. The
+        # zeros are CREATED sharded: built on one device and then spread, a
+        # pool sized for the mesh does not fit the chip it starts on.
+        pool_s = G.tp_pool_sharding(self._tp_mesh) if self._tp else None
+        self._kpool = jnp.zeros(shape, self._dtype, device=pool_s)
+        self._vpool = jnp.zeros(shape, self._dtype, device=pool_s)
         self._pool = PagePool(cfg.num_blocks)
         self._prefill_buckets = self._make_prefill_buckets()
         self._prefix = (_PrefixCache(self._pool, cfg.block_size)
@@ -685,7 +681,7 @@ class Engine:
         self._prefilling: List[_Seq] = []
         # analytic floor for the shed ETA while the decode EMA is cold: the
         # cost model's estimate of the per-step tp collective term (0.0 on
-        # a single chip or when the backend is unknown to the model)
+        # a single chip; a device the model holds no peaks for is an error)
         self._step_floor_s = 0.0
         if self._tp:
             from ..cost_model import CostModel
@@ -2510,10 +2506,7 @@ class Engine:
                     raise RuntimeError(
                         f"serving: program kind {kind!r} has no "
                         "tensor-parallel build")
-                if jax.default_backend() == "cpu":
-                    fn = jax.jit(raw)
-                else:
-                    fn = jax.jit(raw, donate_argnums=donate)
+                fn = jax.jit(raw, donate_argnums=donate)
                 self._fns[key] = fn
                 counter_inc("serve_compiles")
                 return fn
@@ -2560,12 +2553,11 @@ class Engine:
                 def raw(params, *args, _dq=dq, _inner=inner):
                     return _inner(_dq(params), *args)
 
-            # donation lets XLA update the pools in place; CPU ignores the
-            # hint (it would only warn), so only pass it off-CPU
-            if jax.default_backend() == "cpu":
-                fn = jax.jit(raw)
-            else:
-                fn = jax.jit(raw, donate_argnums=donate)
+            # donation lets XLA update the pools in place — on every backend,
+            # the CPU tier included, so a host-side reference that outlives a
+            # step fails in tier-1 ("Array has been deleted") and not first
+            # on the chip
+            fn = jax.jit(raw, donate_argnums=donate)
             self._fns[key] = fn
             counter_inc("serve_compiles")
         return fn

@@ -110,8 +110,7 @@ def test_functional_flash_routing(monkeypatch):
     from paddle_tpu.nn.functional import attention as attention_mod
 
     monkeypatch.setattr(attention_mod, "_flash_eligible", lambda *a: True)
-    # assert the Pallas route actually ran — the silent except/fallback in
-    # scaled_dot_product_attention would otherwise make this test vacuous
+    # assert the Pallas route actually ran
     from paddle_tpu.ops.pallas import flash_attention as fa_mod
 
     calls = []
@@ -144,6 +143,38 @@ def test_functional_flash_routing(monkeypatch):
     out2.sum().backward()
     for a, b in zip(g_flash, [np.asarray(t.grad._data) for t in x2]):
         np.testing.assert_allclose(a, b, atol=5e-4, rtol=1e-3)
+
+
+def test_functional_flash_sharded_over_a_mesh_matches_unsharded():
+    # Under a step partitioned over a mesh the functional wraps the kernel in
+    # a shard_map over 'dp' (batch) and 'mp' (heads) — GSPMD cannot partition
+    # a Mosaic call. Same values and gradients as the plain call.
+    import paddle_tpu as paddle
+    import paddle_tpu.nn.functional as F
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.distributed.mesh import partitioned_over
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "mp"))
+    sharded = NamedSharding(mesh, P("dp", None, "mp", None))
+    rng = np.random.RandomState(6)
+    x = [jnp.asarray(rng.randn(4, 256, 4, 32).astype(np.float32)) for _ in range(3)]
+
+    def loss(q, k, v):
+        out = F.scaled_dot_product_attention(
+            paddle.Tensor(q), paddle.Tensor(k), paddle.Tensor(v),
+            is_causal=True, impl="flash")
+        return (out._data ** 2).sum()
+
+    def partitioned_loss(q, k, v):
+        with partitioned_over(mesh):
+            return loss(q, k, v)
+
+    want = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))(*x)
+    got = jax.jit(jax.value_and_grad(partitioned_loss, argnums=(0, 1, 2)))(
+        *[jax.device_put(a, sharded) for a in x])
+    assert got[1][0].sharding.is_equivalent_to(sharded, 4)
+    for a, b in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-4, rtol=1e-4)
 
 
 class TestStreamedPath:
