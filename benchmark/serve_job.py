@@ -1,0 +1,239 @@
+"""A serving cell: ``serving.Engine`` at default flags behind an open-loop
+generator. Set-up builds the model from the seed, sizes the KV pool to what
+the weights leave, warms exactly the prefill and decode shapes the traffic
+file reaches, and ramps; the window then counts tokens and requests at the
+client's side of the stream; afterwards arrivals stop, in-flight requests
+drain, the engine is freed and the plain reference is run over a seeded
+sample of what was served."""
+from __future__ import annotations
+
+import gc
+import time
+
+import numpy as np
+
+from . import generator as G, model as M, weights as W
+from .reference import gpt as R
+
+
+def pool_blocks(cfg, block_size, headroom_bytes):
+    """KV blocks that fill what the weights leave, less the headroom for the
+    programs' own temporaries (the sizing of ``chip_smoke.pool_blocks_for``)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    block_bytes = 2 * cfg["num_layers"] * block_size * cfg["hidden_size"] * 2
+    free = stats["bytes_limit"] - stats["bytes_in_use"]
+    return int((free - headroom_bytes) // block_bytes)
+
+
+def prefill_buckets(lengths, block_size):
+    """The engine's prefill buckets (block_size x powers of two) that these
+    prompt lengths fall into."""
+    out = set()
+    for n in set(int(x) for x in lengths):
+        b = block_size
+        while b < n:
+            b *= 2
+        out.add(b)
+    return sorted(out)
+
+
+def warm(eng, schedule, traffic, block_size, vocab, log):
+    """Reach every shape the traffic reaches, through ``submit`` alone.
+
+    Prefill: one request per bucket the mix's prompt lengths fall into.
+    Decode: the engine keeps ONE program per batch-width bucket, gathered
+    over the widest context that bucket has ever held (it only grows). A
+    server that has run for a while holds the widest in every bucket, so the
+    warm-up puts it there: one request as long as the mix's longest context
+    stays live while a staircase of short ones retires one by one, which
+    walks the live-row count from the engine's ``max_batch`` down through
+    every bucket."""
+    rng = np.random.default_rng(0xC0FFEE)
+    mk = lambda n: rng.integers(0, vocab, int(n), dtype=np.int32)
+    buckets = prefill_buckets(schedule.prompt_len, block_size)
+    for b in buckets:
+        n = max(int(x) for x in schedule.prompt_len if x <= b)
+        eng.submit(mk(n), max_new_tokens=1).result(timeout=1200)
+    log(f"warm: prefill buckets {buckets}")
+    rows = int(traffic.get("warm_rows", eng.config.max_batch))  # the rehearsal walks fewer
+    longest_ctx = int((schedule.prompt_len + schedule.out_len).max())
+    longest_prompt = int(schedule.prompt_len.max())
+    tail = max(longest_ctx - longest_prompt, 1) + 2 * rows + 8
+    long_h = eng.submit(mk(longest_prompt), max_new_tokens=tail, stream=True)
+    it = iter(long_h)
+    next(it)
+    short = [eng.submit(mk(block_size), max_new_tokens=2 + 2 * i)
+             for i in range(1, rows)]
+    for h in short:
+        h.result(timeout=1200)
+    list(it)
+    log(f"warm: decode rows 1..{rows} with a context of {longest_ctx} live; "
+        f"{eng.stats()['compiles']} programs")
+
+
+def served_gap(cfg, weights, prompt, tokens, mode="f32", pad_to=256):
+    """Run the reference once over prompt + served tokens. Returns, for each
+    served token, how far its reference logit lies below the reference's
+    best at that position; with ``mode`` set to the control's precision the
+    token judged at each position is the one the control puts first."""
+    import jax.numpy as jnp
+
+    ids = np.concatenate([np.asarray(prompt, np.int64), np.asarray(tokens, np.int64)])
+    n = len(ids) - 1  # the last served token is never fed back
+    padded = -(-n // pad_to) * pad_to
+    x = np.zeros((1, padded), np.int64)
+    x[0, :n] = ids[:n]
+    ref = R.forward_logits(cfg, weights, x, "f32")[0, len(prompt) - 1:n]
+    best = jnp.max(ref, axis=-1)
+    if mode == "f32":
+        judged = jnp.asarray(ids[len(prompt):])
+    else:
+        ctl = R.forward_logits(cfg, weights, x, mode)[0, len(prompt) - 1:n]
+        judged = jnp.argmax(ctl, axis=-1)
+    picked = jnp.take_along_axis(ref, judged[:, None], -1)[:, 0]
+    return np.asarray(best - picked)
+
+
+def sample_finished(served, schedule, seed, k):
+    """A seeded sample of the window's finished requests, the one with the
+    most served tokens always in it."""
+    done = [r for r in served if schedule.in_window[r.index] and r.done
+            and not r.error and len(r.tokens) == schedule.out_len[r.index]]
+    if not done:
+        return []
+    longest = max(done, key=lambda r: len(r.tokens))
+    rest = [r for r in done if r is not longest]
+    rng = np.random.default_rng([int(seed), 0x5A11])
+    pick = rng.permutation(len(rest))[:max(k - 1, 0)]
+    return [longest] + [rest[i] for i in pick]
+
+
+def setup(ctx, schedule):
+    """Model from the seed, the engine with its pool, every shape warm."""
+    from paddle_tpu.framework import flags
+    from paddle_tpu.serving import Engine
+
+    cfg, traffic = ctx.config, ctx.traffic
+    t = time.monotonic()
+    weights = W.make_weights(cfg, ctx.seed)
+    ctx.note("setup_weights_s", time.monotonic() - t)
+    t = time.monotonic()
+    model, _ = M.build_model(cfg, weights)
+    del weights
+    model.eval()
+    gc.collect()
+    ctx.note("setup_model_s", time.monotonic() - t)
+    block_size = int(flags.flag("FLAGS_serve_block_size"))
+    blocks = traffic.get("pool_blocks") or pool_blocks(
+        cfg, block_size, int(traffic["headroom_bytes"]))
+    ctx.note("pool_blocks", blocks)
+    eng = Engine(model, num_blocks=blocks)  # default flags; the pool's size is ours
+    t = time.monotonic()
+    warm(eng, schedule, traffic, block_size, cfg["vocab_size"], print)
+    ctx.note("setup_warm_s", time.monotonic() - t)
+    return model, eng
+
+
+def drive(ctx, eng, schedule, window=False, sample_every=None):
+    """Ramp and window. Returns (loop, t_open, t_close, rows at open, the
+    engine's stats at close, samples of (t, running, queue_depth)). With
+    ``window`` it is a run's measured window (``ctx.open_window``)."""
+    temp = float(ctx.traffic["temperature"])
+    submit = lambda prompt, n: eng.submit(
+        prompt, max_new_tokens=n, temperature=temp, stream=True)
+    loop = G.OpenLoop(schedule, submit)
+    t_open = time.monotonic() + schedule.ramp_s + 0.05
+    t_close = t_open + schedule.seconds
+    loop.start(t_open)
+    time.sleep(max(0.0, t_open - time.monotonic() - 0.5))
+    if window:
+        ctx.open_window(t_open)
+    time.sleep(max(0.0, t_open - time.monotonic()))
+    rows_open, samples = eng.stats()["running"], []
+    while sample_every and time.monotonic() < t_close - sample_every:
+        time.sleep(sample_every)
+        st = eng.stats()
+        samples.append((time.monotonic() - t_open, st["running"], st["queue_depth"]))
+    ctx.sleep_until(t_close)
+    ctx.end_work()
+    st = eng.stats()
+    return loop, t_open, t_close, rows_open, st, samples
+
+
+def run(ctx) -> dict:
+    cfg, traffic, seed = ctx.config, ctx.traffic, ctx.seed
+    log = lambda m: print(m, flush=True)
+    schedule = G.build_schedule(traffic, ctx.seconds, seed, cfg["vocab_size"])
+    model, eng = setup(ctx, schedule)
+    try:
+        loop, t_open, t_close, rows_at_open, at_close, _ = drive(
+            ctx, eng, schedule, window=True)
+        rows_at_close, queue_at_close = at_close["running"], at_close["queue_depth"]
+        spans = ctx.spans
+        ctx.note("setup_ramp_s", schedule.ramp_s)
+        ctx.close_window(t_open, t_close)
+        peak = ctx.memory_peak()
+        served = loop.drain(float(traffic["drain_s"]))
+        ctx.note("drain_s", time.monotonic() - t_close)
+        stats = eng.stats()
+    finally:
+        eng.close()
+    st = G.window_stats(served, schedule, t_open)
+    log(f"window: {st['attempted']} requests due, {st['failed']} failed, "
+        f"{st['tokens_in_window']} tokens, {len(st['gaps'])} gaps, rows "
+        f"{rows_at_open} at open and {rows_at_close} at close, queue "
+        f"{queue_at_close} at close and {stats['queue_depth']} after the drain; "
+        f"{at_close['pages_used']} of {at_close['pages_total']} KV blocks held at close")
+
+    # free the program, then the reference over a sample of what it served
+    picked = sample_finished(served, schedule, seed, int(ctx.cell["check_requests"]))
+    del eng, model, loop
+    gc.collect()
+    t = time.monotonic()
+    weights = W.make_weights(cfg, seed)
+    worst, where, n_tok = 0.0, "no finished request", 0
+    for r in picked:
+        gaps = served_gap(cfg, weights, schedule.prompts[r.index], r.tokens)
+        n_tok += len(gaps)
+        if gaps.max() >= worst:
+            worst, where = float(gaps.max()), f"request {r.index} token {int(gaps.argmax())}"
+    if not picked:
+        worst = float("inf")
+    ctx.note("reference_s", time.monotonic() - t)
+    ctx.note("reference_tokens", n_tok)
+
+    gaps_ms, ttft_ms = st["gaps"] * 1e3, st["ttft"] * 1e3
+    e2e = {}
+    if len(gaps_ms):
+        log("gaps ms p5/25/50/75/95/99: " + " ".join(
+            f"{np.percentile(gaps_ms, q):.2f}" for q in (5, 25, 50, 75, 95, 99)))
+        # how the median settles as the window grows (and the queue with it)
+        log("gap p50 ms over the window's first quarter/half/three quarters/whole: "
+            + " ".join(f"{np.percentile(gaps_ms[st['gap_at'] <= f * schedule.seconds], 50):.3f}"
+                       for f in (0.25, 0.5, 0.75, 1.0)))
+        log("ttft ms p10/50/90: " + " ".join(
+            f"{np.percentile(ttft_ms, q):.1f}" for q in (10, 50, 90))
+            + f"; generator late p99 {np.percentile(st['late'], 99) * 1e3:.2f} ms")
+        e2e["token_gap_p50_ms"] = float(np.percentile(gaps_ms, 50))
+        # ISSUE 23's other end-to-end metrics, for the cells that can bound
+        # them (PERF.md section 7); a cell reports what BENCHMARK.json lists
+        e2e["token_gap_p95_ms"] = float(np.percentile(gaps_ms, 95))
+        e2e["served_tokens_per_s"] = st["tokens_in_window"] / schedule.seconds
+    if len(ttft_ms):
+        e2e["ttft_p50_ms"] = float(np.percentile(ttft_ms, 50))
+    return {
+        "numbers": {"served_logit_gap": (worst, f"{where}, {n_tok} tokens of "
+                                         f"{len(picked)} requests")},
+        "attempted": st["attempted"], "failed": st["failed"],
+        "t_open": t_open, "t_close": t_close, "memory_peak_bytes": peak,
+        "counts": {"requests": st["attempted"], "tokens": st["tokens_in_window"],
+                   "prompt_tokens": int(schedule.prompt_len[schedule.in_window].sum()),
+                   "output_tokens": int(schedule.out_len[schedule.in_window].sum())},
+        "end_to_end": e2e,
+        "facts": {"served": served, "schedule": schedule, "stats": st,
+                  "gaps_ms": gaps_ms, "ttft_ms": ttft_ms, "spans": spans,
+                  "rows_at_open": rows_at_open, "rows_at_close": rows_at_close,
+                  "tokens_per_s": st["tokens_in_window"] / schedule.seconds},
+    }

@@ -1,0 +1,100 @@
+"""Seeded weights, made by the benchmark (not by the program) in one jitted
+call on the device, in the type they are trained and served in.
+
+The program's model and the plain reference are both given these values, so
+neither takes anything the other has made. Every leaf has a key of
+its own (folded from the seed, its group and its layer), so a single leaf can
+be made again later
+(the parameter-change norms of the training check need the first values
+without keeping a second copy of the model on the device).
+"""
+from __future__ import annotations
+
+from functools import partial
+
+import jax
+import jax.numpy as jnp
+
+
+def leaf_specs(cfg: dict) -> list:
+    """``[(name, shape, kind)]`` in a fixed order; kind is ``normal`` (mean
+    0) or ``gain`` (mean 1). Matrices are (in, out), as the layer equations
+    in ``reference/gpt.py`` use them."""
+    d, f = cfg["hidden_size"], cfg["intermediate_size"]
+    specs = [("wte", (cfg["vocab_size"], d), "normal"),
+             ("wpe", (cfg["max_position_embeddings"], d), "normal")]
+    for i in range(cfg["num_layers"]):
+        p = f"h{i}."
+        specs += [
+            (p + "ln1.g", (d,), "gain"), (p + "ln1.b", (d,), "normal"),
+            (p + "qkv.w", (d, 3 * d), "normal"), (p + "qkv.b", (3 * d,), "normal"),
+            (p + "proj.w", (d, d), "normal"), (p + "proj.b", (d,), "normal"),
+            (p + "ln2.g", (d,), "gain"), (p + "ln2.b", (d,), "normal"),
+            (p + "up.w", (d, f), "normal"), (p + "up.b", (f,), "normal"),
+            (p + "down.w", (f, d), "normal"), (p + "down.b", (d,), "normal"),
+        ]
+    specs += [("lnf.g", (d,), "gain"), ("lnf.b", (d,), "normal")]
+    return specs
+
+
+def _draw(key, shape, kind, std, dtype):
+    x = jax.random.normal(key, shape, jnp.float32) * std
+    if kind == "gain":
+        x = x + 1.0
+    return x.astype(dtype)
+
+
+def root_key(seed: int):
+    # --seed may exceed 31 bits; the key takes 32 of them and the rest fold in
+    seed = int(seed)
+    return jax.random.fold_in(jax.random.PRNGKey(seed & 0xFFFFFFFF), seed >> 32)
+
+
+def _groups(specs):
+    """Leaves of one name and shape across the layers are drawn together:
+    {suffix: (first index, [indices])}; a leaf outside the layers is a group
+    of its own. Sixteen draws instead of three hundred keep the program that
+    makes the weights small (it is loaded from the cache in every run)."""
+    groups = {}
+    for i, (name, _, _) in enumerate(specs):
+        suffix = name.split(".", 1)[1] if name[0] == "h" and name[1].isdigit() else name
+        groups.setdefault(suffix, []).append(i)
+    return groups
+
+
+def _leaf_key(key, group_first, member):
+    return jax.random.fold_in(jax.random.fold_in(key, group_first), member)
+
+
+@partial(jax.jit, static_argnames=("specs", "std", "dtype"))
+def _make_all(key, specs, std, dtype):
+    out = {}
+    for members in _groups(specs).values():
+        _, shape, kind = specs[members[0]]
+        keys = jax.vmap(lambda m: _leaf_key(key, members[0], m))(jnp.arange(len(members)))
+        stack = jax.vmap(lambda k: _draw(k, shape, kind, std, dtype))(keys)
+        for m, i in enumerate(members):
+            out[specs[i][0]] = stack[m]
+    return out
+
+
+@partial(jax.jit, static_argnames=("shape", "kind", "std", "dtype"))
+def _make_one(key, group_first, member, shape, kind, std, dtype):
+    return _draw(_leaf_key(key, group_first, member), shape, kind, std, dtype)
+
+
+def make_weights(cfg: dict, seed: int) -> dict:
+    """Every leaf, one jitted call, on the default device."""
+    specs = tuple((n, tuple(s), k) for n, s, k in leaf_specs(cfg))
+    return _make_all(root_key(seed), specs, float(cfg["initializer_range"]),
+                     cfg["dtype"])
+
+
+def make_leaf(cfg: dict, seed: int, index: int):
+    """Leaf ``index`` of ``leaf_specs`` alone: the same values as in
+    ``make_weights``."""
+    specs = leaf_specs(cfg)
+    name, shape, kind = specs[index]
+    members = next(m for m in _groups(specs).values() if index in m)
+    return _make_one(root_key(seed), members[0], members.index(index), tuple(shape),
+                     kind, float(cfg["initializer_range"]), cfg["dtype"])

@@ -1,0 +1,127 @@
+"""The traffic generator: the seed changes the order, never the load."""
+import json
+import threading
+import time
+from collections import Counter
+from pathlib import Path
+
+import numpy as np
+import pytest
+
+from benchmark import generator as G
+
+CHAT = json.loads((Path(G.__file__).parent / "traffic" / "chat-sat.json").read_text())
+SEEDS = (1, 2_147_483_659, 3_000_000_019)
+
+
+@pytest.mark.parametrize("seconds", [10, 30])
+def test_same_multiset_any_seed_other_order(seconds):
+    scheds = [G.build_schedule(CHAT, seconds, s, 50304) for s in SEEDS]
+    ref = scheds[0]
+    n_win = round(CHAT["rate_per_s"] * seconds)
+    for s in scheds:
+        w = s.in_window
+        assert w.sum() == n_win
+        assert Counter(zip(s.prompt_len[w], s.out_len[w])) == \
+            Counter(zip(ref.prompt_len[ref.in_window], ref.out_len[ref.in_window]))
+        assert Counter(zip(s.prompt_len[~w], s.out_len[~w])) == \
+            Counter(zip(ref.prompt_len[~ref.in_window], ref.out_len[~ref.in_window]))
+        gaps = np.diff(np.concatenate([s.due[w], [seconds]]))
+        assert np.allclose(sorted(gaps), sorted(np.diff(np.concatenate(
+            [ref.due[ref.in_window], [seconds]]))))
+        assert gaps.sum() == pytest.approx(seconds)       # due times fill the window
+        assert s.due[w].min() == 0 and s.due[w].max() < seconds
+        assert s.due[~w].min() == pytest.approx(-CHAT["ramp_s"]) and s.due[~w].max() < 0
+        assert [len(p) for p in s.prompts] == list(s.prompt_len)
+    assert not np.array_equal(scheds[0].prompt_len, scheds[1].prompt_len)
+    assert not np.array_equal(scheds[0].prompts[0], scheds[1].prompts[0])
+    same = G.build_schedule(CHAT, seconds, SEEDS[1], 50304)
+    assert all(np.array_equal(a, b) for a, b in zip(same.prompts, scheds[1].prompts))
+
+
+def test_rounds_balance_the_stream_in_time():
+    """Every 16 consecutive requests hold short and long prompts in the mix's
+    proportions: the heaviest round offers under 1.3 x the lightest, where a
+    plain permutation of this heavy-tailed mix offers over 2 x."""
+    def round_sums(traffic):
+        s = G.build_schedule(traffic, 32, 5, 50304)
+        p = s.prompt_len[s.in_window]
+        assert len(p) == 144
+        return [int(p[i:i + 16].sum()) for i in range(0, 144, 16)]
+    balanced = round_sums(CHAT)
+    plain = round_sums({k: v for k, v in CHAT.items() if k != "round"})
+    assert max(balanced) < 1.3 * min(balanced)
+    assert max(plain) > 2.0 * min(plain)
+    assert sum(balanced) == sum(plain)
+
+
+def test_lengths_follow_the_file():
+    s = G.build_schedule(CHAT, 45, 5, 50304)
+    assert s.prompt_len.min() >= 16 and s.prompt_len.max() <= 1024
+    assert s.out_len.min() >= 8 and s.out_len.max() <= 512
+    assert (s.prompt_len + s.out_len).max() <= 1536
+    assert abs(np.median(s.prompt_len[s.in_window]) - 192) <= 4
+    assert abs(np.median(s.out_len[s.in_window]) - 128) <= 3
+
+
+def test_quantiles_kinds():
+    assert list(G.quantiles({"dist": "linspace", "lo": 1024, "hi": 1792}, 4)) == [1024, 1280, 1536, 1792]
+    assert list(G.quantiles({"dist": "const", "value": 32}, 3)) == [32, 32, 32]
+    e = G.quantiles({"dist": "exponential", "mean": 2.0}, 1000)
+    assert e.mean() == pytest.approx(2.0, rel=0.01)
+    with pytest.raises(ValueError):
+        G.quantiles({"dist": "zipf"}, 3)
+
+
+def test_open_loop_times_from_due_and_reports_lateness():
+    traffic = {**CHAT, "rate_per_s": 40.0, "ramp_rate_per_s": 40.0, "ramp_s": 0.25,
+               "prompt_len": {"dist": "const", "value": 4},
+               "output_len": {"dist": "const", "value": 3}}
+    sched = G.build_schedule(traffic, 1.0, 9, 100)
+    lock, seen = threading.Lock(), []
+
+    def submit(prompt, n):
+        with lock:
+            seen.append(len(prompt))
+        if len(seen) == 15:
+            time.sleep(0.3)                       # the generator is held up once
+
+        def stream():
+            for k in range(n):
+                time.sleep(0.002)
+                yield k
+        return stream()
+
+    loop = G.OpenLoop(sched, submit)
+    t_open = time.monotonic() + sched.ramp_s + 0.02
+    loop.start(t_open)
+    served = loop.drain(10.0)
+    st = G.window_stats(served, sched, t_open)
+    assert st["attempted"] == 40 and st["failed"] == 0 and len(seen) == 50
+    assert all(r.done and r.tokens == [0, 1, 2] for r in served)
+    assert st["late"].max() >= 0.1                # the stall shows as lateness
+    assert len(st["ttft"]) == 40 and (st["ttft"] > 0).all()
+    # TTFT counts from the due time: requests behind the stall waited for it
+    assert st["ttft"].max() >= 0.1
+
+
+def test_failed_requests_are_counted():
+    traffic = {**CHAT, "rate_per_s": 20.0, "ramp_s": 0.0,
+               "prompt_len": {"dist": "const", "value": 4},
+               "output_len": {"dist": "const", "value": 2}}
+    sched = G.build_schedule(traffic, 0.5, 3, 100)
+    calls = []
+
+    def submit(prompt, n):
+        calls.append(1)
+        if len(calls) == 2:
+            raise RuntimeError("shed")
+        if len(calls) == 3:
+            return iter([7])                      # one token short
+        return iter(range(n))
+
+    loop = G.OpenLoop(sched, submit)
+    t_open = time.monotonic() + 0.02
+    loop.start(t_open)
+    st = G.window_stats(loop.drain(5.0), sched, t_open)
+    assert st["attempted"] == 10 and st["failed"] == 2
