@@ -130,7 +130,7 @@ def _attrs_key(attrs):
     return key
 
 
-def _get_jitted(fn, attrs):
+def _get_jitted(name, fn, attrs):
     try:
         key = (_fast_fn_key(fn), _attrs_key(attrs))
         hash(key)
@@ -138,7 +138,13 @@ def _get_jitted(fn, attrs):
         return lambda *arrays: fn(*arrays, **attrs)
     jf = _jit_cache.get(key)
     if jf is None:
-        jf = jax.jit(lambda *arrays: fn(*arrays, **attrs))
+        def op(*arrays):
+            return fn(*arrays, **attrs)
+
+        # the program (and, inside a larger one, the device line's op names)
+        # says which op it is, not ``jit__lambda_``
+        op.__name__ = op.__qualname__ = name
+        jf = jax.jit(op)
         _jit_cache[key] = jf
         if len(_jit_cache) > _JIT_CACHE_MAX:
             _jit_cache.popitem(last=False)
@@ -301,7 +307,7 @@ def _eager_call_impl(
         arrays = tuple(lazy_mod.concrete(a) for a in arrays)
 
     if not need_grad:
-        outs = _get_jitted(fn, attrs)(*arrays)
+        outs = _get_jitted(name, fn, attrs)(*arrays)
         single = not isinstance(outs, (tuple, list))
         if check_naninf:
             _check_nan_inf(name, (outs,) if single else outs)

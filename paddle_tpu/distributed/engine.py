@@ -156,8 +156,11 @@ class HybridParallelEngine:
                     for t, a in zip(params, p_arrays):
                         t._data = a
                     inputs = [Tensor(a, stop_gradient=True) for a in batch_arrays]
+                    # "loss" in the step's op names: the forward is
+                    # jvp(loss), the backward transpose(jvp(loss))
                     with random_state.traced_keys(key), no_grad(), \
-                            partitioned_over(self.mesh):
+                            partitioned_over(self.mesh), \
+                            jax.named_scope("loss"):
                         out = loss_fn(model, *inputs)
                     return out._data if isinstance(out, Tensor) else out
                 finally:
@@ -201,9 +204,10 @@ class HybridParallelEngine:
                 [jnp.zeros(a.shape, a.dtype) for a in param_arrays]
             )
             (grads, loss, _), _ = lax.scan(body, (g0, jnp.float32(0.0), key), chunked)
-            new_params, new_state = opt._functional_update(
-                param_arrays, grads, opt_state, lr, params=params
-            )
+            with jax.named_scope("optimizer_update"):
+                new_params, new_state = opt._functional_update(
+                    param_arrays, grads, opt_state, lr, params=params
+                )
             return loss, new_params, new_state
 
         return accum_step_fn
@@ -216,9 +220,10 @@ class HybridParallelEngine:
             loss_of = make_loss_of(batch_arrays, key)
             loss, grads = jax.value_and_grad(loss_of)(list(param_arrays))
             grads = self._constrain_grads(grads)
-            new_params, new_state = opt._functional_update(
-                param_arrays, grads, opt_state, lr, params=params
-            )
+            with jax.named_scope("optimizer_update"):
+                new_params, new_state = opt._functional_update(
+                    param_arrays, grads, opt_state, lr, params=params
+                )
             return loss, new_params, new_state
 
         donate = (0, 1) if self.donate else ()
@@ -264,7 +269,8 @@ class HybridParallelEngine:
             k = jax.random.fold_in(key, lax.axis_index(axis))
             loss_of = make_loss_of(batch_local, k)
             loss, grads = jax.value_and_grad(loss_of)(list(p_arrays))
-            new_params, new_state = wus.apply(p_arrays, grads, dp_state, lr)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_state = wus.apply(p_arrays, grads, dp_state, lr)
             return lax.pmean(loss, axis), tuple(new_params), new_state
 
         valid = set(self.mesh.axis_names)

@@ -287,16 +287,19 @@ class CompiledTrainStep:
                     for t, a in zip(params, params_arrays):
                         t._data = a
                     inputs = [Tensor(a, stop_gradient=True) for a in batch_arrays]
-                    with random_state.traced_keys(key):
-                        with no_grad():
-                            out = loss_fn(model, *inputs)
+                    # "loss" in the step's op names: the forward is
+                    # jvp(loss), the backward transpose(jvp(loss))
+                    with random_state.traced_keys(key), no_grad(), \
+                            jax.named_scope("loss"):
+                        out = loss_fn(model, *inputs)
                     return out._data if isinstance(out, Tensor) else out
                 finally:
                     for t, a in saved:
                         t._data = a
 
             loss, grads = jax.value_and_grad(loss_of)(list(param_arrays))
-            new_params, new_state = opt._functional_update(param_arrays, grads, opt_state, lr)
+            with jax.named_scope("optimizer_update"):
+                new_params, new_state = opt._functional_update(param_arrays, grads, opt_state, lr)
             return loss, new_params, new_state
 
         donate = (0, 1) if self._donate else ()

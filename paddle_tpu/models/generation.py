@@ -69,12 +69,13 @@ def _grouped_attention(q, kc, vc, live, rep):
     materialized, so the cache streams once regardless of H/KV."""
     B, T, H, D = q.shape
     KV = kc.shape[2]
-    scale = jnp.asarray(1.0 / np.sqrt(D), q.dtype)
-    qg = q.reshape(B, T, KV, rep, D)
-    s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kc) * scale  # (B,KV,rep,T,Tk)
-    p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
-    o = jnp.einsum("bgrqk,bkgd->bqgrd", p, vc)
-    return o.reshape(B, T, H * D)
+    with jax.named_scope("attention"):
+        scale = jnp.asarray(1.0 / np.sqrt(D), q.dtype)
+        qg = q.reshape(B, T, KV, rep, D)
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg, kc) * scale  # (B,KV,rep,T,Tk)
+        p = jax.nn.softmax(jnp.where(live, s, -jnp.inf), axis=-1)
+        o = jnp.einsum("bgrqk,bkgd->bqgrd", p, vc)
+        return o.reshape(B, T, H * D)
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +147,12 @@ def _gpt_arch(H, D):
         pos = starts[:, None] + jnp.arange(T)[None, :]
         return params["wte"][ids] + params["wpe"][pos]
 
+    def mlp(w, x):
+        with jax.named_scope("mlp"):
+            h2 = _ln(x, w["ln2_w"], w["ln2_b"])
+            ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
+            return x + ff
+
     def block_tail(w, x, k_ctx, v_ctx, live, starts):
         # multi-token packed pass against a gathered paged context: x
         # (B,T,H·D) holds T consecutive tokens per row starting at absolute
@@ -165,9 +172,7 @@ def _gpt_arch(H, D):
         vc = v_ctx.at[rows, posm].set(v_new)
         o = _grouped_attention(q, kc, vc, live[:, None, None], rep=1)
         x = x + (o @ w["proj_w"] + w["proj_b"])
-        h2 = _ln(x, w["ln2_w"], w["ln2_b"])
-        ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
-        return x + ff, k_new, v_new
+        return mlp(w, x), k_new, v_new
 
     def block_rows(w, x, k_ctx, v_ctx, live, pos):
         # single-token decode against a GATHERED paged context: x (B,1,H·D);
@@ -185,9 +190,7 @@ def _gpt_arch(H, D):
         vc = v_ctx.at[rows, pos].set(v_new)
         o = _grouped_attention(q, kc, vc, live[:, None, None, None, :], rep=1)
         x = x + (o @ w["proj_w"] + w["proj_b"])
-        h2 = _ln(x, w["ln2_w"], w["ln2_b"])
-        ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
-        return x + ff, k_new, v_new
+        return mlp(w, x), k_new, v_new
 
     def qkv_rows(w, x, pos):
         # the projection half of block_rows (same ops, same order — the
@@ -202,9 +205,7 @@ def _gpt_arch(H, D):
     def attn_out_rows(w, x, o):
         # the post-attention half of block_rows: o (B,1,H·D) attention read
         x = x + (o @ w["proj_w"] + w["proj_b"])
-        h2 = _ln(x, w["ln2_w"], w["ln2_b"])
-        ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
-        return x + ff
+        return mlp(w, x)
 
     def block(w, x, kv=None, pos=None):
         B, T = x.shape[0], x.shape[1]
@@ -222,9 +223,7 @@ def _gpt_arch(H, D):
             o = _grouped_attention(q, kc, vc, live, rep=1)
             new_kv = (kc, vc)
         x = x + (o @ w["proj_w"] + w["proj_b"])
-        h2 = _ln(x, w["ln2_w"], w["ln2_b"])
-        ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
-        return x + ff, new_kv
+        return mlp(w, x), new_kv
 
     def head(params, x):
         x = _ln(x, params["lnf_w"], params["lnf_b"])
@@ -326,6 +325,12 @@ def _llama_arch(H, KV, D, theta, eps):
     def embed_tail(params, ids, starts):
         return params["wte"][ids]
 
+    def mlp(w, x):
+        with jax.named_scope("mlp"):
+            h2 = _rms(x, w["ln2_w"], eps)
+            ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
+            return x + ff
+
     def block_tail(w, x, k_ctx, v_ctx, live, starts):
         # see the GPT plug for the contract; RoPE at each (row, feed)'s own
         # absolute position, GQA against the un-repeated gathered cache
@@ -343,9 +348,7 @@ def _llama_arch(H, KV, D, theta, eps):
         vc = v_ctx.at[rows, posm].set(v_new)
         o = _grouped_attention(q, kc, vc, live[:, None, None], rep)
         x = x + o @ w["o_w"]
-        h2 = _rms(x, w["ln2_w"], eps)
-        ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
-        return x + ff, k_new, v_new
+        return mlp(w, x), k_new, v_new
 
     def block_rows(w, x, k_ctx, v_ctx, live, pos):
         # see the GPT plug for the contract; RoPE applied at each row's own
@@ -363,9 +366,7 @@ def _llama_arch(H, KV, D, theta, eps):
         vc = v_ctx.at[rows, pos].set(v_new)
         o = _grouped_attention(q, kc, vc, live[:, None, None, None, :], rep)
         x = x + o @ w["o_w"]
-        h2 = _rms(x, w["ln2_w"], eps)
-        ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
-        return x + ff, k_new, v_new
+        return mlp(w, x), k_new, v_new
 
     def qkv_rows(w, x, pos):
         # projection half of block_rows (same ops/order — see the GPT plug):
@@ -381,9 +382,7 @@ def _llama_arch(H, KV, D, theta, eps):
 
     def attn_out_rows(w, x, o):
         x = x + o @ w["o_w"]
-        h2 = _rms(x, w["ln2_w"], eps)
-        ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
-        return x + ff
+        return mlp(w, x)
 
     def block(w, x, kv=None, pos=None):
         B, T = x.shape[0], x.shape[1]
@@ -405,9 +404,7 @@ def _llama_arch(H, KV, D, theta, eps):
             o = _grouped_attention(q, kc, vc, live, rep)
             new_kv = (kc, vc)
         x = x + o @ w["o_w"]
-        h2 = _rms(x, w["ln2_w"], eps)
-        ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
-        return x + ff, new_kv
+        return mlp(w, x), new_kv
 
     def head(params, x):
         return _head_mm(params, _rms(x, params["lnf_w"], eps)[:, -1],
@@ -750,7 +747,8 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
             x, (k, v) = arch["block"](w, x)
             kpool = kpool.at[li, tb].set(k.reshape(B, nb, block_size, KV, D))
             vpool = vpool.at[li, tb].set(v.reshape(B, nb, block_size, KV, D))
-        logits = arch["head_rows"](params, x, lens - 1)
+        with jax.named_scope("head"):
+            logits = arch["head_rows"](params, x, lens - 1)
         return kpool, vpool, logits
 
     return prefill
@@ -790,15 +788,17 @@ def build_paged_decode(arch, B, block_size, max_blocks):
         # (``kpool[li][tables]``) XLA:TPU materializes every layer's slice,
         # a second pool's worth of temporaries, and a pool sized to fill the
         # chip no longer compiles (v5e, 1.3B, 3400 blocks: 16.97 of 15.75 GB)
-        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
-                vpool[li, tables].reshape(B, T_pad, KV, D))
-               for li in range(len(layer_ws))]
+        with jax.named_scope("kv_gather"):
+            ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                    vpool[li, tables].reshape(B, T_pad, KV, D))
+                   for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_rows"](w, x, ctx[li][0], ctx[li][1],
                                                  live, pos)
             kpool = kpool.at[li, bids, offs].set(k_new)
             vpool = vpool.at[li, bids, offs].set(v_new)
-        logits = arch["head"](params, x)
+        with jax.named_scope("head"):
+            logits = arch["head"](params, x)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
         sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
@@ -838,7 +838,8 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
             vpool = vpool.at[li, bids, offs].set(v_new)
             o = paged_attention_rows(q, kpool[li], vpool[li], tables, pos)
             x = arch["attn_out_rows"](w, x, o[:, None])
-        logits = arch["head"](params, x)
+        with jax.named_scope("head"):
+            logits = arch["head"](params, x)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
         sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
@@ -911,9 +912,10 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
         bids = jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
         # gathers hoisted above the scatter chain (see build_paged_decode):
         # avoids a whole-pool copy-on-write per layer
-        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
-                vpool[li, tables].reshape(B, T_pad, KV, D))
-               for li in range(len(layer_ws))]
+        with jax.named_scope("kv_gather"):
+            ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                    vpool[li, tables].reshape(B, T_pad, KV, D))
+                   for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
                                                  live, starts)
@@ -921,7 +923,8 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
                 k_new.reshape(B, nb, block_size, KV, D))
             vpool = vpool.at[li, bids].set(
                 v_new.reshape(B, nb, block_size, KV, D))
-        logits = arch["head_rows"](params, x, lens - 1)
+        with jax.named_scope("head"):
+            logits = arch["head_rows"](params, x, lens - 1)
         return kpool, vpool, logits
 
     return prefill
@@ -964,15 +967,17 @@ def build_paged_spec_decode(arch, B, k, block_size, max_blocks):
         offs = posm % block_size
         # gathers hoisted above the scatter chain (see build_paged_decode):
         # avoids a whole-pool copy-on-write per layer
-        ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
-                vpool[li, tables].reshape(B, T_pad, KV, D))
-               for li in range(len(layer_ws))]
+        with jax.named_scope("kv_gather"):
+            ctx = [(kpool[li, tables].reshape(B, T_pad, KV, D),
+                    vpool[li, tables].reshape(B, T_pad, KV, D))
+                   for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
             x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
                                                  live, pos)
             kpool = kpool.at[li, bids, offs].set(k_new)
             vpool = vpool.at[li, bids, offs].set(v_new)
-        logits = arch["head_all"](params, x)  # (B, k+1, V)
+        with jax.named_scope("head"):
+            logits = arch["head_all"](params, x)  # (B, k+1, V)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits[:, 0]
                   / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
@@ -1350,16 +1355,18 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
         else:
             live = jnp.arange(T_pad)[None, :] <= pos[:, None]
             # gathers hoisted above the scatter chain (see build_paged_decode)
-            ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
-                    vpool[li, tables].reshape(B, T_pad, KVl, D))
-                   for li in range(L)]
+            with jax.named_scope("kv_gather"):
+                ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
+                        vpool[li, tables].reshape(B, T_pad, KVl, D))
+                       for li in range(L)]
             for li in range(L):
                 x, k_new, v_new = arch["layer_rows"](
                     rw["layers"][li], sw["layers"][li], x,
                     ctx[li][0], ctx[li][1], live, pos)
                 kpool = kpool.at[li, bids, offs].set(k_new)
                 vpool = vpool.at[li, bids, offs].set(v_new)
-        logits = arch["head_rows"](rw, sw, x, jnp.zeros((B,), jnp.int32))
+        with jax.named_scope("head"):
+            logits = arch["head_rows"](rw, sw, x, jnp.zeros((B,), jnp.int32))
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(
             jnp.float32)
@@ -1413,7 +1420,8 @@ def build_tp_paged_prefill(arch_key, B, T_bucket, block_size, max_blocks,
                 k.reshape(B, nb, block_size, KVl, D))
             vpool = vpool.at[li, tb].set(
                 v.reshape(B, nb, block_size, KVl, D))
-        logits = arch["head_rows"](rw, sw, x, lens - 1)
+        with jax.named_scope("head"):
+            logits = arch["head_rows"](rw, sw, x, lens - 1)
         return kpool, vpool, logits
 
     wrapped = _tp_shard_map(
@@ -1458,9 +1466,10 @@ def build_tp_paged_tail_prefill(arch_key, B, T_bucket, block_size, max_blocks,
         bids = jnp.take_along_axis(
             tables, jnp.minimum(cols, max_blocks - 1), axis=1)
         bids = jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
-        ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
-                vpool[li, tables].reshape(B, T_pad, KVl, D))
-               for li in range(L)]
+        with jax.named_scope("kv_gather"):
+            ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
+                    vpool[li, tables].reshape(B, T_pad, KVl, D))
+                   for li in range(L)]
         for li in range(L):
             x, k_new, v_new = arch["layer_tail"](
                 rw["layers"][li], sw["layers"][li], x,
@@ -1469,7 +1478,8 @@ def build_tp_paged_tail_prefill(arch_key, B, T_bucket, block_size, max_blocks,
                 k_new.reshape(B, nb, block_size, KVl, D))
             vpool = vpool.at[li, bids].set(
                 v_new.reshape(B, nb, block_size, KVl, D))
-        logits = arch["head_rows"](rw, sw, x, lens - 1)
+        with jax.named_scope("head"):
+            logits = arch["head_rows"](rw, sw, x, lens - 1)
         return kpool, vpool, logits
 
     wrapped = _tp_shard_map(

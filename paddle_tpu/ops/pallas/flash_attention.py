@@ -320,6 +320,7 @@ def _flash_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len):
                     _fwd_kernel_multi, block_k=block_k, causal=causal,
                     scale=scale, t_kv=t_kv, kv_len=kv_len, rows=rows,
                 ),
+                name="flash_fwd",
                 grid=(bh // rows, t // block_q),
                 in_specs=[
                     pl.BlockSpec((rows, block_q, d), lambda b, i: (b, i, 0)),
@@ -342,6 +343,7 @@ def _flash_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len):
                 _fwd_kernel_resident, block_k=block_k, causal=causal,
                 scale=scale, t_kv=t_kv, kv_len=kv_len,
             ),
+            name="flash_fwd",
             grid=(bh, t // block_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -368,6 +370,7 @@ def _flash_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len):
     kv_map = _kv_index_map()
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -715,6 +718,7 @@ def _flash_hd_fwd_inner(q, k, v, causal, block_q, block_k, interpret, kv_len, d,
             _fwd_kernel_hd, block_k=block_k, causal=causal, scale=scale,
             t_kv=t_kv, kv_len=kv_len, d=d, hp=hp,
         ),
+        name="flash_fwd",
         grid=(b, g, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, w), lambda bb, gg, i: (bb, i, gg)),
@@ -752,6 +756,7 @@ def _flash_hd_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpr
     )
     dq = pl.pallas_call(
         functools.partial(_dq_kernel_hd, block_k=block_k, causal=causal, scale=scale, t_kv=t_kv, kv_len=kv_len, d=d, hp=hp),
+        name="flash_dq",
         grid=(b, g, t // block_q),
         in_specs=[
             pl.BlockSpec((1, block_q, w), lambda bb, gg, i: (bb, i, gg)),
@@ -767,6 +772,7 @@ def _flash_hd_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpr
     )(q, k, v, do, lse, delta)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel_hd, block_q=block_q, causal=causal, scale=scale, t_q=t, kv_len=kv_len, d=d, hp=hp),
+        name="flash_dkv",
         grid=(b, g, t_kv // block_k),
         in_specs=[
             pl.BlockSpec((1, block_k, w), lambda bb, gg, j: (bb, j, gg)),
@@ -1042,6 +1048,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
                     _dfused_kernel_resident, block_q=block_q, causal=causal,
                     scale=scale, t_q=t, kv_len=kv_len, n_kv=n_kv,
                 ),
+                name="flash_bwd_fused",
                 grid=(bh, n_kv),
                 in_specs=[
                     pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
@@ -1077,6 +1084,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
         if _MULTI_ROW and rows > 1 and t == t_kv:
             dq = pl.pallas_call(
                 functools.partial(_dq_kernel_multi, block_k=block_k, causal=causal, scale=scale, t_kv=t_kv, kv_len=kv_len, rows=rows),
+                name="flash_dq",
                 grid=(bh // rows, n_q),
                 in_specs=[
                     pl.BlockSpec((rows, block_q, d), lambda b, i: (b, i, 0)),
@@ -1092,6 +1100,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
             )(q, k, v, do, lse, delta)
             dk, dv = pl.pallas_call(
                 functools.partial(_dkv_kernel_multi, block_q=block_q, causal=causal, scale=scale, t_q=t, kv_len=kv_len, rows=rows),
+                name="flash_dkv",
                 grid=(bh // rows, n_kv),
                 in_specs=[
                     pl.BlockSpec((rows, block_k, d), lambda b, j: (b, j, 0)),
@@ -1114,6 +1123,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
             return dq, dk, dv
         dq = pl.pallas_call(
             functools.partial(_dq_kernel_resident, block_k=block_k, causal=causal, scale=scale, t_kv=t_kv, kv_len=kv_len),
+            name="flash_dq",
             grid=(bh, n_q),
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
@@ -1130,6 +1140,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
 
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel_resident, block_q=block_q, causal=causal, scale=scale, t_q=t, kv_len=kv_len),
+            name="flash_dkv",
             grid=(bh, n_kv),
             in_specs=[
                 pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
@@ -1154,6 +1165,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
     kv_map = _kv_index_map()
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, block_k=block_k, causal=causal, scale=scale, n_kv=n_kv, kv_len=kv_len),
+        name="flash_dq",
         grid=(bh, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -1173,6 +1185,7 @@ def _flash_bwd_inner(q, k, v, out, lse, do, causal, block_q, block_k, interpret,
     q_map_lane = _q_index_map(lane=True)
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, block_q=block_q, causal=causal, scale=scale, n_q=n_q, kv_len=kv_len),
+        name="flash_dkv",
         grid=(bh, n_kv, n_q),
         in_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),

@@ -226,6 +226,16 @@ def counters() -> Dict[str, int]:
     ``io_quarantine_skips`` (poisoned input batches skipped), and
     ``lazy_verify_passes`` (FLAGS_lazy_verify replay cross-checks).
 
+    Compilation, charged to the program span it fired under
+    (profiler/spans.py; an event outside any span is not counted):
+    ``compile_trace_ns`` / ``compile_lower_ns`` / ``compile_backend_ns``
+    (nanoseconds of ``jax.monitoring``'s jaxpr-trace, jaxpr-to-MLIR and
+    backend-compile stages; nested traces counted once; a load from the
+    persistent cache is a backend stage) and ``compile_cache_hits``
+    (programs the persistent compilation cache served). The innermost open
+    span carries the same as ``compile_trace_s`` / ``compile_lower_s`` /
+    ``compile_backend_s`` / ``compile_cache_hits`` attributes.
+
     Telemetry: ``flight_dumps`` (flight-recorder post-mortems written by
     this process).
 
@@ -246,6 +256,8 @@ def counters() -> Dict[str, int]:
 KNOWN_COUNTERS = frozenset({
     "ckpt_coordinated_commits", "ckpt_resume_fallbacks",
     "ckpt_save_failures", "ckpt_saves",
+    "compile_backend_ns", "compile_cache_hits", "compile_lower_ns",
+    "compile_trace_ns",
     "dispatch_fastkey_hits",
     "dp_all_reduces", "dp_buckets", "dp_gather_bytes",
     "dp_reduce_scatters", "dp_sync_bytes",
@@ -648,6 +660,11 @@ def profiler_guard(**kwargs):
 from . import flight  # noqa: E402,F401
 from . import spans  # noqa: E402,F401
 from .spans import span  # noqa: E402,F401
+
+# Compilation is charged to the program span it fired under (spans.py). The
+# listeners do work only when jax reports a compile stage.
+jax.monitoring.register_event_duration_secs_listener(spans._on_compile_duration)
+jax.monitoring.register_event_listener(spans._on_compile_event)
 
 
 def events() -> List[_Event]:

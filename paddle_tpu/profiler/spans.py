@@ -22,10 +22,18 @@ Two sinks, different lifetimes:
   Python list; attributes ride in a bounded side table keyed by span id and
   are re-joined at export. Exactly ONE sink holds the timing record, so
   ``export()`` never double-counts.
+
+Beside its host-clock record every span is a ``jax.profiler.TraceAnnotation``
+of the same name: while any ``jax.profiler`` trace runs, the spans lie on the
+host plane of the xplane file, on the clock of the device operations, with
+their scalar attributes as the event's stats. And compilation is charged to
+the span it fired under (:func:`_on_compile_duration`): a ``decode_step`` or
+``train_step`` that carries ``compile_backend_s`` is a step that compiled.
 """
 from __future__ import annotations
 
 import itertools
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -64,7 +72,8 @@ class Span:
     may mutate until ``__exit__`` — e.g. the flush sets ``cache=hit/miss``
     only after the executable-cache probe."""
 
-    __slots__ = ("name", "span_id", "parent_id", "tid", "t0", "t1", "attrs")
+    __slots__ = ("name", "span_id", "parent_id", "tid", "t0", "t1", "attrs",
+                 "_ann")
 
     def __init__(self, name: str, **attrs):
         self.name = name
@@ -74,6 +83,7 @@ class Span:
         self.t0 = 0
         self.t1 = 0
         self.attrs = attrs
+        self._ann = None
 
     # -- context manager ---------------------------------------------------
     def __enter__(self) -> "Span":
@@ -81,11 +91,14 @@ class Span:
         self.parent_id = st[-1].span_id if st else 0
         self.tid = _tid()
         st.append(self)
+        self._ann = _annotate(self)
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         self.t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            _close_annotation(self, exc_type, exc, tb)
         st = _stack()
         if st and st[-1] is self:
             st.pop()
@@ -123,6 +136,50 @@ class Span:
         )
 
 
+# ``jax.profiler.TraceAnnotation``, bound at the first span (this module
+# imports no jax); False once it failed to bind or to construct.
+_annotation = None
+
+
+def _annotate(sp: Span):
+    """The span as an open annotation in the profiler's own trace, or None
+    while no ``jax.profiler`` trace is running (one static call). A failure
+    turns annotations off for the process and never reaches the span."""
+    global _annotation
+    ann = _annotation
+    if ann is None:
+        try:
+            import jax
+
+            ann = _annotation = jax.profiler.TraceAnnotation
+        except Exception:
+            ann = _annotation = False
+    if not ann:
+        return None
+    try:
+        if not ann.is_enabled():
+            return None
+        live = ann(sp.name)
+        live.__enter__()
+        return live
+    except Exception:
+        _annotation = False
+        return None
+
+
+def _close_annotation(sp: Span, exc_type, exc, tb) -> None:
+    """Close the span's annotation; its scalar attributes, as they stand now
+    (``blocks_grown``, ``compile_backend_s``, ... are set while the span is
+    open), become the event's stats."""
+    global _annotation
+    try:
+        sp._ann.set_metadata(**{k: v for k, v in sp.attrs.items()
+                                if type(v) in (int, float, str, bool)})
+        sp._ann.__exit__(exc_type, exc, tb)
+    except Exception:
+        _annotation = False
+
+
 def span(name: str, **attrs) -> Span:
     """``with span("lazy_flush", nodes=n) as sp: ... sp.set(cache="hit")``"""
     return Span(name, **attrs)
@@ -137,6 +194,65 @@ def active_spans() -> List[Span]:
     """The current thread's OPEN span stack, outermost first (post-mortem
     dumps serialize this to name the span a failure happened inside)."""
     return list(getattr(_tls, "stack", ()) or ())
+
+
+# -- compilation, charged to the span it fired under --------------------------
+# ``jax.monitoring`` reports each stage of a compilation as it ends, on the
+# thread that compiled. The two listeners below are registered once, by the
+# package; they run only when such an event fires, never on a warm step. An
+# event outside any span of the program is not the program's and is left
+# alone.
+_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND = "/jax/core/compile/backend_compile_duration"
+_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+
+
+def _own_trace_ns(dur_ns: int, root_t0: int) -> int:
+    """What of a trace event no earlier event has counted. A jit called
+    while another is being traced reports its own trace, inner before outer,
+    so the durations overlap: keep (arrival, duration) of the events of this
+    thread that no later one has swallowed, and take those that arrived
+    inside the new event's stretch off it."""
+    now = time.perf_counter_ns()
+    seen = getattr(_tls, "traces", None)
+    if seen is None:
+        seen = _tls.traces = []
+    elif seen and seen[0][0] < root_t0:  # from before the outermost open span
+        seen[:] = [e for e in seen if e[0] >= root_t0]
+    inner = 0
+    while seen and seen[-1][0] >= now - dur_ns:
+        inner += seen.pop()[1]
+    seen.append((now, dur_ns))
+    return max(dur_ns - inner, 0)
+
+
+def _on_compile_duration(event: str, duration: float, **_) -> None:
+    if event != _BACKEND and event != _LOWER and event != _TRACE:
+        return
+    st = getattr(_tls, "stack", None)
+    if not st:
+        return
+    pkg, ns = sys.modules[__package__], int(duration * 1e9)
+    if event == _BACKEND:  # the compiler itself, or the load of a cached program
+        key = "compile_backend_s"
+        pkg.counter_inc("compile_backend_ns", ns)
+    elif event == _LOWER:
+        key = "compile_lower_s"
+        pkg.counter_inc("compile_lower_ns", ns)
+    else:
+        key, ns = "compile_trace_s", _own_trace_ns(ns, st[0].t0)
+        pkg.counter_inc("compile_trace_ns", ns)
+    attrs = st[-1].attrs
+    attrs[key] = attrs.get(key, 0.0) + ns / 1e9
+
+
+def _on_compile_event(event: str, **_) -> None:
+    st = getattr(_tls, "stack", None)
+    if event == _CACHE_HIT and st:
+        attrs = st[-1].attrs
+        attrs["compile_cache_hits"] = attrs.get("compile_cache_hits", 0) + 1
+        sys.modules[__package__].counter_inc("compile_cache_hits")
 
 
 # -- session sink ------------------------------------------------------------
@@ -174,8 +290,6 @@ def remove_span_observer(fn) -> None:
 def _emit(sp: Span) -> None:
     global _pkg
     if _pkg is None:
-        import sys
-
         _pkg = sys.modules[__package__]
     if _observers:
         for fn in _observers:

@@ -57,10 +57,14 @@ programs in ``models/generation.py``:
   shrink-proof OOM streak falls through to the crash-containment path so
   clients are never hung.
 
-Every scheduler action is a profiler span (``admit``/``schedule``/
-``prefill``/``decode_step``/``page_alloc``/``evict``) with ``serve_*``
-counters, and the engine registers a flight-recorder context provider so
-crash dumps carry the in-flight request table. Chaos points ``serve.crash``
+Every scheduler action is a profiler span (``schedule`` holding ``admit``,
+``prefill`` with ``prefill_readback``/``prefill_land``, ``decode_build``,
+``decode_step`` with ``decode_readback``/``decode_land``, and ``evict``)
+with ``serve_*`` counters: the host phases of a step are named where the
+work happens, and lie in a ``jax.profiler`` trace beside the device's
+operations (profiler/spans.py). The engine registers a flight-recorder
+context provider so crash dumps carry the in-flight request table. Chaos
+points ``serve.crash``
 / ``serve.wedge`` / ``serve.slow_step`` / ``serve.pool_corrupt`` /
 ``hbm.oom`` / ``hbm.pressure`` (fault/inject.py) fire at the scheduler
 step boundary when armed; ``serve.snapshot_corrupt`` tears a state capture
@@ -79,6 +83,7 @@ handles → successor adopts) — the zero-downtime restart/upgrade primitive.
 from __future__ import annotations
 
 import collections
+import contextlib
 import copy
 import itertools
 import queue as _queue
@@ -92,7 +97,7 @@ import numpy as np
 from ..fault import inject as _inject
 from ..framework import flags
 from ..profiler import counter_inc, flight
-from ..profiler.spans import span, update_attrs
+from ..profiler.spans import span
 from .pool import PagePool, SnapshotError, TRASH_BLOCK
 
 __all__ = [
@@ -1932,12 +1937,7 @@ class Engine:
                         self._kpool, self._vpool,
                     )
                     counter_inc("serve_prefills")
-                    rows = np.asarray(logits)
-                    # beat BEFORE dropping the compile grace: a monitor poll
-                    # between the two would see a stale beat at the 1x limit
-                    # and declare a spurious wedge after a long compile
-                    self._beat = time.monotonic()
-                    self._compiling = False
+                    rows = self._prefill_readback(logits)
                     self._land_prefill(chunk, rows)
         for t_bucket in sorted(tail_groups):
             group = tail_groups[t_bucket]
@@ -1969,29 +1969,41 @@ class Engine:
                     )
                     counter_inc("serve_prefills")
                     counter_inc("serve_tail_prefills")
-                    rows = np.asarray(logits)
-                    self._beat = time.monotonic()
-                    self._compiling = False
+                    rows = self._prefill_readback(logits)
                     self._land_prefill(chunk, rows)
 
+    def _prefill_readback(self, logits) -> np.ndarray:
+        """``prefill_readback``: the blocking copy of a prefill program's
+        logits to the host (the wait for the program is in it)."""
+        with span("prefill_readback"):
+            rows = np.asarray(logits)
+        # beat BEFORE dropping the compile grace: a monitor poll between the
+        # two would see a stale beat at the 1x limit and declare a spurious
+        # wedge after a long compile
+        self._beat = time.monotonic()
+        self._compiling = False
+        return rows
+
     def _land_prefill(self, chunk: List[_Seq], rows: np.ndarray):
-        """Post-prefill landing: index cacheable prompt blocks (while the
-        sequence still owns them — the index takes its own reference, so a
-        first-token retirement keeps the KV resident), then sample the
-        first generated token and move the sequence into the running set."""
-        for r, s in enumerate(chunk):
-            if self._prefix is not None:
-                full = s.prompt_len // self.config.block_size
-                if full > s.cached_blocks:
-                    self._prefix.insert(s.tokens, s.blocks,
-                                        s.cached_blocks, full)
-            self._append_token(s, self._sample_host(rows[r], s.req))
-            if not s.req.done.is_set():
-                self._running.append(s)
-        if self._obs is not None:
-            # ONE host clock read covers the whole landed group: prefill
-            # always emits each row's first token (TTFT)
-            self._obs.on_tokens([s.req for s in chunk], time.monotonic())
+        """Post-prefill landing (``prefill_land``): index cacheable prompt
+        blocks (while the sequence still owns them — the index takes its own
+        reference, so a first-token retirement keeps the KV resident), then
+        sample the first generated token from its full-vocabulary row and
+        move the sequence into the running set."""
+        with span("prefill_land", rows=len(chunk)):
+            for r, s in enumerate(chunk):
+                if self._prefix is not None:
+                    full = s.prompt_len // self.config.block_size
+                    if full > s.cached_blocks:
+                        self._prefix.insert(s.tokens, s.blocks,
+                                            s.cached_blocks, full)
+                self._append_token(s, self._sample_host(rows[r], s.req))
+                if not s.req.done.is_set():
+                    self._running.append(s)
+            if self._obs is not None:
+                # ONE host clock read covers the whole landed group: prefill
+                # always emits each row's first token (TTFT)
+                self._obs.on_tokens([s.req for s in chunk], time.monotonic())
 
     # -- chunked prefill (PR 19) ---------------------------------------------
     def _chunk_divert(self, seqs: List[_Seq]) -> List[_Seq]:
@@ -2054,10 +2066,11 @@ class Engine:
             counter_inc("serve_prefill_chunks")
             done = [r for r, s in enumerate(batch)
                     if s.chunk_pos + feeds[r] >= len(s.tokens)]
-            rows = (np.asarray(logits) if done
-                    else None)  # only final chunks need the logits host-side
-            self._beat = time.monotonic()
-            self._compiling = False
+            if done:  # only final chunks need the logits host-side
+                rows = self._prefill_readback(logits)
+            else:
+                self._beat = time.monotonic()
+                self._compiling = False
             for r, s in enumerate(batch):
                 s.chunk_pos += feeds[r]
             if done:
@@ -2085,7 +2098,9 @@ class Engine:
         for re-prefill) — backpressure, never failure. Victim selection is
         priority-then-youngest: the lowest-priority peer goes first, ties
         broken by the youngest request; a grower never evicts a
-        higher-priority peer — it preempts ITSELF instead."""
+        higher-priority peer — it preempts ITSELF instead. Returns the
+        number of blocks it mapped."""
+        grown = 0
         for seq in list(self._running):
             if seq not in self._running:
                 continue  # evicted by an earlier iteration
@@ -2093,15 +2108,15 @@ class Engine:
             need = ((seq.pos + self._spec_k) // self.config.block_size + 1
                     - len(seq.blocks))
             while need > 0:
-                with span("page_alloc", request=seq.req.id, blocks=need):
+                got = self._pool.alloc(need)
+                if got is None and self._prefix is not None \
+                        and len(self._prefix):
+                    # reclaim unpinned cache before preempting a peer
+                    self._prefix.evict(need - self._pool.free_blocks)
                     got = self._pool.alloc(need)
-                    if got is None and self._prefix is not None \
-                            and len(self._prefix):
-                        # reclaim unpinned cache before preempting a peer
-                        self._prefix.evict(need - self._pool.free_blocks)
-                        got = self._pool.alloc(need)
                 if got is not None:
                     seq.blocks.extend(got)
+                    grown += need
                     break
                 victims = [s for s in self._running if s is not seq]
                 if not victims:
@@ -2117,6 +2132,7 @@ class Engine:
                     self._evict(seq)
                     break
                 self._evict(victim)
+        return grown
 
     def _evict(self, seq: _Seq):
         with span("evict", request=seq.req.id, generated=seq.generated) as sp:
@@ -2180,45 +2196,74 @@ class Engine:
             if self._obs is not None:
                 self._obs.on_cow(seq.req.trace, 1)
 
-    def _decode(self):
-        jnp, jax = self._jnp, self._jax
-        self._grow_blocks()
-        if not self._running:
-            return
-        if self._prefix is not None:
-            for s in self._running:
-                self._cow_guard(s)
-        n = len(self._running)
-        bb = next(b for b in self.config.decode_buckets if b >= n)
-        mb = self._gather_width(bb)
-        tables = np.full((bb, mb), TRASH_BLOCK, np.int32)
-        pos = np.zeros((bb,), np.int32)
-        toks = np.zeros((bb,), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        for r, s in enumerate(self._running):
-            tables[r, :len(s.blocks)] = s.blocks
-            pos[r] = s.pos
-            toks[r] = s.tokens[-1]
-            temps[r] = s.req.temperature
-        self._key, sub = jax.random.split(self._key)
-        # a width upgrade pops the old entry, so compare by key presence,
-        # not _fns length
-        warm = ("decode", bb, mb) in self._fns
-        with span("decode_step", bucket=bb, rows=n, step=self._step_i) as sp:
-            if self._obs is not None:
-                sp.set(traces=tuple(s.req.trace for s in self._running))
-            self._beat = time.monotonic()  # staleness clock covers this op
-            fn = self._get_fn("decode", bb, mb)
-            self._compiling = not warm
-            t0 = time.monotonic()
-            self._kpool, self._vpool, nxt = fn(
-                self._compute_params, self._kpool, self._vpool,
-                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(toks),
-                jnp.asarray(temps), sub,
-            )
-        nxt = np.asarray(nxt)
+    # The host phases of a decode step, plain and speculative alike, each a
+    # span where the work happens: ``decode_build`` (under ``schedule``), then
+    # ``decode_step`` from the dispatch to the end of the landing, holding
+    # ``decode_readback`` and ``decode_land``; what is left of ``decode_step``
+    # is the program lookup, the key split, the transfers and the enqueue.
+    def _decode_build(self, k: int = 0):
+        """``decode_build``: map the blocks the step will write, guard shared
+        ones, choose the bucket and fill the step's host arrays (``k`` draft
+        columns beside each row's pending token). Returns None when growing
+        preempted every row, else (rows, bucket, gather width, drafts,
+        tables, positions, tokens, temperatures)."""
+        with span("decode_build") as sp:
+            grown = self._grow_blocks()
+            n = len(self._running)
+            sp.set(rows=n, blocks_grown=grown)
+            if not n:
+                return None
+            if self._prefix is not None:
+                for s in self._running:
+                    self._cow_guard(s)
+            bb = next(b for b in self.config.decode_buckets if b >= n)
+            mb = self._gather_width(bb)
+            sp.set(bucket=bb)
+            drafts = self._propose(bb) if k else None
+            tables = np.full((bb, mb), TRASH_BLOCK, np.int32)
+            pos = np.zeros((bb,), np.int32)
+            toks = np.zeros((bb, k + 1), np.int32)
+            temps = np.zeros((bb,), np.float32)
+            for r, s in enumerate(self._running):
+                tables[r, :len(s.blocks)] = s.blocks
+                pos[r] = s.pos
+                toks[r, 0] = s.tokens[-1]
+                temps[r] = s.req.temperature
+            if k:
+                toks[:n, 1:] = drafts[:n]
+            else:
+                toks = toks[:, 0]
+        return n, bb, mb, drafts, tables, pos, toks, temps
+
+    def _decode_readback(self, *arrays):
+        """``decode_readback``: the blocking copy of the step's tokens to the
+        host (the wait for the step's program is in it)."""
+        with span("decode_readback"):
+            out = [np.asarray(a) for a in arrays]
         self._beat = time.monotonic()  # beat before dropping compile grace
         self._compiling = False
+        return out
+
+    @contextlib.contextmanager
+    def _decode_land(self, rows_live: List[_Seq]):
+        """``decode_land``: the step's tokens into their streams, with the
+        retirements and page frees that follow; the caller appends inside
+        and sets ``tokens``."""
+        with span("decode_land") as sp:
+            yield sp
+            if self._obs is not None:
+                # one host clock read at step retire, attributed to every
+                # row that emitted a token this step (TTFT / inter-token
+                # gap; a multi-accept speculative step IS one interval at
+                # step granularity)
+                self._obs.on_tokens([s.req for s in rows_live],
+                                    time.monotonic())
+            sp.set(retired=len(rows_live) - len(self._running))
+
+    def _step_done(self, sp, warm: bool, t0: float, n: int, bb: int):
+        """Book-keeping of a decode step whose tokens have reached the host,
+        before they land (a client that holds its result sees the counters
+        of the step that produced it)."""
         # decode service-time EMA feeds deadline feasibility + Retry-After
         # hints; compile steps are excluded — they would make every early
         # deadline look doomed
@@ -2229,7 +2274,7 @@ class Engine:
                 # sweep would have used for THIS step vs its measured time
                 rel = self._obs.drift(
                     "step_eta", max(self._ema_step_s, self._step_floor_s), dt)
-                update_attrs(sp, cost_drift=round(rel, 6))
+                sp.set(cost_drift=round(rel, 6))
             self._ema_step_s = (dt if not self._ema_step_s
                                 else 0.8 * self._ema_step_s + 0.2 * dt)
         self._step_i += 1
@@ -2238,13 +2283,36 @@ class Engine:
         counter_inc("serve_decode_steps")
         counter_inc("serve_occupancy_live", n)
         counter_inc("serve_occupancy_slots", bb)
-        rows_live = list(self._running)
-        for r, s in enumerate(rows_live):
-            self._append_token(s, int(nxt[r]))
-        if self._obs is not None:
-            # one host clock read at step retire, attributed to every row
-            # that emitted a token this step (TTFT / inter-token gap)
-            self._obs.on_tokens([s.req for s in rows_live], time.monotonic())
+
+    def _decode(self):
+        jnp, jax = self._jnp, self._jax
+        built = self._decode_build()
+        if built is None:
+            return
+        n, bb, mb, _, tables, pos, toks, temps = built
+        # a width upgrade pops the old entry, so compare by key presence,
+        # not _fns length
+        warm = ("decode", bb, mb) in self._fns
+        with span("decode_step", bucket=bb, rows=n, step=self._step_i) as sp:
+            if self._obs is not None:
+                sp.set(traces=tuple(s.req.trace for s in self._running))
+            self._beat = time.monotonic()  # staleness clock covers this op
+            fn = self._get_fn("decode", bb, mb)
+            self._compiling = not warm
+            self._key, sub = jax.random.split(self._key)
+            t0 = time.monotonic()
+            self._kpool, self._vpool, nxt = fn(
+                self._compute_params, self._kpool, self._vpool,
+                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(toks),
+                jnp.asarray(temps), sub,
+            )
+            nxt, = self._decode_readback(nxt)
+            self._step_done(sp, warm, t0, n, bb)
+            rows_live = list(self._running)
+            with self._decode_land(rows_live) as land:
+                for r, s in enumerate(rows_live):
+                    self._append_token(s, int(nxt[r]))
+                land.set(tokens=n)
 
     # -- speculative decode ---------------------------------------------------
     def _propose(self, bb: int) -> np.ndarray:
@@ -2290,27 +2358,10 @@ class Engine:
         rows take the j=0 sampled token and accept no drafts."""
         jnp, jax = self._jnp, self._jax
         k = self._spec_k
-        self._grow_blocks()
-        if not self._running:
+        built = self._decode_build(k)
+        if built is None:
             return
-        if self._prefix is not None:
-            for s in self._running:
-                self._cow_guard(s)
-        n = len(self._running)
-        bb = next(b for b in self.config.decode_buckets if b >= n)
-        mb = self._gather_width(bb)
-        drafts = self._propose(bb)
-        tables = np.full((bb, mb), TRASH_BLOCK, np.int32)
-        pos = np.zeros((bb,), np.int32)
-        toks = np.zeros((bb, k + 1), np.int32)
-        temps = np.zeros((bb,), np.float32)
-        for r, s in enumerate(self._running):
-            tables[r, :len(s.blocks)] = s.blocks
-            pos[r] = s.pos
-            toks[r, 0] = s.tokens[-1]
-            toks[r, 1:] = drafts[r]
-            temps[r] = s.req.temperature
-        self._key, sub = jax.random.split(self._key)
+        n, bb, mb, drafts, tables, pos, toks, temps = built
         warm = ("spec", bb, mb) in self._fns
         with span("decode_step", bucket=bb, rows=n, step=self._step_i,
                   spec_k=k) as sp:
@@ -2319,53 +2370,39 @@ class Engine:
             self._beat = time.monotonic()
             fn = self._get_fn("spec", bb, mb)
             self._compiling = not warm
+            self._key, sub = jax.random.split(self._key)
             t0 = time.monotonic()
             self._kpool, self._vpool, greedy, sampled = fn(
                 self._compute_params, self._kpool, self._vpool,
                 jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(toks),
                 jnp.asarray(temps), sub,
             )
-            greedy, sampled = np.asarray(greedy), np.asarray(sampled)
-            proposed = accepted = 0
+            greedy, sampled = self._decode_readback(greedy, sampled)
+            self._step_done(sp, warm, t0, n, bb)
+            proposed = accepted = emitted = 0
             rows_live = list(self._running)
-            for r, s in enumerate(rows_live):
-                if temps[r] > 0.0:
-                    self._append_token(s, int(sampled[r]))
-                    continue
-                nprop = int(np.sum(drafts[r] >= 0))
-                m = 0
-                while m < nprop and drafts[r, m] == greedy[r, m]:
-                    m += 1
-                proposed += nprop
-                accepted += m
-                # the m accepted drafts re-emerge as the target's own argmax
-                # continuations, plus the bonus token after the last one
-                for j in range(m + 1):
-                    if s.req.done.is_set():
-                        break
-                    self._append_token(s, int(greedy[r, j]))
+            with self._decode_land(rows_live) as land:
+                for r, s in enumerate(rows_live):
+                    if temps[r] > 0.0:
+                        self._append_token(s, int(sampled[r]))
+                        emitted += 1
+                        continue
+                    nprop = int(np.sum(drafts[r] >= 0))
+                    m = 0
+                    while m < nprop and drafts[r, m] == greedy[r, m]:
+                        m += 1
+                    proposed += nprop
+                    accepted += m
+                    # the m accepted drafts re-emerge as the target's own
+                    # argmax continuations, plus the bonus token after the
+                    # last one
+                    for j in range(m + 1):
+                        if s.req.done.is_set():
+                            break
+                        self._append_token(s, int(greedy[r, j]))
+                        emitted += 1
+                land.set(tokens=emitted)
             sp.set(drafted=proposed, accepted=accepted)
-        self._beat = time.monotonic()
-        self._compiling = False
-        if self._obs is not None:
-            # every live row emits at least its j=0 token per spec step; the
-            # gap histogram sees one sample per row per step (a multi-accept
-            # step IS one inter-token interval at step granularity)
-            self._obs.on_tokens([s.req for s in rows_live], time.monotonic())
-        if warm:
-            dt = time.monotonic() - t0
-            if self._obs is not None and self._ema_step_s:
-                rel = self._obs.drift(
-                    "step_eta", max(self._ema_step_s, self._step_floor_s), dt)
-                update_attrs(sp, cost_drift=round(rel, 6))
-            self._ema_step_s = (dt if not self._ema_step_s
-                                else 0.8 * self._ema_step_s + 0.2 * dt)
-        self._step_i += 1
-        self._occ_live += n
-        self._occ_slots += bb
-        counter_inc("serve_decode_steps")
-        counter_inc("serve_occupancy_live", n)
-        counter_inc("serve_occupancy_slots", bb)
         counter_inc("serve_draft_proposed", proposed)
         counter_inc("serve_draft_accepted", accepted)
 
@@ -2552,6 +2589,10 @@ class Engine:
 
                 def raw(params, *args, _dq=dq, _inner=inner):
                     return _inner(_dq(params), *args)
+
+                # the device line tells programs apart by name (``jit_step``
+                # / ``jit_prefill``): the int8 wrapper keeps the builder's
+                raw.__name__ = raw.__qualname__ = inner.__name__
 
             # donation lets XLA update the pools in place — on every backend,
             # the CPU tier included, so a host-side reference that outlives a

@@ -244,7 +244,9 @@ class TestServingTelemetry:
                 [h.result(timeout=300) for h in hs]
             names = {s["name"] for s in profiler.span_events()}
         del prof
-        assert {"schedule", "admit", "prefill", "decode_step"} <= names
+        assert {"schedule", "admit", "prefill", "decode_step", "decode_build",
+                "decode_readback", "decode_land", "prefill_readback",
+                "prefill_land"} <= names
         c1 = profiler.counters()
         for k in ("serve_requests", "serve_admitted", "serve_retired",
                   "serve_prefills", "serve_decode_steps", "serve_tokens",
@@ -253,6 +255,110 @@ class TestServingTelemetry:
                   "serve_occupancy_slots"):
             assert c1.get(k, 0) > c0.get(k, 0), k
         assert c1.get("serve_pages_allocated") is not None
+
+    @staticmethod
+    def _spans_of(model, n_requests=3, max_new=6, seed=12, **kw):
+        """Every span the program finished while one engine served a few
+        requests (the public observer hook; Span objects, attrs final)."""
+        from paddle_tpu.profiler import spans
+
+        rng = np.random.RandomState(seed)
+        rows = []
+        spans.add_span_observer(rows.append)
+        try:
+            with Engine(model, **dict(_ENGINE_KW, **kw)) as eng:
+                hs = [eng.submit(p, max_new_tokens=max_new)
+                      for p in _prompts(n_requests, rng)]
+                outs = [h.result(timeout=300) for h in hs]
+        finally:
+            spans.remove_span_observer(rows.append)
+        assert all(len(o) for o in outs)
+        return rows
+
+    @pytest.mark.parametrize("kw", [{}, {"spec_k": 2, "drafter": "ngram"},
+                                    {"prefix_cache": True},
+                                    {"prefill_chunk": 8}],
+                             ids=["plain", "spec", "prefix", "chunked"])
+    def test_phase_spans_nest_where_the_work_happens(self, model, kw):
+        rows = self._spans_of(model, **kw)
+        by_id = {sp.span_id: sp for sp in rows}
+        parents = {}
+        for sp in rows:
+            up = by_id.get(sp.parent_id)
+            parents.setdefault(sp.name, set()).add(up.name if up else None)
+        assert parents["decode_build"] == {"schedule"}
+        assert parents["decode_step"] == {"schedule"}
+        assert parents["decode_readback"] == {"decode_step"}
+        assert parents["decode_land"] == {"decode_step"}
+        assert parents["prefill"] == {"schedule"}
+        assert parents["prefill_readback"] == {"prefill"}
+        assert parents["prefill_land"] == {"prefill"}
+        assert "page_alloc" not in parents  # its count rides decode_build
+        n = {name: sum(sp.name == name for sp in rows) for name in parents}
+        assert n["decode_build"] == n["decode_step"] == n["decode_readback"] \
+            == n["decode_land"] > 0
+        assert n["prefill_readback"] == n["prefill_land"] <= n["prefill"]
+        for sp in rows:
+            if sp.name == "decode_build":
+                assert {"rows", "bucket", "blocks_grown"} <= set(sp.attrs)
+            elif sp.name == "decode_step":
+                assert {"rows", "bucket", "step"} <= set(sp.attrs)
+            elif sp.name == "decode_land":
+                assert sp.attrs["tokens"] >= 1 and sp.attrs["retired"] >= 0
+        # every block the steps mapped and every token they landed is counted
+        lands = [sp for sp in rows if sp.name == "decode_land"]
+        assert sum(sp.attrs["retired"] for sp in lands) <= 3
+        assert sum(sp.attrs["tokens"] for sp in lands) == 3 * (6 - 1)
+        assert sum(sp.attrs["blocks_grown"] for sp in rows
+                   if sp.name == "decode_build") >= 1
+
+    def test_build_and_step_cover_the_scheduler_step(self, model):
+        """``decode_build`` and ``decode_step`` (with ``admit`` and
+        ``prefill``) leave of ``schedule`` a small unnamed rest, and
+        readback + land + the step's own time ARE the step."""
+        rows = self._spans_of(model, n_requests=4, max_new=24)
+        kids = {}
+        for sp in rows:
+            kids.setdefault(sp.parent_id, []).append(sp)
+        rest, steps = [], 0
+        for sched in (sp for sp in rows if sp.name == "schedule"):
+            mine = kids.get(sched.span_id, [])
+            if not any(k.name == "decode_step" for k in mine):
+                continue
+            if any("compile_backend_s" in k.attrs for k in mine):
+                continue  # a step that compiled says nothing of a warm one
+            steps += 1
+            assert all(sched.t0 <= k.t0 and k.t1 <= sched.t1 for k in mine)
+            rest.append((sched.dur_ns - sum(k.dur_ns for k in mine))
+                        / sched.dur_ns)
+        assert steps >= 10
+        assert sorted(rest)[len(rest) // 2] < 0.25, sorted(rest)
+        for step in (sp for sp in rows if sp.name == "decode_step"):
+            inner = kids[step.span_id]
+            assert [k.name for k in sorted(inner, key=lambda k: k.t0)] \
+                == ["decode_readback", "decode_land"]
+            assert 0 <= step.dur_ns - sum(k.dur_ns for k in inner)
+
+    def test_first_step_of_a_program_carries_its_compile(self, model):
+        """The ``prefill`` / ``decode_step`` span a program was built under
+        carries the compile stages (named with its bucket); the warm steps
+        of the same program carry none."""
+        rows = self._spans_of(model, n_requests=1, max_new=12, seed=13)
+        stages = ("compile_trace_s", "compile_lower_s", "compile_backend_s")
+        for name in ("decode_step", "prefill"):
+            mine = [sp for sp in rows if sp.name == name]
+            built = [sp for sp in mine if any(a in sp.attrs for a in stages)]
+            # the first of a kind builds its program; a later one only when
+            # its bucket (or the bucket's gather width) is new; and a
+            # compile is whole: all three stages or none
+            assert mine[0] in built
+            assert all(sp.attrs.get(a, 0) > 0 for sp in built for a in stages)
+            assert len(built) <= 3, [sp.attrs for sp in built]
+        assert len([sp for sp in rows if sp.name == "decode_step"]) >= 8
+        # nothing of a compile lands on a span that only waits or lands
+        for sp in rows:
+            if sp.name in ("decode_land", "prefill_land", "admit"):
+                assert not any(a in sp.attrs for a in stages), sp
 
     def test_flight_context_provider_carries_request_table(self, model):
         from paddle_tpu.profiler import flight
