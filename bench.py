@@ -1501,8 +1501,6 @@ def bench_serving(paddle, jax, np, on_tpu):
         np, model, cfg.vocab_size, ekw, on_tpu)
     line["recovery"] = _bench_serving_recovery(np, model, ekw, prompts,
                                                max_new)
-    line["paged_kernel"] = _bench_serving_paged_kernel(
-        np, model, ekw, prompts, max_new)
     line["mesh"] = _bench_serving_mesh(
         np, model, ekw, prompts, max_new, on_tpu)
     line["chunked_prefill"] = _bench_serving_chunked_prefill(
@@ -1724,46 +1722,6 @@ def _bench_serving_chunked_prefill(np, model, vocab, ekw, max_new, on_tpu):
     }
 
 
-def _bench_serving_paged_kernel(np, model, ekw, prompts, max_new):
-    """Decode A/B (ISSUE-18): the gather-then-dense paged read vs the
-    block-table-aware Pallas paged-attention kernel behind
-    ``FLAGS_serve_paged_kernel``, same prompts both arms. Reports per-arm
-    generated tokens/sec, the speedup, and whether the outputs stayed
-    bit-identical (the kernel's correctness contract — a False here is a
-    bug, not a perf note)."""
-    from paddle_tpu.framework import flags
-    from paddle_tpu.serving import Engine
-
-    sub = prompts[: min(16, len(prompts))]
-    arms, outs = {}, {}
-    for arm, on in (("gather", False), ("kernel", True)):
-        old = flags._FLAGS.get("FLAGS_serve_paged_kernel")
-        flags._FLAGS["FLAGS_serve_paged_kernel"] = on
-        try:
-            with Engine(model, **ekw) as eng:
-                warm = [eng.submit(p, max_new_tokens=max_new) for p in sub]
-                [h.result(timeout=600) for h in warm]
-                t0 = time.monotonic()
-                hs = [eng.submit(p, max_new_tokens=max_new) for p in sub]
-                res = [h.result(timeout=600) for h in hs]
-                wall = time.monotonic() - t0
-        finally:
-            if old is None:
-                flags._FLAGS.pop("FLAGS_serve_paged_kernel", None)
-            else:
-                flags._FLAGS["FLAGS_serve_paged_kernel"] = old
-        gen = sum(len(o) - len(p) for o, p in zip(res, sub))
-        arms[arm] = round(gen / max(wall, 1e-9), 1)
-        outs[arm] = res
-    return {
-        "streams": len(sub),
-        "gather_tokens_per_sec": arms["gather"],
-        "kernel_tokens_per_sec": arms["kernel"],
-        "speedup": round(arms["kernel"] / max(arms["gather"], 1e-9), 3),
-        "identical_tokens": outs["gather"] == outs["kernel"],
-    }
-
-
 def bench_kernel_autotune(paddle, jax, np, on_tpu):
     """Kernel-registry autotune A/B (ISSUE-18): a real measured-timing
     search over the flash-attention config space against a throwaway tuning
@@ -1848,7 +1806,8 @@ def bench_kernel_autotune(paddle, jax, np, on_tpu):
     # paged decode: gather builder vs Pallas-kernel builder, one step
     paddle.seed(0)
     if on_tpu:
-        gcfg = GPTConfig(vocab_size=8192, hidden_size=512, num_layers=4,
+        # head width 128: Mosaic takes the paged kernel at multiples of it
+        gcfg = GPTConfig(vocab_size=8192, hidden_size=1024, num_layers=4,
                          num_heads=8, max_position_embeddings=2048,
                          hidden_dropout=0.0, attention_dropout=0.0)
         B, BS, MB, NB = 64, 16, 16, 2048
@@ -1902,6 +1861,119 @@ def bench_kernel_autotune(paddle, jax, np, on_tpu):
                "budget_stops": delta("kernel_tune_budget_stops")},
     }
     print("KERNEL_PERF " + json.dumps(line))
+    return line
+
+
+def bench_paged_kernel(paddle, jax, np, on_tpu):
+    """The paged decode-attention kernel ALONE, at the shape of the
+    benchmark's serving cell on the chip (GPT-3 XL: 24 layers chained as the
+    decode step chains them, 64 rows, a table 128 wide, 16 x 16 x 128 bf16
+    blocks in a 3,679-block pool): milliseconds for the 24 reads and the
+    share of the HBM peak that the LIVE K/V bytes over that time make, for
+    three fillings (live block counts spread 1-96 a row as the chat mix
+    spreads them; every row at 56 blocks; 16 live rows beside 48 dead ones)
+    and every ``blocks_per_chunk`` of the registry's space; plus the largest
+    difference from the gather read of the same bf16 inputs. ``python
+    bench.py paged_kernel`` runs it alone and prints its line. On the CPU a
+    tiny float32 shape under the interpreter, and no times."""
+    import jax.numpy as jnp
+
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.cost_model import device_peaks
+    from paddle_tpu.ops import kernels as K
+
+    if on_tpu:
+        L, NB, BS, KV, D, H, B, MB = 24, 3679, 16, 16, 128, 16, 64, 128
+        dtype, longest, samples = jnp.bfloat16, 96, 5
+    else:
+        L, NB, BS, KV, D, H, B, MB = 2, 64, 8, 2, 16, 2, 4, 8
+        dtype, longest, samples = jnp.float32, 6, 1
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    kpool = jax.random.normal(ks[0], (L, NB, BS, KV, D), dtype)
+    vpool = jax.random.normal(ks[1], (L, NB, BS, KV, D), dtype)
+    q = jax.random.normal(ks[2], (B, H, D), dtype)
+    rng = np.random.RandomState(3)
+    ragged = np.exp(rng.normal(np.log(longest / 5), 0.8, B))
+    ragged = (ragged * (0.4 * (NB - 1)) / ragged.sum()).astype(int).clip(
+        1, longest)
+    fillings = {
+        "ragged": ragged,
+        "every_row_full": np.full(B, longest * 7 // 12),
+        "quarter_live": np.r_[ragged[:B // 4], np.zeros(B - B // 4, int)],
+    }
+
+    def tables_for(lens):
+        free = rng.permutation(np.arange(1, NB))
+        tables = np.zeros((B, MB), np.int32)  # dead columns: the trash block
+        pos = np.zeros((B,), np.int32)
+        at = 0
+        for b, n in enumerate(lens):
+            if n:
+                tables[b, :n] = free[at:at + n]
+                at += n
+                pos[b] = n * BS - 1 - rng.randint(0, BS)
+        return jnp.asarray(tables), jnp.asarray(pos)
+
+    def layers(config):
+        def run(q, kpool, vpool, tables, pos):
+            x, acc = q, jnp.zeros((B, H * D), jnp.float32)
+            for li in range(L):
+                o = K.paged_attention_rows(x, kpool, vpool, li, tables, pos,
+                                           config=config)
+                acc = acc + o.astype(jnp.float32)
+                x = (q + o.reshape(B, H, D) * 0.001).astype(dtype)
+            return acc
+
+        return jax.jit(run)
+
+    def gather_read(q, kpool, vpool, tables, pos):
+        T = MB * BS
+        kc = kpool[L - 1, tables].reshape(B, T, KV, D)
+        vc = vpool[L - 1, tables].reshape(B, T, KV, D)
+        live = jnp.arange(T)[None, :] <= pos[:, None]
+        return G._grouped_attention(
+            q[:, None], kc, vc, live[:, None, None, None, :],
+            H // KV).reshape(B, H * D)
+
+    def best_ms(fn, *args):
+        jax.block_until_ready(fn(*args))
+        ts = []
+        for _ in range(samples):
+            t0 = time.perf_counter()
+            jax.block_until_ready(fn(*args))
+            ts.append(time.perf_counter() - t0)
+        return min(ts) * 1e3
+
+    tables, pos = tables_for(fillings["ragged"])
+    ref = jax.jit(gather_read)(q, kpool, vpool, tables, pos)
+    got = jax.jit(lambda q, k, v, t, p: K.paged_attention_rows(
+        q, k, v, L - 1, t, p))(q, kpool, vpool, tables, pos)
+    ref, got = (np.asarray(a, np.float32) for a in (ref, got))
+    peak = float(device_peaks()["hbm_bw"])
+    block_bytes = 2 * BS * KV * D * jnp.dtype(dtype).itemsize  # K and V
+    chunks = K.get_kernel("paged_attention").space["blocks_per_chunk"]
+    line = {
+        "name": "paged_attention kernel alone",
+        "shape": f"L{L} B{B} MB{MB} block {BS}x{KV}x{D} "
+                 f"{jnp.dtype(dtype).name} pool {NB}",
+        "max_abs_diff_vs_gather": float(np.abs(ref - got).max()),
+        "max_abs_gather": float(np.abs(ref).max()),
+        "fillings": {},
+    }
+    filled = {name: (tables_for(lens), int(np.maximum(lens, 1).sum()))
+              for name, lens in fillings.items()}  # a dead row reads a block
+    for name, (_, blocks) in filled.items():
+        line["fillings"][name] = {"live_blocks": blocks,
+                                  "blocks_per_chunk": {}}
+    for c in chunks:
+        read = layers({"blocks_per_chunk": c})  # compiled once a chunk size
+        for name, ((tables, pos), blocks) in filled.items():
+            ms = best_ms(read, q, kpool, vpool, tables, pos)
+            line["fillings"][name]["blocks_per_chunk"][str(c)] = {
+                "ms": round(ms, 3),
+                "hbm_peak_pct": round(
+                    100 * blocks * block_bytes * L / (ms * 1e-3) / peak, 1),
+            } if on_tpu else "not measured"
     return line
 
 
@@ -2156,7 +2228,7 @@ def main():
                bench_gpt_1p3b, bench_gpt_8k_flash,
                bench_vit_l_aot, bench_yolov3_aot, bench_llama_1b,
                bench_dp8_gpt, bench_serving, bench_host_embedding,
-               bench_kernel_autotune):
+               bench_kernel_autotune, bench_paged_kernel):
         if remaining() < 30.0:
             extras.append({"name": fn.__name__, "skipped": "budget"})
             continue
@@ -2274,5 +2346,22 @@ def main():
     )
 
 
+def only(names):
+    """``python bench.py <name> ...``: the named ``bench_<name>`` functions
+    alone, each printing its own line."""
+    import numpy as np
+    import jax
+
+    import paddle_tpu as paddle
+
+    on_tpu = any(d.platform != "cpu" for d in jax.devices())
+    for name in names:
+        print(json.dumps(globals()[f"bench_{name}"](paddle, jax, np, on_tpu)),
+              flush=True)
+
+
 if __name__ == "__main__":
-    main()
+    if len(sys.argv) > 1:
+        only(sys.argv[1:])
+    else:
+        main()

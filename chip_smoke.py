@@ -10,7 +10,10 @@ with seeded random weights:
 * serve phase — the same weights through ``paddle_tpu.serving.Engine`` at
   default flags with the KV pool filling what the weights leave: two client
   threads, mixed prompt lengths, one stream; checked against
-  ``model.generate``;
+  ``model.generate``; the decode program it compiled must hold the paged
+  attention kernel, one call a layer (no hidden fallback to the gather);
+  then a model of GPT-2's head width (64, which Mosaic does not take for that
+  kernel) must be given the gather step by the engine, and serve;
 * four-chip phase (when the host has four chips) — the same model under
   ``fleet`` dp2 x mp2 through ``HybridParallelEngine`` and under
   ``Engine(tp=4)``.
@@ -39,10 +42,10 @@ PROMPT_LEN = (64, 512)
 # them or some layer ran XLA's exact attention unseen
 FLASH_CALLS_PER_LAYER = 3
 # HBM left free beside weights and KV pool for the programs' own temporaries.
-# The largest program the engine can build at default flags (decode at 64
-# rows x 2k context, every layer's gathered context live at once) compiles to
-# 1.57 GB of temporaries beside a full pool on this chip; the rest is margin
-# for the reference generate() and allocator fragmentation.
+# The decode step reads K/V through the block-table kernel (tens of MB of
+# temporaries, printed by the serve phase); the widest prefill program, the
+# reference generate() and allocator fragmentation take the rest. The
+# benchmark's serving cell sizes its pool with the same headroom.
 SERVE_HEADROOM_BYTES = 5 * 2**29  # 2.5 GiB
 # Greedy decoding through two correct bf16 programs may part ways at a
 # near-tie: logits are bf16 (8 significant bits) and each path rounds the
@@ -122,12 +125,53 @@ def _backend_compiles():
     return seen
 
 
+def kernel_calls(compiled):
+    """Mosaic kernel calls in the optimized HLO of a compiled program.
+    (Counted after compilation: the StableHLO shares one function between
+    identical layers.)"""
+    return compiled.as_text().count('custom_call_target="tpu_custom_call"')
+
+
 def count_flash_calls(lowered):
-    """Mosaic kernel calls in the optimized HLO of a lowered step. (Counted
-    after compilation: the StableHLO shares one function between identical
-    layers. With the compile cache on, this second compile is a cache hit.)"""
-    return lowered.compile().as_text().count(
-        'custom_call_target="tpu_custom_call"')
+    """Kernel calls of a lowered step. With the compile cache on, this
+    second compile is a cache hit."""
+    return kernel_calls(lowered.compile())
+
+
+def decode_program_report(eng, n_layers):
+    """The widest decode program the engine holds, compiled again from its
+    shapes (a cache hit where the compile cache is on). Where the engine
+    chose the block-table kernel it must read K/V through it, one call a
+    layer, never through a fallback to the gather; where it chose the gather
+    (a head width Mosaic does not take) it holds no kernel call. Logs the
+    program's temporaries."""
+    import jax
+
+    key = max(k for k in eng._fns if k[0] == "decode")
+    _, rows, width = key
+
+    def shape_of(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
+
+    # tables, positions, tokens, temperatures and the key go in uncommitted,
+    # as the engine passes them
+    small = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
+        ((rows, width), np.int32), ((rows,), np.int32), ((rows,), np.int32),
+        ((rows,), np.float32), (eng._key.shape, eng._key.dtype))]
+    compiled = eng._fns[key].lower(
+        jax.tree_util.tree_map(shape_of, eng._compute_params),
+        shape_of(eng._kpool), shape_of(eng._vpool), *small).compile()
+    calls = kernel_calls(compiled)
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    log(f"  decode program {key}: {calls} kernel call(s) for {n_layers} "
+        f"layers, temporaries {temps / 2**20:.1f} MiB beside a pool of "
+        f"{eng._kpool.shape[1]} blocks")
+    if eng._paged_kernel:
+        assert width == eng._max_blocks, "the decode table is not full width"
+        assert calls == n_layers, \
+            "the decode program does not read K/V through the paged kernel"
+    else:
+        assert calls == 0, "a kernel call in the gather step"
 
 
 # -- train phase --------------------------------------------------------------
@@ -264,6 +308,8 @@ def serve_phase(model, prompts, num_blocks, compiles):
         f"{len(prompts)} requests from {N_CLIENTS} threads, prompt lengths "
         f"{[len(p) for p in prompts]}, {NEW_TOKENS} new tokens, greedy")
     with Engine(model, num_blocks=num_blocks) as eng:
+        assert eng._paged_kernel, \
+            "head width 128 on a TPU: the engine must choose the paged kernel"
         t0 = time.monotonic()
         outs = run_wave(eng, prompts)
         wave1_s = time.monotonic() - t0
@@ -302,6 +348,7 @@ def serve_phase(model, prompts, num_blocks, compiles):
         stats = eng.stats()
         assert stats["pages_used"] == 0 and stats["running"] == 0, stats
         eng._pool.check()
+        decode_program_report(eng, model.config.num_layers)
 
         for i in (0, 1):  # the shortest and the longest prompt
             ref = model.generate(paddle.to_tensor(np.asarray([prompts[i]])),
@@ -312,6 +359,36 @@ def serve_phase(model, prompts, num_blocks, compiles):
                 f"engine vs generate(), prompt of {len(prompts[i])}")
         _hbm("serve")
     return outs
+
+
+def narrow_serve_phase():
+    """GPT-2's widths (768 / 12 heads: head width 64) at 2 layers. Mosaic
+    takes the paged kernel at multiples of 128 only, so the engine must hand
+    this model the gather step, and serve it."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models.gpt import GPTConfig
+    from paddle_tpu.serving import Engine
+
+    cfg = GPTConfig(num_layers=2, hidden_dropout=0.0, attention_dropout=0.0)
+    model = make_model(cfg)
+    model.eval()
+    rng = np.random.default_rng(SEED + 2)
+    prompts = [rng.integers(0, cfg.vocab_size, n).tolist() for n in (24, 200)]
+    head = cfg.hidden_size // cfg.num_heads
+    log(f"serve, head width {head}: serving.Engine, default flags")
+    with Engine(model, num_blocks=256) as eng:
+        assert not eng._paged_kernel, \
+            f"the engine chose the paged kernel at head width {head}"
+        handles = [eng.submit(p, max_new_tokens=NEW_TOKENS, temperature=0.0)
+                   for p in prompts]
+        outs = [h.result(timeout=900) for h in handles]
+        decode_program_report(eng, cfg.num_layers)
+        for p, o in zip(prompts, outs):
+            ref = model.generate(paddle.to_tensor(np.asarray([p])),
+                                 max_new_tokens=NEW_TOKENS, do_sample=False)
+            assert_same_or_near_tie(
+                eng, o, [int(t) for t in np.asarray(ref.numpy())[0]],
+                f"engine vs generate(), prompt of {len(p)}")
 
 
 # -- four-chip phase ----------------------------------------------------------
@@ -329,6 +406,7 @@ def tp_serve_phase(model, prompts, num_blocks, one_chip_outs):
         log(f"  KV pool sharded {eng._kpool.sharding.spec} over "
             f"{len(eng._kpool.sharding.device_set)} devices; "
             f"{stats['compiles']} programs")
+        decode_program_report(eng, model.config.num_layers)
         for i, (a, b) in enumerate(zip(outs, one_chip_outs)):
             assert_same_or_near_tie(
                 eng, a, b, f"tp=4 vs one chip, request {i}")
@@ -395,6 +473,8 @@ def main():
     outs = serve_phase(model, prompts, num_blocks, compiles)
     gc.collect()  # ... and the engine with its pool
     _hbm("closing the engine")
+    narrow_serve_phase()
+    gc.collect()
 
     if device["count"] >= 4:
         tp_serve_phase(model, prompts, num_blocks, outs)

@@ -171,14 +171,15 @@ class CostModel:
             spill = 4.0 if vmem > 16 * 1024 * 1024 else 1.0
         elif name == "paged_attention":
             b, mb, bs, kv, rep, d, dtype = key
-            r = int(config["rows_per_program"])
-            t_pad = mb * bs
-            # "live" scores ~half the padded context on average; "full" all
-            frac = 0.5 if config.get("score_mode") == "live" else 1.0
-            flops = 4.0 * b * kv * rep * t_pad * d * frac
-            bytes_ = 2.0 * b * t_pad * kv * d * 2.0 + b * kv * rep * d * 4
-            progs = b // max(r, 1)
-            vmem = 2 * t_pad * kv * d * 4 * r
+            c = int(config["blocks_per_chunk"])
+            # a row reads its live blocks, about half the table on average,
+            # rounded up to whole chunks of MXU work
+            t_live = pad(max(mb // 2, 1), c) * bs
+            flops = 4.0 * b * kv * rep * t_live * kv * d
+            bytes_ = 2.0 * b * t_live * kv * d * 2.0 + b * kv * rep * d * 4
+            progs = b  # one row a grid step
+            # two double-buffered chunks of K and of V
+            vmem = 4 * c * bs * kv * d * 4
             spill = 4.0 if vmem > 8 * 1024 * 1024 else 1.0
         elif name == "int8_matmul":
             m, k_dim, n, transpose_w, dtype = key

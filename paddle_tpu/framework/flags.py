@@ -255,13 +255,12 @@ _FLAGS: Dict[str, object] = {
     "FLAGS_kernel_tune_dir": "",
     "FLAGS_kernel_tune_budget_s": 20.0,
     "FLAGS_kernel_tune_samples": 5,
-    # Serving kernel kill-switches. FLAGS_serve_paged_kernel routes engine
-    # decode through the paged-attention Pallas kernel (reads K/V straight
-    # from PagePool blocks — bit-identical to the gather path; spec-decode
-    # keeps the gather). FLAGS_serve_int8_kernel keeps the int8 LM-head
-    # weight quantized end-to-end via the fused int8 matmul kernel instead
-    # of dequantizing it densely each step.
-    "FLAGS_serve_paged_kernel": False,
+    # Serving kernel kill-switch. FLAGS_serve_int8_kernel keeps the int8
+    # LM-head weight quantized end-to-end via the fused int8 matmul kernel
+    # instead of dequantizing it densely each step. (The paged-attention
+    # decode kernel has no flag: the engine builds it wherever Mosaic
+    # compiles it, by backend and head width: models/generation.py
+    # paged_kernel_default.)
     "FLAGS_serve_int8_kernel": False,
 }
 

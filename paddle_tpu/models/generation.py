@@ -809,22 +809,27 @@ def build_paged_decode(arch, B, block_size, max_blocks):
 
 
 def build_paged_decode_kernel(arch, B, block_size, max_blocks):
-    """``build_paged_decode`` with the attention read done by the
-    block-table-aware Pallas kernel (``ops/kernels/paged_attention``)
-    instead of the gather-then-dense path. Same step signature, same
-    sampling — a drop-in the engine selects behind FLAGS_serve_paged_kernel.
+    """``build_paged_decode`` with the attention read done by the block-table
+    Pallas kernel (``ops/kernels/paged_attention``) instead of the
+    gather-then-dense path: same step signature, same sampling. The engine
+    builds this step for kind ``decode`` wherever Mosaic compiles
+    (:func:`paged_kernel_default`).
 
-    Differences from the gather builder, neither visible in the output:
-    - no ``kpool[li, tables]`` HBM materialization — the kernel DMAs each
-      row's blocks straight out of the pool;
+    Differences from the gather builder:
+    - no ``kpool[li, tables]`` is materialized: the kernel copies each row's
+      LIVE blocks straight out of the whole pool, so the step's work follows
+      ``pos`` and ``max_blocks`` is only the width of the table it is handed;
     - the fresh K/V is scattered into the pool BEFORE the kernel reads it
-      (the gather path overwrites the gathered copy at ``pos`` in-context —
-      same values land in the same slot, so attention sees identical state).
-    The surrounding per-layer math is the same ``block_rows`` code factored
-    into ``qkv_rows``/``attn_out_rows``, so the whole step is bit-identical
-    to the gather builder on the CPU tier (kernel in interpret mode)."""
-    KV, D = arch["kv_heads"], arch["head_dim"]
-
+      (the gather path overwrites the gathered copy at ``pos`` in-context:
+      the same values in the same slot). Each layer's kernel call stands
+      between that layer's scatter and the next one's in the data flow, so
+      XLA updates the donated pool in place (``tests/test_tpu_lowering.py``
+      holds the step's temporaries under 1 GB beside a pool that fills the
+      chip);
+    - the online softmax sums in another order: outputs agree with the gather
+      builder within the kernel's stated tolerance, not bit for bit.
+    The per-layer math around the read is ``block_rows``' own, factored into
+    ``qkv_rows``/``attn_out_rows``."""
     def step(params, kpool, vpool, tables, pos, toks, temps, key):
         from ..ops.kernels import paged_attention_rows
 
@@ -836,7 +841,8 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
             q, k_new, v_new = arch["qkv_rows"](w, x, pos)
             kpool = kpool.at[li, bids, offs].set(k_new)
             vpool = vpool.at[li, bids, offs].set(v_new)
-            o = paged_attention_rows(q, kpool[li], vpool[li], tables, pos)
+            with jax.named_scope("attention"):
+                o = paged_attention_rows(q, kpool, vpool, li, tables, pos)
             x = arch["attn_out_rows"](w, x, o[:, None])
         with jax.named_scope("head"):
             logits = arch["head"](params, x)
@@ -847,6 +853,24 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
         return kpool, vpool, nxt
 
     return step
+
+
+def paged_kernel_default(arch, mosaic=None) -> bool:
+    """Whether the engine's ``decode`` program for ``arch`` reads K/V through
+    the block-table kernel (:func:`build_paged_decode_kernel`): wherever
+    Mosaic compiles it. That is a matter of the backend (``mosaic``: chosen
+    as the flash kernel is chosen, by ``interpret_default``; under the Pallas
+    interpreter of the CPU tier the kernel is only a slower way to the same
+    numbers) and of the arch's head width, which Mosaic takes in multiples of
+    128 (``paged_attention.mosaic_takes``): GPT-3 XL and up, the Llamas. A
+    narrower model (GPT-2, GPT-3 up to 2.7B, the tiny test models) keeps the
+    gather step on the chip too."""
+    from ..ops.kernels.paged_attention import mosaic_takes
+    from ..ops.pallas import interpret_default
+
+    if mosaic is None:
+        mosaic = not interpret_default()
+    return bool(mosaic) and mosaic_takes(arch["head_dim"])
 
 
 def kv_block_checksums(kpool, vpool, bids):
@@ -1318,10 +1342,10 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
     ``use_kernel``): same step signature with the packed param tree from
     :func:`tp_pack_params` in place of ``params``, kpool/vpool tp-sharded on
     the kv-heads axis, tables/pos/toks/temps/key replicated. Greedy tokens
-    are bit-identical to the single-chip builders (see the section comment);
-    the paged-attention kernel path works unchanged on the local shard —
-    its block DMA reads local (NB, BS, KVl, D) pools and H/KV keeps the
-    same GQA ratio."""
+    equal the single-chip builders' (see the section comment); the
+    paged-attention kernel is a drop-in on the local shard: its block copies
+    read the chip's (L, NB, BS, KVl, D) pools and H/KV keeps the same GQA
+    ratio."""
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape["tp"]
@@ -1349,8 +1373,9 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
                 q, k, v = arch["qkv"](rwl, swl, x, pos[:, None])
                 kpool = kpool.at[li, bids, offs].set(k[:, 0])
                 vpool = vpool.at[li, bids, offs].set(v[:, 0])
-                o = paged_attention_rows(q[:, 0], kpool[li], vpool[li],
-                                         tables, pos)
+                with jax.named_scope("attention"):
+                    o = paged_attention_rows(q[:, 0], kpool, vpool, li,
+                                             tables, pos)
                 x = arch["post_attn"](rwl, swl, x, o[:, None])
         else:
             live = jnp.arange(T_pad)[None, :] <= pos[:, None]

@@ -123,7 +123,13 @@ def counters() -> Dict[str, int]:
     exhaustion), ``serve_preempted`` (sequences evicted for re-prefill),
     ``serve_occupancy_live`` / ``serve_occupancy_slots`` (live rows vs
     padded batch slots per decode step — their ratio is mean batch
-    occupancy), and ``serve_engine_errors``. Live gauges (queue depth,
+    occupancy), ``serve_decode_blocks_read`` (KV blocks the block-table
+    kernel's decode steps read: ``decode_build``'s ``blocks_live``, what the
+    rows hold, summed over those steps; over slots x the engine's table
+    width it is the share of a padded read that was needed. Steps that
+    gather, the speculative verify and decode where the engine keeps the
+    gather builder, read bucket x gather width whatever is live and do not
+    move it), and ``serve_engine_errors``. Live gauges (queue depth,
     page-pool utilization, in-flight request table) come from
     ``Engine.stats()`` and ride every flight-recorder dump via the
     engine's context provider.
@@ -286,7 +292,8 @@ KNOWN_COUNTERS = frozenset({
     "serve_admitted", "serve_adoptions", "serve_backpressure",
     "serve_cancelled", "serve_compiles", "serve_cow_copies",
     "serve_crash_detected", "serve_deadline_expired",
-    "serve_deadline_shed", "serve_decode_steps",
+    "serve_deadline_shed", "serve_decode_blocks_read",
+    "serve_decode_steps",
     "serve_draft_accepted", "serve_draft_proposed",
     "serve_engine_errors", "serve_failed", "serve_handoffs",
     "serve_http_bind_failed", "serve_http_requests",

@@ -15,8 +15,10 @@ programs in ``models/generation.py``:
   ONCE per engine (``serve_compiles``) and warm executables reuse the
   persistent compilation cache across processes (PR 1);
 * **paged KV cache** — fixed-size KV blocks in a preallocated pool, a
-  per-sequence block table, gather-based paged attention reads
-  (``build_paged_decode``), so HBM holds ``Σ ceil(len/block)`` blocks
+  per-sequence block table read by the block-table attention kernel
+  (``build_paged_decode_kernel``: only the blocks a row has live; the
+  gather step ``build_paged_decode`` is its plain reference and the CPU
+  tier's decode), so HBM holds ``Σ ceil(len/block)`` blocks
   instead of ``B × T_max`` dense caches. Pool exhaustion is backpressure:
   admission stalls the queue, and a running sequence that can't grow evicts
   the youngest peer (freed blocks, state requeued for re-prefill from its
@@ -674,6 +676,10 @@ class Engine:
         self._fns: Dict[tuple, object] = {}
         # per-decode-bucket gather width (blocks), high-water, pow2-rounded
         self._decode_mb: Dict[int, int] = {}
+        # decode reads K/V through the block-table kernel wherever Mosaic
+        # compiles it for this arch (backend and head width); the
+        # speculative and tail-prefill programs gather
+        self._paged_kernel = bool(self._G.paged_kernel_default(arch))
         self._running: List[_Seq] = []
         self._resume: List[_Seq] = []  # preempted, awaiting re-prefill
         self._admitting: List[_Seq] = []  # popped off the queue, mid-prefill
@@ -2145,9 +2151,12 @@ class Engine:
             counter_inc("serve_preempted")
 
     def _gather_width(self, bb: int) -> int:
-        """Per-decode-bucket gather width (ROADMAP item 1 leftover): the
-        compiled step gathers this many blocks per row instead of the
-        engine-wide ``_max_blocks`` — sized to the bucket's HIGH-WATER live
+        """Per-decode-bucket gather width, for the steps that GATHER their
+        context (the speculative verify, and decode where the engine keeps
+        the gather builder; the kernel decode step takes the table whole and
+        never comes here). The compiled step gathers this many blocks per
+        row instead of the engine-wide ``_max_blocks`` — sized to the
+        bucket's HIGH-WATER live
         block count, rounded up to a power of two (recompiles bounded at
         log2 per bucket), never shrinking. A width upgrade REPLACES the
         bucket's compiled entry, so ``stats()['compiles']`` stays bounded
@@ -2205,7 +2214,7 @@ class Engine:
         """``decode_build``: map the blocks the step will write, guard shared
         ones, choose the bucket and fill the step's host arrays (``k`` draft
         columns beside each row's pending token). Returns None when growing
-        preempted every row, else (rows, bucket, gather width, drafts,
+        preempted every row, else (rows, bucket, table width, drafts,
         tables, positions, tokens, temperatures)."""
         with span("decode_build") as sp:
             grown = self._grow_blocks()
@@ -2217,8 +2226,15 @@ class Engine:
                 for s in self._running:
                     self._cow_guard(s)
             bb = next(b for b in self.config.decode_buckets if b >= n)
-            mb = self._gather_width(bb)
-            sp.set(bucket=bb)
+            # the kernel step's work follows each row's live blocks, so it
+            # takes the table whole: one program a bucket, no regrowth
+            kernel_step = self._paged_kernel and not k
+            mb = self._max_blocks if kernel_step else self._gather_width(bb)
+            blocks_live = sum(len(s.blocks) for s in self._running)
+            sp.set(bucket=bb, blocks_live=blocks_live)
+            if kernel_step:
+                # a gathering step reads bucket x width blocks, live or not
+                counter_inc("serve_decode_blocks_read", blocks_live)
             drafts = self._propose(bb) if k else None
             tables = np.full((bb, mb), TRASH_BLOCK, np.int32)
             pos = np.zeros((bb,), np.int32)
@@ -2535,9 +2551,7 @@ class Engine:
                     bb, mb = bucket
                     raw = G.build_tp_paged_decode(
                         self._arch_key, bb, self.config.block_size, mb,
-                        use_kernel=bool(
-                            flags.flag("FLAGS_serve_paged_kernel", False)),
-                        **tpkw)
+                        use_kernel=self._paged_kernel, **tpkw)
                     donate = (1, 2)
                 else:  # spec/draft excluded by EngineConfig validation
                     raise RuntimeError(
@@ -2575,14 +2589,9 @@ class Engine:
                 return fn
             else:
                 bb, mb = bucket
-                # opt-in Pallas paged-attention decode (bit-identical to the
-                # gather builder; spec-decode above keeps the gather path)
-                if flags.flag("FLAGS_serve_paged_kernel", False):
-                    raw = G.build_paged_decode_kernel(
-                        self._arch, bb, self.config.block_size, mb)
-                else:
-                    raw = G.build_paged_decode(
-                        self._arch, bb, self.config.block_size, mb)
+                build = (G.build_paged_decode_kernel if self._paged_kernel
+                         else G.build_paged_decode)
+                raw = build(self._arch, bb, self.config.block_size, mb)
                 donate = (1, 2)
             if self._dequant is not None:
                 dq, inner = self._dequant, raw

@@ -2,9 +2,12 @@
 test_serving_resilience.py, test_serving_chaos.py): ONE tiny-GPT config,
 one prompt generator, one engine-kwargs base — change the model here and
 all three suites move together instead of silently diverging."""
+import contextlib
+
 import numpy as np
 
 import paddle_tpu as paddle
+import paddle_tpu.models.generation as G
 from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
 
 # 64 usable blocks of 8 tokens, 8-wide decode, 128-token sequences — small
@@ -27,3 +30,22 @@ def tiny_gpt(seed=0):
 def make_prompts(n, rng, lo=3, hi=24):
     return [rng.randint(0, 211, (int(rng.randint(lo, hi)),)).tolist()
             for _ in range(n)]
+
+
+@contextlib.contextmanager
+def paged_kernel(on):
+    """Engines BUILT inside read decode K/V through the block-table kernel
+    (``True``: at any head width, which the interpreter runs), through the
+    gather (``False``), or as the chip would choose for their arch
+    (``"mosaic"``: the kernel where Mosaic takes the head width, run here by
+    the interpreter). Patches the one function that chooses
+    (``generation.paged_kernel_default``; on this tier it says gather)."""
+    real = G.paged_kernel_default
+    if on == "mosaic":
+        G.paged_kernel_default = lambda arch, mosaic=None: real(arch, True)
+    else:
+        G.paged_kernel_default = lambda arch, mosaic=None: bool(on)
+    try:
+        yield
+    finally:
+        G.paged_kernel_default = real

@@ -35,7 +35,7 @@ from paddle_tpu.profiler import counters
 PINNED = {
     "flash_attention": {"block_q": 512, "block_k": 512},
     "fused_ce": {"block_rows": 2048},
-    "paged_attention": {"rows_per_program": 1, "score_mode": "live"},
+    "paged_attention": {"blocks_per_chunk": 8},
     "int8_matmul": {"block_n": 512},
 }
 
@@ -122,11 +122,11 @@ class TestInertOff:
 
         np.asarray(flash_attention_array(q, q, q, causal=True))
 
-        kpool = jnp.asarray(rng.randn(16, 8, 2, 16), jnp.float32)
+        kpool = jnp.asarray(rng.randn(1, 16, 8, 2, 16), jnp.float32)
         tables = jnp.asarray(rng.randint(1, 16, (2, 2)), jnp.int32)
         pos = jnp.asarray([3, 9], jnp.int32)
         qr = jnp.asarray(rng.randn(2, 4, 16), jnp.float32)
-        np.asarray(K.paged_attention_rows(qr, kpool, kpool, tables, pos))
+        np.asarray(K.paged_attention_rows(qr, kpool, kpool, 0, tables, pos))
 
         qw = jnp.asarray(rng.randint(-127, 127, (32, 16)), jnp.int8)
         np.asarray(K.int8_matmul(jnp.asarray(rng.randn(3, 16), jnp.float32),

@@ -1,14 +1,15 @@
-"""Paged-attention decode kernel + int8 head kernel — serving bit-identity.
+"""Paged-attention decode kernel + int8 head kernel: the serving kernels.
 
-The ISSUE-18 acceptance surface for the two new serving kernels:
-
-- ``ops/kernels/paged_attention`` reads K/V straight from PagePool blocks
-  through the block table (no gather-then-dense-attend) and must be
-  **bit-identical** to the existing gather path — at the kernel level
-  against the same ``_grouped_attention`` math, at the builder level
-  (``build_paged_decode_kernel`` vs ``build_paged_decode``, GPT and
-  Llama/GQA), and engine end-to-end behind ``FLAGS_serve_paged_kernel``
-  (prefix cache on and off). CPU runs the kernel in Pallas interpret mode.
+- ``ops/kernels/paged_attention`` reads K/V straight from the PagePool
+  blocks through the block table, only the blocks a row has live, and must
+  agree with the gather path (``build_paged_decode``, the plain reference)
+  within the float32 tolerance its docstring states: at the kernel level
+  against the same ``_grouped_attention`` math over ragged tables, at the
+  builder level (``build_paged_decode_kernel`` vs ``build_paged_decode``,
+  GPT and Llama/GQA), and engine end to end with EQUAL greedy token streams
+  on the seeded tiny models (prefix cache on and off). The engine chooses
+  the builder by backend (``generation.paged_kernel_default``); the tests
+  patch that one function. CPU runs the kernel in Pallas interpret mode.
 - ``ops/kernels/int8_matmul`` (weight-only int8 head matmul behind
   ``FLAGS_serve_int8_kernel``) must match the dequantize-then-matmul it
   replaces bitwise, and the engine's int8 path must produce identical
@@ -19,23 +20,32 @@ import pytest
 
 import paddle_tpu as paddle
 import paddle_tpu.models.generation as G
+from paddle_tpu import profiler
 from paddle_tpu.framework import flags
 from paddle_tpu.ops import kernels as K
 from paddle_tpu.serving import Engine
-from serving_util import ENGINE_KW, make_prompts, tiny_gpt
+from paddle_tpu.serving.pool import TRASH_BLOCK
+from serving_util import ENGINE_KW, make_prompts, paged_kernel, tiny_gpt
 
 jnp = pytest.importorskip("jax.numpy")
 import jax  # noqa: E402
 
 
-def _ref_paged(q, kpool, vpool, tables, pos):
-    """The existing serving read: gather context via the block table, then
+def _close(out, ref):
+    """The kernel's stated float32 tolerance against the gather path."""
+    out, ref = np.asarray(out), np.asarray(ref)
+    tol = 2e-5 * np.abs(ref).max() + 2e-6
+    return np.abs(out - ref).max() <= tol
+
+
+def _ref_paged(q, kpool, vpool, layer, tables, pos):
+    """The gather path's read: gather context via the block table, then
     dense grouped attention over live positions."""
     B, H, D = q.shape
-    NB, BS, KV, _ = kpool.shape
+    _, NB, BS, KV, _ = kpool.shape
     T_pad = tables.shape[1] * BS
-    kc = kpool[tables].reshape(B, T_pad, KV, D)
-    vc = vpool[tables].reshape(B, T_pad, KV, D)
+    kc = kpool[layer, tables].reshape(B, T_pad, KV, D)
+    vc = vpool[layer, tables].reshape(B, T_pad, KV, D)
     live = jnp.arange(T_pad)[None, :] <= pos[:, None]
     o = G._grouped_attention(q[:, None], kc, vc,
                              live[:, None, None, None, :], H // KV)
@@ -49,30 +59,85 @@ def _disjoint_tables(rng, B, MB, NB):
     return jnp.asarray(perm.reshape(B, MB).astype(np.int32))
 
 
-class TestPagedKernelBitIdentity:
+# ragged batches the engine produces; MB = 5 blocks of 8 tokens a row
+_MB, _BS = 5, 8
+_RAGGED = {
+    # live tokens a row (0 = a dead row: pos 0, every column at the trash)
+    "one_block": [1, 5, 8],
+    "at_max_blocks": [40, 33, 40],
+    "not_a_power_of_two": [17, 24, 23, 9, 20],
+    "dead_row_on_trash": [12, 0, 30, 0],
+    "prefix_shared_block": [19, 27, 11],
+}
+
+
+def _ragged_batch(case, rng, NB):
+    lens = _RAGGED[case]
+    B = len(lens)
+    tables = np.full((B, _MB), TRASH_BLOCK, np.int32)
+    pos = np.zeros((B,), np.int32)
+    free = list(rng.permutation(np.arange(1, NB)))
+    for b, n in enumerate(lens):
+        if n:
+            nb = -(-n // _BS)
+            tables[b, :nb] = [free.pop() for _ in range(nb)]
+            pos[b] = n - 1
+    if case == "prefix_shared_block":
+        # rows 0 and 1 read the same first block (a cached prompt head)
+        tables[1, 0] = tables[0, 0]
+    return tables, pos
+
+
+class TestPagedKernelAgainstGather:
     @pytest.mark.parametrize("heads", [(4, 4), (8, 2)],
                              ids=["mha", "gqa_rep4"])
     def test_kernel_matches_gather_reference(self, heads):
         H, KV = heads
-        B, D, BS, MB, NB = 4, 16, 8, 4, 64
+        B, D, BS, MB, NB, L = 4, 16, 8, 4, 64, 3
         rng = np.random.RandomState(1)
-        kpool = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
-        vpool = jnp.asarray(rng.randn(NB, BS, KV, D), jnp.float32)
+        kpool = jnp.asarray(rng.randn(L, NB, BS, KV, D), jnp.float32)
+        vpool = jnp.asarray(rng.randn(L, NB, BS, KV, D), jnp.float32)
         tables = jnp.asarray(rng.randint(1, NB, size=(B, MB)), jnp.int32)
         pos = jnp.asarray([3, 8, 17, 31], jnp.int32)
         q = jnp.asarray(rng.randn(B, H, D), jnp.float32)
-        ref = np.asarray(_ref_paged(q, kpool, vpool, tables, pos))
-        for score_mode in ("live", "full"):
-            for rows in (1, 2, 4):
+        for layer in (0, 2):
+            ref = _ref_paged(q, kpool, vpool, layer, tables, pos)
+            for chunk in (1, 3, 8):
                 out = K.paged_attention_rows(
-                    q, kpool, vpool, tables, pos,
-                    config={"rows_per_program": rows,
-                            "score_mode": score_mode})
-                assert np.array_equal(np.asarray(out), ref), \
-                    (score_mode, rows)
+                    q, kpool, vpool, layer, tables, pos,
+                    config={"blocks_per_chunk": chunk})
+                assert _close(out, ref), (layer, chunk)
+
+    @pytest.mark.parametrize("rep", [1, 4], ids=["rep1", "rep4"])
+    @pytest.mark.parametrize("case", sorted(_RAGGED))
+    def test_ragged_tables(self, case, rep):
+        """Only a row's live blocks are read: whatever sits behind them in
+        the table (here a block of NaN) reaches no output."""
+        KV, D, NB, L = 2, 16, 32, 2
+        H = KV * rep
+        rng = np.random.RandomState(5)
+        tables, pos = _ragged_batch(case, rng, NB - 1)
+        kpool = rng.randn(L, NB, _BS, KV, D).astype(np.float32)
+        vpool = rng.randn(L, NB, _BS, KV, D).astype(np.float32)
+        q = jnp.asarray(rng.randn(len(pos), H, D), jnp.float32)
+        ref = _ref_paged(q, jnp.asarray(kpool), jnp.asarray(vpool), 1,
+                         jnp.asarray(tables), jnp.asarray(pos))
+        # columns behind the live count point at a poisoned block: the
+        # gather would read it (and mask it); the kernel must not touch it
+        poisoned = tables.copy()
+        for b, p_ in enumerate(pos):
+            poisoned[b, p_ // _BS + 1:] = NB - 1
+        kpool[:, NB - 1] = np.nan
+        vpool[:, NB - 1] = np.nan
+        for chunk in (2, 8):
+            out = K.paged_attention_rows(
+                q, jnp.asarray(kpool), jnp.asarray(vpool), 1,
+                jnp.asarray(poisoned), jnp.asarray(pos),
+                config={"blocks_per_chunk": chunk})
+            assert _close(out, ref), (case, rep, chunk)
 
     @pytest.mark.parametrize("which", ["gpt", "llama_gqa"])
-    def test_builder_bitwise_vs_gather_builder(self, which):
+    def test_builder_within_tolerance_of_gather_builder(self, which):
         if which == "gpt":
             _, arch, params, _ = G.gpt_decode_state(tiny_gpt(seed=0))
             vocab = 211
@@ -99,16 +164,18 @@ class TestPagedKernelBitIdentity:
         ker = jax.jit(G.build_paged_decode_kernel(arch, B, BS, MB))
         r = ref(params, kpool, vpool, tables, pos, toks, temps, key)
         k = ker(params, kpool, vpool, tables, pos, toks, temps, key)
-        for a, b, name in zip(r, k, ("kpool", "vpool", "next_tokens")):
-            assert np.array_equal(np.asarray(a), np.asarray(b)), name
+        assert _close(k[0], r[0]) and _close(k[1], r[1])
+        assert np.array_equal(np.asarray(k[2]), np.asarray(r[2]))
 
 
-def _run_engine(prompt_seed=3, n=4, max_new=8, **fl):
-    """Token outputs of a fresh tiny-GPT engine under flag overrides."""
+def _run_engine(prompt_seed=3, n=4, max_new=8, kernel=False, **fl):
+    """Token outputs of a fresh tiny-GPT engine under flag overrides, its
+    decode program built with the kernel step or the gather step."""
     old = {k: flags._FLAGS.get(k) for k in fl}
     flags._FLAGS.update(fl)
     try:
-        with Engine(tiny_gpt(seed=0), **ENGINE_KW) as eng:
+        with paged_kernel(kernel), Engine(tiny_gpt(seed=0),
+                                          **ENGINE_KW) as eng:
             prompts = make_prompts(n, np.random.RandomState(prompt_seed))
             handles = [eng.submit(p, max_new_tokens=max_new, temperature=0.0)
                        for p in prompts]
@@ -125,15 +192,15 @@ class TestEnginePagedKernel:
     @pytest.mark.parametrize("prefix_cache", [False, True],
                              ids=["plain", "prefix_cache"])
     def test_engine_tokens_identical_with_kernel(self, prefix_cache):
-        base = _run_engine(FLAGS_serve_paged_kernel=False,
+        base = _run_engine(kernel=False,
                            FLAGS_serve_prefix_cache=prefix_cache)
-        kern = _run_engine(FLAGS_serve_paged_kernel=True,
+        kern = _run_engine(kernel=True,
                            FLAGS_serve_prefix_cache=prefix_cache)
         assert base == kern
 
     def test_engine_actually_builds_kernel_step(self, monkeypatch):
-        """The flag must really swap the decode builder (not silently keep
-        the gather path)."""
+        """The choice must really swap the decode builder (no hidden
+        fallback to the gather), and on the CPU tier it is the gather."""
         called = {"n": 0}
         real = G.build_paged_decode_kernel
 
@@ -142,9 +209,123 @@ class TestEnginePagedKernel:
             return real(*a, **k)
 
         monkeypatch.setattr(G, "build_paged_decode_kernel", spy)
-        out = _run_engine(FLAGS_serve_paged_kernel=True)
+        _, arch, _, _ = G.gpt_decode_state(tiny_gpt(seed=0))
+        assert G.paged_kernel_default(arch) is False  # this tier interprets
+        c0 = profiler.counters().get("serve_decode_blocks_read", 0)
+        base = _run_engine()  # the backend's own choice
+        assert called["n"] == 0
+        # a gathering step reads bucket x width blocks, not the live ones
+        assert profiler.counters().get("serve_decode_blocks_read", 0) == c0
+        out = _run_engine(kernel=True)
         assert called["n"] >= 1
-        assert out == _run_engine(FLAGS_serve_paged_kernel=False)
+        assert out == base
+
+    def test_one_full_width_program_a_bucket_and_blocks_counted(self):
+        """The kernel step takes the table ``_max_blocks`` wide, so a bucket
+        has ONE decode program however long its rows grow (no
+        ``_gather_width`` regrowth), and ``decode_build``'s ``blocks_live``
+        / ``serve_decode_blocks_read`` count what the tables hold."""
+        from paddle_tpu.profiler import spans
+
+        rows, held = [], []
+        spans.add_span_observer(rows.append)
+        c0 = profiler.counters().get("serve_decode_blocks_read", 0)
+        try:
+            with paged_kernel(True), Engine(tiny_gpt(seed=0),
+                                            **ENGINE_KW) as eng:
+                real = eng._decode_build
+
+                def build(k=0):
+                    built = real(k)
+                    if built is not None:
+                        held.append(int(np.count_nonzero(
+                            built[4] != TRASH_BLOCK)))
+                        assert built[4].shape[1] == eng._max_blocks
+                    return built
+
+                eng._decode_build = build
+                rng = np.random.RandomState(21)
+                # a short stream first, then one that crosses every power
+                # of two of blocks up to the sequence limit
+                eng.submit(rng.randint(0, 211, (4,)).tolist(),
+                           max_new_tokens=4).result(timeout=600)
+                eng.submit(rng.randint(0, 211, (10,)).tolist(),
+                           max_new_tokens=100).result(timeout=600)
+                decode_keys = [k for k in eng._fns if k[0] == "decode"]
+                assert decode_keys == [("decode", 1, eng._max_blocks)]
+                assert eng._decode_mb == {}
+        finally:
+            spans.remove_span_observer(rows.append)
+        live = [sp.attrs["blocks_live"] for sp in rows
+                if sp.name == "decode_build" and "bucket" in sp.attrs]
+        assert live == held and len(live) >= 100
+        assert max(live) == -(-(10 + 100 - 1) // ENGINE_KW["block_size"])
+        c1 = profiler.counters().get("serve_decode_blocks_read", 0)
+        assert c1 - c0 == sum(held)
+
+
+def _gpt_of_width(head_dim, seed=0):
+    """A two-head, two-layer GPT whose heads are ``head_dim`` wide."""
+    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
+
+    paddle.seed(seed)
+    m = GPTForPretraining(GPTConfig(
+        vocab_size=211, hidden_size=2 * head_dim, num_layers=2, num_heads=2,
+        max_position_embeddings=128, hidden_dropout=0.0,
+        attention_dropout=0.0))
+    m.eval()
+    return m
+
+
+class TestChosenByHeadWidth:
+    """Mosaic takes the kernel only where a K/V line fills whole 128-lane
+    rows (``tests/test_tpu_lowering.py`` holds the compiler's own answer);
+    the chooser sees the arch, so a narrower model keeps the gather step on
+    the chip instead of failing to build its decode program."""
+
+    @pytest.mark.parametrize("head_dim,takes", [
+        (32, False), (64, False), (80, False), (96, False), (128, True),
+        (256, True)])
+    def test_chooser_follows_backend_and_head_width(self, head_dim, takes):
+        arch = {"head_dim": head_dim, "kv_heads": 2}
+        assert G.paged_kernel_default(arch, mosaic=True) is takes
+        assert G.paged_kernel_default(arch, mosaic=False) is False
+        assert G.paged_kernel_default(arch) is False  # this tier interprets
+
+    def test_kernel_names_the_rule_where_mosaic_would_refuse(self):
+        z = jnp.zeros((2, 4, 8, 2, 64), jnp.float32)
+        with pytest.raises(ValueError, match="multiples of 128, not 64"):
+            K.paged_attention_rows(
+                jnp.zeros((1, 2, 64)), z, z, 0, jnp.zeros((1, 2), jnp.int32),
+                jnp.zeros((1,), jnp.int32), interpret=False)
+
+    @pytest.mark.parametrize("head_dim,kernel", [(64, False), (128, True)],
+                             ids=["d64_gathers", "d128_kernel"])
+    def test_engine_built_under_the_chips_rule_serves(self, head_dim, kernel,
+                                                      monkeypatch):
+        built = {"kernel": 0, "gather": 0}
+        for name, real in (("kernel", G.build_paged_decode_kernel),
+                           ("gather", G.build_paged_decode)):
+            def spy(*a, _name=name, _real=real, **k):
+                built[_name] += 1
+                return _real(*a, **k)
+
+            monkeypatch.setattr(
+                G, "build_paged_decode_kernel" if name == "kernel"
+                else "build_paged_decode", spy)
+        prompts = make_prompts(3, np.random.RandomState(11))
+        outs = {}
+        for mode in (False, "mosaic"):
+            with paged_kernel(mode), Engine(_gpt_of_width(head_dim),
+                                            **ENGINE_KW) as eng:
+                assert eng._paged_kernel is (kernel and mode == "mosaic")
+                outs[mode] = [
+                    eng.submit(p, max_new_tokens=6, temperature=0.0)
+                    .result(timeout=600) for p in prompts]
+        assert outs["mosaic"] == outs[False]
+        assert all(len(o) == len(p) + 6
+                   for o, p in zip(outs["mosaic"], prompts))
+        assert (built["kernel"] > 0) is kernel and built["gather"] > 0
 
 
 class TestInt8Kernel:
@@ -196,7 +377,6 @@ class TestInt8Kernel:
                            FLAGS_serve_int8_kernel=True)
         assert calls["n"] >= 1  # kernel on: the head traced through it
         assert base == kern
-        both = _run_engine(FLAGS_serve_int8=True,
-                           FLAGS_serve_int8_kernel=True,
-                           FLAGS_serve_paged_kernel=True)
+        both = _run_engine(kernel=True, FLAGS_serve_int8=True,
+                           FLAGS_serve_int8_kernel=True)
         assert base == both

@@ -6,8 +6,9 @@ Pins the ISSUE-19 acceptance surface:
   columns, the LM head, and the KV ``PagePool`` over a ``tp`` mesh axis via
   shard_map, with every tp boundary a CONCAT-style all_gather of
   column-partitioned outputs — greedy decode must be **bit-identical** to
-  the single-chip engine (GPT and Llama/GQA, ``FLAGS_serve_paged_kernel``
-  on and off, prefix cache on and off, engine int8 on).
+  the single-chip engine (GPT and Llama/GQA, the decode program built
+  with the block-table kernel and with the gather, prefix cache on and off,
+  engine int8 on).
 - ``FLAGS_serve_prefill_chunk`` splits prompt prefill into block-multiple
   chunks interleaved one per scheduler step with the live decode batch;
   the chunked path must be bit-identical to monolithic prefill (prefix
@@ -20,7 +21,8 @@ Pins the ISSUE-19 acceptance surface:
   and never called.
 
 Cross-feature gap (same ISSUE): preemption (evict + re-prefill) and
-snapshot/adopt pinned bit-identical with ``FLAGS_serve_paged_kernel=1``.
+snapshot/adopt pinned to equal greedy tokens with the kernel decode step
+(``serving_util.paged_kernel`` patches the one function that chooses it).
 """
 import time
 
@@ -32,7 +34,7 @@ import paddle_tpu.models.generation as G
 from paddle_tpu import profiler
 from paddle_tpu.framework import flags
 from paddle_tpu.serving import Engine, ServeError, SnapshotError
-from serving_util import ENGINE_KW, make_prompts, tiny_gpt
+from serving_util import ENGINE_KW, make_prompts, paged_kernel, tiny_gpt
 
 jnp = pytest.importorskip("jax.numpy")
 import jax  # noqa: E402
@@ -57,14 +59,15 @@ def _llama_gqa():
 
 
 def _run_engine(model, prompt_seed=3, n=4, max_new=8, vocab=211,
-                prompts=None, flag_overrides=None, **kw):
+                prompts=None, flag_overrides=None, kernel=False, **kw):
     """Greedy token outputs of a fresh engine under flag + config
-    overrides."""
+    overrides, its decode program the kernel step or the gather step."""
     fl = dict(flag_overrides or {})
     old = {k: flags._FLAGS.get(k) for k in fl}
     flags._FLAGS.update(fl)
     try:
-        with Engine(model, **dict(ENGINE_KW, **kw)) as eng:
+        with paged_kernel(kernel), \
+                Engine(model, **dict(ENGINE_KW, **kw)) as eng:
             if prompts is None:
                 rng = np.random.RandomState(prompt_seed)
                 prompts = [rng.randint(0, vocab, (int(rng.randint(3, 24)),))
@@ -95,10 +98,9 @@ class TestTpBitIdentity:
                       marks=pytest.mark.slow),
          pytest.param(True, True, id="prefix_cache-paged_kernel")])
     def test_gpt_tokens_identical(self, model, kernel, prefix):
-        fl = {"FLAGS_serve_paged_kernel": kernel,
-              "FLAGS_serve_prefix_cache": prefix}
-        base = _run_engine(model, flag_overrides=fl)
-        tp2 = _run_engine(model, flag_overrides=fl, tp=2)
+        fl = {"FLAGS_serve_prefix_cache": prefix}
+        base = _run_engine(model, flag_overrides=fl, kernel=kernel)
+        tp2 = _run_engine(model, flag_overrides=fl, kernel=kernel, tp=2)
         assert base == tp2
 
     @pytest.mark.slow
@@ -106,10 +108,10 @@ class TestTpBitIdentity:
                              ids=["gather", "paged_kernel"])
     def test_llama_gqa_tokens_identical(self, kernel):
         m = _llama_gqa()
-        fl = {"FLAGS_serve_paged_kernel": kernel,
-              "FLAGS_serve_prefix_cache": True}
-        base = _run_engine(m, vocab=1024, flag_overrides=fl)
-        tp2 = _run_engine(m, vocab=1024, flag_overrides=fl, tp=2)
+        fl = {"FLAGS_serve_prefix_cache": True}
+        base = _run_engine(m, vocab=1024, flag_overrides=fl, kernel=kernel)
+        tp2 = _run_engine(m, vocab=1024, flag_overrides=fl, kernel=kernel,
+                          tp=2)
         assert base == tp2
 
     @pytest.mark.slow
@@ -290,27 +292,19 @@ class TestSnapshotMeshGeometry:
 
 # ---------------------------------------------- paged kernel cross-feature
 class TestPagedKernelCrossFeature:
-    """ISSUE-19 satellite: preemption and snapshot/adopt had no coverage
-    with FLAGS_serve_paged_kernel=1."""
+    """ISSUE-19 satellite: preemption and snapshot/adopt with the kernel
+    decode step."""
 
     PREEMPT_KW = dict(block_size=8, num_blocks=10, max_batch=4,
                       max_seq_len=72)
 
     def _preempt_run(self, model, kernel):
-        old = flags._FLAGS.get("FLAGS_serve_paged_kernel")
-        flags._FLAGS["FLAGS_serve_paged_kernel"] = kernel
-        try:
-            rng = np.random.RandomState(7)
-            with Engine(model, **self.PREEMPT_KW) as eng:
-                hs = [eng.submit(rng.randint(0, 211, (8,)).tolist(),
-                                 max_new_tokens=24, temperature=0.0)
-                      for _ in range(4)]
-                return [h.result(timeout=600) for h in hs]
-        finally:
-            if old is None:
-                flags._FLAGS.pop("FLAGS_serve_paged_kernel", None)
-            else:
-                flags._FLAGS["FLAGS_serve_paged_kernel"] = old
+        rng = np.random.RandomState(7)
+        with paged_kernel(kernel), Engine(model, **self.PREEMPT_KW) as eng:
+            hs = [eng.submit(rng.randint(0, 211, (8,)).tolist(),
+                             max_new_tokens=24, temperature=0.0)
+                  for _ in range(4)]
+            return [h.result(timeout=600) for h in hs]
 
     @pytest.mark.slow
     def test_preemption_bit_identical_with_kernel(self, model):
@@ -326,9 +320,7 @@ class TestPagedKernelCrossFeature:
 
     @pytest.mark.slow
     def test_handoff_adopt_bit_identical_with_kernel(self, model):
-        old_fl = flags._FLAGS.get("FLAGS_serve_paged_kernel")
-        flags._FLAGS["FLAGS_serve_paged_kernel"] = True
-        try:
+        with paged_kernel(True):
             rng = np.random.RandomState(23)
             prompts = [rng.randint(0, 211,
                                    (int(rng.randint(3, 24)),)).tolist()
@@ -353,11 +345,6 @@ class TestPagedKernelCrossFeature:
                 assert outs == baseline
             finally:
                 old.close()
-        finally:
-            if old_fl is None:
-                flags._FLAGS.pop("FLAGS_serve_paged_kernel", None)
-            else:
-                flags._FLAGS["FLAGS_serve_paged_kernel"] = old_fl
 
 
 # ------------------------------------------------------------ inert tripwire
@@ -382,9 +369,8 @@ class TestInertTripwire:
         rng = np.random.RandomState(4)
         prompts = [rng.randint(0, 211, (int(rng.randint(3, 24)),)).tolist()
                    for _ in range(4)]
-        out = _run_engine(model, prompts=prompts, flag_overrides={
-            "FLAGS_serve_prefix_cache": True,
-            "FLAGS_serve_paged_kernel": True})
+        out = _run_engine(model, prompts=prompts, kernel=True,
+                          flag_overrides={"FLAGS_serve_prefix_cache": True})
         assert [len(o) for o in out] == [len(p) + 8 for p in prompts]
         eng = Engine(model, **ENGINE_KW)
         try:

@@ -34,19 +34,22 @@ def v5e():
         platform="tpu", topology_name="v5e:2x2").devices
 
 
-def _compile_for_tpu(fn, *args):
-    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
-    n_kernels = lowered.as_text().count("tpu_custom_call")
+def _compile_uncached(lowered):
     # an executable for a chip that is not here cannot be loaded back from
     # the persistent cache ("DeserializeLoadedExecutable not implemented"):
     # keep these out of it, or every later run warns and recompiles anyway
     threshold = jax.config.jax_persistent_cache_min_compile_time_secs
     jax.config.update("jax_persistent_cache_min_compile_time_secs", float("inf"))
     try:
-        lowered.compile()
+        return lowered.compile()
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", threshold)
-    return n_kernels
+
+
+def _compile_for_tpu(fn, *args):
+    lowered = jax.jit(fn).trace(*args).lower(lowering_platforms=("tpu",))
+    _compile_uncached(lowered)
+    return lowered.as_text().count("tpu_custom_call")
 
 
 def _on(sharding, shape, dtype):
@@ -104,21 +107,100 @@ def test_int8_matmul_compiles_at_the_1p3b_head(v5e):
     assert n == 1
 
 
-@pytest.mark.xfail(
-    strict=True, raises=ValueError,
-    reason="Mosaic refuses the kernel as written (handed to ROADMAP S2a): "
-           "'The Pallas TPU lowering currently requires that the last two "
-           "dimensions of your block shape are divisible by 8 and 128 "
-           "respectively, or be equal to the respective dimensions of the "
-           "overall array' — the (R, MB) = (1, 16) SMEM block of the (8, 16) "
-           "block table")
-def test_paged_attention_compiles_at_b8_mb16(v5e):
-    B, H, D, KV, BS, MB, NB = 8, 16, 128, 16, 16, 16, 512
+# the paged decode kernel: a small shape, the serving cell's (64 rows, a table
+# 128 wide, the pool that fills the chip beside GPT-3 XL) and grouped heads
+@pytest.mark.parametrize(
+    "B,MB,H,KV,NB,L",
+    [pytest.param(8, 16, 16, 16, 512, 2, id="b8_mb16"),
+     pytest.param(64, 128, 16, 16, 3679, 24, id="cell_b64_mb128"),
+     pytest.param(8, 16, 32, 8, 512, 2, id="gqa_rep4")])
+def test_paged_attention_compiles(v5e, B, MB, H, KV, NB, L):
+    D, BS = 128, 16
     s = SingleDeviceSharding(v5e[0])
-    pool = _on(s, (NB, BS, KV, D), jnp.bfloat16)
+    pool = _on(s, (L, NB, BS, KV, D), jnp.bfloat16)
     n = _compile_for_tpu(
         lambda q, k, v, tables, pos: paged_attention_rows(
-            q, k, v, tables, pos, interpret=False),
+            q, k, v, L - 1, tables, pos, interpret=False),
         _on(s, (B, H, D), jnp.bfloat16), pool, pool,
         _on(s, (B, MB), jnp.int32), _on(s, (B,), jnp.int32))
     assert n == 1
+
+
+def _gpt_step_operands(s, L, d, H, D, BS, B, MB, NB, vocab=50304):
+    """``ShapeDtypeStruct`` operands of a bfloat16 GPT decode step."""
+    bf = jnp.bfloat16
+    layer = {"ln1_w": (d,), "ln1_b": (d,), "qkv_w": (d, 3 * d),
+             "qkv_b": (3 * d,), "proj_w": (d, d), "proj_b": (d,),
+             "ln2_w": (d,), "ln2_b": (d,), "up_w": (d, 4 * d),
+             "up_b": (4 * d,), "down_w": (4 * d, d), "down_b": (d,)}
+    params = {"wte": _on(s, (vocab, d), bf), "wpe": _on(s, (2048, d), bf),
+              "lnf_w": _on(s, (d,), bf), "lnf_b": _on(s, (d,), bf),
+              "layers": [{k: _on(s, v, bf) for k, v in layer.items()}
+                         for _ in range(L)]}
+    pool = _on(s, (L, NB, BS, H, D), bf)
+    return (params, pool, pool, _on(s, (B, MB), jnp.int32),
+            _on(s, (B,), jnp.int32), _on(s, (B,), jnp.int32),
+            _on(s, (B,), jnp.float32), _on(s, (2,), jnp.uint32))
+
+
+@pytest.mark.parametrize("d,H,kernel_calls", [
+    pytest.param(768, 12, 0, id="gpt2_d64_gathers"),
+    pytest.param(2560, 32, 0, id="gpt3_2p7b_d80_gathers"),
+    pytest.param(1024, 8, 2, id="d128_kernel")])
+def test_decode_step_the_chip_chooses_compiles(v5e, monkeypatch, d, H,
+                                               kernel_calls):
+    """The decode program the engine builds on the chip for an arch
+    (``paged_kernel_default``, by head width) compiles whole: Mosaic refuses
+    the kernel at a head width off the 128-lane tile, and there the step is
+    the gather's."""
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.ops.kernels import paged_attention as pa
+
+    monkeypatch.setattr(pa, "interpret_default", lambda: False)
+    L, BS, B, MB, NB = 2, 16, 8, 16, 512
+    D = d // H
+    arch = G._gpt_arch(H, D)
+    on_chip = G.paged_kernel_default(arch, mosaic=True)
+    assert on_chip is (kernel_calls > 0)
+    build = G.build_paged_decode_kernel if on_chip else G.build_paged_decode
+    s = SingleDeviceSharding(v5e[0])
+    lowered = jax.jit(build(arch, B, BS, MB), donate_argnums=(1, 2)).trace(
+        *_gpt_step_operands(s, L, d, H, D, BS, B, MB, NB, vocab=1024),
+    ).lower(lowering_platforms=("tpu",))
+    compiled = _compile_uncached(lowered)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == kernel_calls
+    if not on_chip:
+        # and the refusal the rule stands for is still the compiler's answer
+        monkeypatch.setattr(pa, "mosaic_takes", lambda head_dim: True)
+        with pytest.raises(Exception, match="Mosaic failed to compile"):
+            _compile_uncached(jax.jit(
+                G.build_paged_decode_kernel(arch, B, BS, MB)).trace(
+                *_gpt_step_operands(s, L, d, H, D, BS, B, MB, NB, vocab=1024),
+            ).lower(lowering_platforms=("tpu",)))
+
+
+def test_kernel_decode_step_at_the_cell_shape(v5e, monkeypatch):
+    """The whole B64 decode program of GPT-3 XL beside the 3,679-block pool:
+    one kernel call a layer, and temporaries that do not grow with the pool
+    (the gather step has 1.57 GB there; XLA updates the donated pool in
+    place between the kernel reads)."""
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.ops.kernels import paged_attention as pa
+
+    monkeypatch.setattr(pa, "interpret_default", lambda: False)
+    L, d, H, D, BS, B, MB, NB = 24, 2048, 16, 128, 16, 64, 128, 3679
+    s = SingleDeviceSharding(v5e[0])
+    step = jax.jit(G.build_paged_decode_kernel(G._gpt_arch(H, D), B, BS, MB),
+                   donate_argnums=(1, 2))
+    lowered = step.trace(
+        *_gpt_step_operands(s, L, d, H, D, BS, B, MB, NB),
+    ).lower(lowering_platforms=("tpu",))
+    # the 24 layers share one lowered kernel ...
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    compiled = _compile_uncached(lowered)
+    # ... which the program calls once a layer
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == L
+    temps = compiled.memory_analysis().temp_size_in_bytes
+    assert temps < 1e9, f"decode step temporaries {temps / 1e9:.2f} GB"
