@@ -1,5 +1,5 @@
 """The control of the correctness check, on the chip at the cell's own size:
-the plain reference put in the program's place and computed in float8 e4m3, a
+the family's plain reference put in the program's place and computed in float8 e4m3, a
 precision below the bfloat16 the configurations state. It has to
 come out as NOT correct; the limits in ``cells/<cell>.json`` are set between
 the largest number sound runs give and the smallest the control gives
@@ -22,20 +22,19 @@ import sys
 
 from . import check, generator as G, serve_job, train_job, weights as W
 from .manifest import Manifest
-from .reference import gpt as R
 from .run import Ctx, _cache_dir, _device
 
 
 def train_control(ctx, seed):
     import jax
 
-    cfg, job = ctx.config, ctx.traffic
-    names = [s[0] for s in W.leaf_specs(cfg)]
-    first = lambda leaf: W.make_leaf(cfg, seed, names.index(leaf))
+    cfg, job, specs = ctx.config, ctx.traffic, ctx.family.leaf_specs(ctx.config)
+    names = [s[0] for s in specs]
+    first = lambda leaf: W.make_leaf(cfg, seed, specs, names.index(leaf))
     packs = {}
     for mode in ("f32", ctx.args.mode):
-        ref = R.TrainReference(cfg, W.make_weights(cfg, seed), job["optimizer"],
-                               mode=mode, devices=jax.devices()[:ctx.chips])
+        ref = ctx.family.TrainReference(cfg, W.make_weights(cfg, seed, specs), job["optimizer"],
+                                        mode=mode, devices=jax.devices()[:ctx.chips])
         for k in range(job["check_steps"]):
             ref.step(train_job.feed_ids(job, cfg["vocab_size"], seed, k))
         packs[mode] = {"loss": ref.losses, "grad_norm": ref.grad_norms,
@@ -48,7 +47,7 @@ def train_control(ctx, seed):
 def serve_control(ctx, seed):
     import numpy as np
 
-    cfg = ctx.config
+    cfg, family = ctx.config, ctx.family
     ctx.seed = seed
     sched = G.build_schedule(ctx.traffic, ctx.seconds, seed, cfg["vocab_size"])
     model, eng = serve_job.setup(ctx, sched)
@@ -60,12 +59,12 @@ def serve_control(ctx, seed):
     picked = serve_job.sample_finished(served, sched, seed, int(ctx.cell["check_requests"]))
     del eng, model, loop
     gc.collect()
-    weights = W.make_weights(cfg, seed)
+    weights = W.make_weights(cfg, seed, family.leaf_specs(cfg))
     sound, ctl, n = 0.0, 0.0, 0
     for r in picked:
         prompt = sched.prompts[r.index]
-        sound = max(sound, float(serve_job.served_gap(cfg, weights, prompt, r.tokens).max()))
-        g = serve_job.served_gap(cfg, weights, prompt, r.tokens, mode=ctx.args.mode)
+        sound = max(sound, float(serve_job.served_gap(family, cfg, weights, prompt, r.tokens).max()))
+        g = serve_job.served_gap(family, cfg, weights, prompt, r.tokens, mode=ctx.args.mode)
         ctl, n = max(ctl, float(g.max())), n + len(g)
     return {"served_logit_gap": (ctl, f"the control's first token, {n} positions"),
             "program_served_logit_gap": (sound, f"the program's tokens, {n} positions")}

@@ -3,25 +3,15 @@ nothing recomputed: a share worked out from these can reach 100% only if the
 program wastes nothing, and a share over 100% is a bug in a count or in the
 time it is divided by (``share`` raises).
 
-Hand-worked values for GPT-3 XL are in tests/benchmark/test_benchmark_flops.py.
+Here is what belongs to no model family: the flash-attention kernels' counts,
+the roofline and the share. A model's own counts (parameters, FLOPs per
+trained token, bytes a decode step reads, cache bytes per context token) are
+its family's, ``families/<family>.py``; a family that gives none has no such
+metric, never another family's formula.
+
+Hand-worked values are in tests/benchmark/test_benchmark_flops.py.
 """
 from __future__ import annotations
-
-
-def matmul_params_per_layer(cfg: dict) -> int:
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    return 3 * d * d + d * d + 2 * d * f  # qkv, proj, up, down
-
-
-def train_flops_per_token(cfg: dict, seq: int) -> float:
-    """Forward + backward (3 x forward), causal attention counted once: a
-    token at position t attends t+1 keys, so QK^T and PV cost 4*d*(t+1)
-    and the mean over a sequence is 2*d*(seq+1). Biases, norms, GELU and the
-    softmax are left out (under 1% at these widths)."""
-    d, layers, vocab = cfg["hidden_size"], cfg["num_layers"], cfg["vocab_size"]
-    fwd = layers * (2 * matmul_params_per_layer(cfg) + 2 * d * (seq + 1)) \
-        + 2 * d * vocab
-    return 3.0 * fwd
 
 
 def flash_flops(batch: int, heads: int, head_dim: int, seq: int, kind: str) -> float:
@@ -39,20 +29,6 @@ def flash_bytes(batch: int, heads: int, head_dim: int, seq: int, kind: str,
     and writes dq; dkv reads q,k,v,do and writes dk,dv."""
     tensors = {"fwd": 4, "dq": 5, "dkv": 6, "bwd": 8}[kind]
     return float(tensors * batch * seq * heads * head_dim * itemsize)
-
-
-def weight_bytes(cfg: dict, itemsize: int = 2) -> float:
-    """Bytes of weights one decode step must read: every layer's matrices,
-    biases and norms, and the tied embedding once (the head reads all of it;
-    the token and position lookups read rows of what is already counted)."""
-    d, f, layers = cfg["hidden_size"], cfg["intermediate_size"], cfg["num_layers"]
-    per_layer = matmul_params_per_layer(cfg) + (3 * d + d + f + d) + 4 * d
-    return float(itemsize * (layers * per_layer + cfg["vocab_size"] * d + 2 * d))
-
-
-def kv_bytes_per_context_token(cfg: dict, itemsize: int = 2) -> float:
-    """K and V of one cached token over all layers."""
-    return float(2 * cfg["num_layers"] * cfg["hidden_size"] * itemsize)
 
 
 def roofline_seconds(flops: float, bytes_: float, peaks: dict):

@@ -5,6 +5,8 @@ and metrics; each has a file of its own under the benchmark's directory.
     traffic/<traffic>.json   parameters of a traffic mix or a training job
     cells/<cell>.json        limits of the correctness check, sample sizes
     metrics/<metric>.py      one per-layer metric: ``read(run) -> float|None``
+    families/<family>.py     what a configuration's ``family`` is: leaves,
+                             builder, plain reference, counts (README.md)
     peaks.json               peaks by ``device_kind``
 """
 from __future__ import annotations
@@ -12,12 +14,25 @@ from __future__ import annotations
 import importlib.util
 import json
 import re
+import sys
 from pathlib import Path
 
 REPO = Path(__file__).resolve().parent.parent
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# what a family's module has to give: always, and for a cell of a traffic kind
+FAMILY_NEEDS = ("REHEARSE", "leaf_specs", "build", "forward_logits")
+KIND_NEEDS = {"train": ("TrainReference",),
+              "open_loop": ("cache_bytes_per_context_token",)}
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod  # a dataclass defined there looks its module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 class Manifest:
@@ -26,6 +41,7 @@ class Manifest:
         self.repo = self.path.parent
         self.data = json.loads(self.path.read_text())
         self.root = self.repo / self.data["paths"][0]
+        self._families = {}
 
     def _json(self, *parts):
         return json.loads(self.root.joinpath(*parts).read_text())
@@ -71,12 +87,19 @@ class Manifest:
 
     def reader(self, metric: str):
         """The ``read`` function of ``metrics/<metric>.py``."""
-        path = self.root / "metrics" / f"{metric}.py"
-        spec = importlib.util.spec_from_file_location(
-            "benchmark_metric_" + re.sub(r"\W", "_", metric), path)
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod.read
+        return _load(self.root / "metrics" / f"{metric}.py",
+                     "benchmark_metric_" + re.sub(r"\W", "_", metric)).read
+
+    def family(self, name: str):
+        """The module ``families/<name>.py``, loaded once."""
+        if name not in self._families:
+            path = self.root / "families" / f"{name}.py"
+            if not path.is_file():
+                raise FileNotFoundError(
+                    f"no module for model family {name!r}: looked for {path}")
+            self._families[name] = _load(
+                path, "benchmark_family_" + re.sub(r"\W", "_", name))
+        return self._families[name]
 
     def validate(self) -> list:
         """Faults against the parts of the contract that can be checked
@@ -105,19 +128,37 @@ class Manifest:
         pairs = [(w["config"], w["traffic"]) for w in d["workloads"]]
         if len(set(pairs)) != len(pairs):
             bad.append("a (config, traffic) pair appears twice")
+        families = {}
         for c in d["configs"]:
             if c["name"] not in {w["config"] for w in d["workloads"]}:
                 bad.append(f"config {c['name']} is used by no cell")
             if not (self.repo / c["file"]).is_file():
                 bad.append(f"config file {c['file']} missing")
+                continue
+            cfg = self.config(c["name"])
+            bad += [f"config {c['name']}: reduced key {k!r} is not a key of "
+                    f"{c['file']}" for k in c["reduced"] if k not in cfg]
+            try:
+                fam = families[c["name"]] = self.family(cfg.get("family"))
+            except FileNotFoundError as e:
+                bad.append(f"config {c['name']}: {e}")
+                continue
+            bad += [f"config {c['name']}: families/{cfg['family']}.py has no {n}"
+                    for n in FAMILY_NEEDS if not hasattr(fam, n)]
         for w in d["workloads"]:
             if w["config"] not in names(d["configs"]):
                 bad.append(f"cell {w['name']}: unknown config")
             if w["chips"] not in (1, 4):
                 bad.append(f"cell {w['name']}: chips must be 1 or 4")
-            for kind, stem in (("traffic", w["traffic"]), ("cells", w["name"])):
-                if not (self.root / kind / f"{stem}.json").is_file():
-                    bad.append(f"cell {w['name']}: no {kind}/{stem}.json")
+            files = {kind: self.root / kind / f"{stem}.json"
+                     for kind, stem in (("traffic", w["traffic"]), ("cells", w["name"]))}
+            bad += [f"cell {w['name']}: no {kind}/{f.name}"
+                    for kind, f in files.items() if not f.is_file()]
+            if w["config"] in families and files["traffic"].is_file():
+                kind, fam = self.traffic(w["traffic"])["kind"], families[w["config"]]
+                bad += [f"cell {w['name']}: a {kind} cell of a family without {n} "
+                        f"({Path(fam.__file__).name})"
+                        for n in KIND_NEEDS.get(kind, ()) if not hasattr(fam, n)]
             mine = names(self.metrics_of(w["name"], "end_to_end"))
             if "setup_s" not in mine or len(mine) < 2:
                 bad.append(f"cell {w['name']}: needs setup_s and one more metric")

@@ -1,8 +1,9 @@
 """Roofline share of the paged decode programs against HBM bandwidth: the
 bytes the decode steps inside the traced window MUST read (the weights once
-per step, ``flops.weight_bytes``, plus K and V of every live row at its real
-context length, ``flops.kv_bytes_per_context_token`` x the context of each
-token the clients received from a decode step in that window) over the HBM
+per step, the family's ``weight_bytes``, plus the cache of every live row at
+its real context length, the family's ``cache_bytes_per_context_token`` x the
+context of each token the clients received from a decode step in that window;
+a family that gives no ``weight_bytes`` has no such share) over the HBM
 peak, over the device time of the decode program's executions in the window
 (the ``jit_step`` events of the device's per-program line; prefill programs
 are ``jit_prefill``). What the gather reads beyond the live context (the
@@ -16,7 +17,8 @@ DECODE_PROGRAM = re.compile(r"^jit_step\(")
 
 
 def read(run):
-    if run["trace"] is None:
+    weight_bytes = getattr(run["family"], "weight_bytes", None)
+    if run["trace"] is None or weight_bytes is None:
         return None
     red = run["trace"]
     t0, t1 = summary.window_ns(red)
@@ -34,7 +36,7 @@ def read(run):
         plen = int(sched.prompt_len[rec.index])
         context_tokens += sum(plen + k for k, t in enumerate(rec.stamps)
                               if k > 0 and a <= t <= b)
-    need = steps * flops.weight_bytes(run["config"]) \
-        + context_tokens * flops.kv_bytes_per_context_token(run["config"])
+    need = steps * weight_bytes(run["config"]) \
+        + context_tokens * run["family"].cache_bytes_per_context_token(run["config"])
     return flops.share(need / run["peaks"]["hbm_bytes_per_s"], spent / 1e9,
                        "decode_hbm_roofline")

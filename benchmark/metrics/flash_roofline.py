@@ -5,7 +5,9 @@ recomputed; the larger of FLOPs over the bf16 peak and bytes over the HBM
 peak) summed over the calls in the traced window, over the summed device
 time of those calls. The calls are the ``tpu_custom_call`` events of the
 device's operation line; a call's kind and local shape are read from its
-HLO line: the forward returns (out, lse), dQ one array, dK/dV two. At these
+HLO line: the forward returns (out, lse), dQ one array, dK/dV two; the width
+of one head, which splits a call's local width into heads, is the family's
+``head_dim`` (a family that gives none has no such share). At these
 shapes every call is compute-bound (the reader would say so otherwise by
 raising on a memory-bound call it does not expect: see ``bound``)."""
 import re
@@ -38,9 +40,10 @@ def classify(name: str):
 
 
 def read(run):
-    if run["trace"] is None:
+    width_of_head = getattr(run["family"], "head_dim", None)
+    if run["trace"] is None or width_of_head is None:
         return None
-    head_dim, peaks = run["config"]["head_dim"], run["peaks"]
+    head_dim, peaks = width_of_head(run["config"]), run["peaks"]
     need = spent = 0.0
     for events in summary.device_ops(run["trace"]).values():
         for name, _, dur in events:

@@ -16,11 +16,10 @@ bfloat16 state; a float32 state would not be the job the cell runs.
 
 ``mode="fp8"`` is the control of the benchmark's correctness check: the same
 reference with the operands of every linear layer and of the head rounded to
-float8 e4m3 (one scale per tensor, straight-through gradient), a precision
-below the configurations' bfloat16 that a later PR could be tempted by. It
-is never used to judge a run. (An int8 control, per-token activations and
-per-output-channel weights, read no further from float32 on the chip than
-the bfloat16 program itself, PERF.md section 2, and is gone.)
+float8 e4m3 (``common.py``, shared by every family's reference).
+
+The harness reaches this module through ``benchmark/families/gpt.py``
+alone, which hands it ``forward_logits`` and ``TrainReference``.
 """
 from __future__ import annotations
 
@@ -31,32 +30,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HI = jax.lax.Precision.HIGHEST
-F32 = jnp.float32
+from .common import F32, HI, diff_norm, linear, operands
+
 LAYER_LEAVES = ("ln1.g", "ln1.b", "qkv.w", "qkv.b", "proj.w", "proj.b",
                 "ln2.g", "ln2.b", "up.w", "up.b", "down.w", "down.b")
-
-
-def _fake_fp8(x):
-    """float8 e4m3 (3 mantissa bits) with one scale per tensor that puts its
-    largest magnitude at the format's maximum, 448."""
-    amax = jnp.max(jnp.abs(x))
-    scale = jnp.where(amax > 0, 448.0 / amax, 1.0)
-    q = (x * scale).astype(jnp.float8_e4m3fn).astype(F32) / scale
-    return x + jax.lax.stop_gradient(q - x)
-
-
-def _operands(x, w, mode):
-    if mode == "fp8":
-        return _fake_fp8(x), _fake_fp8(w)
-    if mode != "f32":
-        raise ValueError(f"unknown precision mode {mode!r}")
-    return x, w
-
-
-def linear(x, w, b, mode):
-    x, w = _operands(x, w, mode)
-    return jnp.matmul(x, w, precision=HI) + b
 
 
 def layer_norm(x, g, b, eps):
@@ -101,7 +78,7 @@ def embed(wte, wpe, ids):
 
 
 def head_logits(x, g, b, wte, eps, mode):
-    h, wte = _operands(layer_norm(x, g, b, eps), wte, mode)
+    h, wte = operands(layer_norm(x, g, b, eps), wte, mode)
     return jnp.matmul(h, wte.T, precision=HI)
 
 
@@ -199,11 +176,6 @@ def _embed_bwd(dwte_head, dx0, ids, positions):
     return dwte, dwpe
 
 
-@jax.jit
-def _diff_norm(a, b):
-    return jnp.sqrt(jnp.sum(jnp.square(a.astype(F32) - b.astype(F32))))
-
-
 class TrainReference:
     """Follows the job's first steps: loss of each, per-leaf norm of the first
     gradient, per-leaf norm of the parameters' change at the end.
@@ -280,5 +252,5 @@ class TrainReference:
     def change_norms(self, first_leaf):
         """Per-leaf ||theta_now - theta_0||; ``first_leaf(name)`` makes leaf
         ``name`` as it was before the first step."""
-        return {k: float(_diff_norm(w, jax.device_put(first_leaf(k), self._dev(k))))
+        return {k: float(diff_norm(w, jax.device_put(first_leaf(k), self._dev(k))))
                 for k, w in self.p.items()}

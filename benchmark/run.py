@@ -9,7 +9,8 @@ contract's JSON object as the last line of its standard output. The only
 thing two runs share is JAX's persistent compilation cache.
 
 ``--rehearse`` (no chip needed) drives the same code at the sizes of
-``rehearse.json`` and prints counts only: never a time, a rate or a share.
+``rehearse.json`` (traffic) and of the family's ``REHEARSE`` (model) and
+prints counts only: never a time, a rate or a share.
 """
 from __future__ import annotations
 
@@ -41,6 +42,7 @@ class Ctx:
         self.workload = manifest.workload(args.workload)
         self.cell = manifest.cell(args.workload)
         self.config = manifest.config(self.workload["config"])
+        self.family = manifest.family(self.config["family"])
         self.traffic = manifest.traffic(self.workload["traffic"])
         self.chips = int(self.workload["chips"])
         self.seed, self.seconds = int(args.seed), float(args.seconds)
@@ -49,7 +51,7 @@ class Ctx:
         self.capture, self.trace_at, self.reduced, self.window = None, None, None, None
         if self.rehearse:
             over = json.loads((manifest.root / "rehearse.json").read_text())
-            self.config = {**self.config, **over["config"]}
+            self.config = {**self.config, **self.family.REHEARSE}
             self.traffic = {**self.traffic, **over[self.traffic["kind"]]}
 
     def note(self, key, value):
@@ -130,6 +132,17 @@ def _device(ctx):
     return {"platform": dev.platform, "kind": dev.device_kind, "count": ctx.chips}
 
 
+def per_layer(manifest, cell, facts) -> dict:
+    """The cell's per-layer metrics as the result's line carries them; a
+    reader that found nothing to read (``None``) leaves its metric out."""
+    out = {}
+    for m in manifest.metrics_of(cell, "per_layer"):
+        value = manifest.reader(m["name"])(facts)
+        if value is not None:
+            out[m["name"]] = {"value": float(value), "unit": m["unit"]}
+    return out
+
+
 def _cache_dir():
     """JAX's persistent compilation cache: where JAX_COMPILATION_CACHE_DIR
     says, else the fixed path inside the checkout that the program itself
@@ -179,7 +192,8 @@ def main(argv=None):
 
     setup_s = t_open - T0
     log(f"part: setup_s = {setup_s:.3f}")
-    facts = {**res["facts"], "config": ctx.config, "traffic": ctx.traffic,
+    facts = {**res["facts"], "config": ctx.config, "family": ctx.family,
+             "traffic": ctx.traffic,
              "cell": ctx.cell, "chips": ctx.chips, "window": (t_open, t_close),
              "seconds": t_close - t_open, "end_to_end": res["end_to_end"],
              "compiles": ctx.compiles, "setup_s": setup_s,
@@ -192,9 +206,9 @@ def main(argv=None):
         # fault in one shows here and not on the chip; their values are of a
         # CPU and are not printed
         if ctx.trace:
+            read = per_layer(manifest, args.workload, facts)
             for m in manifest.metrics_of(args.workload, "per_layer"):
-                value = manifest.reader(m["name"])(facts)
-                log(f"reader: {m['name']} " + ("read something" if value is not None
+                log(f"reader: {m['name']} " + ("read something" if m["name"] in read
                                                 else "found nothing to read"))
         print(json.dumps({"rehearsal": True, "correct": correct,
                           "attempted": res["attempted"], "failed": res["failed"],
@@ -212,10 +226,7 @@ def main(argv=None):
         from .trace import summary
 
         t = time.monotonic()
-        for m in manifest.metrics_of(args.workload, "per_layer"):
-            value = manifest.reader(m["name"])(facts)
-            if value is not None:
-                line["metrics"][m["name"]] = {"value": float(value), "unit": m["unit"]}
+        line["metrics"] = per_layer(manifest, args.workload, facts)
         dev_out.update(summary.busy_and_window(ctx.reduced))
         line["breakdown"] = summary.breakdown(ctx.reduced, ctx.spans)
         log(f"part: reduce_s = {time.monotonic() - t:.3f} over "

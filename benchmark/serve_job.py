@@ -1,5 +1,5 @@
 """A serving cell: ``serving.Engine`` at default flags behind an open-loop
-generator. Set-up builds the model from the seed, sizes the KV pool to what
+generator. Set-up builds the model from the seed, sizes the cache pool to what
 the weights leave, warms exactly the prefill and decode shapes the traffic
 file reaches, and ramps; the window then counts tokens and requests at the
 client's side of the stream; afterwards arrivals stop, in-flight requests
@@ -12,17 +12,18 @@ import time
 
 import numpy as np
 
-from . import generator as G, model as M, weights as W
-from .reference import gpt as R
+from . import generator as G, weights as W
 
 
-def pool_blocks(cfg, block_size, headroom_bytes):
-    """KV blocks that fill what the weights leave, less the headroom for the
-    programs' own temporaries (the sizing of ``chip_smoke.pool_blocks_for``)."""
+def pool_blocks(family, cfg, block_size, headroom_bytes):
+    """Cache blocks that fill what the weights leave, less the headroom for
+    the programs' own temporaries (the sizing of
+    ``chip_smoke.pool_blocks_for``); what a cached token costs is the
+    family's count."""
     import jax
 
     stats = jax.devices()[0].memory_stats()
-    block_bytes = 2 * cfg["num_layers"] * block_size * cfg["hidden_size"] * 2
+    block_bytes = family.cache_bytes_per_context_token(cfg) * block_size
     free = stats["bytes_limit"] - stats["bytes_in_use"]
     return int((free - headroom_bytes) // block_bytes)
 
@@ -73,8 +74,8 @@ def warm(eng, schedule, traffic, block_size, vocab, log):
         f"{eng.stats()['compiles']} programs")
 
 
-def served_gap(cfg, weights, prompt, tokens, mode="f32", pad_to=256):
-    """Run the reference once over prompt + served tokens. Returns, for each
+def served_gap(family, cfg, weights, prompt, tokens, mode="f32", pad_to=256):
+    """Run the family's reference once over prompt + served tokens. Returns, for each
     served token, how far its reference logit lies below the reference's
     best at that position; with ``mode`` set to the control's precision the
     token judged at each position is the one the control puts first."""
@@ -85,12 +86,12 @@ def served_gap(cfg, weights, prompt, tokens, mode="f32", pad_to=256):
     padded = -(-n // pad_to) * pad_to
     x = np.zeros((1, padded), np.int64)
     x[0, :n] = ids[:n]
-    ref = R.forward_logits(cfg, weights, x, "f32")[0, len(prompt) - 1:n]
+    ref = family.forward_logits(cfg, weights, x, "f32")[0, len(prompt) - 1:n]
     best = jnp.max(ref, axis=-1)
     if mode == "f32":
         judged = jnp.asarray(ids[len(prompt):])
     else:
-        ctl = R.forward_logits(cfg, weights, x, mode)[0, len(prompt) - 1:n]
+        ctl = family.forward_logits(cfg, weights, x, mode)[0, len(prompt) - 1:n]
         judged = jnp.argmax(ctl, axis=-1)
     picked = jnp.take_along_axis(ref, judged[:, None], -1)[:, 0]
     return np.asarray(best - picked)
@@ -115,19 +116,19 @@ def setup(ctx, schedule):
     from paddle_tpu.framework import flags
     from paddle_tpu.serving import Engine
 
-    cfg, traffic = ctx.config, ctx.traffic
+    cfg, traffic, family = ctx.config, ctx.traffic, ctx.family
     t = time.monotonic()
-    weights = W.make_weights(cfg, ctx.seed)
+    weights = W.make_weights(cfg, ctx.seed, family.leaf_specs(cfg))
     ctx.note("setup_weights_s", time.monotonic() - t)
     t = time.monotonic()
-    model, _ = M.build_model(cfg, weights)
+    model, _ = family.build(cfg, weights)
     del weights
     model.eval()
     gc.collect()
     ctx.note("setup_model_s", time.monotonic() - t)
     block_size = int(flags.flag("FLAGS_serve_block_size"))
     blocks = traffic.get("pool_blocks") or pool_blocks(
-        cfg, block_size, int(traffic["headroom_bytes"]))
+        family, cfg, block_size, int(traffic["headroom_bytes"]))
     ctx.note("pool_blocks", blocks)
     eng = Engine(model, num_blocks=blocks)  # default flags; the pool's size is ours
     t = time.monotonic()
@@ -163,7 +164,7 @@ def drive(ctx, eng, schedule, window=False, sample_every=None):
 
 
 def run(ctx) -> dict:
-    cfg, traffic, seed = ctx.config, ctx.traffic, ctx.seed
+    cfg, traffic, seed, family = ctx.config, ctx.traffic, ctx.seed, ctx.family
     log = lambda m: print(m, flush=True)
     schedule = G.build_schedule(traffic, ctx.seconds, seed, cfg["vocab_size"])
     model, eng = setup(ctx, schedule)
@@ -192,10 +193,10 @@ def run(ctx) -> dict:
     del eng, model, loop
     gc.collect()
     t = time.monotonic()
-    weights = W.make_weights(cfg, seed)
+    weights = W.make_weights(cfg, seed, family.leaf_specs(cfg))
     worst, where, n_tok = 0.0, "no finished request", 0
     for r in picked:
-        gaps = served_gap(cfg, weights, schedule.prompts[r.index], r.tokens)
+        gaps = served_gap(family, cfg, weights, schedule.prompts[r.index], r.tokens)
         n_tok += len(gaps)
         if gaps.max() >= worst:
             worst, where = float(gaps.max()), f"request {r.index} token {int(gaps.argmax())}"

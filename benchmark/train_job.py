@@ -8,8 +8,8 @@ import time
 
 import numpy as np
 
-from . import check, model as M, weights as W
-from .reference import gpt as R
+from . import check, weights as W
+from .reference.common import diff_norm
 
 ENGINES = ("compile_train_step", "hybrid")
 # steps the host may run ahead of the device in the window: with two, two of
@@ -74,14 +74,14 @@ def _first_gradient_norms(opt, params, beta1):
     return {k: v / (1.0 - beta1) for k, v in zip(leaves, _norms(moments))}
 
 
-def _change_norms(cfg, seed, params):
+def _change_norms(cfg, seed, specs, params):
     import jax
 
     out = {}
-    for i, (leaf, _, _) in enumerate(W.leaf_specs(cfg)):
+    for i, (leaf, _, _) in enumerate(specs):
         now = params[leaf]._data
-        first = jax.device_put(W.make_leaf(cfg, seed, i), now.sharding)
-        out[leaf] = float(R._diff_norm(now, first))
+        first = jax.device_put(W.make_leaf(cfg, seed, specs, i), now.sharding)
+        out[leaf] = float(diff_norm(now, first))
     return out
 
 
@@ -89,15 +89,16 @@ def run(ctx) -> dict:
     import jax
     import paddle_tpu as paddle
 
-    cfg, job, seed = ctx.config, ctx.traffic, ctx.seed
+    cfg, job, seed, family = ctx.config, ctx.traffic, ctx.seed, ctx.family
+    specs = family.leaf_specs(cfg)
     tokens_per_step = job["batch"] * job["seq"]
     init_mesh(job)
     t = time.monotonic()
-    weights = W.make_weights(cfg, seed)
+    weights = W.make_weights(cfg, seed, specs)
     jax.block_until_ready(weights)
     ctx.note("setup_weights_s", time.monotonic() - t)
     t = time.monotonic()
-    model, params = M.build_model(cfg, weights)
+    model, params = family.build(cfg, weights)
     del weights
     opt, step = _build_step(job, model)
     ctx.note("setup_model_s", time.monotonic() - t)
@@ -114,7 +115,7 @@ def run(ctx) -> dict:
     program = {"grad_norm": _first_gradient_norms(opt, params, job["optimizer"]["beta1"])}
     losses += [step(*feed[k % len(feed)]) for k in range(1, n_check)]
     program["loss"] = [float(l._data) for l in losses]
-    program["change_norm"] = _change_norms(cfg, seed, params)
+    program["change_norm"] = _change_norms(cfg, seed, specs, params)
     for k in range(n_check, n_check + 2):  # settle the dispatch queue
         last = step(*feed[k % len(feed)])
     jax.block_until_ready(last._data)
@@ -143,14 +144,14 @@ def run(ctx) -> dict:
     del model, opt, step, feed, pending, last, losses, params
     gc.collect()
     t = time.monotonic()
-    ref = R.TrainReference(cfg, W.make_weights(cfg, seed), job["optimizer"],
-                           devices=jax.devices()[:ctx.chips])
+    ref = family.TrainReference(cfg, W.make_weights(cfg, seed, specs), job["optimizer"],
+                                devices=jax.devices()[:ctx.chips])
     for a in ids[:n_check]:
         ref.step(a)
-    names = [s[0] for s in W.leaf_specs(cfg)]
+    names = [s[0] for s in specs]
     reference = {"loss": ref.losses, "grad_norm": ref.grad_norms,
                  "change_norm": ref.change_norms(
-                     lambda leaf: W.make_leaf(cfg, seed, names.index(leaf)))}
+                     lambda leaf: W.make_leaf(cfg, seed, specs, names.index(leaf)))}
     ctx.note("reference_s", time.monotonic() - t)
 
     numbers = check.train_numbers(program, reference)

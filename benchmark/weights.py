@@ -1,6 +1,13 @@
 """Seeded weights, made by the benchmark (not by the program) in one jitted
 call on the device, in the type they are trained and served in.
 
+Which leaves a model has is its family's: ``specs`` is what
+``families/<family>.py``'s ``leaf_specs(cfg)`` returns, ``[(name, shape,
+kind)]`` in a fixed order with kind ``normal`` (mean 0) or ``gain`` (mean 1),
+a leaf of layer ``i`` named ``h<i>.<suffix>``. The drawing, the grouping and
+the keys are here and the same for every family; of the configuration they
+read ``initializer_range`` and ``dtype``.
+
 The program's model and the plain reference are both given these values, so
 neither takes anything the other has made. Every leaf has a key of
 its own (folded from the seed, its group and its layer), so a single leaf can
@@ -14,27 +21,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-
-def leaf_specs(cfg: dict) -> list:
-    """``[(name, shape, kind)]`` in a fixed order; kind is ``normal`` (mean
-    0) or ``gain`` (mean 1). Matrices are (in, out), as the layer equations
-    in ``reference/gpt.py`` use them."""
-    d, f = cfg["hidden_size"], cfg["intermediate_size"]
-    specs = [("wte", (cfg["vocab_size"], d), "normal"),
-             ("wpe", (cfg["max_position_embeddings"], d), "normal")]
-    for i in range(cfg["num_layers"]):
-        p = f"h{i}."
-        specs += [
-            (p + "ln1.g", (d,), "gain"), (p + "ln1.b", (d,), "normal"),
-            (p + "qkv.w", (d, 3 * d), "normal"), (p + "qkv.b", (3 * d,), "normal"),
-            (p + "proj.w", (d, d), "normal"), (p + "proj.b", (d,), "normal"),
-            (p + "ln2.g", (d,), "gain"), (p + "ln2.b", (d,), "normal"),
-            (p + "up.w", (d, f), "normal"), (p + "up.b", (f,), "normal"),
-            (p + "down.w", (f, d), "normal"), (p + "down.b", (d,), "normal"),
-        ]
-    specs += [("lnf.g", (d,), "gain"), ("lnf.b", (d,), "normal")]
-    return specs
 
 
 def _draw(key, shape, kind, std, dtype):
@@ -83,17 +69,16 @@ def _make_one(key, group_first, member, shape, kind, std, dtype):
     return _draw(_leaf_key(key, group_first, member), shape, kind, std, dtype)
 
 
-def make_weights(cfg: dict, seed: int) -> dict:
+def make_weights(cfg: dict, seed: int, specs) -> dict:
     """Every leaf, one jitted call, on the default device."""
-    specs = tuple((n, tuple(s), k) for n, s, k in leaf_specs(cfg))
+    specs = tuple((n, tuple(s), k) for n, s, k in specs)
     return _make_all(root_key(seed), specs, float(cfg["initializer_range"]),
                      cfg["dtype"])
 
 
-def make_leaf(cfg: dict, seed: int, index: int):
-    """Leaf ``index`` of ``leaf_specs`` alone: the same values as in
+def make_leaf(cfg: dict, seed: int, specs, index: int):
+    """Leaf ``index`` of ``specs`` alone: the same values as in
     ``make_weights``."""
-    specs = leaf_specs(cfg)
     name, shape, kind = specs[index]
     members = next(m for m in _groups(specs).values() if index in m)
     return _make_one(root_key(seed), members[0], members.index(index), tuple(shape),

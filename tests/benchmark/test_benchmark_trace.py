@@ -51,10 +51,12 @@ def test_recorded_flash_roofline(recorded):
     m = Manifest()
     read = m.reader("flash_roofline")
     run = {"trace": recorded, "config": m.config("gpt3-xl-1p3b"),
-           "peaks": m.peaks("TPU v5 lite")}
+           "family": m.family("gpt"), "peaks": m.peaks("TPU v5 lite")}
     # 72 kernels (24 layers x fwd, dQ, dK/dV) took 39.6 ms; the causal FLOPs
     # they need take 24 x 103.1 GFLOP / 197 TFLOP/s = 12.6 ms
     assert read(run) == pytest.approx(31.8, abs=0.3)
+    # a family that states no head width has no such share, never GPT's
+    assert read(dict(run, family=object())) is None
     import importlib.util
     spec = importlib.util.spec_from_file_location("fr", m.root / "metrics" / "flash_roofline.py")
     fr = importlib.util.module_from_spec(spec)
