@@ -160,12 +160,12 @@ def decode_program_report(eng, n_layers):
         ((rows,), np.float32), (eng._key.shape, eng._key.dtype))]
     compiled = eng._fns[key].lower(
         jax.tree_util.tree_map(shape_of, eng._compute_params),
-        shape_of(eng._kpool), shape_of(eng._vpool), *small).compile()
+        *map(shape_of, eng._cache), *small).compile()
     calls = kernel_calls(compiled)
     temps = compiled.memory_analysis().temp_size_in_bytes
     log(f"  decode program {key}: {calls} kernel call(s) for {n_layers} "
         f"layers, temporaries {temps / 2**20:.1f} MiB beside a pool of "
-        f"{eng._kpool.shape[1]} blocks")
+        f"{eng._cache[0].shape[1]} blocks")
     if eng._paged_kernel:
         assert width == eng._max_blocks, "the decode table is not full width"
         assert calls == n_layers, \
@@ -398,13 +398,13 @@ def tp_serve_phase(model, prompts, num_blocks, one_chip_outs):
     log(f"four chips: serving.Engine(tp=4), num_blocks={num_blocks}")
     with Engine(model, tp=4, num_blocks=num_blocks) as eng:
         assert all(len(pool.sharding.device_set) == 4
-                   for pool in (eng._kpool, eng._vpool)), eng._kpool.sharding
+                   for pool in eng._cache), eng._cache[0].sharding
         outs = run_wave(eng, prompts)
         stats = eng.stats()
         assert stats["pages_used"] == 0, stats
         eng._pool.check()
-        log(f"  KV pool sharded {eng._kpool.sharding.spec} over "
-            f"{len(eng._kpool.sharding.device_set)} devices; "
+        log(f"  KV pool sharded {eng._cache[0].sharding.spec} over "
+            f"{len(eng._cache[0].sharding.device_set)} devices; "
             f"{stats['compiles']} programs")
         decode_program_report(eng, model.config.num_layers)
         for i, (a, b) in enumerate(zip(outs, one_chip_outs)):

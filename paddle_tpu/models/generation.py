@@ -117,6 +117,39 @@ def _head_mm(params, rows, key, transpose):
     return rows @ (w.T if transpose else w)
 
 
+def _with_paged_layers(arch):
+    """``arch`` with the two layer functions the paged builders call, for an
+    arch that caches K and V per KV head (GPT, Llama), made of its own
+    ``block`` / ``qkv_rows`` / ``attn_out_rows``:
+
+    - ``prompt_layer(w, x, live) -> (x, cached rows a pool, counts)``: a layer
+      over whole prompts (``live`` marks the real positions; causality makes
+      them exact whatever pads the bucket, so it is not looked at);
+    - ``decode_layer(w, x, pools, li, tables, pos, bids, offs, live) -> (x,
+      pools, counts)``: a layer over one fresh token a row: the row's K and V
+      go into the pools at ``(bids, offs)`` BEFORE the block-table kernel
+      reads them (``build_paged_decode_kernel`` says why).
+
+    ``counts`` is what an arch with routed experts reports a layer (None
+    here). The MLA arch brings both functions itself (``_mla_moe_arch``)."""
+    def prompt_layer(w, x, live):
+        x, rows = arch["block"](w, x)
+        return x, rows, None
+
+    def decode_layer(w, x, pools, li, tables, pos, bids, offs, live):
+        from ..ops.kernels import paged_attention_rows
+
+        kpool, vpool = pools
+        q, k_new, v_new = arch["qkv_rows"](w, x, pos)
+        kpool = kpool.at[li, bids, offs].set(k_new)
+        vpool = vpool.at[li, bids, offs].set(v_new)
+        with jax.named_scope("attention"):
+            o = paged_attention_rows(q, kpool, vpool, li, tables, pos)
+        return arch["attn_out_rows"](w, x, o[:, None]), (kpool, vpool), None
+
+    return dict(arch, prompt_layer=prompt_layer, decode_layer=decode_layer)
+
+
 def _gpt_arch(H, D):
     def embed_prompt(params, ids, T0):
         return params["wte"][ids] + params["wpe"][jnp.arange(T0)][None]
@@ -229,12 +262,13 @@ def _gpt_arch(H, D):
         x = _ln(x, params["lnf_w"], params["lnf_b"])
         return _head_mm(params, x[:, -1], "wte", True)  # tied head
 
-    return {"embed_prompt": embed_prompt, "embed_token": embed_token,
-            "embed_rows": embed_rows, "head_rows": head_rows,
-            "head_all": head_all, "embed_tail": embed_tail,
-            "block_rows": block_rows, "block_tail": block_tail,
-            "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
-            "block": block, "head": head, "kv_heads": H, "head_dim": D}
+    return _with_paged_layers(
+        {"embed_prompt": embed_prompt, "embed_token": embed_token,
+         "embed_rows": embed_rows, "head_rows": head_rows,
+         "head_all": head_all, "embed_tail": embed_tail,
+         "block_rows": block_rows, "block_tail": block_tail,
+         "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
+         "block": block, "head": head, "kv_heads": H, "head_dim": D})
 
 
 # ---------------------------------------------------------------------------
@@ -410,12 +444,13 @@ def _llama_arch(H, KV, D, theta, eps):
         return _head_mm(params, _rms(x, params["lnf_w"], eps)[:, -1],
                         "head_w", False)
 
-    return {"embed_prompt": embed_prompt, "embed_token": embed_token,
-            "embed_rows": embed_rows, "head_rows": head_rows,
-            "head_all": head_all, "embed_tail": embed_tail,
-            "block_rows": block_rows, "block_tail": block_tail,
-            "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
-            "block": block, "head": head, "kv_heads": KV, "head_dim": D}
+    return _with_paged_layers(
+        {"embed_prompt": embed_prompt, "embed_token": embed_token,
+         "embed_rows": embed_rows, "head_rows": head_rows,
+         "head_all": head_all, "embed_tail": embed_tail,
+         "block_rows": block_rows, "block_tail": block_tail,
+         "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
+         "block": block, "head": head, "kv_heads": KV, "head_dim": D})
 
 
 # ---------------------------------------------------------------------------
@@ -717,19 +752,165 @@ def llama_decode_state(model):
     return arch_key, arch, params, cfg.max_position_embeddings
 
 
-def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
-    """Compiled prompt prefill over a length-bucketed batch, writing KV into
-    the paged pool.
+def mla_moe_params(cfg, sd):
+    """The weight tree the serving programs take, from ``{state_dict key:
+    array}`` (arrays or their shapes: ``jax.eval_shape`` goes through)."""
+    H, nope = cfg.num_attention_heads, cfg.qk_nope_head_dim
+    layers = []
+    for i in range(cfg.num_hidden_layers):
+        p = f"model.layers.{i}."
+        w = {"attn_norm": sd[p + "attn_norm.weight"],
+             "ffn_norm": sd[p + "ffn_norm.weight"]}
+        for sub in ("attn", "ffn"):
+            if cfg.hc_mult > 1:
+                w[f"hc_{sub}"] = {k: sd[p + f"{sub}_hc.{k}"]
+                                  for k in ("phi", "alpha", "bias")}
+        for k in ("q_a", "q_b", "q", "kv_a", "kv_b", "o"):
+            if p + f"attn.{k}.weight" in sd:
+                w[k] = sd[p + f"attn.{k}.weight"]
+        for k in ("q_a_norm", "kv_a_norm"):
+            if p + f"attn.{k}.weight" in sd:
+                w[k] = sd[p + f"attn.{k}.weight"]
+        # the absorbed form's per-head views of the up-projection, cut once
+        kv_b = w["kv_b"].reshape(cfg.kv_lora_rank, H, -1)
+        w["uk"] = jnp.transpose(kv_b[..., :nope], (1, 2, 0))
+        w["uv"] = jnp.transpose(kv_b[..., nope:], (1, 0, 2))
+        if cfg.is_expert_layer(i):
+            w.update(router=sd[p + "mlp.router.weight"],
+                     e_bias=sd[p + "mlp.router.e_bias"],
+                     experts_gate=sd[p + "mlp.experts.gate"],
+                     experts_up=sd[p + "mlp.experts.up"],
+                     experts_down=sd[p + "mlp.experts.down"])
+            if cfg.n_shared_experts:
+                w.update({f"shared_{k}": sd[p + f"mlp.shared.{k}.weight"]
+                          for k in ("gate", "up", "down")})
+        else:
+            w.update({k: sd[p + f"mlp.{k}.weight"] for k in ("gate", "up", "down")})
+        layers.append(w)
+    params = {"wte": sd["model.embed_tokens.weight"],
+              "norm": sd["model.norm.weight"], "layers": layers}
+    if not cfg.tie_word_embeddings:
+        params["head_w"] = sd["lm_head.weight"]
+    return params
 
-    The returned pure fn ``prefill(params, ids, lens, tables, kpool, vpool)``
-    runs the dense causal forward over ``ids`` (B, T_bucket) — causality
-    makes the K/V of every REAL position exact regardless of the padding
-    behind it — reshapes each layer's (B, T_bucket, KV, D) K/V into
-    ``T_bucket // block_size`` blocks and scatters them at ``tables[:, :nb]``
-    (rows shorter than the bucket point their tail entries at the reserved
-    trash block 0), and returns ``(kpool, vpool, logits)`` with logits taken
-    at each row's true last prompt token (``lens - 1``)."""
-    KV, D = arch["kv_heads"], arch["head_dim"]
+
+def mla_moe_decode_state(model, kernels=None):
+    """(arch_key, arch, params, max_positions) for ``MLAMoEForCausalLM``: the
+    weight tree the serving programs take and the arch plug around
+    ``models/mla_moe.py``'s layer functions. ``kernels``: whether the layer
+    takes its Pallas kernels (default: wherever Mosaic compiles, as the flash
+    kernel is chosen) or their plain forms."""
+    import dataclasses
+
+    from ..ops.pallas import interpret_default
+
+    cfg = model.config
+    if kernels is None:
+        kernels = not interpret_default()
+    params = mla_moe_params(
+        cfg, {k: v._data for k, v in model.state_dict().items()})
+    # every number the compiled programs bake in keys the cache
+    arch_key = ("mla_moe", bool(kernels)) + tuple(
+        (f.name, repr(getattr(cfg, f.name))) for f in dataclasses.fields(cfg))
+    return arch_key, _mla_moe_arch(cfg, bool(kernels)), params, \
+        cfg.max_position_embeddings
+
+
+def _mla_moe_arch(cfg, kernels):
+    """The arch plug of the latent-attention / routed-expert lineage. Its
+    cache is ONE pool a layer, a padded latent row a token; its layer is
+    ``mla_moe.decoder_layer`` for prompts (``prompt_layer``) and for decode
+    rows (``decode_layer``) alike, so the plug has no per-builder copy of it.
+    It has the plain prefill and decode programs and no other yet
+    (``plain_paths_only``: the engine refuses the rest by name)."""
+    from . import mla_moe as M
+
+    tabs = M.rope_tables(cfg)
+    H = cfg.num_attention_heads
+
+    def embed_prompt(params, ids, T0):
+        return M.embed_streams(cfg, params, ids)
+
+    def embed_rows(params, toks, pos):
+        return M.embed_streams(cfg, params, toks)
+
+    def _head(params, h):
+        if "head_w" in params:
+            return h @ params["head_w"]
+        return h @ params["wte"].T
+
+    def head_rows(params, X, idx):
+        rows = jnp.take_along_axis(X, idx[:, None, None, None], axis=1)[:, 0]
+        return _head(params, M.final_hidden(cfg, params, rows))
+
+    def head_all(params, X):
+        return _head(params, M.final_hidden(cfg, params, X))
+
+    def prompt_layer(w, X, live):
+        X, latent, counts = M.prompt_layer(cfg, tabs, w, X, live, kernels)
+        return X, (latent,), counts
+
+    def decode_layer(w, X, pools, li, tables, pos, bids, offs, live):
+        pool = pools[0]
+
+        def attend(u):
+            nonlocal pool
+            q_nope, q_rope, latent = M.latent_project(cfg, w, u, pos, tabs)
+            # the fresh row goes into the pool BEFORE the read, as in the
+            # K/V kernel step
+            pool = pool.at[li, bids, offs].set(latent)
+            q = M.absorb_queries(cfg, w, q_nope, q_rope)
+            if kernels:
+                from ..ops.kernels.mla_paged_attention import mla_paged_attention
+
+                with jax.named_scope("attention"):
+                    ol = mla_paged_attention(q, pool, li, tables, pos,
+                                             cfg.kv_lora_rank, tabs[2])
+            else:
+                ol = M.attend_absorbed_plain(cfg, q, pool, li, tables, pos,
+                                             tabs[2])
+            o = jnp.einsum("bhc,hcv->bhv", ol, w["uv"])
+            return o.reshape(o.shape[0], H * cfg.v_head_dim) @ w["o"]
+
+        X, counts = M.decoder_layer(cfg, w, X, attend, live, kernels)
+        return X, (pool,), counts
+
+    return {"name": "mla_moe", "embed_prompt": embed_prompt,
+            "embed_rows": embed_rows, "head_rows": head_rows,
+            "head_all": head_all, "head": head_all,
+            "prompt_layer": prompt_layer, "decode_layer": decode_layer,
+            "cache": ((cfg.cache_row,),), "plain_paths_only": True,
+            "expert_layers": sum(cfg.is_expert_layer(i)
+                                 for i in range(cfg.num_hidden_layers)),
+            "experts": cfg.n_routed_experts}
+
+
+def cache_row_shapes(arch):
+    """What the arch caches of one token in one layer, a trailing shape for
+    each pool: the engine's pools are ``(layers, blocks, block_size) +
+    shape``. An arch that declares none caches K and V per KV head."""
+    if "cache" in arch:
+        return tuple(tuple(s) for s in arch["cache"])
+    return ((arch["kv_heads"], arch["head_dim"]),) * 2
+
+
+def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
+    """Compiled prompt prefill over a length-bucketed batch, writing the
+    cache rows into the paged pools.
+
+    The returned pure fn ``prefill(params, ids, lens, tables, *pools)``
+    (``pools``: what the arch declares, :func:`cache_row_shapes`; K and V for
+    GPT and Llama, one latent pool for the MLA arch) runs the dense causal
+    forward over ``ids`` (B, T_bucket) — causality makes the cached rows of
+    every REAL position exact regardless of the padding behind it — reshapes
+    each layer's (B, T_bucket, ...) rows into ``T_bucket // block_size``
+    blocks and scatters them at ``tables[:, :nb]`` (rows shorter than the
+    bucket point their tail entries at the reserved trash block 0), and
+    returns ``(*pools, logits)`` with logits taken at each row's true last
+    prompt token (``lens - 1``). The layer is the arch's ``prompt_layer``,
+    told which positions are real (``live``: inside ``lens``, of a row whose
+    table is mapped); where it routes experts the program returns after the
+    logits the tokens each expert took, ``(expert layers, experts)``."""
     if T_bucket % block_size:
         raise ValueError(
             f"prefill bucket {T_bucket} must be a multiple of block_size "
@@ -739,17 +920,23 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
     if nb > max_blocks:
         raise ValueError("prefill bucket exceeds max sequence blocks")
 
-    def prefill(params, ids, lens, tables, kpool, vpool):
+    def prefill(params, ids, lens, tables, *pools):
         layer_ws = params["layers"]
         x = arch["embed_prompt"](params, ids, T_bucket)
         tb = tables[:, :nb]
+        counts = []
+        live = ((jnp.arange(T_bucket)[None, :] < lens[:, None])
+                & (tables[:, :1] != 0))
         for li, w in enumerate(layer_ws):
-            x, (k, v) = arch["block"](w, x)
-            kpool = kpool.at[li, tb].set(k.reshape(B, nb, block_size, KV, D))
-            vpool = vpool.at[li, tb].set(v.reshape(B, nb, block_size, KV, D))
+            x, rows, c = arch["prompt_layer"](w, x, live)
+            if c is not None:
+                counts.append(c)
+            pools = tuple(
+                p.at[li, tb].set(r.reshape((B, nb, block_size) + r.shape[2:]))
+                for p, r in zip(pools, rows))
         with jax.named_scope("head"):
             logits = arch["head_rows"](params, x, lens - 1)
-        return kpool, vpool, logits
+        return (*pools, logits) + ((jnp.stack(counts),) if counts else ())
 
     return prefill
 
@@ -829,28 +1016,38 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     - the online softmax sums in another order: outputs agree with the gather
       builder within the kernel's stated tolerance, not bit for bit.
     The per-layer math around the read is ``block_rows``' own, factored into
-    ``qkv_rows``/``attn_out_rows``."""
-    def step(params, kpool, vpool, tables, pos, toks, temps, key):
-        from ..ops.kernels import paged_attention_rows
+    ``qkv_rows``/``attn_out_rows`` (``_with_paged_layers`` puts them around
+    the scatter and the kernel as the arch's ``decode_layer``).
 
+    ``step(params, *pools, tables, pos, toks, temps, key)`` returns
+    ``(*pools, next_tokens)``: the embedding, the write slots, the arch's
+    ``decode_layer`` a layer (K and V pools for GPT and Llama; the MLA arch's
+    own: one latent pool, several residual streams, routed experts), the head
+    and the sampling. Where the layers route experts the program returns after
+    the tokens the rows each expert took, ``(expert layers, experts)``, for
+    the engine's one read-back."""
+    def step(params, *args):
+        *pools, tables, pos, toks, temps, key = args
         layer_ws = params["layers"]
         x = arch["embed_rows"](params, toks, pos)
         bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
         offs = pos % block_size
+        live = tables[:, 0] != 0  # a row whose table is unmapped pads the bucket
+        counts = []
         for li, w in enumerate(layer_ws):
-            q, k_new, v_new = arch["qkv_rows"](w, x, pos)
-            kpool = kpool.at[li, bids, offs].set(k_new)
-            vpool = vpool.at[li, bids, offs].set(v_new)
-            with jax.named_scope("attention"):
-                o = paged_attention_rows(q, kpool, vpool, li, tables, pos)
-            x = arch["attn_out_rows"](w, x, o[:, None])
+            # the layer scatters the step's fresh rows and reads its pools
+            # by block table itself
+            x, pools, c = arch["decode_layer"](w, x, tuple(pools), li, tables,
+                                               pos, bids, offs, live)
+            if c is not None:
+                counts.append(c)
         with jax.named_scope("head"):
             logits = arch["head"](params, x)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
         sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
         nxt = jnp.where(temps > 0, sampled, greedy)
-        return kpool, vpool, nxt
+        return (*pools, nxt) + ((jnp.stack(counts),) if counts else ())
 
     return step
 
@@ -868,6 +1065,11 @@ def paged_kernel_default(arch, mosaic=None) -> bool:
     from ..ops.kernels.paged_attention import mosaic_takes
     from ..ops.pallas import interpret_default
 
+    if "cache" in arch:
+        # an arch with a cache of its own reads it by block table on every backend
+        # (through its kernel where Mosaic compiles, a plain gather of the
+        # table elsewhere): the step takes the table whole either way
+        return True
     if mosaic is None:
         mosaic = not interpret_default()
     return bool(mosaic) and mosaic_takes(arch["head_dim"])

@@ -123,7 +123,12 @@ def counters() -> Dict[str, int]:
     exhaustion), ``serve_preempted`` (sequences evicted for re-prefill),
     ``serve_occupancy_live`` / ``serve_occupancy_slots`` (live rows vs
     padded batch slots per decode step — their ratio is mean batch
-    occupancy), ``serve_decode_blocks_read`` (KV blocks the block-table
+    occupancy), ``serve_experts_touched`` / ``serve_expert_assignments``
+    (a routed-expert arch: distinct experts the live tokens of a program hit,
+    summed over its expert layers, and the (token, choice) pairs it routed;
+    padding counts in neither; the table by layer and expert is
+    ``Engine.stats()["expert_tokens"]``),
+    ``serve_decode_blocks_read`` (KV blocks the block-table
     kernel's decode steps read: ``decode_build``'s ``blocks_live``, what the
     rows hold, summed over those steps; over slots x the engine's table
     width it is the share of a padded read that was needed. Steps that
@@ -295,7 +300,8 @@ KNOWN_COUNTERS = frozenset({
     "serve_deadline_shed", "serve_decode_blocks_read",
     "serve_decode_steps",
     "serve_draft_accepted", "serve_draft_proposed",
-    "serve_engine_errors", "serve_failed", "serve_handoffs",
+    "serve_engine_errors", "serve_expert_assignments",
+    "serve_experts_touched", "serve_failed", "serve_handoffs",
     "serve_http_bind_failed", "serve_http_requests",
     "serve_occupancy_live", "serve_occupancy_slots",
     "serve_pages_allocated", "serve_pages_freed", "serve_pages_parked",

@@ -549,9 +549,16 @@ class RequestHandle:
 class Engine:
     """Continuous-batching serving engine over a paged KV cache.
 
-    ``model`` is a ``GPTForPretraining`` or ``LlamaForCausalLM`` instance
-    with full logical weights. The engine thread owns all scheduler state;
-    only the submission queue and stop flag cross threads (guarded below).
+    ``model`` is a ``GPTForPretraining``, ``LlamaForCausalLM`` or
+    ``MLAMoEForCausalLM`` instance with full logical weights. The engine
+    thread owns all scheduler state; only the submission queue and stop flag
+    cross threads (guarded below).
+
+    The cache is what the arch declares (``generation.cache_row_shapes``): a
+    K pool and a V pool of ``(layers, blocks, block_size, kv_heads,
+    head_dim)`` for GPT and Llama, ONE pool of padded latent rows for the MLA
+    arch. ``PagePool``, block tables, growth, eviction and copy-on-write
+    count blocks and never look inside one, so they serve either.
     """
 
     def __init__(self, model, config: Optional[EngineConfig] = None, **overrides):
@@ -563,12 +570,15 @@ class Engine:
         self._jax, self._jnp, self._G = jax, jnp, G
         if hasattr(model, "gpt"):
             arch_key, arch, params, max_pos = G.gpt_decode_state(model)
+        elif hasattr(getattr(model, "config", None), "kv_lora_rank"):
+            arch_key, arch, params, max_pos = G.mla_moe_decode_state(model)
         elif hasattr(model, "lm_head") and hasattr(model, "model"):
             arch_key, arch, params, max_pos = G.llama_decode_state(model)
         else:
             raise TypeError(
                 f"serving.Engine: unsupported model {type(model).__name__} "
-                "(expected GPTForPretraining or LlamaForCausalLM)"
+                "(expected GPTForPretraining, LlamaForCausalLM or "
+                "MLAMoEForCausalLM)"
             )
         if config is not None and overrides:
             raise ValueError("pass EngineConfig OR keyword overrides, not both")
@@ -585,6 +595,15 @@ class Engine:
         # columns / the LM head / the KV pool over a "tp" mesh axis. 0/1
         # leaves every code path below byte-for-byte the single-chip one.
         self._tp = int(cfg.tp) if int(cfg.tp) >= 2 else 0
+        # an arch with the plain prefill and decode programs and no other
+        # yet (the MLA arch): every other path is refused by name, here or at
+        # its call, never served by GPT's code
+        for path, on in (("tp", self._tp), ("int8", cfg.int8),
+                         ("speculative verify", int(cfg.spec_k)),
+                         ("prefix cache / tail prefill", cfg.prefix_cache),
+                         ("chunked prefill", int(cfg.prefill_chunk))):
+            if on:
+                self._refuse(path)
         self._tp_mesh = None
         self._tp_vocab = None
         if self._tp:
@@ -629,22 +648,26 @@ class Engine:
             }
             self._dequant = None
         self._n_layers = len(params["layers"])
-        kv, hd = arch["kv_heads"], arch["head_dim"]
         self._spec_k = int(cfg.spec_k)
         # speculative verify writes reach pos + spec_k: widen the block
         # tables so a real write can never clamp into the trash block
         self._max_blocks = -(-(cfg.max_seq_len + self._spec_k)
                              // cfg.block_size)
-        shape = (self._n_layers, cfg.num_blocks, cfg.block_size, kv, hd)
+        lead = (self._n_layers, cfg.num_blocks, cfg.block_size)
         # under tp the KV pool is sharded on the kv-heads axis: every device
         # owns heads/tp of EVERY block, so the replicated host-side block
         # tables / PagePool bookkeeping index all shards identically. The
         # zeros are CREATED sharded: built on one device and then spread, a
         # pool sized for the mesh does not fit the chip it starts on.
         pool_s = G.tp_pool_sharding(self._tp_mesh) if self._tp else None
-        self._kpool = jnp.zeros(shape, self._dtype, device=pool_s)
-        self._vpool = jnp.zeros(shape, self._dtype, device=pool_s)
+        self._cache = tuple(jnp.zeros(lead + row, self._dtype, device=pool_s)
+                            for row in G.cache_row_shapes(arch))
         self._pool = PagePool(cfg.num_blocks)
+        # routed experts: live tokens each expert took, by expert layer, as
+        # the programs report them beside their tokens
+        self._expert_tokens = (
+            np.zeros((arch["expert_layers"], arch["experts"]), np.int64)
+            if arch.get("experts") else None)
         self._prefill_buckets = self._make_prefill_buckets()
         self._prefix = (_PrefixCache(self._pool, cfg.block_size)
                         if cfg.prefix_cache else None)
@@ -911,7 +934,28 @@ class Engine:
                             if self._prefix is not None else 0),
             "compiles": len(self._fns),
             "decode_steps": self._step_i,
+            **({"expert_tokens": self._expert_tokens.tolist()}
+               if self._expert_tokens is not None else {}),
         }
+
+    def _refuse(self, path: str):
+        """Raise for a path the arch has no program for (an arch that says
+        ``plain_paths_only``; GPT and Llama have every path)."""
+        if self._arch.get("plain_paths_only"):
+            raise NotImplementedError(
+                f"serving: the {self._arch['name']} arch does not support "
+                f"{path} yet")
+
+    def _note_experts(self, sp, counts: np.ndarray):
+        """A program's ``(expert layers, experts)`` count of the live tokens
+        each expert took, onto its span, the counters and ``stats()``."""
+        self._expert_tokens += counts
+        touched = int(np.count_nonzero(counts))
+        assigned = int(counts.sum())
+        sp.set(experts_touched=touched, expert_tokens_max=int(counts.max()),
+               expert_assignments=assigned)
+        counter_inc("serve_experts_touched", touched)
+        counter_inc("serve_expert_assignments", assigned)
 
     def debug_requests(self) -> List[dict]:
         """Live in-flight request table (``/debug/requests``): phase, age,
@@ -1085,7 +1129,7 @@ class Engine:
         # replicated block tables — refuse instead (structured error,
         # re-prefill fallback)
         return (self._n_layers, int(cfg.num_blocks), int(cfg.block_size),
-                int(self._arch["kv_heads"]), int(self._arch["head_dim"]),
+                *self._G.cache_row_shapes(self._arch)[0],
                 str(self._dtype), int(self._tp),
                 "kv-shard/tp" if self._tp else "replicated")
 
@@ -1101,6 +1145,7 @@ class Engine:
         shares the engine's immutable jnp arrays (cheap); on donating
         backends discard it after ``adopt`` — the successor's first step
         consumes the buffers."""
+        self._refuse("snapshots")
         if self._thread.is_alive() and not self._quiesced.is_set() \
                 and not self._failed.is_set():
             raise ServeError(
@@ -1129,10 +1174,11 @@ class Engine:
                                       in self._prefix._entries.items()},
                           "tick": self._prefix._tick}
             owned = sorted(self._pool._owned)
-            sums = self._G.kv_block_checksums(self._kpool, self._vpool, owned)
+            kpool, vpool = self._cache
+            sums = self._G.kv_block_checksums(kpool, vpool, owned)
             snap = {"version": SNAPSHOT_VERSION, "compat": self._compat_key(),
-                    "pool": pool_snap, "kpool": self._kpool,
-                    "vpool": self._vpool, "seqs": seqs, "prefix": prefix,
+                    "pool": pool_snap, "kpool": kpool,
+                    "vpool": vpool, "seqs": seqs, "prefix": prefix,
                     "step_i": self._step_i,
                     "fingerprint": {"bids": owned, "sums": sums}}
             if _inject.should_fire("serve.snapshot_corrupt"):
@@ -1169,6 +1215,7 @@ class Engine:
         (request ids now owned by this engine), block/token counts, and
         ``duration_s``."""
         t0 = time.monotonic()
+        self._refuse("snapshots (adopt)")
         with span("serve_adopt", seqs=len(snap.get("seqs", ()))) as sp:
             try:
                 pool = self._validate_snapshot(snap)
@@ -1206,8 +1253,8 @@ class Engine:
             raise SnapshotError(
                 f"snapshot geometry {compat} does not match this engine's "
                 f"{self._compat_key()} — cross-config adoption refused")
-        if kpool.shape != self._kpool.shape or kpool.dtype != self._dtype \
-                or vpool.shape != self._vpool.shape:
+        if kpool.shape != self._cache[0].shape or kpool.dtype != self._dtype \
+                or vpool.shape != self._cache[1].shape:
             raise SnapshotError("KV pool array shape/dtype mismatch")
         pool = PagePool.restore(snap["pool"])
         bs = self.config.block_size
@@ -1347,8 +1394,7 @@ class Engine:
                     or self._admitting or self._waiting:
                 raise ServeError("adopt requires a fresh engine (no traffic)")
             self._pool = pool
-            self._kpool = snap["kpool"]
-            self._vpool = snap["vpool"]
+            self._cache = (snap["kpool"], snap["vpool"])
             self._prefix = new_prefix
             self._running.extend(running)
             self._resume.extend(resume)
@@ -1434,6 +1480,7 @@ class Engine:
         this raises ``ServeError`` and the normal crash path owns the
         handles (failed, or supervisor-recovered) — every interleaving
         either completes the handoff or falls back whole."""
+        self._refuse("snapshots (handoff)")
         if threading.current_thread() is self._thread:
             raise ServeError("handoff() cannot run on the scheduler thread")
         with self._cv:
@@ -1937,13 +1984,13 @@ class Engine:
                         ids[r, :len(s.tokens)] = s.tokens
                         lens[r] = len(s.tokens)
                         tables[r, :len(s.blocks)] = s.blocks
-                    self._kpool, self._vpool, logits = fn(
-                        self._compute_params, jnp.asarray(ids),
-                        jnp.asarray(lens), jnp.asarray(tables),
-                        self._kpool, self._vpool,
-                    )
+                    logits, *extras = self._run(
+                        fn, self._compute_params, jnp.asarray(ids),
+                        jnp.asarray(lens), jnp.asarray(tables))
                     counter_inc("serve_prefills")
-                    rows = self._prefill_readback(logits)
+                    rows, *extras = self._prefill_readback(logits, *extras)
+                    if extras:
+                        self._note_experts(sp, extras[0])
                     self._land_prefill(chunk, rows)
         for t_bucket in sorted(tail_groups):
             group = tail_groups[t_bucket]
@@ -1968,21 +2015,31 @@ class Engine:
                         starts[r] = start
                         lens[r] = len(s.tokens) - start
                         tables[r, :len(s.blocks)] = s.blocks
-                    self._kpool, self._vpool, logits = fn(
-                        self._compute_params, jnp.asarray(ids),
+                    logits, = self._run(
+                        fn, self._compute_params, jnp.asarray(ids),
                         jnp.asarray(starts), jnp.asarray(lens),
-                        jnp.asarray(tables), self._kpool, self._vpool,
-                    )
+                        jnp.asarray(tables))
                     counter_inc("serve_prefills")
                     counter_inc("serve_tail_prefills")
-                    rows = self._prefill_readback(logits)
+                    rows, = self._prefill_readback(logits)
                     self._land_prefill(chunk, rows)
 
-    def _prefill_readback(self, logits) -> np.ndarray:
+    def _run(self, fn, params, *args, pools_first=False):
+        """Call a compiled program with the cache pools in their slot (last,
+        or right after the parameters) and keep the pools it returns; what
+        else it returned comes back."""
+        args = (*self._cache, *args) if pools_first else (*args, *self._cache)
+        out = fn(params, *args)
+        n = len(self._cache)
+        self._cache = tuple(out[:n])
+        return out[n:]
+
+    def _prefill_readback(self, *arrays) -> List[np.ndarray]:
         """``prefill_readback``: the blocking copy of a prefill program's
-        logits to the host (the wait for the program is in it)."""
+        logits (and what else it reports) to the host (the wait for the
+        program is in it)."""
         with span("prefill_readback"):
-            rows = np.asarray(logits)
+            rows = [np.asarray(a) for a in arrays]
         # beat BEFORE dropping the compile grace: a monitor poll between the
         # two would see a stale beat at the 1x limit and declare a spurious
         # wedge after a long compile
@@ -2064,16 +2121,14 @@ class Engine:
                 starts[r] = s.chunk_pos
                 lens[r] = feeds[r]
                 tables[r, :len(s.blocks)] = s.blocks
-            self._kpool, self._vpool, logits = fn(
-                self._compute_params, jnp.asarray(ids),
-                jnp.asarray(starts), jnp.asarray(lens),
-                jnp.asarray(tables), self._kpool, self._vpool,
-            )
+            logits, = self._run(
+                fn, self._compute_params, jnp.asarray(ids),
+                jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(tables))
             counter_inc("serve_prefill_chunks")
             done = [r for r, s in enumerate(batch)
                     if s.chunk_pos + feeds[r] >= len(s.tokens)]
             if done:  # only final chunks need the logits host-side
-                rows = self._prefill_readback(logits)
+                rows, = self._prefill_readback(logits)
             else:
                 self._beat = time.monotonic()
                 self._compiling = False
@@ -2197,8 +2252,8 @@ class Engine:
                     f"(request {seq.req.id})"
                 )
             new = repl[0]
-            self._kpool = self._kpool.at[:, new].set(self._kpool[:, bid])
-            self._vpool = self._vpool.at[:, new].set(self._vpool[:, bid])
+            self._cache = tuple(p.at[:, new].set(p[:, bid])
+                                for p in self._cache)
             seq.blocks[col] = new
             self._pool.free([bid])
             counter_inc("serve_cow_copies")
@@ -2317,12 +2372,14 @@ class Engine:
             self._compiling = not warm
             self._key, sub = jax.random.split(self._key)
             t0 = time.monotonic()
-            self._kpool, self._vpool, nxt = fn(
-                self._compute_params, self._kpool, self._vpool,
-                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(toks),
-                jnp.asarray(temps), sub,
-            )
-            nxt, = self._decode_readback(nxt)
+            # an arch with routed experts reports the rows each expert took
+            # beside the tokens: ONE blocking read for both
+            nxt, *extras = self._decode_readback(*self._run(
+                fn, self._compute_params, jnp.asarray(tables),
+                jnp.asarray(pos), jnp.asarray(toks), jnp.asarray(temps), sub,
+                pools_first=True))
+            if extras:
+                self._note_experts(sp, extras[0])
             self._step_done(sp, warm, t0, n, bb)
             rows_live = list(self._running)
             with self._decode_land(rows_live) as land:
@@ -2388,12 +2445,10 @@ class Engine:
             self._compiling = not warm
             self._key, sub = jax.random.split(self._key)
             t0 = time.monotonic()
-            self._kpool, self._vpool, greedy, sampled = fn(
-                self._compute_params, self._kpool, self._vpool,
-                jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(toks),
-                jnp.asarray(temps), sub,
-            )
-            greedy, sampled = self._decode_readback(greedy, sampled)
+            greedy, sampled = self._decode_readback(*self._run(
+                fn, self._compute_params, jnp.asarray(tables),
+                jnp.asarray(pos), jnp.asarray(toks), jnp.asarray(temps), sub,
+                pools_first=True))
             self._step_done(sp, warm, t0, n, bb)
             proposed = accepted = emitted = 0
             rows_live = list(self._running)
@@ -2566,7 +2621,7 @@ class Engine:
                 raw = G.build_paged_prefill(
                     self._arch, bw, t_bucket, self.config.block_size,
                     self._max_blocks)
-                donate = (4, 5)
+                donate = tuple(range(4, 4 + len(self._cache)))
             elif kind == "prefill_tail":
                 bw, t_bucket = bucket
                 raw = G.build_paged_tail_prefill(
@@ -2592,7 +2647,7 @@ class Engine:
                 build = (G.build_paged_decode_kernel if self._paged_kernel
                          else G.build_paged_decode)
                 raw = build(self._arch, bb, self.config.block_size, mb)
-                donate = (1, 2)
+                donate = tuple(range(1, 1 + len(self._cache)))
             if self._dequant is not None:
                 dq, inner = self._dequant, raw
 
@@ -2658,10 +2713,9 @@ class Engine:
         lens = np.ones((bw,), np.int32)
         lens[0] = len(prompt)
         tables = np.full((bw, self._max_blocks), TRASH_BLOCK, np.int32)
-        self._kpool, self._vpool, logits = fn(
-            self._compute_params, jnp.asarray(ids), jnp.asarray(lens),
-            jnp.asarray(tables), self._kpool, self._vpool,
-        )
+        logits, *_ = self._run(
+            fn, self._compute_params, jnp.asarray(ids), jnp.asarray(lens),
+            jnp.asarray(tables))
         return np.asarray(logits[0])
 
 

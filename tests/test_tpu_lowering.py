@@ -204,3 +204,138 @@ def test_kernel_decode_step_at_the_cell_shape(v5e, monkeypatch):
         'custom_call_target="tpu_custom_call"') == L
     temps = compiled.memory_analysis().temp_size_in_bytes
     assert temps < 1e9, f"decode step temporaries {temps / 1e9:.2f} GB"
+
+
+# -- the MLA / routed-expert / hyper-connection arch (PR 27) -------------------
+# Xing4.0-29B-A4B's published widths: d 3584, 32 heads, a cached row of 576
+# numbers padded to 640, 64 experts of width 1024
+def _xing4_kernels(monkeypatch):
+    from paddle_tpu.ops.kernels import mhc_mix, mla_paged_attention, moe_experts
+
+    for mod in (mhc_mix, mla_paged_attention, moe_experts):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    return mhc_mix, mla_paged_attention, moe_experts
+
+
+@pytest.mark.parametrize("B", [8, 64])
+def test_mla_paged_attention_compiles(v5e, B):
+    from paddle_tpu.ops.kernels.mla_paged_attention import mla_paged_attention
+
+    s = SingleDeviceSharding(v5e[0])
+    n = _compile_for_tpu(
+        lambda q, pool, tables, pos: mla_paged_attention(
+            q, pool, 7, tables, pos, 512, 0.1, interpret=False),
+        _on(s, (B, 32, 640), jnp.bfloat16),
+        _on(s, (8, 10600, 16, 640), jnp.bfloat16),
+        _on(s, (B, 128), jnp.int32), _on(s, (B,), jnp.int32))
+    assert n == 1
+
+
+def test_mla_paged_attention_row_off_the_lane_tile_is_refused(v5e):
+    """Mosaic's verdict on the UNPADDED latent row, kept as a test: 576 is
+    not a multiple of its 128-lane tile (PR 25 found the same of 64-wide K/V
+    heads), which is why a pooled row is padded to 640; the wrapper says so
+    before Mosaic would."""
+    from paddle_tpu.ops.kernels import mla_paged_attention as mod
+
+    s = SingleDeviceSharding(v5e[0])
+    args = (_on(s, (8, 32, 576), jnp.bfloat16),
+            _on(s, (8, 512, 16, 576), jnp.bfloat16),
+            _on(s, (8, 16), jnp.int32), _on(s, (8,), jnp.int32))
+    with pytest.raises(ValueError, match="whole 128-lane tiles"):
+        _compile_for_tpu(lambda q, pool, tables, pos: mod.mla_paged_attention(
+            q, pool, 0, tables, pos, 512, 0.1, interpret=False), *args)
+    with pytest.raises(Exception, match="Mosaic failed to compile|aligned|tiling"):
+        _compile_for_tpu(lambda q, pool, tables, pos: mod._mla_call(
+            q, pool, jnp.zeros((1,), jnp.int32), tables, pos, R=512, scale=0.1,
+            C=8, interpret=False), *args)
+
+
+@pytest.mark.parametrize("N", [pytest.param(8, id="decode_8_rows"),
+                               pytest.param(64, id="decode_64_rows"),
+                               pytest.param(4096, id="prefill_4x1024")])
+def test_moe_experts_compiles(v5e, N):
+    from paddle_tpu.ops.kernels.moe_experts import moe_experts
+
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    n = _compile_for_tpu(
+        lambda x, slot, g, wg, wu, wd: moe_experts(x, slot, g, wg, wu, wd,
+                                                   interpret=False),
+        _on(s, (N, 3584), bf), _on(s, (N, 4), jnp.int32),
+        _on(s, (N, 4), jnp.float32), _on(s, (64, 3584, 1024), bf),
+        _on(s, (64, 3584, 1024), bf), _on(s, (64, 1024, 3584), bf))
+    assert n == 1
+
+
+@pytest.mark.parametrize("T", [32, 4096])
+def test_mhc_mix_compiles(v5e, T):
+    from paddle_tpu.ops.kernels.mhc_mix import mhc_mix
+
+    n = _compile_for_tpu(
+        lambda z: mhc_mix(z, 4, 20, 1e-6, (-30.0, 30.0), interpret=False),
+        _on(SingleDeviceSharding(v5e[0]), (T, 24), jnp.float32))
+    assert n == 1
+
+
+def _xing4_operands(s, layers, nb):
+    """(arch, params as shapes, the latent pool) of the benchmark's
+    configuration at ``layers`` layers."""
+    import json
+    import pathlib
+
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
+
+    path = pathlib.Path(__file__).parent.parent / "benchmark/configs/xing4-29b-a4b-8l.json"
+    cfg = MLAMoEConfig.from_dict({**json.loads(path.read_text()),
+                                  "num_hidden_layers": layers})
+    sd = {k: _on(s, shape, jnp.bfloat16)
+          for k, shape, _ in MLAMoEForCausalLM.parameter_specs(cfg)}
+    params = jax.tree_util.tree_map(
+        lambda x: _on(s, x.shape, x.dtype),
+        jax.eval_shape(lambda sd: G.mla_moe_params(cfg, sd), sd))
+    pool = _on(s, (layers, nb, 16, cfg.cache_row), jnp.bfloat16)
+    return G._mla_moe_arch(cfg, True), params, pool
+
+
+def test_xing4_decode_step_beside_a_full_pool(v5e, monkeypatch):
+    """The 32-row decode program of the cell's configuration, whole (2 dense
+    + 6 expert layers, 11.3 GB of weights) beside the pool that fills what
+    they leave: 16 + 8 + 6 kernel calls, the donated pool updated in place,
+    and temporaries that follow neither the pool nor the experts (23.5 MiB
+    when written; a copy of ONE expert matrix stack would be 470 MB)."""
+    import paddle_tpu.models.generation as G
+
+    _xing4_kernels(monkeypatch)
+    s, B, MB, NB = SingleDeviceSharding(v5e[0]), 32, 128, 10600
+    arch, params, pool = _xing4_operands(s, 8, NB)
+    assert G.paged_kernel_default(arch, mosaic=True)
+    step = jax.jit(G.build_paged_decode_kernel(arch, B, 16, MB), donate_argnums=(1,))
+    compiled = _compile_uncached(step.trace(
+        params, pool, _on(s, (B, MB), jnp.int32), _on(s, (B,), jnp.int32),
+        _on(s, (B,), jnp.int32), _on(s, (B,), jnp.float32),
+        _on(s, (2,), jnp.uint32)).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 16 + 8 + 6
+    for name in ("mhc_mix", "mla_paged_attention", "moe_experts_t16"):
+        assert f"%{name}" in text, name
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 8 * NB * 16 * 640 * 2
+    assert mem.temp_size_in_bytes < 256e6, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
+def test_xing4_prefill_program_compiles(v5e, monkeypatch):
+    """The widest prefill program of the cell (4 prompts x 1,024 positions):
+    expanded attention, experts in 256-row tiles, temporaries inside the 2.5
+    GiB the pool's sizing leaves (1.26 GB when written)."""
+    import paddle_tpu.models.generation as G
+
+    _xing4_kernels(monkeypatch)
+    s, NB = SingleDeviceSharding(v5e[0]), 10600
+    arch, params, pool = _xing4_operands(s, 8, NB)
+    pre = jax.jit(G.build_paged_prefill(arch, 4, 1024, 16, 128), donate_argnums=(4,))
+    compiled = _compile_uncached(pre.trace(
+        params, _on(s, (4, 1024), jnp.int32), _on(s, (4,), jnp.int32),
+        _on(s, (4, 128), jnp.int32), pool).lower(lowering_platforms=("tpu",)))
+    assert "%moe_experts_t256" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
