@@ -153,11 +153,14 @@ def decode_program_report(eng, n_layers):
     def shape_of(a):
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=a.sharding)
 
-    # tables, positions, tokens, temperatures and the key go in uncommitted,
-    # as the engine passes them
+    # the step's packed operand, the previous step's tokens and the base
+    # key go in uncommitted, as the engine passes them
+    from paddle_tpu.models.generation import STEP_COLS
+
     small = [jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in (
-        ((rows, width), np.int32), ((rows,), np.int32), ((rows,), np.int32),
-        ((rows,), np.float32), (eng._key.shape, eng._key.dtype))]
+        ((rows, width + STEP_COLS), np.int32),
+        (eng._no_prev.shape, eng._no_prev.dtype),
+        (eng._key.shape, eng._key.dtype))]
     compiled = eng._fns[key].lower(
         jax.tree_util.tree_map(shape_of, eng._compute_params),
         *map(shape_of, eng._cache), *small).compile()

@@ -955,7 +955,12 @@ def build_paged_decode(arch, B, block_size, max_blocks):
     at that temperature (one PRNG key per step — not replay-stable across
     batch compositions); rows at 0 are greedy. Dead/padding rows should
     point their tables at the trash block with ``pos = 0``; their outputs
-    are garbage the scheduler ignores."""
+    are garbage the scheduler ignores.
+
+    The serving engine jits this step inside :func:`feed_tokens_back`, whose
+    operand list is ``(params, *pools, ints, prev, key)``: the tables,
+    ``pos`` and the temperatures packed into ONE int32 array, and ``toks`` taken from the previous step's ``next_tokens`` on
+    the device (``prev[src]``) wherever a row was in that step."""
     KV, D = arch["kv_heads"], arch["head_dim"]
     T_pad = block_size * max_blocks
 
@@ -1025,7 +1030,9 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     own: one latent pool, several residual streams, routed experts), the head
     and the sampling. Where the layers route experts the program returns after
     the tokens the rows each expert took, ``(expert layers, experts)``, for
-    the engine's one read-back."""
+    the engine's one read-back. The engine jits it inside
+    :func:`feed_tokens_back` (operands ``(params, *pools, ints, prev,
+    key)``), as it does the gather step."""
     def step(params, *args):
         *pools, tables, pos, toks, temps, key = args
         layer_ws = params["layers"]
@@ -1048,6 +1055,52 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
         sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
         nxt = jnp.where(temps > 0, sampled, greedy)
         return (*pools, nxt) + ((jnp.stack(counts),) if counts else ())
+
+    return step
+
+
+# columns of a decode step's packed operand, after the block table
+STEP_COLS = 4  # position, src, host token, temperature (float32 bits)
+
+
+def feed_tokens_back(inner, B, max_batch, max_blocks, n_pools):
+    """Wrap a decode step (``build_paged_decode``, ``build_paged_decode_kernel``
+    or ``build_tp_paged_decode``; their bodies stay as they are) so that the
+    tokens it feeds may come from the step before it WITHOUT a trip to the
+    host (the serving loop enqueues step k+1 before it has read step k's
+    tokens), and so that the host hands it ONE array a step.
+
+    ``step(params, *pools, ints, prev, key)``:
+
+    - ``ints`` (B, max_blocks + ``STEP_COLS``) int32 packs what the host
+      builds: the block table, then a column each of ``pos``, ``src``, the
+      host's token and the temperature (float32, bit for bit);
+    - ``prev`` is the previous step's ``next_tokens`` as the device array it
+      is, ``max_batch`` long whatever bucket produced it; ``src`` is a row's
+      index in that step, or -1 for a row that was not in it (one a prefill
+      just landed, or any row when nothing is in flight), which takes the
+      host's token: ``toks = where(src >= 0, prev[src], host_toks)``;
+    - ``key`` is the step's PRNG key, which only sampling rows read: the
+      engine makes one (``fold_in`` of its base key and the step's number)
+      for a step that has such a row, and hands every other step the base
+      key as the device array it already is. (Folding inside the program
+      cost each decode program 0.6 s more to lower on the chip's host, seven
+      programs an engine: PERF.md, PR 28.)
+
+    Returns ``inner``'s outputs with ``next_tokens`` padded to ``max_batch``,
+    so that one program a bucket serves whatever bucket ran before it. The
+    function keeps the name ``step``: the device line names programs
+    ``jit_step`` by it."""
+    def step(params, *args):
+        *pools, ints, prev, key = args
+        tables = ints[:, :max_blocks]
+        pos, src, host_toks, temps = (
+            ints[:, max_blocks + c] for c in range(STEP_COLS))
+        toks = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], host_toks)
+        out = inner(params, *pools, tables, pos, toks,
+                    lax.bitcast_convert_type(temps, jnp.float32), key)
+        nxt = jnp.zeros((max_batch,), jnp.int32).at[:B].set(out[n_pools])
+        return (*out[:n_pools], nxt, *out[n_pools + 1:])
 
     return step
 
@@ -1547,7 +1600,9 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
     equal the single-chip builders' (see the section comment); the
     paged-attention kernel is a drop-in on the local shard: its block copies
     read the chip's (L, NB, BS, KVl, D) pools and H/KV keeps the same GQA
-    ratio."""
+    ratio. The engine jits it inside :func:`feed_tokens_back` like the
+    single-chip steps (operands ``(packed, kpool, vpool, ints, prev,
+    key)``, all three replicated)."""
     from jax.sharding import PartitionSpec as P
 
     tp = mesh.shape["tp"]

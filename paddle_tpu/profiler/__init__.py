@@ -128,6 +128,12 @@ def counters() -> Dict[str, int]:
     summed over its expert layers, and the (token, choice) pairs it routed;
     padding counts in neither; the table by layer and expert is
     ``Engine.stats()["expert_tokens"]``),
+    ``serve_decode_ahead`` (decode steps enqueued while the step before was
+    still unread on the device: over ``serve_decode_steps``, how often the
+    loop ran one step ahead of the host), ``serve_decode_drains`` (landings
+    forced with nothing enqueued behind: eviction, the OOM back-off, handoff,
+    shutdown, containment), ``serve_decode_wasted_rows`` (row-steps computed
+    for a row that had ended on an EOS value, a cancel or a deadline),
     ``serve_decode_blocks_read`` (KV blocks the block-table
     kernel's decode steps read: ``decode_build``'s ``blocks_live``, what the
     rows hold, summed over those steps; over slots x the engine's table
@@ -297,8 +303,8 @@ KNOWN_COUNTERS = frozenset({
     "serve_admitted", "serve_adoptions", "serve_backpressure",
     "serve_cancelled", "serve_compiles", "serve_cow_copies",
     "serve_crash_detected", "serve_deadline_expired",
-    "serve_deadline_shed", "serve_decode_blocks_read",
-    "serve_decode_steps",
+    "serve_deadline_shed", "serve_decode_ahead", "serve_decode_blocks_read",
+    "serve_decode_drains", "serve_decode_steps", "serve_decode_wasted_rows",
     "serve_draft_accepted", "serve_draft_proposed",
     "serve_engine_errors", "serve_expert_assignments",
     "serve_experts_touched", "serve_failed", "serve_handoffs",

@@ -26,6 +26,24 @@ programs in ``models/generation.py``:
 * **prefill/decode phase separation** — prompt prefill is a dense causal
   pass batched by length bucket; decode is one packed batch with per-row
   positions and live masks;
+* **the decode loop runs one step ahead of the host** — in one scheduler
+  iteration ``_decode`` builds and enqueues step k+1, THEN reads back and
+  lands step k; the tokens step k+1 feeds stay on the device
+  (``generation.feed_tokens_back``: ``where(src >= 0, prev[src], host)``),
+  so host and device no longer take turns. The price is a ONE-STEP
+  RETIREMENT LAG: a row whose budget ends with the token in flight is left
+  out of step k+1 (the host can count), but a row that ends on an EOS value,
+  a cancel or a deadline is found one step late; the row-step computed for
+  it is thrown away (``serve_decode_wasted_rows``), its token reaches neither
+  the result nor the stream, and its blocks are freed when it retires (the
+  device runs programs in order, so whoever inherits a block writes it after
+  the dead row's last write). THE DRAIN RULE: anything that takes a sequence
+  out of the running set other than a landing first lands the step in
+  flight (``_drain``, ``serve_decode_drains``): eviction from block growth,
+  the OOM back-off, snapshot / handoff, shutdown and crash containment. The
+  speculative step stays synchronous (its accept length is a host
+  comparison). Nothing chooses between the two but what the engine can
+  observe: ``spec_k``, a row's budget, whether a request is done;
 * **int8 serving** (``int8=True``) — weight-only int8 via the PTQ rounding
   (serving/int8.py), dequantized inside the compiled programs;
 * **deadlines, priorities, load shedding** (resilience layer) —
@@ -64,7 +82,12 @@ Every scheduler action is a profiler span (``schedule`` holding ``admit``,
 ``decode_step`` with ``decode_readback``/``decode_land``, and ``evict``)
 with ``serve_*`` counters: the host phases of a step are named where the
 work happens, and lie in a ``jax.profiler`` trace beside the device's
-operations (profiler/spans.py). The engine registers a flight-recorder
+operations (profiler/spans.py). In the plain loop a ``decode_step`` holds the
+enqueue of step k+1 (its self time) and the read-back and landing of step k,
+whose ``rows`` / ``bucket`` / ``step`` it carries (``ahead`` 1; 0 where
+nothing was enqueued behind another); ``serve_decode_ahead`` over
+``serve_decode_steps`` is how often the loop ran ahead. The engine registers
+a flight-recorder
 context provider so crash dumps carry the in-flight request table. Chaos
 points ``serve.crash``
 / ``serve.wedge`` / ``serve.slow_step`` / ``serve.pool_corrupt`` /
@@ -92,7 +115,7 @@ import queue as _queue
 import threading
 import time
 import weakref
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, NamedTuple, Optional, Sequence
 
 import numpy as np
 
@@ -461,10 +484,13 @@ class _Seq:
     admission matched from the prefix cache (shared, already filled — the
     prefill pass runs only the tail). ``chunk_pos`` is the chunked-prefill
     cursor: prompt tokens below it have K/V in cache (0 outside the chunked
-    path, where the whole prompt lands in one prefill pass)."""
+    path, where the whole prompt lands in one prefill pass). ``slot`` is the
+    row's index in the decode step in flight (-1 when it has none): that
+    step writes ``pos`` and its token is still on the device, so the step
+    enqueued behind it feeds ``prev[slot]`` at ``pos + 1``."""
 
     __slots__ = ("req", "tokens", "blocks", "prompt_len", "cached_blocks",
-                 "chunk_pos")
+                 "chunk_pos", "slot")
 
     def __init__(self, req: _Request, tokens: List[int]):
         self.req = req
@@ -473,14 +499,40 @@ class _Seq:
         self.prompt_len = len(req.prompt)
         self.cached_blocks = 0
         self.chunk_pos = 0
+        self.slot = -1
 
     @property
     def pos(self) -> int:
         return len(self.tokens) - 1
 
     @property
+    def next_pos(self) -> int:
+        """The position the next step to be BUILT writes: one past ``pos``
+        while a step is in flight for the row."""
+        return len(self.tokens) - (self.slot < 0)
+
+    @property
+    def ends_in_flight(self) -> bool:
+        """The token in flight is the last of the row's budget: known
+        without reading it, so the row is not fed again."""
+        return self.slot >= 0 \
+            and self.generated + 1 >= self.req.max_new_tokens
+
+    @property
     def generated(self) -> int:
         return len(self.tokens) - self.prompt_len
+
+
+class _Flight(NamedTuple):
+    """The decode step in flight: enqueued, its tokens not yet read."""
+
+    arrays: tuple    # the program's outputs past the pools: ``next_tokens``
+    #                  padded to ``max_batch``, then what the arch reports
+    rows: list       # the sequences by row at dispatch
+    bucket: int
+    pos: np.ndarray  # the position each row's step writes
+    t0: float        # dispatch time and whether the program was warm: what
+    warm: bool       # the step-time EMA needs at landing
 
 
 class RequestHandle:
@@ -704,6 +756,13 @@ class Engine:
         # speculative and tail-prefill programs gather
         self._paged_kernel = bool(self._G.paged_kernel_default(arch))
         self._running: List[_Seq] = []
+        # the decode loop runs one step ahead of the host: the step whose
+        # tokens are still on the device (None between bursts and wherever
+        # the true state was needed: _drain), what a step is fed for
+        # ``prev`` when nothing is, and how often the mechanism engaged
+        self._flight: Optional[_Flight] = None
+        self._no_prev = jnp.zeros((cfg.max_batch,), jnp.int32)
+        self._ahead = self._drains = self._wasted_rows = 0
         self._resume: List[_Seq] = []  # preempted, awaiting re-prefill
         self._admitting: List[_Seq] = []  # popped off the queue, mid-prefill
         # chunked prefill (PR 19): seqs whose prompt is being prefilled one
@@ -934,6 +993,9 @@ class Engine:
                             if self._prefix is not None else 0),
             "compiles": len(self._fns),
             "decode_steps": self._step_i,
+            "decode_ahead": self._ahead,
+            "decode_drains": self._drains,
+            "decode_wasted_rows": self._wasted_rows,
             **({"expert_tokens": self._expert_tokens.tolist()}
                if self._expert_tokens is not None else {}),
         }
@@ -1151,6 +1213,7 @@ class Engine:
             raise ServeError(
                 "snapshot requires a quiesced or dead scheduler thread "
                 "(use handoff(), or capture after a supervised crash)")
+        self._settle()  # a capture holds landed tokens only
         with span("serve_snapshot", step=self._step_i,
                   running=len(self._running)) as sp:
             pool_snap = self._pool.snapshot()
@@ -1533,16 +1596,18 @@ class Engine:
         WITHOUT ``_shutdown`` — the handoff snapshot owns the handles."""
         self._beat = time.monotonic()  # heartbeat: health() / supervisor
         with self._cv:
-            if self._handoff_req and not self._stop:
+            land_first = self._handoff_req and self._flight is not None
+            if self._handoff_req and not self._stop and not land_first:
                 # handoff quiesce: this is a step boundary (no _step in
-                # flight), so the capture is consistent by construction.
+                # flight, the decode step that ran ahead landed), so the
+                # capture is consistent by construction.
                 # _stop flips under the same lock, so submit() raises and a
                 # supervisor monitor sees a closed engine, never a crash.
                 self._stop = True
                 self._quiesced.set()
                 return "handoff"
             idle = not (self._waiting or self._running or self._resume
-                        or self._prefilling)
+                        or self._prefilling or self._flight is not None)
             if self._draining and idle:
                 self._stop = True  # drain complete: fall through to stop
             if not self._stop and idle:
@@ -1550,8 +1615,11 @@ class Engine:
             if self._stop:
                 return True
             has_work = bool(self._waiting or self._running or self._resume
-                            or self._prefilling)
-        if has_work:
+                            or self._prefilling or self._flight is not None)
+        if land_first:
+            # outside the lock (a device read); the next iteration quiesces
+            self._drain()
+        elif has_work:
             self._step()
         if self._watchdog is not None:
             # supervised engines ride the PR 8 progress table: the scheduler
@@ -1598,11 +1666,11 @@ class Engine:
             self._admitting = []
             if self._prefilling:
                 self._chunk_step()
-            if self._running:
-                if self._spec_k:
+            if self._spec_k:
+                if self._running:
                     self._decode_spec()
-                else:
-                    self._decode()
+            elif self._running or self._flight is not None:
+                self._decode()
             sp.set(running_after=len(self._running))
             if self._obs is not None and flags.flag(
                     "FLAGS_hbm_admission", "off") != "off":
@@ -1680,6 +1748,9 @@ class Engine:
             # not recorded twice)
             raise exc
         _mem.note_oom("serve.step", exc)
+        # the decode step in flight lands, or is dropped where the error was
+        # its own: what follows works on landed positions
+        self._settle()
         # a mid-prefill OOM strands sequences in _admitting (blocks granted,
         # KV never written): free the grant and route them through the
         # preemption/resume path — they re-prefill from their accumulated
@@ -2159,16 +2230,20 @@ class Engine:
         for re-prefill) — backpressure, never failure. Victim selection is
         priority-then-youngest: the lowest-priority peer goes first, ties
         broken by the youngest request; a grower never evicts a
-        higher-priority peer — it preempts ITSELF instead. Returns the
-        number of blocks it mapped."""
+        higher-priority peer — it preempts ITSELF instead. The block is the
+        one the step to be built writes (``next_pos``: one past the position
+        of a step in flight), and a row whose budget ends in flight needs
+        none. Eviction takes a sequence out of the running set, so the step
+        in flight lands first (``_drain``), which may itself free what was
+        missing. Returns the number of blocks it mapped."""
         grown = 0
         for seq in list(self._running):
-            if seq not in self._running:
-                continue  # evicted by an earlier iteration
-            # spec verify writes k slots past pos — map those blocks too
-            need = ((seq.pos + self._spec_k) // self.config.block_size + 1
-                    - len(seq.blocks))
-            while need > 0:
+            while seq in self._running and not seq.ends_in_flight:
+                # spec verify writes k slots past pos — map those blocks too
+                need = ((seq.next_pos + self._spec_k)
+                        // self.config.block_size + 1 - len(seq.blocks))
+                if need <= 0:
+                    break
                 got = self._pool.alloc(need)
                 if got is None and self._prefix is not None \
                         and len(self._prefix):
@@ -2179,6 +2254,9 @@ class Engine:
                     seq.blocks.extend(got)
                     grown += need
                     break
+                if self._flight is not None:
+                    self._drain()
+                    continue
                 victims = [s for s in self._running if s is not seq]
                 if not victims:
                     # a lone sequence always fits (submit() bounds it), so
@@ -2240,7 +2318,7 @@ class Engine:
         keeps peers bit-intact even if a future scheduler maps shared
         blocks more aggressively."""
         bs = self.config.block_size
-        lo, hi = seq.pos // bs, (seq.pos + self._spec_k) // bs
+        lo, hi = seq.next_pos // bs, (seq.next_pos + self._spec_k) // bs
         for col in range(lo, min(hi + 1, len(seq.blocks))):
             bid = seq.blocks[col]
             if self._pool.refcount(bid) <= 1:
@@ -2264,47 +2342,69 @@ class Engine:
     # span where the work happens: ``decode_build`` (under ``schedule``), then
     # ``decode_step`` from the dispatch to the end of the landing, holding
     # ``decode_readback`` and ``decode_land``; what is left of ``decode_step``
-    # is the program lookup, the key split, the transfers and the enqueue.
+    # is the program lookup, the transfer of the step's operand and the
+    # enqueue (the speculative step still splits its key and makes four
+    # transfers). In the plain loop the step BUILT and enqueued is one ahead
+    # of the step read and landed (``_decode``).
     def _decode_build(self, k: int = 0):
         """``decode_build``: map the blocks the step will write, guard shared
         ones, choose the bucket and fill the step's host arrays (``k`` draft
-        columns beside each row's pending token). Returns None when growing
-        preempted every row, else (rows, bucket, table width, drafts,
-        tables, positions, tokens, temperatures)."""
+        columns beside each row's pending token). The rows are the running
+        set less those whose budget ends with the token in flight; a row in
+        flight is fed from the device (``src``: its row there) at one past
+        its landed position, the others their last token from the host
+        (``src`` -1). Returns None when no row is left to step, else (rows,
+        bucket, table width, drafts, tables, positions, tokens,
+        temperatures, packed): for the plain step (``k`` 0) the arrays are
+        views of ``packed``, the one operand that crosses to the device."""
         with span("decode_build") as sp:
             grown = self._grow_blocks()
-            n = len(self._running)
+            rows = [s for s in self._running if not s.ends_in_flight]
+            n = len(rows)
             sp.set(rows=n, blocks_grown=grown)
             if not n:
                 return None
             if self._prefix is not None:
-                for s in self._running:
+                for s in rows:
                     self._cow_guard(s)
             bb = next(b for b in self.config.decode_buckets if b >= n)
             # the kernel step's work follows each row's live blocks, so it
             # takes the table whole: one program a bucket, no regrowth
             kernel_step = self._paged_kernel and not k
             mb = self._max_blocks if kernel_step else self._gather_width(bb)
-            blocks_live = sum(len(s.blocks) for s in self._running)
+            blocks_live = sum(len(s.blocks) for s in rows)
             sp.set(bucket=bb, blocks_live=blocks_live)
             if kernel_step:
                 # a gathering step reads bucket x width blocks, live or not
                 counter_inc("serve_decode_blocks_read", blocks_live)
             drafts = self._propose(bb) if k else None
-            tables = np.full((bb, mb), TRASH_BLOCK, np.int32)
-            pos = np.zeros((bb,), np.int32)
-            toks = np.zeros((bb, k + 1), np.int32)
-            temps = np.zeros((bb,), np.float32)
-            for r, s in enumerate(self._running):
+            if k:
+                tables = np.empty((bb, mb), np.int32)
+                pos = np.zeros((bb,), np.int32)
+                toks = np.zeros((bb, k + 1), np.int32)
+                temps = np.zeros((bb,), np.float32)
+                ints = src = None
+            else:
+                # the plain step takes ONE operand from the host, its
+                # arrays side by side (generation.feed_tokens_back)
+                ints = np.zeros((bb, mb + self._G.STEP_COLS), np.int32)
+                tables, (pos, src, toks, temps) = ints[:, :mb], (
+                    ints[:, mb + c] for c in range(self._G.STEP_COLS))
+                temps = temps.view(np.float32)
+                src[:] = -1
+            tables[:] = TRASH_BLOCK
+            for r, s in enumerate(rows):
                 tables[r, :len(s.blocks)] = s.blocks
-                pos[r] = s.pos
-                toks[r, 0] = s.tokens[-1]
+                pos[r] = s.next_pos
                 temps[r] = s.req.temperature
+                if k:
+                    toks[r, 0] = s.tokens[-1]
+                else:
+                    toks[r] = s.tokens[-1]
+                    src[r] = s.slot
             if k:
                 toks[:n, 1:] = drafts[:n]
-            else:
-                toks = toks[:, 0]
-        return n, bb, mb, drafts, tables, pos, toks, temps
+        return rows, bb, mb, drafts, tables, pos, toks, temps, ints
 
     def _decode_readback(self, *arrays):
         """``decode_readback``: the blocking copy of the step's tokens to the
@@ -2319,7 +2419,8 @@ class Engine:
     def _decode_land(self, rows_live: List[_Seq]):
         """``decode_land``: the step's tokens into their streams, with the
         retirements and page frees that follow; the caller appends inside
-        and sets ``tokens``."""
+        and sets ``tokens``. ``rows_live``: the step's rows that are still
+        running when it lands."""
         with span("decode_land") as sp:
             yield sp
             if self._obs is not None:
@@ -2329,7 +2430,7 @@ class Engine:
                 # step granularity)
                 self._obs.on_tokens([s.req for s in rows_live],
                                     time.monotonic())
-            sp.set(retired=len(rows_live) - len(self._running))
+            sp.set(retired=sum(s.req.done.is_set() for s in rows_live))
 
     def _step_done(self, sp, warm: bool, t0: float, n: int, bb: int):
         """Book-keeping of a decode step whose tokens have reached the host,
@@ -2356,36 +2457,152 @@ class Engine:
         counter_inc("serve_occupancy_slots", bb)
 
     def _decode(self):
-        jnp, jax = self._jnp, self._jax
+        """One plain decode iteration, one step AHEAD of the host: build and
+        enqueue step k+1 for the rows that will still be live, THEN read back
+        and land step k, which the device finished, or is finishing, while
+        the host built. The fed tokens of the rows in flight never visit the
+        host (``generation.feed_tokens_back``).
+
+        What the host knows without the token it uses: a row whose budget
+        ends with the token in flight is left out of step k+1. A row that
+        ends on an EOS value, is cancelled or misses its deadline is found
+        one step late: the row-step computed for it is thrown away
+        (``serve_decode_wasted_rows``) and its token reaches neither the
+        result nor the stream. Its blocks are freed when it retires: the
+        device runs programs in the order they were enqueued, so whoever
+        inherits a block writes it after the dead row's last write.
+
+        The ``decode_step`` span holds the enqueue of step k+1 (its self
+        time), then ``decode_readback`` and ``decode_land`` of step k;
+        ``rows`` / ``bucket`` / ``step`` and the expert counts describe the
+        step that LANDS in it (``ahead`` 1); where the enqueue built a new
+        program the span also carries its compile stages, with
+        ``compiled_bucket``. The iteration that finds nothing in flight only
+        enqueues (``ahead`` 0, no landing; its attributes are the enqueued
+        step's), and one whose rows all end with the step in flight only
+        lands."""
+        jnp = self._jnp
         built = self._decode_build()
+        prev = self._flight  # read AFTER the build, which may have drained
         if built is None:
+            if prev is not None:
+                with self._landing_span(prev) as sp:
+                    self._land(prev, sp)
             return
-        n, bb, mb, _, tables, pos, toks, temps = built
+        rows, bb, mb, _, _, pos, _, temps, ints = built
         # a width upgrade pops the old entry, so compare by key presence,
         # not _fns length
         warm = ("decode", bb, mb) in self._fns
-        with span("decode_step", bucket=bb, rows=n, step=self._step_i) as sp:
-            if self._obs is not None:
-                sp.set(traces=tuple(s.req.trace for s in self._running))
+        with (self._landing_span(prev, ahead=1) if prev is not None else
+              span("decode_step", bucket=bb, rows=len(rows),
+                   step=self._step_i, ahead=0)) as sp:
             self._beat = time.monotonic()  # staleness clock covers this op
+            if not warm:
+                # the compile stages this span will carry are of the program
+                # it ENQUEUES, which may not be the landing step's bucket
+                sp.set(compiled_bucket=bb)
             fn = self._get_fn("decode", bb, mb)
             self._compiling = not warm
-            self._key, sub = jax.random.split(self._key)
+            # only a sampling row reads the key: a step that has one gets a
+            # key of its own, every other the base key as it lies on the
+            # device (sampled streams are not replay-stable: one key a step,
+            # shared by the rows of whatever batch the step holds)
+            key = self._key
+            if temps.any():
+                key = self._jax.random.fold_in(
+                    key, self._step_i + (prev is not None))
             t0 = time.monotonic()
             # an arch with routed experts reports the rows each expert took
-            # beside the tokens: ONE blocking read for both
-            nxt, *extras = self._decode_readback(*self._run(
-                fn, self._compute_params, jnp.asarray(tables),
-                jnp.asarray(pos), jnp.asarray(toks), jnp.asarray(temps), sub,
-                pools_first=True))
+            # beside the tokens: ONE blocking read for both, when it lands
+            arrays = self._run(
+                fn, self._compute_params, jnp.asarray(ints),
+                self._no_prev if prev is None else prev.arrays[0], key,
+                pools_first=True)
+            for s in prev.rows if prev is not None else ():
+                s.slot = -1
+            for r, s in enumerate(rows):
+                s.slot = r
+            self._flight = _Flight(arrays, rows, bb, pos, t0, warm)
+            # the call compiled if it had to: its grace ends here, and the
+            # read that follows (of a step enqueued earlier) has its own beat
+            self._beat = time.monotonic()
+            self._compiling = False
+            if prev is None:
+                if self._obs is not None:
+                    sp.set(traces=tuple(s.req.trace for s in rows))
+                return
+            self._ahead += 1
+            counter_inc("serve_decode_ahead")
+            self._land(prev, sp)
+
+    def _landing_span(self, fl: _Flight, ahead: int = 0, **attrs):
+        """The ``decode_step`` span a step lands in, with the attributes
+        that describe it."""
+        sp = span("decode_step", bucket=fl.bucket, rows=len(fl.rows),
+                  step=self._step_i, ahead=ahead, **attrs)
+        if self._obs is not None:
+            sp.set(traces=tuple(s.req.trace for s in fl.rows))
+        return sp
+
+    def _land(self, fl: _Flight, sp):
+        """Read step ``fl``'s tokens back and land them, through the row list
+        of its dispatch: a row whose request is already done is skipped. A
+        device error of the step surfaces here, at its read; the record goes
+        with whatever was enqueued behind it (which was fed its tokens), so
+        the error's handler finds landed positions and an empty pipeline."""
+        if self._flight is fl:  # nothing was enqueued behind it
+            self._drop_flight()
+        try:
+            nxt, *extras = self._decode_readback(*fl.arrays)
             if extras:
                 self._note_experts(sp, extras[0])
-            self._step_done(sp, warm, t0, n, bb)
-            rows_live = list(self._running)
-            with self._decode_land(rows_live) as land:
-                for r, s in enumerate(rows_live):
+            self._step_done(sp, fl.warm, fl.t0, len(fl.rows), fl.bucket)
+            live = [(r, s) for r, s in enumerate(fl.rows)
+                    if not s.req.done.is_set()]
+            with self._decode_land([s for _, s in live]) as land:
+                for r, s in live:
+                    if s.pos != fl.pos[r]:
+                        raise ServeError(
+                            f"request {s.req.id}: the step in flight wrote "
+                            f"position {int(fl.pos[r])}, the sequence is at "
+                            f"{s.pos}")
                     self._append_token(s, int(nxt[r]))
-                land.set(tokens=n)
+                land.set(tokens=len(live))
+        except Exception:
+            self._drop_flight()
+            raise
+        wasted = len(fl.rows) - len(live)
+        if wasted:
+            self._wasted_rows += wasted
+            counter_inc("serve_decode_wasted_rows", wasted)
+
+    def _drop_flight(self):
+        fl, self._flight = self._flight, None
+        for s in fl.rows if fl is not None else ():
+            s.slot = -1
+
+    def _drain(self):
+        """Land the step in flight NOW, with nothing enqueued behind it:
+        whatever takes a sequence out of the running set other than a
+        landing (eviction, the OOM back-off, snapshot and handoff, shutdown,
+        crash containment) needs the true positions first. Scheduler thread,
+        or a thread that owns a dead or quiesced scheduler's state."""
+        fl = self._flight
+        if fl is None:
+            return
+        self._drains += 1
+        counter_inc("serve_decode_drains")
+        with self._landing_span(fl, drain=1) as sp:
+            self._land(fl, sp)
+
+    def _settle(self):
+        """``_drain`` for the error paths: a step whose read fails is
+        dropped (``_land``), and its rows are stepped again from their
+        landed positions or fail with the engine."""
+        try:
+            self._drain()
+        except Exception:  # lint: ok(oom-handler) — the record is dropped; the caller is already handling the failure
+            pass
 
     # -- speculative decode ---------------------------------------------------
     def _propose(self, bb: int) -> np.ndarray:
@@ -2431,10 +2648,14 @@ class Engine:
         rows take the j=0 sampled token and accept no drafts."""
         jnp, jax = self._jnp, self._jax
         k = self._spec_k
+        # synchronous: the accept length is a host comparison, so a step
+        # cannot be built before the one before it has landed
+        self._drain()
         built = self._decode_build(k)
         if built is None:
             return
-        n, bb, mb, drafts, tables, pos, toks, temps = built
+        rows_live, bb, mb, drafts, tables, pos, toks, temps, _ = built
+        n = len(rows_live)
         warm = ("spec", bb, mb) in self._fns
         with span("decode_step", bucket=bb, rows=n, step=self._step_i,
                   spec_k=k) as sp:
@@ -2451,7 +2672,6 @@ class Engine:
                 pools_first=True))
             self._step_done(sp, warm, t0, n, bb)
             proposed = accepted = emitted = 0
-            rows_live = list(self._running)
             with self._decode_land(rows_live) as land:
                 for r, s in enumerate(rows_live):
                     if temps[r] > 0.0:
@@ -2545,6 +2765,7 @@ class Engine:
 
     def _shutdown(self):
         err = self._broken or ServeError("serving engine closed")
+        self._settle()  # the tokens of the step in flight, before the error
         if self._prefix is not None:
             try:
                 self._prefix.release_all()
@@ -2612,6 +2833,9 @@ class Engine:
                     raise RuntimeError(
                         f"serving: program kind {kind!r} has no "
                         "tensor-parallel build")
+                if kind == "decode":
+                    raw = G.feed_tokens_back(raw, bb, self.config.max_batch,
+                                             mb, len(self._cache))
                 fn = jax.jit(raw, donate_argnums=donate)
                 self._fns[key] = fn
                 counter_inc("serve_compiles")
@@ -2646,7 +2870,9 @@ class Engine:
                 bb, mb = bucket
                 build = (G.build_paged_decode_kernel if self._paged_kernel
                          else G.build_paged_decode)
-                raw = build(self._arch, bb, self.config.block_size, mb)
+                raw = G.feed_tokens_back(
+                    build(self._arch, bb, self.config.block_size, mb), bb,
+                    self.config.max_batch, mb, len(self._cache))
                 donate = tuple(range(1, 1 + len(self._cache)))
             if self._dequant is not None:
                 dq, inner = self._dequant, raw
@@ -2689,6 +2915,7 @@ class Engine:
             "pages": {"used": self._pool.used_blocks,
                       "free": self._pool.free_blocks,
                       "parked": self._pool.parked_blocks},
+            "decode_in_flight": self._flight is not None,
             "running": [
                 {"id": s.req.id, "prompt_len": s.prompt_len,
                  "generated": s.generated, "pos": s.pos,
@@ -2740,6 +2967,9 @@ def _engine_loop(wr):
             if _mem.is_oom(e):
                 _mem.note_oom("serve.loop", e)
             eng._broken = e
+            # what is harvested, captured or failed below holds landed
+            # tokens only
+            eng._settle()
             try:
                 counter_inc("serve_engine_errors")
                 flight.dump("serving_loop_error", extra={"exception": repr(e)})

@@ -511,11 +511,11 @@ class TestHandoff:
         rng = np.random.RandomState(36)
         prompts = _prompts(3, rng)
         with Engine(model, **_KW) as eng:
-            baseline = [eng.submit(p, max_new_tokens=8).result(timeout=300)
+            baseline = [eng.submit(p, max_new_tokens=32).result(timeout=300)
                         for p in prompts]
         old = Engine(model, **_KW)
         try:
-            hs = [old.submit(p, max_new_tokens=8) for p in prompts]
+            hs = [old.submit(p, max_new_tokens=32) for p in prompts]
             deadline = time.monotonic() + 30
             while old.stats()["decode_steps"] < 2 \
                     and time.monotonic() < deadline:

@@ -180,6 +180,34 @@ def test_decode_step_the_chip_chooses_compiles(v5e, monkeypatch, d, H,
             ).lower(lowering_platforms=("tpu",)))
 
 
+def test_decode_step_with_token_feedback_compiles(v5e, monkeypatch):
+    """The program the engine jits (``feed_tokens_back`` around the kernel
+    step): ONE packed int32 operand unpacked in the program, the fed tokens
+    taken from the previous step's on the device, the key folded in from the
+    step's number, ``next_tokens`` padded to ``max_batch``; the kernel calls
+    are the step's own."""
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.ops.kernels import paged_attention as pa
+
+    monkeypatch.setattr(pa, "interpret_default", lambda: False)
+    L, d, H, D, BS, B, MB, NB, max_batch = 2, 1024, 8, 128, 16, 8, 16, 512, 64
+    s = SingleDeviceSharding(v5e[0])
+    params, kpool, vpool, *_, key = _gpt_step_operands(
+        s, L, d, H, D, BS, B, MB, NB, vocab=1024)
+    step = G.feed_tokens_back(
+        G.build_paged_decode_kernel(G._gpt_arch(H, D), B, BS, MB), B,
+        max_batch, MB, 2)
+    lowered = jax.jit(step, donate_argnums=(1, 2)).trace(
+        params, kpool, vpool, _on(s, (B, MB + G.STEP_COLS), jnp.int32),
+        _on(s, (max_batch,), jnp.int32), key,
+    ).lower(lowering_platforms=("tpu",))
+    compiled = _compile_uncached(lowered)
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == L
+    assert [o.shape for o in jax.tree_util.tree_leaves(lowered.out_info)][-1] \
+        == (max_batch,)
+
+
 def test_kernel_decode_step_at_the_cell_shape(v5e, monkeypatch):
     """The whole B64 decode program of GPT-3 XL beside the 3,679-block pool:
     one kernel call a layer, and temporaries that do not grow with the pool
