@@ -28,7 +28,7 @@ their live input objects) and cross-checks:
 
 Violations raise :class:`GraphInvariantError` naming the offending node
 (index + op name) and rule. The disabled path costs one flag probe per
-flush (pinned by a tier-1 tripwire + ``bench_verify_overhead``).
+flush (pinned by a tier-1 tripwire).
 """
 from __future__ import annotations
 

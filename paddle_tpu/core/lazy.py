@@ -35,7 +35,7 @@ overlap host graph construction with device execution):
     the host traces step k+1 while the device executes step k. Host waits are
     instrumented: ``timed_block`` (called by ``Tensor.numpy()`` and
     ``LazyArray.__array__``) emits a ``block`` span and feeds the
-    ``lazy_block_ns`` counter — the dispatch-gap metric in bench.py.
+    ``lazy_block_ns`` counter (the dispatch gap: host waits on the device).
   * the FLAGS_check_nan_inf scan and the telemetry memory census move off the
     critical path: they are enqueued against the dispatched arrays and run at
     the next flush, the next materialization, or :func:`sync` — the trip

@@ -34,8 +34,7 @@ _FLAGS: Dict[str, object] = {
     # cache signature immediately before every dispatch, raising a
     # structured GraphInvariantError naming the offending node. Default on
     # in the test suite (conftest); off in production, where the disabled
-    # path costs one flag probe per flush (bench_verify_overhead pins the
-    # enabled cost <2% on the CPU LeNet loop).
+    # path costs one flag probe per flush (a tier-1 tripwire pins it).
     "FLAGS_lazy_verify": False,
     # Runtime ownership assertions (analysis/thread_checks.py): wrap
     # `# guarded_by:`-annotated shared structures in proxies that make an

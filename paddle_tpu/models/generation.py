@@ -8,10 +8,16 @@ sampling — is one jitted program per (architecture, prompt-shape,
 max-length): the step loop is a ``lax.fori_loop`` whose carry holds the KV
 caches, so tokens never bounce to the host between steps.
 
-Two architecture plugs share one loop driver:
+Two architecture plugs share one loop driver and the paged builders:
   GPT   — LayerNorm + learned positions + fused qkv + GELU MLP, tied head;
   Llama — RMSNorm + RoPE at absolute cache positions + GQA (grouped-query
           attention against the UN-repeated KV cache) + SwiGLU, untied head.
+Each states its layer ONCE (``embed`` / ``qkv`` / ``finish`` / ``head``); the
+four reads of the attention context that the programs put between ``qkv`` and
+``finish`` (causal, gathered context, block table, contiguous cache) are
+written once beside ``_grouped_attention``, for both plugs and for their
+tensor-parallel shards. A third plug (``_mla_moe_arch``) brings the layers of
+``models/mla_moe.py``, whose cache is its own.
 """
 from __future__ import annotations
 
@@ -79,6 +85,124 @@ def _grouped_attention(q, kc, vc, live, rep):
 
 
 # ---------------------------------------------------------------------------
+# The four reads of the attention context
+# ---------------------------------------------------------------------------
+# An arch that caches K and V per KV head (GPT, Llama, and their
+# tensor-parallel shards) says what a layer IS, once: ``qkv(w, x, posm) ->
+# (q (B,T,H,D), k (B,T,KV,D), v (B,T,KV,D))`` (first norm, projections, the
+# rotary step at ``posm``) and ``finish(w, x, o) -> x`` (output projection,
+# residual, second norm, MLP, residual), with ``rep`` = H // KV. How the
+# context is READ between the two is no business of the arch: the four
+# functions below are every read the programs make, each written once.
+# ``posm`` is (B, T) absolute positions, or (1, T) where the rows share them.
+# (A step of one token a row embeds ``toks`` (B,) and then adds the token
+# axis, not the other way round: rows looked up at (B, 1) indices come back
+# in a layout XLA:TPU carries through the whole step, 89 MB of temporaries
+# in the gather step at GPT-3 XL's widths where this order has 6.)
+
+def _causal_layer(arch, w, x):
+    """T prompt tokens against themselves: ``x`` (B,T,H·D) -> ``(x, k, v)``,
+    the K and V rows to cache (the KV heads, never the repeats)."""
+    T = x.shape[1]
+    q, k, v = arch["qkv"](w, x, jnp.arange(T)[None])
+    live = jnp.tril(jnp.ones((T, T), bool))[None, None, None]
+    o = _grouped_attention(q, k, v, live, arch["rep"])
+    return arch["finish"](w, x, o), k, v
+
+
+def _context_layer(arch, w, x, k_ctx, v_ctx, live, posm):
+    """T fresh tokens a row at ``posm`` (B,T) against a context gathered in
+    sequence order, ``k_ctx``/``v_ctx`` (B,Tp,KV,D): their fresh K/V overwrite
+    the slots ``posm`` in-context before the joint causal pass (token j
+    attends to the fresh K/V of tokens <= j plus the cached context), ``live``
+    (B,T,Tp) masks per (row, token). One token a row (the gather decode step,
+    the drafter) is its T = 1 case. Returns ``(x, k, v)``; the caller owns
+    writing the fresh (B,T,KV,D) rows back for later steps."""
+    rows = jnp.arange(x.shape[0])[:, None]
+    q, k, v = arch["qkv"](w, x, posm)
+    kc = k_ctx.at[rows, posm].set(k)
+    vc = v_ctx.at[rows, posm].set(v)
+    o = _grouped_attention(q, kc, vc, live[:, None, None], arch["rep"])
+    return arch["finish"](w, x, o), k, v
+
+
+def _block_table_layer(arch, w, x, pools, li, tables, pos, bids, offs):
+    """One fresh token a row, ``x`` (B,1,H·D) at ``pos`` (B,): the row's K and
+    V go into the pools at ``(bids, offs)`` BEFORE the block-table kernel
+    reads them (``build_paged_decode_kernel`` says why). Returns ``(x,
+    pools)``."""
+    from ..ops.kernels import paged_attention_rows
+
+    kpool, vpool = pools
+    q, k, v = arch["qkv"](w, x, pos[:, None])
+    kpool = kpool.at[li, bids, offs].set(k[:, 0])
+    vpool = vpool.at[li, bids, offs].set(v[:, 0])
+    with jax.named_scope("attention"):
+        o = paged_attention_rows(q[:, 0], kpool, vpool, li, tables, pos)
+    return arch["finish"](w, x, o[:, None]), (kpool, vpool)
+
+
+def _contiguous_layer(arch, w, x, kv, pos):
+    """One fresh token a row at the SHARED position ``pos`` (a scalar)
+    against dense (B,T_max,KV,D) caches, written by ``dynamic_update_slice``:
+    the ``generate()`` and beam loops. Returns ``(x, (k cache, v cache))``."""
+    q, k, v = arch["qkv"](w, x, pos[None, None])
+    kc = lax.dynamic_update_slice(kv[0], k, (0, pos, 0, 0))
+    vc = lax.dynamic_update_slice(kv[1], v, (0, pos, 0, 0))
+    live = (jnp.arange(kc.shape[1]) <= pos)[None, None, None, None, :]
+    o = _grouped_attention(q, kc, vc, live, arch["rep"])
+    return arch["finish"](w, x, o), (kc, vc)
+
+
+def _kv_arch(embed, qkv, finish, head, kv_heads, head_dim, rep):
+    """The plug of an arch that caches K and V per KV head, from its one
+    statement of itself: ``embed(params, ids, posm)`` (ids of any shape at
+    the absolute positions ``posm``, its shape or one that broadcasts to it),
+    ``qkv``, ``finish``, ``head(params, h)`` (final norm and the LM-head
+    product over whatever rows the builder selected). Beside them the two
+    layer functions the paged builders call of ANY arch:
+
+    - ``prompt_layer(w, x, live) -> (x, cached rows a pool, counts)``: a layer
+      over whole prompts (``live`` marks the real positions; causality makes
+      them exact whatever pads the bucket, so it is not looked at);
+    - ``decode_layer(w, x, pools, li, tables, pos, bids, offs, live) -> (x,
+      pools, counts)``: a layer over one fresh token a row, ``x`` (B,1,...),
+      read by block table.
+
+    ``counts`` is what an arch with routed experts reports a layer (None
+    here). The MLA arch brings both functions itself (``_mla_moe_arch``)."""
+    arch = {"embed": embed, "qkv": qkv, "finish": finish, "head": head,
+            "kv_heads": kv_heads, "head_dim": head_dim, "rep": rep}
+
+    def prompt_layer(w, x, live):
+        x, k, v = _causal_layer(arch, w, x)
+        return x, (k, v), None
+
+    def decode_layer(w, x, pools, li, tables, pos, bids, offs, live):
+        x, pools = _block_table_layer(arch, w, x, pools, li, tables, pos,
+                                      bids, offs)
+        return x, pools, None
+
+    return dict(arch, prompt_layer=prompt_layer, decode_layer=decode_layer)
+
+
+def _last_rows(x, lens):
+    """``x`` (B,T,...) at each row's own last position ``lens - 1``: the
+    batch-packed analogue of ``x[:, -1]`` under per-row prompt lengths."""
+    idx = (lens - 1).reshape((-1,) + (1,) * (x.ndim - 1))
+    return jnp.take_along_axis(x, idx, axis=1)[:, 0]
+
+
+def _next_tokens(logits, temps, key):
+    """A decode step's sampling tail: rows with ``temps > 0`` sample at that
+    temperature from the step's one key, rows at 0 are greedy."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
+    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    return jnp.where(temps > 0, sampled, greedy)
+
+
+# ---------------------------------------------------------------------------
 # GPT architecture plug
 # ---------------------------------------------------------------------------
 
@@ -117,158 +241,28 @@ def _head_mm(params, rows, key, transpose):
     return rows @ (w.T if transpose else w)
 
 
-def _with_paged_layers(arch):
-    """``arch`` with the two layer functions the paged builders call, for an
-    arch that caches K and V per KV head (GPT, Llama), made of its own
-    ``block`` / ``qkv_rows`` / ``attn_out_rows``:
-
-    - ``prompt_layer(w, x, live) -> (x, cached rows a pool, counts)``: a layer
-      over whole prompts (``live`` marks the real positions; causality makes
-      them exact whatever pads the bucket, so it is not looked at);
-    - ``decode_layer(w, x, pools, li, tables, pos, bids, offs, live) -> (x,
-      pools, counts)``: a layer over one fresh token a row: the row's K and V
-      go into the pools at ``(bids, offs)`` BEFORE the block-table kernel
-      reads them (``build_paged_decode_kernel`` says why).
-
-    ``counts`` is what an arch with routed experts reports a layer (None
-    here). The MLA arch brings both functions itself (``_mla_moe_arch``)."""
-    def prompt_layer(w, x, live):
-        x, rows = arch["block"](w, x)
-        return x, rows, None
-
-    def decode_layer(w, x, pools, li, tables, pos, bids, offs, live):
-        from ..ops.kernels import paged_attention_rows
-
-        kpool, vpool = pools
-        q, k_new, v_new = arch["qkv_rows"](w, x, pos)
-        kpool = kpool.at[li, bids, offs].set(k_new)
-        vpool = vpool.at[li, bids, offs].set(v_new)
-        with jax.named_scope("attention"):
-            o = paged_attention_rows(q, kpool, vpool, li, tables, pos)
-        return arch["attn_out_rows"](w, x, o[:, None]), (kpool, vpool), None
-
-    return dict(arch, prompt_layer=prompt_layer, decode_layer=decode_layer)
-
-
 def _gpt_arch(H, D):
-    def embed_prompt(params, ids, T0):
-        return params["wte"][ids] + params["wpe"][jnp.arange(T0)][None]
+    def embed(params, ids, posm):
+        return params["wte"][ids] + params["wpe"][posm]
 
-    def embed_token(params, tok, pos):
-        return params["wte"][tok][:, None] + params["wpe"][pos][None, None]
+    def qkv(w, x, posm):
+        B, T = x.shape[0], x.shape[1]
+        h = _ln(x, w["ln1_w"], w["ln1_b"])
+        qkv_ = (h @ w["qkv_w"] + w["qkv_b"]).reshape(B, T, 3, H, D)
+        return qkv_[:, :, 0], qkv_[:, :, 1], qkv_[:, :, 2]
 
-    def embed_rows(params, toks, pos):
-        # packed decode: one token per row at per-row absolute positions —
-        # toks (B,), pos (B,) -> (B, 1, H·D)
-        return params["wte"][toks][:, None] + params["wpe"][pos][:, None]
-
-    def head_rows(params, x, idx):
-        # logits at each row's own position (per-row prompt lengths): the
-        # batch-packed analogue of head()'s x[:, -1]
-        h = _ln(x, params["lnf_w"], params["lnf_b"])
-        rows = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        return _head_mm(params, rows, "wte", True)
-
-    def head_all(params, x):
-        # logits at EVERY fed position (speculative verify reads all k+1)
-        return _head_mm(params, _ln(x, params["lnf_w"], params["lnf_b"]),
-                        "wte", True)
-
-    def embed_tail(params, ids, starts):
-        # T tokens per row at per-row absolute positions starts + [0..T)
-        T = ids.shape[1]
-        pos = starts[:, None] + jnp.arange(T)[None, :]
-        return params["wte"][ids] + params["wpe"][pos]
-
-    def mlp(w, x):
+    def finish(w, x, o):
+        x = x + (o @ w["proj_w"] + w["proj_b"])
         with jax.named_scope("mlp"):
             h2 = _ln(x, w["ln2_w"], w["ln2_b"])
             ff = jax.nn.gelu(h2 @ w["up_w"] + w["up_b"], approximate=True) @ w["down_w"] + w["down_b"]
             return x + ff
 
-    def block_tail(w, x, k_ctx, v_ctx, live, starts):
-        # multi-token packed pass against a gathered paged context: x
-        # (B,T,H·D) holds T consecutive tokens per row starting at absolute
-        # position starts (B,); their fresh K/V overwrite the in-context
-        # slots starts+[0..T) before attention (the joint causal pass over
-        # the feeds — token j attends to the fresh K/V of tokens <= j plus
-        # the cached context), live (B,T,Tp) masks per (row, feed). The
-        # caller scatters (k_new, v_new) (B,T,KV,D) back into the pool.
-        B, T = x.shape[0], x.shape[1]
-        rows = jnp.arange(B)[:, None]
-        posm = starts[:, None] + jnp.arange(T)[None, :]
-        h = _ln(x, w["ln1_w"], w["ln1_b"])
-        qkv = (h @ w["qkv_w"] + w["qkv_b"]).reshape(B, T, 3, H, D)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        k_new, v_new = k, v
-        kc = k_ctx.at[rows, posm].set(k_new)
-        vc = v_ctx.at[rows, posm].set(v_new)
-        o = _grouped_attention(q, kc, vc, live[:, None, None], rep=1)
-        x = x + (o @ w["proj_w"] + w["proj_b"])
-        return mlp(w, x), k_new, v_new
+    def head(params, h):
+        return _head_mm(params, _ln(h, params["lnf_w"], params["lnf_b"]),
+                        "wte", True)  # tied head
 
-    def block_rows(w, x, k_ctx, v_ctx, live, pos):
-        # single-token decode against a GATHERED paged context: x (B,1,H·D);
-        # k_ctx/v_ctx (B,Tp,KV,D) hold each row's blocks in sequence order
-        # with a stale slot at pos that the fresh k/v overwrites in-ctx;
-        # live (B,Tp) masks positions <= pos. The caller owns scattering
-        # (k_new, v_new) back into the pool for future steps.
-        B = x.shape[0]
-        rows = jnp.arange(B)
-        h = _ln(x, w["ln1_w"], w["ln1_b"])
-        qkv = (h @ w["qkv_w"] + w["qkv_b"]).reshape(B, 1, 3, H, D)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        k_new, v_new = k[:, 0], v[:, 0]
-        kc = k_ctx.at[rows, pos].set(k_new)
-        vc = v_ctx.at[rows, pos].set(v_new)
-        o = _grouped_attention(q, kc, vc, live[:, None, None, None, :], rep=1)
-        x = x + (o @ w["proj_w"] + w["proj_b"])
-        return mlp(w, x), k_new, v_new
-
-    def qkv_rows(w, x, pos):
-        # the projection half of block_rows (same ops, same order — the
-        # kernel decode path must trace byte-identical math around the
-        # attention read): x (B,1,H·D) -> q (B,H,D), k_new/v_new (B,H,D)
-        B = x.shape[0]
-        h = _ln(x, w["ln1_w"], w["ln1_b"])
-        qkv = (h @ w["qkv_w"] + w["qkv_b"]).reshape(B, 1, 3, H, D)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        return q[:, 0], k[:, 0], v[:, 0]
-
-    def attn_out_rows(w, x, o):
-        # the post-attention half of block_rows: o (B,1,H·D) attention read
-        x = x + (o @ w["proj_w"] + w["proj_b"])
-        return mlp(w, x)
-
-    def block(w, x, kv=None, pos=None):
-        B, T = x.shape[0], x.shape[1]
-        h = _ln(x, w["ln1_w"], w["ln1_b"])
-        qkv = (h @ w["qkv_w"] + w["qkv_b"]).reshape(B, T, 3, H, D)
-        q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        if kv is None:
-            live = jnp.tril(jnp.ones((T, T), bool))[None, None, None]
-            o = _grouped_attention(q, k, v, live, rep=1)
-            new_kv = (k, v)
-        else:
-            kc = lax.dynamic_update_slice(kv[0], k, (0, pos, 0, 0))
-            vc = lax.dynamic_update_slice(kv[1], v, (0, pos, 0, 0))
-            live = (jnp.arange(kc.shape[1]) <= pos)[None, None, None, None, :]
-            o = _grouped_attention(q, kc, vc, live, rep=1)
-            new_kv = (kc, vc)
-        x = x + (o @ w["proj_w"] + w["proj_b"])
-        return mlp(w, x), new_kv
-
-    def head(params, x):
-        x = _ln(x, params["lnf_w"], params["lnf_b"])
-        return _head_mm(params, x[:, -1], "wte", True)  # tied head
-
-    return _with_paged_layers(
-        {"embed_prompt": embed_prompt, "embed_token": embed_token,
-         "embed_rows": embed_rows, "head_rows": head_rows,
-         "head_all": head_all, "embed_tail": embed_tail,
-         "block_rows": block_rows, "block_tail": block_tail,
-         "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
-         "block": block, "head": head, "kv_heads": H, "head_dim": D})
+    return _kv_arch(embed, qkv, finish, head, H, D, 1)
 
 
 # ---------------------------------------------------------------------------
@@ -293,37 +287,9 @@ def _rms(x, w, eps):
     return (x * lax.rsqrt(var + eps).astype(x.dtype)) * w
 
 
-def _rope_at(x, pos0, theta):
-    """Rotary embedding at absolute positions pos0 + [0..T)."""
-    B, T, H, D = x.shape
-    pos = pos0 + jnp.arange(T, dtype=jnp.float32)
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = pos[:, None] * inv[None, :]
-    cos = jnp.cos(ang)[None, :, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[None, :, None, :].astype(x.dtype)
-    x1, x2 = x[..., ::2], x[..., 1::2]
-    o1 = x1 * cos - x2 * sin
-    o2 = x2 * cos + x1 * sin
-    return jnp.stack([o1, o2], axis=-1).reshape(x.shape)
-
-
-def _rope_rows(x, pos, theta):
-    """Rotary embedding for ONE token per row at per-row absolute positions
-    (packed decode): x (B, 1, H, D), pos (B,) int."""
-    D = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
-    ang = pos.astype(jnp.float32)[:, None] * inv[None, :]  # (B, D/2)
-    cos = jnp.cos(ang)[:, None, None, :].astype(x.dtype)
-    sin = jnp.sin(ang)[:, None, None, :].astype(x.dtype)
-    x1, x2 = x[..., ::2], x[..., 1::2]
-    o1 = x1 * cos - x2 * sin
-    o2 = x2 * cos + x1 * sin
-    return jnp.stack([o1, o2], axis=-1).reshape(x.shape)
-
-
 def _rope_grid(x, pos, theta):
-    """Rotary embedding at a per-(row, token) position grid (tail prefill /
-    speculative verify): x (B, T, H, D), pos (B, T) int."""
+    """Rotary embedding of ``x`` (B, T, H, D) at the absolute positions
+    ``pos``, int, (B, T) a (row, token) or (1, T) where the rows share them."""
     D = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, D, 2, dtype=jnp.float32) / D))
     ang = pos.astype(jnp.float32)[:, :, None] * inv[None, None, :]  # (B,T,D/2)
@@ -336,144 +302,70 @@ def _rope_grid(x, pos, theta):
 
 
 def _llama_arch(H, KV, D, theta, eps):
-    rep = H // KV
-
-    def embed_prompt(params, ids, T0):
+    def embed(params, ids, posm):
         return params["wte"][ids]
 
-    def embed_token(params, tok, pos):
-        return params["wte"][tok][:, None]
+    def qkv(w, x, posm):
+        # RoPE at each (row, token)'s own absolute position; K and V keep
+        # the un-repeated KV heads
+        B, T = x.shape[0], x.shape[1]
+        h = _rms(x, w["ln1_w"], eps)
+        q = (h @ w["q_w"]).reshape(B, T, H, D)
+        k = (h @ w["k_w"]).reshape(B, T, KV, D)
+        v = (h @ w["v_w"]).reshape(B, T, KV, D)
+        return _rope_grid(q, posm, theta), _rope_grid(k, posm, theta), v
 
-    def embed_rows(params, toks, pos):
-        return params["wte"][toks][:, None]
-
-    def head_rows(params, x, idx):
-        h = _rms(x, params["lnf_w"], eps)
-        rows = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        return _head_mm(params, rows, "head_w", False)
-
-    def head_all(params, x):
-        return _head_mm(params, _rms(x, params["lnf_w"], eps),
-                        "head_w", False)
-
-    def embed_tail(params, ids, starts):
-        return params["wte"][ids]
-
-    def mlp(w, x):
+    def finish(w, x, o):
+        x = x + o @ w["o_w"]
         with jax.named_scope("mlp"):
             h2 = _rms(x, w["ln2_w"], eps)
             ff = (jax.nn.silu(h2 @ w["gate_w"]) * (h2 @ w["up_w"])) @ w["down_w"]
             return x + ff
 
-    def block_tail(w, x, k_ctx, v_ctx, live, starts):
-        # see the GPT plug for the contract; RoPE at each (row, feed)'s own
-        # absolute position, GQA against the un-repeated gathered cache
-        B, T = x.shape[0], x.shape[1]
-        rows = jnp.arange(B)[:, None]
-        posm = starts[:, None] + jnp.arange(T)[None, :]
-        h = _rms(x, w["ln1_w"], eps)
-        q = (h @ w["q_w"]).reshape(B, T, H, D)
-        k = (h @ w["k_w"]).reshape(B, T, KV, D)
-        v = (h @ w["v_w"]).reshape(B, T, KV, D)
-        q = _rope_grid(q, posm, theta)
-        k = _rope_grid(k, posm, theta)
-        k_new, v_new = k, v
-        kc = k_ctx.at[rows, posm].set(k_new)
-        vc = v_ctx.at[rows, posm].set(v_new)
-        o = _grouped_attention(q, kc, vc, live[:, None, None], rep)
-        x = x + o @ w["o_w"]
-        return mlp(w, x), k_new, v_new
+    def head(params, h):
+        return _head_mm(params, _rms(h, params["lnf_w"], eps), "head_w", False)
 
-    def block_rows(w, x, k_ctx, v_ctx, live, pos):
-        # see the GPT plug for the contract; RoPE applied at each row's own
-        # absolute position, GQA against the un-repeated gathered cache
-        B = x.shape[0]
-        rows = jnp.arange(B)
-        h = _rms(x, w["ln1_w"], eps)
-        q = (h @ w["q_w"]).reshape(B, 1, H, D)
-        k = (h @ w["k_w"]).reshape(B, 1, KV, D)
-        v = (h @ w["v_w"]).reshape(B, 1, KV, D)
-        q = _rope_rows(q, pos, theta)
-        k = _rope_rows(k, pos, theta)
-        k_new, v_new = k[:, 0], v[:, 0]
-        kc = k_ctx.at[rows, pos].set(k_new)
-        vc = v_ctx.at[rows, pos].set(v_new)
-        o = _grouped_attention(q, kc, vc, live[:, None, None, None, :], rep)
-        x = x + o @ w["o_w"]
-        return mlp(w, x), k_new, v_new
-
-    def qkv_rows(w, x, pos):
-        # projection half of block_rows (same ops/order — see the GPT plug):
-        # RoPE at each row's own absolute position, un-repeated KV heads
-        B = x.shape[0]
-        h = _rms(x, w["ln1_w"], eps)
-        q = (h @ w["q_w"]).reshape(B, 1, H, D)
-        k = (h @ w["k_w"]).reshape(B, 1, KV, D)
-        v = (h @ w["v_w"]).reshape(B, 1, KV, D)
-        q = _rope_rows(q, pos, theta)
-        k = _rope_rows(k, pos, theta)
-        return q[:, 0], k[:, 0], v[:, 0]
-
-    def attn_out_rows(w, x, o):
-        x = x + o @ w["o_w"]
-        return mlp(w, x)
-
-    def block(w, x, kv=None, pos=None):
-        B, T = x.shape[0], x.shape[1]
-        h = _rms(x, w["ln1_w"], eps)
-        q = (h @ w["q_w"]).reshape(B, T, H, D)
-        k = (h @ w["k_w"]).reshape(B, T, KV, D)
-        v = (h @ w["v_w"]).reshape(B, T, KV, D)
-        pos0 = jnp.float32(0.0) if kv is None else pos.astype(jnp.float32)
-        q = _rope_at(q, pos0, theta)
-        k = _rope_at(k, pos0, theta)
-        if kv is None:
-            live = jnp.tril(jnp.ones((T, T), bool))[None, None, None]
-            o = _grouped_attention(q, k, v, live, rep)
-            new_kv = (k, v)  # cache the KV heads, not the repeats
-        else:
-            kc = lax.dynamic_update_slice(kv[0], k, (0, pos, 0, 0))
-            vc = lax.dynamic_update_slice(kv[1], v, (0, pos, 0, 0))
-            live = (jnp.arange(kc.shape[1]) <= pos)[None, None, None, None, :]
-            o = _grouped_attention(q, kc, vc, live, rep)
-            new_kv = (kc, vc)
-        x = x + o @ w["o_w"]
-        return mlp(w, x), new_kv
-
-    def head(params, x):
-        return _head_mm(params, _rms(x, params["lnf_w"], eps)[:, -1],
-                        "head_w", False)
-
-    return _with_paged_layers(
-        {"embed_prompt": embed_prompt, "embed_token": embed_token,
-         "embed_rows": embed_rows, "head_rows": head_rows,
-         "head_all": head_all, "embed_tail": embed_tail,
-         "block_rows": block_rows, "block_tail": block_tail,
-         "qkv_rows": qkv_rows, "attn_out_rows": attn_out_rows,
-         "block": block, "head": head, "kv_heads": KV, "head_dim": D})
+    return _kv_arch(embed, qkv, finish, head, KV, D, H // KV)
 
 
 # ---------------------------------------------------------------------------
 # Shared decode driver
 # ---------------------------------------------------------------------------
 
+def _prompt_pass(arch, params, ids, T_max):
+    """The prompt pass of a dense loop: the causal forward over ``ids``
+    (B,T0), each layer's K and V rows at the head of zeroed (B,T_max,KV,D)
+    caches. Returns ``(x, [(k cache, v cache) a layer])``."""
+    B, T0 = ids.shape
+    shape = (B, T_max, arch["kv_heads"], arch["head_dim"])
+    x = arch["embed"](params, ids, jnp.arange(T0)[None])
+    caches = []
+    for w in params["layers"]:
+        x, k, v = _causal_layer(arch, w, x)
+        caches.append((jnp.zeros(shape, x.dtype).at[:, :T0].set(k),
+                       jnp.zeros(shape, x.dtype).at[:, :T0].set(v)))
+    return x, caches
+
+
+def _token_pass(arch, params, toks, caches, pos):
+    """One step of a dense loop: ``toks`` (B,) at the shared position ``pos``
+    against the dense caches. Returns ``(logits, caches)``."""
+    x = arch["embed"](params, toks, pos[None])[:, None]
+    new_caches = []
+    for w, kv in zip(params["layers"], caches):
+        x, kv = _contiguous_layer(arch, w, x, kv, pos)
+        new_caches.append(kv)
+    return arch["head"](params, x[:, -1]), tuple(new_caches)
+
+
 def _build_decode(arch, T0, T_max, max_new_tokens, temperature, top_k, top_p,
                   eos_token_id, do_sample):
-    KV, D = arch["kv_heads"], arch["head_dim"]
-
     def decode(params, ids, key):
-        layer_ws = params["layers"]
         B = ids.shape[0]
 
         # ---- prefill: full forward over the prompt, caches captured -------
-        x = arch["embed_prompt"](params, ids, T0)
-        caches = []
-        for w in layer_ws:
-            x, (k, v) = arch["block"](w, x)
-            kc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :T0].set(k)
-            vc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :T0].set(v)
-            caches.append((kc, vc))
-        logits0 = arch["head"](params, x)
+        x, caches = _prompt_pass(arch, params, ids, T_max)
+        logits0 = arch["head"](params, x[:, -1])
 
         # Tail pre-filled with eos: a finished row's remaining slots already
         # hold the pad value, so its writes below are no-ops (live-row
@@ -502,13 +394,8 @@ def _build_decode(arch, T0, T_max, max_new_tokens, temperature, top_k, top_p,
             out = lax.dynamic_update_slice(
                 out, nxt[:, None], (jnp.asarray(0, pos.dtype), pos)
             )
-            x = arch["embed_token"](params, nxt, pos)
-            new_caches = []
-            for w, kv in zip(layer_ws, caches):
-                x, kv = arch["block"](w, x, kv=kv, pos=pos)
-                new_caches.append(kv)
-            logits = arch["head"](params, x)
-            return i + 1, out, tuple(new_caches), finished, key, logits
+            logits, caches = _token_pass(arch, params, nxt, caches, pos)
+            return i + 1, out, caches, finished, key, logits
 
         def cond(carry):
             i, _, _, finished, _, _ = carry
@@ -520,7 +407,7 @@ def _build_decode(arch, T0, T_max, max_new_tokens, temperature, top_k, top_p,
         steps, out, _, _, _, _ = lax.while_loop(
             cond, step,
             # default int dtype (x64-dependent) so `pos = T0 + i` matches the
-            # literal indices inside arch["block"]'s dynamic_update_slice
+            # literal indices inside _contiguous_layer's dynamic_update_slice
             (jnp.asarray(0), out, tuple(caches), finished, key, logits0),
         )
         return out, steps
@@ -535,24 +422,16 @@ def _build_beam_decode(arch, T0, T_max, max_new_tokens, num_beams, eos_token_id,
     ``beam_search_decode_op`` roles): the KV caches are stacked per beam
     (B·K leading dim) and re-gathered along the beam axis every step inside
     the ``lax.fori_loop`` carry — no host round trips."""
-    KV, D = arch["kv_heads"], arch["head_dim"]
     K = int(num_beams)
 
     def decode(params, ids, key):
-        layer_ws = params["layers"]
         B = ids.shape[0]
 
         # ---- prefill on the raw batch, then tile caches across beams ------
-        x = arch["embed_prompt"](params, ids, T0)
-        caches = []
-        for w in layer_ws:
-            x, (k, v) = arch["block"](w, x)
-            kc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :T0].set(k)
-            vc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :T0].set(v)
-            caches.append(
-                (jnp.repeat(kc, K, axis=0), jnp.repeat(vc, K, axis=0))
-            )
-        logits0 = jnp.repeat(arch["head"](params, x), K, axis=0)  # (B*K, V)
+        x, caches = _prompt_pass(arch, params, ids, T_max)
+        caches = [(jnp.repeat(kc, K, axis=0), jnp.repeat(vc, K, axis=0))
+                  for kc, vc in caches]
+        logits0 = jnp.repeat(arch["head"](params, x[:, -1]), K, axis=0)  # (B*K, V)
 
         out = jnp.zeros((B * K, T_max), jnp.int32).at[:, :T0].set(
             jnp.repeat(ids, K, axis=0)
@@ -594,13 +473,9 @@ def _build_beam_decode(arch, T0, T_max, max_new_tokens, num_beams, eos_token_id,
 
             pos = T0 + i
             out = lax.dynamic_update_slice(out, token.reshape(-1)[:, None], (0, pos))
-            x = arch["embed_token"](params, token.reshape(-1), pos)
-            new_caches = []
-            for w, kv in zip(layer_ws, caches):
-                x, kv = arch["block"](w, x, kv=kv, pos=pos)
-                new_caches.append(kv)
-            logits = arch["head"](params, x)
-            return out, tuple(new_caches), new_scores, finished, logits
+            logits, caches = _token_pass(arch, params, token.reshape(-1),
+                                         caches, pos)
+            return out, caches, new_scores, finished, logits
 
         out, _, scores, _, _ = lax.fori_loop(
             0, max_new_tokens, step,
@@ -828,30 +703,23 @@ def _mla_moe_arch(cfg, kernels):
     tabs = M.rope_tables(cfg)
     H = cfg.num_attention_heads
 
-    def embed_prompt(params, ids, T0):
+    def embed(params, ids, posm):
         return M.embed_streams(cfg, params, ids)
 
-    def embed_rows(params, toks, pos):
-        return M.embed_streams(cfg, params, toks)
-
-    def _head(params, h):
+    def head(params, X):
+        h = M.final_hidden(cfg, params, X)
         if "head_w" in params:
             return h @ params["head_w"]
         return h @ params["wte"].T
-
-    def head_rows(params, X, idx):
-        rows = jnp.take_along_axis(X, idx[:, None, None, None], axis=1)[:, 0]
-        return _head(params, M.final_hidden(cfg, params, rows))
-
-    def head_all(params, X):
-        return _head(params, M.final_hidden(cfg, params, X))
 
     def prompt_layer(w, X, live):
         X, latent, counts = M.prompt_layer(cfg, tabs, w, X, live, kernels)
         return X, (latent,), counts
 
     def decode_layer(w, X, pools, li, tables, pos, bids, offs, live):
-        pool = pools[0]
+        # one fresh token a row, (B,1,n,d) like every arch's; the layer's
+        # own shapes have no token axis
+        X, pool = X[:, 0], pools[0]
 
         def attend(u):
             nonlocal pool
@@ -873,11 +741,9 @@ def _mla_moe_arch(cfg, kernels):
             return o.reshape(o.shape[0], H * cfg.v_head_dim) @ w["o"]
 
         X, counts = M.decoder_layer(cfg, w, X, attend, live, kernels)
-        return X, (pool,), counts
+        return X[:, None], (pool,), counts
 
-    return {"name": "mla_moe", "embed_prompt": embed_prompt,
-            "embed_rows": embed_rows, "head_rows": head_rows,
-            "head_all": head_all, "head": head_all,
+    return {"name": "mla_moe", "embed": embed, "head": head,
             "prompt_layer": prompt_layer, "decode_layer": decode_layer,
             "cache": ((cfg.cache_row,),), "plain_paths_only": True,
             "expert_layers": sum(cfg.is_expert_layer(i)
@@ -922,7 +788,7 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
 
     def prefill(params, ids, lens, tables, *pools):
         layer_ws = params["layers"]
-        x = arch["embed_prompt"](params, ids, T_bucket)
+        x = arch["embed"](params, ids, jnp.arange(T_bucket)[None])
         tb = tables[:, :nb]
         counts = []
         live = ((jnp.arange(T_bucket)[None, :] < lens[:, None])
@@ -935,7 +801,7 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
                 p.at[li, tb].set(r.reshape((B, nb, block_size) + r.shape[2:]))
                 for p, r in zip(pools, rows))
         with jax.named_scope("head"):
-            logits = arch["head_rows"](params, x, lens - 1)
+            logits = arch["head"](params, _last_rows(x, lens))
         return (*pools, logits) + ((jnp.stack(counts),) if counts else ())
 
     return prefill
@@ -966,10 +832,11 @@ def build_paged_decode(arch, B, block_size, max_blocks):
 
     def step(params, kpool, vpool, tables, pos, toks, temps, key):
         layer_ws = params["layers"]
-        x = arch["embed_rows"](params, toks, pos)
+        posm = pos[:, None]
+        x = arch["embed"](params, toks, pos)[:, None]
         bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
         offs = pos % block_size
-        live = jnp.arange(T_pad)[None, :] <= pos[:, None]
+        live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]
         # all context gathers hoisted above the scatter chain: layer li's
         # gather reads kpool[li], which scatters to layers < li never touch,
         # so the values are identical — but with gathers interleaved, every
@@ -985,17 +852,13 @@ def build_paged_decode(arch, B, block_size, max_blocks):
                     vpool[li, tables].reshape(B, T_pad, KV, D))
                    for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
-            x, k_new, v_new = arch["block_rows"](w, x, ctx[li][0], ctx[li][1],
-                                                 live, pos)
-            kpool = kpool.at[li, bids, offs].set(k_new)
-            vpool = vpool.at[li, bids, offs].set(v_new)
+            x, k_new, v_new = _context_layer(arch, w, x, ctx[li][0],
+                                             ctx[li][1], live, posm)
+            kpool = kpool.at[li, bids, offs].set(k_new[:, 0])
+            vpool = vpool.at[li, bids, offs].set(v_new[:, 0])
         with jax.named_scope("head"):
-            logits = arch["head"](params, x)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
-        sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(temps > 0, sampled, greedy)
-        return kpool, vpool, nxt
+            logits = arch["head"](params, x[:, -1])
+        return kpool, vpool, _next_tokens(logits, temps, key)
 
     return step
 
@@ -1020,9 +883,9 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
       chip);
     - the online softmax sums in another order: outputs agree with the gather
       builder within the kernel's stated tolerance, not bit for bit.
-    The per-layer math around the read is ``block_rows``' own, factored into
-    ``qkv_rows``/``attn_out_rows`` (``_with_paged_layers`` puts them around
-    the scatter and the kernel as the arch's ``decode_layer``).
+    The per-layer math around the read is the arch's one ``qkv`` and
+    ``finish``, the same the gather step runs (``_block_table_layer`` puts
+    them around the scatter and the kernel as the arch's ``decode_layer``).
 
     ``step(params, *pools, tables, pos, toks, temps, key)`` returns
     ``(*pools, next_tokens)``: the embedding, the write slots, the arch's
@@ -1036,7 +899,7 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     def step(params, *args):
         *pools, tables, pos, toks, temps, key = args
         layer_ws = params["layers"]
-        x = arch["embed_rows"](params, toks, pos)
+        x = arch["embed"](params, toks, pos)[:, None]
         bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
         offs = pos % block_size
         live = tables[:, 0] != 0  # a row whose table is unmapped pads the bucket
@@ -1049,12 +912,9 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
             if c is not None:
                 counts.append(c)
         with jax.named_scope("head"):
-            logits = arch["head"](params, x)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
-        sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-        nxt = jnp.where(temps > 0, sampled, greedy)
-        return (*pools, nxt) + ((jnp.stack(counts),) if counts else ())
+            logits = arch["head"](params, x[:, -1])
+        return ((*pools, _next_tokens(logits, temps, key))
+                + ((jnp.stack(counts),) if counts else ()))
 
     return step
 
@@ -1182,8 +1042,8 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
 
     def prefill(params, ids, starts, lens, tables, kpool, vpool):
         layer_ws = params["layers"]
-        x = arch["embed_tail"](params, ids, starts)
         posm = starts[:, None] + jnp.arange(T_bucket)[None, :]  # (B, T)
+        x = arch["embed"](params, ids, posm)
         live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]  # (B,T,Tp)
         cols = (starts // block_size)[:, None] + jnp.arange(nb)[None, :]
         bids = jnp.take_along_axis(
@@ -1196,14 +1056,14 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
                     vpool[li, tables].reshape(B, T_pad, KV, D))
                    for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
-            x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
-                                                 live, starts)
+            x, k_new, v_new = _context_layer(arch, w, x, ctx[li][0],
+                                             ctx[li][1], live, posm)
             kpool = kpool.at[li, bids].set(
                 k_new.reshape(B, nb, block_size, KV, D))
             vpool = vpool.at[li, bids].set(
                 v_new.reshape(B, nb, block_size, KV, D))
         with jax.named_scope("head"):
-            logits = arch["head_rows"](params, x, lens - 1)
+            logits = arch["head"](params, _last_rows(x, lens))
         return kpool, vpool, logits
 
     return prefill
@@ -1236,8 +1096,8 @@ def build_paged_spec_decode(arch, B, k, block_size, max_blocks):
 
     def step(params, kpool, vpool, tables, pos, toks, temps, key):
         layer_ws = params["layers"]
-        x = arch["embed_tail"](params, toks, pos)
         posm = pos[:, None] + jnp.arange(T)[None, :]  # (B, k+1)
+        x = arch["embed"](params, toks, posm)
         live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]
         cols = posm // block_size
         bids = jnp.take_along_axis(
@@ -1251,12 +1111,12 @@ def build_paged_spec_decode(arch, B, k, block_size, max_blocks):
                     vpool[li, tables].reshape(B, T_pad, KV, D))
                    for li in range(len(layer_ws))]
         for li, w in enumerate(layer_ws):
-            x, k_new, v_new = arch["block_tail"](w, x, ctx[li][0], ctx[li][1],
-                                                 live, pos)
+            x, k_new, v_new = _context_layer(arch, w, x, ctx[li][0],
+                                             ctx[li][1], live, posm)
             kpool = kpool.at[li, bids, offs].set(k_new)
             vpool = vpool.at[li, bids, offs].set(v_new)
         with jax.named_scope("head"):
-            logits = arch["head_all"](params, x)  # (B, k+1, V)
+            logits = arch["head"](params, x)  # (B, k+1, V)
         greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         scaled = (logits[:, 0]
                   / jnp.maximum(temps, 1e-6)[:, None]).astype(jnp.float32)
@@ -1447,35 +1307,24 @@ def _tp_gather(y, quantized):
 
 
 def _tp_arch(arch_key, tp, vocab, int8_wire):
-    """Per-device layer drivers for the tp programs: local column-sharded
-    projections + local grouped attention, an all_gather at the attention
-    and FFN boundaries, replicated second matmuls. Mirrors the single-chip
-    arch plugs op for op so the concat of the shards is bitwise the
-    single-chip activation."""
+    """One device's share of a KV-per-head arch, for the four reads above: a
+    layer's weights are the pair ``(replicated, local shard)``. ``qkv``:
+    local column-sharded projections; ``finish``: an all_gather at the
+    attention and FFN boundaries, replicated second matmuls. Mirrors the
+    single-chip arch plugs op for op so the concat of the shards is bitwise
+    the single-chip activation."""
     kind, H, KV, D, L, theta, eps = _tp_dims(arch_key)
     Hl, KVl = H // tp, KV // tp
-    rep = H // KV  # GQA group width is tp-invariant (both axes sharded)
 
-    def embed_prompt(rw, ids, T0):
+    def embed(rw, ids, posm):
         if kind == "gpt":
-            return rw["wte"][ids] + rw["wpe"][jnp.arange(T0)][None]
+            return rw["wte"][ids] + rw["wpe"][posm]
         return rw["wte"][ids]
 
-    def embed_rows(rw, toks, pos):
-        if kind == "gpt":
-            return rw["wte"][toks][:, None] + rw["wpe"][pos][:, None]
-        return rw["wte"][toks][:, None]
-
-    def embed_tail(rw, ids, starts):
-        if kind == "gpt":
-            T = ids.shape[1]
-            pos = starts[:, None] + jnp.arange(T)[None, :]
-            return rw["wte"][ids] + rw["wpe"][pos]
-        return rw["wte"][ids]
-
-    def qkv(rwl, swl, x, posm):
+    def qkv(w, x, posm):
         # local projections: x (B,T,H·D) replicated -> q (B,T,Hl,D),
         # k/v (B,T,KVl,D) — column slices of the single-chip projections
+        rwl, swl = w
         B, T = x.shape[0], x.shape[1]
         if kind == "gpt":
             h = _ln(x, rwl["ln1_w"], rwl["ln1_b"])
@@ -1487,9 +1336,10 @@ def _tp_arch(arch_key, tp, vocab, int8_wire):
         v = (h @ swl["v_w"]).reshape(B, T, KVl, D)
         return _rope_grid(q, posm, theta), _rope_grid(k, posm, theta), v
 
-    def post_attn(rwl, swl, x, o):
+    def finish(w, x, o):
         # o (B,T,Hl·D) local attention read -> gathered full heads, then
         # the replicated proj/down matmuls (identical inputs everywhere)
+        rwl, swl = w
         o = _tp_gather(o, int8_wire)
         if kind == "gpt":
             x = x + (o @ rwl["proj_w"] + rwl["proj_b"])
@@ -1503,52 +1353,18 @@ def _tp_arch(arch_key, tp, vocab, int8_wire):
                         int8_wire)
         return x + ff @ rwl["down_w"]
 
-    def layer_rows(rwl, swl, x, k_ctx, v_ctx, live, pos):
-        # decode mirror of block_rows against the gathered local-shard ctx
-        B = x.shape[0]
-        rows_i = jnp.arange(B)
-        q, k, v = qkv(rwl, swl, x, pos[:, None])
-        k_new, v_new = k[:, 0], v[:, 0]
-        kc = k_ctx.at[rows_i, pos].set(k_new)
-        vc = v_ctx.at[rows_i, pos].set(v_new)
-        o = _grouped_attention(q, kc, vc, live[:, None, None, None, :], rep)
-        return post_attn(rwl, swl, x, o), k_new, v_new
-
-    def layer_tail(rwl, swl, x, k_ctx, v_ctx, live, starts):
-        # multi-token mirror of block_tail (tail prefill / chunked prefill)
-        B, T = x.shape[0], x.shape[1]
-        rows_i = jnp.arange(B)[:, None]
-        posm = starts[:, None] + jnp.arange(T)[None, :]
-        q, k, v = qkv(rwl, swl, x, posm)
-        kc = k_ctx.at[rows_i, posm].set(k)
-        vc = v_ctx.at[rows_i, posm].set(v)
-        o = _grouped_attention(q, kc, vc, live[:, None, None], rep)
-        return post_attn(rwl, swl, x, o), k, v
-
-    def layer_full(rwl, swl, x):
-        # dense causal prefill mirror of arch["block"]'s prefill branch
-        B, T = x.shape[0], x.shape[1]
-        posm = jnp.broadcast_to(jnp.arange(T), (B, T))
-        q, k, v = qkv(rwl, swl, x, posm)
-        live = jnp.tril(jnp.ones((T, T), bool))[None, None, None]
-        o = _grouped_attention(q, k, v, live, rep)
-        return post_attn(rwl, swl, x, o), k, v
-
-    def head_rows(rw, sw, x, idx):
+    def head(rw, sw, h):
         if kind == "gpt":
-            h = _ln(x, rw["lnf_w"], rw["lnf_b"])
+            h = _ln(h, rw["lnf_w"], rw["lnf_b"])
         else:
-            h = _rms(x, rw["lnf_w"], eps)
-        rows = jnp.take_along_axis(h, idx[:, None, None], axis=1)[:, 0]
-        loc = rows @ (sw["head_w"].T if kind == "gpt" else sw["head_w"])
+            h = _rms(h, rw["lnf_w"], eps)
+        loc = h @ (sw["head_w"].T if kind == "gpt" else sw["head_w"])
         # padded vocab columns are sliced off post-gather (static slice)
         return _tp_gather(loc, int8_wire)[:, :vocab]
 
-    return {"embed_prompt": embed_prompt, "embed_rows": embed_rows,
-            "embed_tail": embed_tail, "qkv": qkv, "post_attn": post_attn,
-            "layer_rows": layer_rows, "layer_tail": layer_tail,
-            "layer_full": layer_full, "head_rows": head_rows,
-            "n_layers": L, "kv_local": KVl, "head_dim": D}
+    return {"embed": embed, "qkv": qkv, "finish": finish, "head": head,
+            # GQA group width is tp-invariant (both axes sharded)
+            "rep": H // KV, "n_layers": L, "kv_local": KVl, "head_dim": D}
 
 
 def _tp_pool_spec():
@@ -1617,45 +1433,31 @@ def build_tp_paged_decode(arch_key, B, block_size, max_blocks, mesh, vocab,
 
         rw = dequantize_tree(rep_tree, dtype)
         sw = _tp_local(shard_tree, dtype)
-        x = arch["embed_rows"](rw, toks, pos)
+        layer_ws = list(zip(rw["layers"], sw["layers"]))
+        posm = pos[:, None]
+        x = arch["embed"](rw, toks, pos)[:, None]
         bids = jnp.take_along_axis(tables, (pos // block_size)[:, None],
                                    axis=1)[:, 0]
         offs = pos % block_size
         if use_kernel:
-            from ..ops.kernels import paged_attention_rows
-
-            for li in range(L):
-                rwl = rw["layers"][li]
-                swl = sw["layers"][li]
-                q, k, v = arch["qkv"](rwl, swl, x, pos[:, None])
-                kpool = kpool.at[li, bids, offs].set(k[:, 0])
-                vpool = vpool.at[li, bids, offs].set(v[:, 0])
-                with jax.named_scope("attention"):
-                    o = paged_attention_rows(q[:, 0], kpool, vpool, li,
-                                             tables, pos)
-                x = arch["post_attn"](rwl, swl, x, o[:, None])
+            for li, w in enumerate(layer_ws):
+                x, (kpool, vpool) = _block_table_layer(
+                    arch, w, x, (kpool, vpool), li, tables, pos, bids, offs)
         else:
-            live = jnp.arange(T_pad)[None, :] <= pos[:, None]
+            live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]
             # gathers hoisted above the scatter chain (see build_paged_decode)
             with jax.named_scope("kv_gather"):
                 ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
                         vpool[li, tables].reshape(B, T_pad, KVl, D))
                        for li in range(L)]
-            for li in range(L):
-                x, k_new, v_new = arch["layer_rows"](
-                    rw["layers"][li], sw["layers"][li], x,
-                    ctx[li][0], ctx[li][1], live, pos)
-                kpool = kpool.at[li, bids, offs].set(k_new)
-                vpool = vpool.at[li, bids, offs].set(v_new)
+            for li, w in enumerate(layer_ws):
+                x, k_new, v_new = _context_layer(arch, w, x, ctx[li][0],
+                                                 ctx[li][1], live, posm)
+                kpool = kpool.at[li, bids, offs].set(k_new[:, 0])
+                vpool = vpool.at[li, bids, offs].set(v_new[:, 0])
         with jax.named_scope("head"):
-            logits = arch["head_rows"](rw, sw, x, jnp.zeros((B,), jnp.int32))
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = (logits / jnp.maximum(temps, 1e-6)[:, None]).astype(
-            jnp.float32)
-        sampled = jax.random.categorical(key, scaled, axis=-1).astype(
-            jnp.int32)
-        nxt = jnp.where(temps > 0, sampled, greedy)
-        return kpool, vpool, nxt
+            logits = arch["head"](rw, sw, x[:, -1])
+        return kpool, vpool, _next_tokens(logits, temps, key)
 
     wrapped = _tp_shard_map(
         body, mesh,
@@ -1685,7 +1487,7 @@ def build_tp_paged_prefill(arch_key, B, T_bucket, block_size, max_blocks,
     if nb > max_blocks:
         raise ValueError("prefill bucket exceeds max sequence blocks")
     arch = _tp_arch(arch_key, tp, vocab, int8_wire)
-    L, KVl, D = arch["n_layers"], arch["kv_local"], arch["head_dim"]
+    KVl, D = arch["kv_local"], arch["head_dim"]
     pool_s = _tp_pool_spec()
 
     def body(rep_tree, shard_tree, ids, lens, tables, kpool, vpool):
@@ -1693,17 +1495,16 @@ def build_tp_paged_prefill(arch_key, B, T_bucket, block_size, max_blocks,
 
         rw = dequantize_tree(rep_tree, dtype)
         sw = _tp_local(shard_tree, dtype)
-        x = arch["embed_prompt"](rw, ids, T_bucket)
+        x = arch["embed"](rw, ids, jnp.arange(T_bucket)[None])
         tb = tables[:, :nb]
-        for li in range(L):
-            x, k, v = arch["layer_full"](rw["layers"][li], sw["layers"][li],
-                                         x)
+        for li, w in enumerate(zip(rw["layers"], sw["layers"])):
+            x, k, v = _causal_layer(arch, w, x)
             kpool = kpool.at[li, tb].set(
                 k.reshape(B, nb, block_size, KVl, D))
             vpool = vpool.at[li, tb].set(
                 v.reshape(B, nb, block_size, KVl, D))
         with jax.named_scope("head"):
-            logits = arch["head_rows"](rw, sw, x, lens - 1)
+            logits = arch["head"](rw, sw, _last_rows(x, lens))
         return kpool, vpool, logits
 
     wrapped = _tp_shard_map(
@@ -1741,8 +1542,8 @@ def build_tp_paged_tail_prefill(arch_key, B, T_bucket, block_size, max_blocks,
 
         rw = dequantize_tree(rep_tree, dtype)
         sw = _tp_local(shard_tree, dtype)
-        x = arch["embed_tail"](rw, ids, starts)
         posm = starts[:, None] + jnp.arange(T_bucket)[None, :]
+        x = arch["embed"](rw, ids, posm)
         live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]
         cols = (starts // block_size)[:, None] + jnp.arange(nb)[None, :]
         bids = jnp.take_along_axis(
@@ -1752,16 +1553,15 @@ def build_tp_paged_tail_prefill(arch_key, B, T_bucket, block_size, max_blocks,
             ctx = [(kpool[li, tables].reshape(B, T_pad, KVl, D),
                     vpool[li, tables].reshape(B, T_pad, KVl, D))
                    for li in range(L)]
-        for li in range(L):
-            x, k_new, v_new = arch["layer_tail"](
-                rw["layers"][li], sw["layers"][li], x,
-                ctx[li][0], ctx[li][1], live, starts)
+        for li, w in enumerate(zip(rw["layers"], sw["layers"])):
+            x, k_new, v_new = _context_layer(arch, w, x, ctx[li][0],
+                                             ctx[li][1], live, posm)
             kpool = kpool.at[li, bids].set(
                 k_new.reshape(B, nb, block_size, KVl, D))
             vpool = vpool.at[li, bids].set(
                 v_new.reshape(B, nb, block_size, KVl, D))
         with jax.named_scope("head"):
-            logits = arch["head_rows"](rw, sw, x, lens - 1)
+            logits = arch["head"](rw, sw, _last_rows(x, lens))
         return kpool, vpool, logits
 
     wrapped = _tp_shard_map(
@@ -1786,34 +1586,27 @@ def build_window_draft(arch, B, W, k):
     rate, never correctness: the target verifies every proposal — then runs
     k single-token greedy steps against a dense per-row cache and returns
     the proposals (B, k) int32."""
-    KV, D = arch["kv_heads"], arch["head_dim"]
     T_max = W + k
 
     def draft(params, ids, lens):
         layer_ws = params["layers"]
-        rows = jnp.arange(B)
-        x = arch["embed_prompt"](params, ids, W)
-        caches = []
-        for w in layer_ws:
-            x, (kk, vv) = arch["block"](w, x)
-            kc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :W].set(kk)
-            vc = jnp.zeros((B, T_max, KV, D), x.dtype).at[:, :W].set(vv)
-            caches.append((kc, vc))
-        logits = arch["head_rows"](params, x, lens - 1)
+        rows = jnp.arange(B)[:, None]
+        x, caches = _prompt_pass(arch, params, ids, T_max)
+        logits = arch["head"](params, _last_rows(x, lens))
         out = jnp.zeros((B, k), jnp.int32)
         for j in range(k):
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             out = out.at[:, j].set(nxt)
-            pos = lens + j  # per-row write position of the new token
-            x = arch["embed_rows"](params, nxt, pos)
-            live = jnp.arange(T_max)[None, :] <= pos[:, None]
+            posm = (lens + j)[:, None]  # per-row write position of the new token
+            x = arch["embed"](params, nxt, posm[:, 0])[:, None]
+            live = jnp.arange(T_max)[None, None, :] <= posm[:, :, None]
             new_caches = []
             for w, (kc, vc) in zip(layer_ws, caches):
-                x, k_new, v_new = arch["block_rows"](w, x, kc, vc, live, pos)
-                new_caches.append((kc.at[rows, pos].set(k_new),
-                                   vc.at[rows, pos].set(v_new)))
+                x, k_new, v_new = _context_layer(arch, w, x, kc, vc, live, posm)
+                new_caches.append((kc.at[rows, posm].set(k_new),
+                                   vc.at[rows, posm].set(v_new)))
             caches = new_caches
-            logits = arch["head"](params, x)
+            logits = arch["head"](params, x[:, -1])
         return out
 
     return draft

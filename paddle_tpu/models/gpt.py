@@ -1,7 +1,7 @@
 """GPT model family — the flagship training config (BASELINE: GPT-3 1.3B).
 
 Parity: the reference trains GPT via PaddleNLP on Fleet hybrid parallel
-(BASELINE.json); the in-tree building blocks are the fused transformer ops
+(BASELINE.md); the in-tree building blocks are the fused transformer ops
 (``paddle/fluid/operators/fused/fused_attention_op.cc``) and the Megatron
 layers (``fleet/meta_parallel/parallel_layers/mp_layers.py``). This model is
 built TPU-first:
@@ -265,6 +265,14 @@ class GPTForPretraining(nn.Layer):
         return F.cross_entropy(
             logits.reshape([-1, logits.shape[-1]]), labels.reshape([-1])
         )
+
+    def decode_state(self):
+        """``(arch_key, arch, params, max_positions)``: the arch plug and the
+        weight tree that ``generate()`` and ``serving.Engine`` run this model
+        through (``models/generation.py``)."""
+        from . import generation
+
+        return generation.gpt_decode_state(self)
 
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0, top_k=0,
                  top_p=1.0, eos_token_id=None, do_sample=True, num_beams=1,

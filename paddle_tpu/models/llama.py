@@ -176,6 +176,14 @@ class LlamaForCausalLM(nn.Layer):
         logits = self(input_ids)
         return F.cross_entropy(logits.reshape([-1, logits.shape[-1]]), labels.reshape([-1]))
 
+    def decode_state(self):
+        """``(arch_key, arch, params, max_positions)``: the arch plug and the
+        weight tree that ``generate()`` and ``serving.Engine`` run this model
+        through (``models/generation.py``)."""
+        from . import generation
+
+        return generation.llama_decode_state(self)
+
     def generate(self, input_ids, max_new_tokens=32, temperature=1.0, top_k=0,
                  top_p=1.0, eos_token_id=None, do_sample=True):
         """KV-cached compiled decode (models/generation.py Llama path: RoPE

@@ -523,15 +523,21 @@ class MLAMoEForCausalLM(nn.Layer):
 
     def forward(self, input_ids):
         """Logits (B, T, vocab) of whole prompts: the prefill path, no cache."""
-        from .generation import mla_moe_decode_state
-
         ids = jnp.asarray(getattr(input_ids, "_data", input_ids), jnp.int32)
-        _, arch, params, _ = mla_moe_decode_state(self)
-        X = arch["embed_prompt"](params, ids, ids.shape[1])
+        _, arch, params, _ = self.decode_state()
+        X = arch["embed"](params, ids, None)
         live = jnp.ones(ids.shape, bool)
         for w in params["layers"]:
             X, _, _ = arch["prompt_layer"](w, X, live)
-        return Tensor(arch["head_all"](params, X))
+        return Tensor(arch["head"](params, X))
+
+    def decode_state(self):
+        """``(arch_key, arch, params, max_positions)``: the arch plug and the
+        weight tree that ``forward`` and ``serving.Engine`` run this model
+        through (``models/generation.py``)."""
+        from . import generation
+
+        return generation.mla_moe_decode_state(self)
 
     def generate(self, *a, **kw):
         raise NotImplementedError(

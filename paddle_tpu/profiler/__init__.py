@@ -11,7 +11,8 @@ Layers (each usable alone):
 
 * **engine counters** — always-on integer bumps at flush/step granularity
   (:func:`counters`), exported as JSON or Prometheus text
-  (:mod:`.export`), folded into every ``bench.py`` JSON line;
+  (:mod:`.export`); the benchmark's per-layer readers take their deltas
+  over the timed window (``benchmark/``);
 * **span tracer** (:mod:`.spans`) — nested, attributed spans
   (``train_step`` → ``lazy_flush`` → ``trace``/``donate``/``compile``/
   ``execute``; ``dp_sync`` → per-bucket; ``ckpt_save`` →
@@ -86,8 +87,8 @@ def counters() -> Dict[str, int]:
     is running, to keep the dispatch hot path free of bookkeeping.
 
     Async runtime (FLAGS_lazy_async): ``lazy_blocks`` / ``lazy_block_ns``
-    (attributed host waits on the device — the dispatch-gap metric bench.py
-    reports per step), ``lazy_deferred_checks`` (NaN/Inf scans moved off the
+    (attributed host waits on the device: the dispatch gap a step),
+    ``lazy_deferred_checks`` (NaN/Inf scans moved off the
     critical path), ``lazy_bg_compiles`` / ``lazy_bg_replays`` /
     ``lazy_bg_pickups`` / ``lazy_bg_compile_failures`` /
     ``lazy_bg_aot_fallbacks`` (FLAGS_lazy_bg_compile background compilation:
@@ -258,7 +259,7 @@ def counters() -> Dict[str, int]:
 
     Export: :func:`export_metrics` (JSON or Prometheus text) embeds this
     snapshot plus the memory gauges; ``Profiler.export`` embeds it as
-    chrome-trace metadata; ``bench.py`` folds it into every BENCH JSON line.
+    chrome-trace metadata.
     """
     return dict(_counters)
 

@@ -18,7 +18,8 @@ programs in ``models/generation.py``:
   per-sequence block table read by the block-table attention kernel
   (``build_paged_decode_kernel``: only the blocks a row has live; the
   gather step ``build_paged_decode`` is its plain reference and the CPU
-  tier's decode), so HBM holds ``Σ ceil(len/block)`` blocks
+  tier's decode: the arch's one ``qkv`` / ``finish`` around another read of
+  the context), so HBM holds ``Σ ceil(len/block)`` blocks
   instead of ``B × T_max`` dense caches. Pool exhaustion is backpressure:
   admission stalls the queue, and a running sequence that can't grow evicts
   the youngest peer (freed blocks, state requeued for re-prefill from its
@@ -601,8 +602,9 @@ class RequestHandle:
 class Engine:
     """Continuous-batching serving engine over a paged KV cache.
 
-    ``model`` is a ``GPTForPretraining``, ``LlamaForCausalLM`` or
-    ``MLAMoEForCausalLM`` instance with full logical weights. The engine
+    ``model`` says what serves it: ``model.decode_state()`` returns its arch
+    plug and weight tree (``GPTForPretraining``, ``LlamaForCausalLM``,
+    ``MLAMoEForCausalLM``; full logical weights). The engine
     thread owns all scheduler state; only the submission queue and stop flag
     cross threads (guarded below).
 
@@ -620,18 +622,14 @@ class Engine:
         from ..models import generation as G
 
         self._jax, self._jnp, self._G = jax, jnp, G
-        if hasattr(model, "gpt"):
-            arch_key, arch, params, max_pos = G.gpt_decode_state(model)
-        elif hasattr(getattr(model, "config", None), "kv_lora_rank"):
-            arch_key, arch, params, max_pos = G.mla_moe_decode_state(model)
-        elif hasattr(model, "lm_head") and hasattr(model, "model"):
-            arch_key, arch, params, max_pos = G.llama_decode_state(model)
-        else:
+        # the model says what serves it: its arch plug and weight tree
+        if not callable(getattr(model, "decode_state", None)):
             raise TypeError(
                 f"serving.Engine: unsupported model {type(model).__name__} "
                 "(expected GPTForPretraining, LlamaForCausalLM or "
                 "MLAMoEForCausalLM)"
             )
+        arch_key, arch, params, max_pos = model.decode_state()
         if config is not None and overrides:
             raise ValueError("pass EngineConfig OR keyword overrides, not both")
         # resolve a COPY: the caller's EngineConfig stays pristine (this
@@ -732,13 +730,11 @@ class Engine:
                 self._drafter = True
             elif isinstance(d, str):
                 raise ValueError(f"serving: unknown drafter {d!r}")
-            elif hasattr(d, "gpt"):
-                _, darch, dparams, dmax = G.gpt_decode_state(d)
-                self._drafter = (darch, dparams,
-                                 max(2, min(cfg.draft_window,
-                                            int(dmax) - self._spec_k)))
-            elif hasattr(d, "lm_head") and hasattr(d, "model"):
-                _, darch, dparams, dmax = G.llama_decode_state(d)
+            elif callable(getattr(d, "decode_state", None)):
+                _, darch, dparams, dmax = d.decode_state()
+                if darch.get("plain_paths_only"):
+                    raise TypeError(
+                        f"serving: unsupported drafter {type(d).__name__}")
                 self._drafter = (darch, dparams,
                                  max(2, min(cfg.draft_window,
                                             int(dmax) - self._spec_k)))

@@ -40,7 +40,8 @@ to REQUESTS, in four layers (each inert until armed):
 
 Everything here is O(1) per scheduler step amortized (per emitted token for
 the gap histogram — the same order as the per-row work the scheduler
-already does) and covered by ``bench_observe_overhead``.
+already does); ``tests/test_serving_observe.py`` holds the flag-off path
+to nothing at all.
 """
 from __future__ import annotations
 

@@ -168,14 +168,13 @@ def test_expanded_and_absorbed_forms_agree(tiny):
     pool, logits, _ = pre(params, jnp.asarray(ids[None, :cut]), jnp.asarray([cut]), tables, pool)
     close(logits[0], full[cut - 1], "prefill's last row")
     for t in range(cut, 24):
-        x = arch["embed_rows"](params, jnp.asarray(ids[t:t + 1]), None)
+        X = arch["embed"](params, jnp.asarray(ids[None, t:t + 1]), None)
         pos = jnp.asarray([t], jnp.int32)
-        X = x
         for li, lw in enumerate(params["layers"]):
             X, (pool,), _ = arch["decode_layer"](
                 lw, X, (pool,), li, tables, pos, tables[:, t // bs], pos % bs,
                 jnp.asarray([True]))
-        close(arch["head"](params, X)[0], full[t], f"absorbed, position {t}")
+        close(arch["head"](params, X[:, -1])[0], full[t], f"absorbed, position {t}")
 
 
 # -- (c) (d) (f) the expert layer ---------------------------------------------------

@@ -585,8 +585,8 @@ class TestOverheadGuard:
     def test_closed_profiler_does_not_tax_dispatch(self):
         """Tier-1 tripwire: the disabled path (profiler constructed but
         CLOSED, flight recorder running) must stay within noise of no
-        profiler at all on a hot record+flush loop. bench.py measures the
-        precise number; this guard uses interleaved min-of-N so CI noise
+        profiler at all on a hot record+flush loop. This guard uses
+        interleaved min-of-N so CI noise
         can't fail it while a real regression (a per-op allocation, an
         unconditional census) still trips."""
 

@@ -432,6 +432,88 @@ class TestLlamaServing:
         assert batched[1] == np.asarray(ref._data)[0].tolist()
 
 
+def _seam_model(which):
+    if which == "gpt":
+        return _tiny_gpt(seed=0), 211
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+
+    paddle.seed(0)
+    cfg = llama_tiny(num_kv_heads=2)
+    m = LlamaForCausalLM(cfg)
+    m.eval()
+    return m, cfg.vocab_size
+
+
+class TestLayerSeam:
+    """An arch states its layer once (``qkv`` / ``finish``) and the programs
+    differ only in how they read the context between the two: the equalities
+    that five copies of a layer used to hold by hand."""
+
+    @pytest.mark.parametrize("which", ["gpt", "llama_gqa"])
+    def test_reads_agree_across_programs(self, which):
+        import jax
+        import jax.numpy as jnp
+
+        import paddle_tpu.models.generation as G
+
+        m, vocab = _seam_model(which)
+        _, arch, params, _ = m.decode_state()
+        B, BS, MB, NB, T = 3, 8, 4, 32, 16
+        L = len(params["layers"])
+        rng = np.random.RandomState(4)
+        ids = jnp.asarray(rng.randint(0, vocab, (B, T)), jnp.int32)
+        lens = jnp.asarray([16, 13, 9], jnp.int32)
+        tables = jnp.asarray(1 + np.arange(B * MB).reshape(B, MB), jnp.int32)
+        empty = tuple(jnp.zeros((L, NB, BS) + row, jnp.float32)
+                      for row in G.cache_row_shapes(arch))
+        whole = jax.jit(G.build_paged_prefill(arch, B, T, BS, MB))
+        kpool, vpool, logits = whole(params, ids, lens, tables, *empty)
+
+        # tail prefill of the second half over a prefilled first half: the
+        # context read at T = 8 against the causal read of the whole prompt
+        half = jax.jit(G.build_paged_prefill(arch, B, BS, BS, MB))
+        tail = jax.jit(G.build_paged_tail_prefill(arch, B, T - BS, BS, MB))
+        first = jnp.full((B,), BS, jnp.int32)
+        k1, v1, _ = half(params, ids[:, :BS], first, tables, *empty)
+        k2, v2, tail_logits = tail(params, ids[:, BS:], first, lens - BS,
+                                   tables, k1, v1)
+        assert np.abs(np.asarray(tail_logits) - np.asarray(logits)).max() <= 1e-5
+        blk = int(tables[0, 1])  # row 0's second block: all tail, all real
+        for got, want in ((k2, kpool), (v2, vpool)):
+            assert np.abs(np.asarray(got)[:, blk]).max() > 0
+            assert np.abs(np.asarray(got)[:, blk]
+                          - np.asarray(want)[:, blk]).max() <= 1e-5
+
+        # one token a row: the gather decode step against the verify step
+        # fed no draft (k = 0), the context read at T = 1 through another
+        # builder. Tokens and pools bit for bit, 8 steps on.
+        step = jax.jit(G.build_paged_decode(arch, B, BS, MB))
+        verify = jax.jit(G.build_paged_spec_decode(arch, B, 0, BS, MB))
+        temps, key = jnp.zeros((B,), jnp.float32), jax.random.PRNGKey(0)
+        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        pools_a = pools_b = (kpool, vpool)
+        for i in range(8):
+            pos = lens + i
+            *pools_a, nxt = step(params, *pools_a, tables, pos, toks, temps, key)
+            *pools_b, greedy, _ = verify(params, *pools_b, tables, pos,
+                                         toks[:, None], temps, key)
+            assert np.array_equal(np.asarray(nxt), np.asarray(greedy)[:, 0]), i
+            for a, b in zip(pools_a, pools_b):
+                assert np.array_equal(np.asarray(a), np.asarray(b)), i
+            toks = nxt
+
+    def test_the_model_says_what_serves_it(self):
+        from paddle_tpu.models.gpt import GPTForPretraining
+        from paddle_tpu.models.llama import LlamaForCausalLM
+        from paddle_tpu.models.mla_moe import MLAMoEForCausalLM
+
+        with pytest.raises(TypeError) as err:
+            Engine(object())
+        for cls in (GPTForPretraining, LlamaForCausalLM, MLAMoEForCausalLM):
+            assert cls.__name__ in str(err.value)
+            assert callable(cls.decode_state)
+
+
 class TestGenerateEosSatellite:
     """models/generation.py satellite: per-sequence EOS handling in batched
     decode — frozen finished rows, eos-padded tails, early loop exit —
