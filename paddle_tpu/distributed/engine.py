@@ -9,16 +9,32 @@ formulation: ONE compiled XLA program per train step —
  * the batch is sharded over 'dp' (and 'sp' for sequence parallel);
  * GSPMD partitions every matmul and inserts the all-reduces /
    reduce-scatters / all-gathers the reference codes as c_allreduce_sum /
-   partial_* ops, scheduled by XLA's latency-hiding scheduler over ICI;
- * optimizer state sharded over the ZeRO axis makes the weight update a
-   sharded computation (ZeRO-1/2 semantics) with an all-gather of updated
-   params — "Automatic Cross-Replica Sharding of Weight Update" (PAPERS.md).
+   partial_* ops. XLA:TPU runs an all-gather beside compute (a start/done
+   pair) and an all-reduce or reduce-scatter NOT: each is a synchronous
+   instruction that holds the chip's line alone, and no compile option of
+   this libtpu changes that (PERF.md section 6, PR 30);
+ * so on a mesh with ONE data-parallel axis, alone or beside 'mp', the step
+   is one ``shard_map`` manual over that axis only (``_build_dp_step``) and
+   reduces its gradients over it by hand, leaf by leaf in reverse order of
+   the backward pass: beside 'mp' by ``lax.ppermute`` exchange, which the
+   compiler does schedule as a start/done pair under the backward matmuls
+   that remain, each leaf's update behind its own reduce; alone by
+   reduce-scatter with the weight update sharded 1/dp (ZeRO-1: "Automatic
+   Cross-Replica Sharding of Weight Update", PAPERS.md). The ``train_step``
+   span says what came of it, from the compiled step's own scheduled text:
+   ``dp_reduce_leaves`` and ``dp_reduce_async`` (``dp_reduce_counts``);
+ * any other mesh ('sp', 'pp', a ZeRO 'sharding' axis), gradient
+   accumulation and non-elementwise optimizers keep the replicated GSPMD
+   step; optimizer state sharded over the ZeRO axis makes its weight update
+   a sharded computation (ZeRO-1/2 semantics) with an all-gather of updated
+   params.
 
 The engine is the TPU replacement for the reference's per-op executor hot
 loop + DDP reducer + sharding-stage hooks, collapsed into compile time.
 """
 from __future__ import annotations
 
+import re
 from typing import Callable, Optional, Sequence
 
 import numpy as np
@@ -30,6 +46,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..core import random as random_state
 from ..core.engine import no_grad
 from ..core.tensor import Tensor
+from .fleet.meta_optimizers.hybrid_parallel_optimizer import (
+    DP_REDUCE_SCOPE, ShardedWeightUpdate,
+)
 from .mesh import global_mesh, partitioned_over
 
 
@@ -78,11 +97,16 @@ class HybridParallelEngine:
         self.buffers = list(model.buffers())
         self._jit = None
         self._placed = False
-        # ZeRO-1 sharded weight update (FLAGS_shard_weight_update): built at
-        # first step for pure-DP meshes; _dp_state holds the engine-resident
-        # bucket-flat optimizer state, physically sharded over the dp axis.
+        # the data-parallel step's gradient sync (FLAGS_shard_weight_update):
+        # built at first step for a mesh with one dp axis, alone or beside
+        # 'mp'; alone, _dp_state holds the engine-resident bucket-flat
+        # optimizer state, physically sharded over the dp axis (ZeRO-1).
         self._wus = None
         self._dp_state = None
+        # the dp step's executables by batch signature, and what their text
+        # says of the gradient reduces (``dp_reduce_counts``)
+        self._compiled = {}
+        self._dp_reduce = None
         # stability sentinel (fault/sentinel.py); None keeps the zero-cost
         # path — one attribute check per train_step
         self._sentinel = None
@@ -227,16 +251,12 @@ class HybridParallelEngine:
             return loss, new_params, new_state
 
         donate = (0, 1) if self.donate else ()
-        from .fleet.meta_optimizers.hybrid_parallel_optimizer import (
-            ShardedWeightUpdate,
-        )
-
         self._wus = ShardedWeightUpdate.maybe_build(
             opt, params, self.mesh, self.dp_axes, self.grad_accumulate
         )
         if self._wus is not None:
             self._jit = jax.jit(
-                self._build_dp_sharded(make_loss_of), donate_argnums=donate
+                self._build_dp_step(make_loss_of), donate_argnums=donate
             )
             from .. import profiler
 
@@ -249,15 +269,25 @@ class HybridParallelEngine:
         )
         self._jit = jax.jit(fn, donate_argnums=donate)
 
-    def _build_dp_sharded(self, make_loss_of):
-        """Communication-optimized pure-DP step: ONE shard_map over the dp
-        axis — local forward/backward on the batch shard, bucketed gradient
-        reduce-scatter (reverse-backward order so XLA overlaps sync with
-        remaining backward compute), 1/dp-shard optimizer update, updated
-        params all-gathered (ZeRO-1; arXiv:2004.13336)."""
+    def _build_dp_step(self, make_loss_of):
+        """The step of a mesh with ONE data-parallel axis, alone or beside
+        'mp': one shard_map that is manual over that axis and not 'mp' — local
+        forward/backward on the batch shard ('mp' inside it stays GSPMD's),
+        the gradients reduced over the axis by hand, bucket by bucket in
+        reverse order of the backward pass, in the form ``ShardedWeightUpdate``
+        chooses by what else the mesh holds: alone, flat buckets
+        reduce-scattered, a 1/dp-shard update and the updated params
+        all-gathered (ZeRO-1; arXiv:2004.13336); beside 'mp', each leaf
+        exchanged as it lies by ``ppermute``, whose transfer XLA:TPU runs
+        under the backward matmuls that remain, and updated whole."""
         wus = self._wus
         axis = wus.axis
-        from jax.sharding import PartitionSpec as P
+        # an axis of size 1 holds nothing: the map takes those too, so that
+        # inside it only 'mp' is left to GSPMD (and nothing on a mesh that
+        # is 'dp' alone: Mosaic refuses a kernel call while any axis is)
+        manual = frozenset(
+            a for a, n in zip(self.mesh.axis_names, self.mesh.devices.shape)
+            if a == axis or n == 1)
 
         from .mesh import shard_map_compat
 
@@ -273,31 +303,28 @@ class HybridParallelEngine:
                 new_params, new_state = wus.apply(p_arrays, grads, dp_state, lr)
             return lax.pmean(loss, axis), tuple(new_params), new_state
 
-        valid = set(self.mesh.axis_names)
-
-        def clean_spec(spec):
-            out = []
-            for s in tuple(spec):
-                if isinstance(s, (tuple, list)):  # multi-axis entry
-                    kept = tuple(a for a in s if a in valid)
-                    out.append(kept if len(kept) > 1 else (kept[0] if kept else None))
-                else:
-                    out.append(s if (s is None or s in valid) else None)
-            return P(*out)
+        def over_axis(spec):
+            # the map is manual over ``axis`` alone: its specs may name no
+            # other axis (what 'mp' or 'sp' hold of an operand comes in with
+            # the operand's own sharding)
+            return P(*(
+                axis if axis in (s if isinstance(s, (tuple, list)) else (s,))
+                else None for s in tuple(spec)))
 
         def step_fn(param_arrays, dp_state, batch_arrays, lr, key):
             batch_specs = tuple(
-                clean_spec(self.batch_specs[i])
+                over_axis(self.batch_specs[i])
                 if self.batch_specs is not None and i < len(self.batch_specs)
                 else P(axis)
                 for i in range(len(batch_arrays))
             )
+            state_specs = wus.state_specs(dp_state)
             fn = _shard_map(
                 spmd,
                 mesh=self.mesh,
                 in_specs=(
                     tuple(P() for _ in param_arrays),
-                    wus.state_specs(),
+                    state_specs,
                     batch_specs,
                     P(),
                     P(),
@@ -305,11 +332,25 @@ class HybridParallelEngine:
                 out_specs=(
                     P(),
                     tuple(P() for _ in param_arrays),
-                    wus.state_specs(),
+                    state_specs,
                 ),
+                axis_names=manual,
                 **_check,
             )
-            return fn(tuple(param_arrays), dp_state, tuple(batch_arrays), lr, key)
+            loss, new_params, new_state = fn(
+                tuple(param_arrays), dp_state, tuple(batch_arrays), lr, key)
+            if not wus.flat:
+                # what comes out lies as what went in, so that the next
+                # step's operands are this step's results (for an axis the
+                # map left to it GSPMD is free to choose otherwise)
+                lie = lax.with_sharding_constraint
+                new_params = tuple(
+                    lie(a, _sharding(self.mesh, getattr(p, "pspec", None)))
+                    for p, a in zip(self.params, new_params))
+                new_state = dict(new_state, accums=[
+                    {k: lie(v, self._opt_sharding(p)) for k, v in st.items()}
+                    for p, st in zip(self.params, new_state["accums"])])
+            return loss, new_params, new_state
 
         return step_fn
 
@@ -337,16 +378,21 @@ class HybridParallelEngine:
             arr = b._data if isinstance(b, Tensor) else jnp.asarray(b)
             batch_arrays.append(jax.device_put(arr, self._batch_sharding(i, arr)))
         param_arrays = [p._data for p in self.params]
-        if self._wus is not None:
+        if self._resident():
             if self._dp_state is None:
                 self._dp_state = self._wus.init_state(self.mesh)
-            lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
-            key = random_state.next_key()
-            return param_arrays, self._dp_state, tuple(batch_arrays), lr, key
-        opt_state = self._replicated_opt_state()
+            opt_state = self._dp_state
+        else:
+            opt_state = self._replicated_opt_state()
         lr = jnp.asarray(self.optimizer.get_lr(), jnp.float32)
         key = random_state.next_key()
         return param_arrays, opt_state, tuple(batch_arrays), lr, key
+
+    def _resident(self) -> bool:
+        """Whether the optimizer state lives in the engine (``_dp_state``:
+        the ZeRO-1 bucket-flat shards of a mesh that is 'dp' alone) or in
+        the optimizer's own per-leaf accumulators, restored every step."""
+        return self._wus is not None and self._wus.flat
 
     def _replicated_opt_state(self):
         """Optimizer state for the replicated (non-wus) step, accumulators
@@ -429,21 +475,29 @@ class HybridParallelEngine:
             )
         for p, a in zip(self.params, new_params):
             p._set_data(a)
-        if self._wus is not None:
+        if self._resident():
             # bucket-flat sharded state stays engine-resident (per-replica
             # optimizer memory is 1/dp); sync_optimizer_state() unpacks it
             # into the optimizer's per-param accumulators on demand
             self._dp_state = new_state
-            self.optimizer._step_count += 1
+        else:
+            self.optimizer._functional_restore(self.params, new_state)
+        self.optimizer._step_count += 1
+        if self._wus is not None:
             from .. import profiler
 
-            profiler.counter_inc("wus_enabled", 1 - profiler.counters().get("wus_enabled", 0))
+            have, said = profiler.counters(), self._dp_reduce
+            # states, not sums: what the step that runs is, not how often
+            profiler.counter_inc("wus_enabled", 1 - have.get("wus_enabled", 0))
+            profiler.counter_inc(
+                "dp_reduce_leaves",
+                said["dp_reduce_leaves"] - have.get("dp_reduce_leaves", 0))
+            profiler.counter_inc(
+                "dp_reduce_async",
+                said["dp_reduce_async"] - have.get("dp_reduce_async", 0))
             for k, v in self._wus.step_counters().items():
                 profiler.counter_inc(k, v)
-            self._observe_stability(loss)
-            return Tensor(loss)
-        self.optimizer._functional_restore(self.params, new_state)
-        self.optimizer._step_count += 1
+            sp.set(**said)
         self._observe_stability(loss)
         return Tensor(loss)
 
@@ -457,7 +511,23 @@ class HybridParallelEngine:
             _dsp._fault_inject.maybe_hbm_oom(
                 self._dispatch_op, step=self.optimizer._step_count + 1
             )
-        return self._jit(*args)
+        if self._wus is None:
+            return self._jit(*args)
+        # the dp step is compiled ahead of time, once a batch signature, and
+        # that object is what runs: its scheduled text says whether the
+        # gradient reduces run beside compute (no second compile to ask)
+        sig = tuple((a.shape, str(a.dtype)) for a in args[2])
+        exe = self._compiled.get(sig)
+        if exe is None:
+            exe = self._compiled[sig] = self._jit.lower(*args).compile()
+            self._dp_reduce = dp_reduce_counts(exe.as_text())
+        try:
+            return exe(*args)
+        except ValueError:
+            # an operand lies otherwise than the step was compiled for (a
+            # parameter restored from a checkpoint onto one device): where
+            # jit would compile a second program, put it where it belongs
+            return exe(*jax.device_put(args, exe.input_shardings[0]))
 
     def _recover_oom(self, exc, param_arrays, opt_state, batch_arrays, lr,
                      key, sp):
@@ -616,3 +686,45 @@ def shard_model_params(model, mesh=None):
     for b in model.buffers():
         b._set_data(jax.device_put(b._data, _sharding(mesh, None)))
     return model
+
+
+_DP_REDUCE = re.compile(
+    r"^\s*(?:ROOT )?(%\S+) = (\(.*?\)|\S+) "
+    r"(all-reduce|reduce-scatter|all-to-all|collective-permute)(-start)?\((.*?)\)"
+    r".*op_name=\"[^\"]*/" + DP_REDUCE_SCOPE + r"/")
+_COMPUTE = re.compile(r" (fusion|convolution|custom-call)\(")
+
+
+def dp_reduce_counts(text: str) -> dict:
+    """What the scheduled text of a compiled dp step says of its gradient
+    reduces (the collectives traced under ``DP_REDUCE_SCOPE``), in the order
+    the chip runs the entry computation's instructions:
+    ``dp_reduce_leaves``, the gradient arrays reduced over the data-parallel
+    axis (a combined collective counts each operand), and ``dp_reduce_async``,
+    those whose reduce is a ``-start`` / ``-done`` pair with at least one
+    compute instruction (a fusion, a convolution, a kernel call) scheduled
+    between the two: the transfer runs beside that work. A synchronous
+    collective holds the chip's line alone and counts as a leaf only."""
+    entry = text[text.find("\nENTRY "):]
+    lines = entry.split("\n")
+    leaves = hidden = 0
+    open_ = {}  # name of a start -> [operands, compute seen since]
+    for line in lines:
+        m = _DP_REDUCE.match(line)
+        if m:
+            n = m.group(5).count("%")
+            leaves += n
+            if m.group(4):
+                open_[m.group(1)] = [n, False]
+            continue
+        if not open_:
+            continue
+        if _COMPUTE.search(line):
+            for st in open_.values():
+                st[1] = True
+        elif "-done(" in line:
+            name = line[line.index("-done(") + 6:].split(")")[0].split(",")[0]
+            st = open_.pop(name.strip(), None)
+            if st is not None and st[1]:
+                hidden += st[0]
+    return {"dp_reduce_leaves": leaves, "dp_reduce_async": hidden}

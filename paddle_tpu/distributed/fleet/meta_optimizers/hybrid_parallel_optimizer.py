@@ -1,4 +1,5 @@
-"""HybridParallelOptimizer + the ZeRO-1 sharded weight update.
+"""HybridParallelOptimizer + the data-parallel gradient sync of the engine's
+step: the ZeRO-1 sharded weight update, and the pairwise exchange.
 
 Parity: reference ``fleet/meta_optimizers/dygraph_optimizer/
 hybrid_parallel_optimizer.py:170`` — wraps the user optimizer, fixes grad
@@ -17,8 +18,12 @@ dtype-homogeneous flat buckets) and applies the per-shard update INSIDE a
 ``shard_map`` over the dp mesh axis, with optional EQuARX-style int8
 compression of the gradient reduce-scatter (collective.py quantized prims,
 ``FLAGS_quantized_allreduce``) and an error-feedback accumulator. The
-distributed engine (distributed/engine.py) builds its pure-DP train step
-around it when ``FLAGS_shard_weight_update`` is on.
+distributed engine (distributed/engine.py) builds its train step around it
+wherever the mesh has one data-parallel axis of size > 1, alone or beside
+'mp', when ``FLAGS_shard_weight_update`` is on. Beside 'mp' the leaves are not
+flattened (another axis already holds one of their dimensions): each is
+exchanged as it lies with ``lax.ppermute``, the one collective this compiler
+runs beside compute by default (``ShardedWeightUpdate._exchange_mean``).
 """
 from __future__ import annotations
 
@@ -31,7 +36,12 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ....framework import flags as _flags
 from ....optimizer import Optimizer
 from ...collective import quantized_psum_scatter_mean
-from ..grad_buckets import build_bucket_plan
+from ..grad_buckets import DEFAULT_BUCKET_BYTES, build_bucket_plan
+
+
+# the scope every data-parallel gradient reduce is traced under: the engine
+# finds them by it in the compiled step's text (``dp_reduce_leaves``)
+DP_REDUCE_SCOPE = "dp_reduce"
 
 
 class ShardedWeightUpdate:
@@ -46,13 +56,21 @@ class ShardedWeightUpdate:
 
     Only ELEMENTWISE update rules are eligible (``Optimizer._elementwise_rule``
     — LAMB/LARS need full-param norms and fall back to the replicated path).
+
+    ``flat`` says what the mesh holds beside ``axis``. Nothing (True): every
+    leaf is whole on every replica, so the buckets are flattened and the
+    state lives bucket-flat, 1/dp a replica, as above. 'mp' (False): a leaf's
+    dimension is already taken, so a bucket is its leaves as they lie, in the
+    same reverse-backward order; each is summed over the replicas by pairwise
+    exchange and updated whole, with the optimizer's own per-leaf state.
     """
 
-    def __init__(self, optimizer, params, axis: str, nranks: int):
+    def __init__(self, optimizer, params, axis: str, nranks: int, flat=True):
         self.optimizer = optimizer
         self.params = list(params)
         self.axis = axis
         self.nranks = int(nranks)
+        self.flat = bool(flat)
         self.quantized = bool(_flags.flag("FLAGS_quantized_allreduce", False))
         self.block = int(_flags.flag("FLAGS_quantized_allreduce_block", 128))
         self.error_feedback = self.quantized and bool(
@@ -64,10 +82,12 @@ class ShardedWeightUpdate:
                 return p.optimize_attr.get("learning_rate", 1.0)
             return 1.0
 
+        self.bucket_bytes = int(
+            _flags.flag("FLAGS_dp_bucket_bytes") or DEFAULT_BUCKET_BYTES)
         self.plan = build_bucket_plan(
             self.params,
             nranks=self.nranks,
-            bucket_bytes=_flags.flag("FLAGS_dp_bucket_bytes"),
+            bucket_bytes=self.bucket_bytes,
             block=self.block,
             wd_of=optimizer._wd_on,
             plr_of=plr_of,
@@ -81,9 +101,10 @@ class ShardedWeightUpdate:
     # -- enablement --------------------------------------------------------
     @staticmethod
     def maybe_build(optimizer, params, mesh, dp_axes, grad_accumulate=1):
-        """Return a ShardedWeightUpdate when the configuration is a pure-DP
-        group eligible for weight-update sharding, else None (the caller
-        falls back to the replicated GSPMD update)."""
+        """Return a ShardedWeightUpdate when the mesh has ONE data-parallel
+        axis of size > 1, alone or beside 'mp', and nothing shards a leaf, its
+        gradient or its state over that axis; else None (the caller falls
+        back to the replicated GSPMD update)."""
         if not _flags.flag("FLAGS_shard_weight_update", True):
             return None
         if grad_accumulate and int(grad_accumulate) > 1:
@@ -95,28 +116,32 @@ class ShardedWeightUpdate:
         sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
         dp_present = [a for a in dp_axes if sizes.get(a, 1) > 1]
         other = [a for a, s in sizes.items() if a not in tuple(dp_axes) and s > 1]
-        if len(dp_present) != 1 or other:
-            return None  # hybrid mesh: GSPMD owns the sharding
+        if len(dp_present) != 1 or any(a != "mp" for a in other):
+            return None
+        axis, n = dp_present[0], sizes[dp_present[0]]
+        if other and n & (n - 1):
+            return None  # the pairwise exchange halves a power of two
 
-        def live(spec):
-            # a spec is only a real sharding if it names a mesh axis of
-            # size > 1 (Megatron pspecs are inert on a pure-DP mesh)
-            if spec is None:
-                return False
-            for s in tuple(spec):
-                axes = s if isinstance(s, (tuple, list)) else (s,)
-                if any(isinstance(a, str) and sizes.get(a, 1) > 1 for a in axes):
-                    return True
-            return False
+        def over_dp(spec):
+            # a Megatron pspec names 'mp'; one that names the dp axis itself
+            # (ZeRO-2/3 layouts on a mesh without a 'sharding' axis) leaves
+            # the reduction to the partitioner
+            return spec is not None and any(
+                axis in (s if isinstance(s, (tuple, list)) else (s,))
+                for s in tuple(spec))
 
-        if any(live(getattr(p, "pspec", None)) or
-               live(getattr(p, "grad_pspec", None)) for p in params):
-            return None  # model/grad sharding present: not pure DP
-        return ShardedWeightUpdate(optimizer, params, dp_present[0],
-                                   sizes[dp_present[0]])
+        if any(over_dp(getattr(p, k, None)) for p in params
+               for k in ("pspec", "grad_pspec", "opt_state_pspec")):
+            return None
+        return ShardedWeightUpdate(optimizer, params, axis, n, flat=not other)
 
     # -- state (global arrays, engine-resident) ----------------------------
-    def state_specs(self):
+    def state_specs(self, state):
+        if not self.flat:
+            # the optimizer's own per-leaf state, whole on every replica (what
+            # 'mp' holds of a leaf is the partitioner's: the specs of the
+            # engine's map name its manual axis only)
+            return jax.tree_util.tree_map(lambda _: P(), state)
         specs = {
             "t": P(),
             "accums": [
@@ -188,11 +213,64 @@ class ShardedWeightUpdate:
                         host[off:off + sz].reshape(b.shapes[pos])
                     )
 
-    # -- the sharded update (inside shard_map) -----------------------------
+    # -- the update (inside shard_map over ``self.axis``) -------------------
+    def _exchange_mean(self, g):
+        """Mean of ``g`` over the replicas by pairwise exchange: log2(dp)
+        rounds of one ``ppermute`` with the partner whose index differs in
+        one bit, and a local add. Both partners add the same two arrays, so
+        every replica ends with the same bits whatever dp is; at dp 2 it is
+        one transfer of the gradient each way, the bytes of an all-reduce.
+        XLA:TPU schedules a collective-permute as a start/done pair with the
+        rest of the backward pass between them, which it does not do for an
+        all-reduce or a reduce-scatter (PERF.md section 6, PR 30)."""
+        n = self.nranks
+        bit = 1
+        while bit < n:
+            g = g + lax.ppermute(g, self.axis, [(i, i ^ bit) for i in range(n)])
+            bit *= 2
+        return g / n
+
+    def _apply_leaves(self, p_arrays, grads, state, lr):
+        """Beside 'mp': bucket by bucket in reverse order of the backward
+        pass. A bucket that is ONE leaf over the bucket cap (a weight matrix)
+        is exchanged as it lies, started behind the matmul that made it. The
+        leaves of every other bucket (biases, norms) travel STACKED with the
+        model's other leaves of their shape and layout, one transfer a shape
+        at the end of the backward pass: the chip's transfers queue, so a
+        bias sent behind its layer's matrices is done only when they are, and
+        the compiler, which takes a small transfer for instant, waits for it
+        there (1 ms for each of 128 leaves: PERF.md section 6, PR 30). Then
+        the optimizer's own per-leaf update of step k on step k's gradient."""
+        grads = list(grads)
+        alone, stacks = [], {}  # stacks: (shape, dtype, layout) -> leaves
+        for b in self.plan.buckets:
+            matrix = len(b.indices) == 1 and b.size * b.itemsize > self.bucket_bytes
+            for i in b.indices:
+                if grads[i] is None:
+                    continue
+                if matrix:
+                    alone.append([i])
+                else:
+                    layout = str(getattr(self.params[i], "pspec", None))
+                    stacks.setdefault(
+                        (grads[i].shape, str(grads[i].dtype), layout), []).append(i)
+        with jax.named_scope(DP_REDUCE_SCOPE):
+            for idx in alone + list(stacks.values()):  # one transfer each
+                if len(idx) == 1:
+                    grads[idx[0]] = self._exchange_mean(grads[idx[0]])
+                    continue
+                whole = self._exchange_mean(jnp.stack([grads[i] for i in idx]))
+                for row, i in enumerate(idx):
+                    grads[i] = whole[row]
+        return self.optimizer._functional_update(
+            p_arrays, grads, state, lr, params=self.params)
+
     def apply(self, p_arrays, grads, state, lr):
-        """(full replicated params, local grads, local state shards, lr) →
-        (new full params, new state shards). Traced inside shard_map over
-        ``self.axis``; collectives are the real reduce-scatter/all-gather."""
+        """(full replicated params, local grads, local state, lr) → (new full
+        params, new state). Traced inside shard_map over ``self.axis``;
+        collectives are the real exchange / reduce-scatter / all-gather."""
+        if not self.flat:
+            return self._apply_leaves(p_arrays, grads, state, lr)
         opt = self.optimizer
         axis, n = self.axis, self.nranks
         ridx = lax.axis_index(axis)
@@ -205,13 +283,16 @@ class ShardedWeightUpdate:
             if self.quantized:
                 if self.error_feedback:
                     gf = gf + state["ef"][bi].reshape(-1)
-                gshard, err = quantized_psum_scatter_mean(gf, axis, n, self.block)
+                with jax.named_scope(DP_REDUCE_SCOPE):
+                    gshard, err = quantized_psum_scatter_mean(
+                        gf, axis, n, self.block)
                 if self.error_feedback:
                     new_efs.append(err.reshape(1, -1))
             else:
-                gshard = lax.psum_scatter(
-                    gf, axis, scatter_dimension=0, tiled=True
-                ) / n
+                with jax.named_scope(DP_REDUCE_SCOPE):
+                    gshard = lax.psum_scatter(
+                        gf, axis, scatter_dimension=0, tiled=True
+                    ) / n
             pflat = self.plan.flatten(b, [p_arrays[i] for i in b.indices])
             s = self.plan.shard_size(b)
             pshard = lax.dynamic_slice_in_dim(pflat, ridx * s, s)
@@ -230,6 +311,8 @@ class ShardedWeightUpdate:
 
     # -- analytic per-step wire accounting ---------------------------------
     def step_counters(self):
+        if not self.flat:
+            return {"dp_buckets": len(self.plan)}
         return {
             "dp_sync_bytes": self.plan.sync_bytes("reduce_scatter", self.quantized),
             "dp_gather_bytes": self.plan.gather_bytes(),

@@ -65,9 +65,11 @@ _FLAGS: Dict[str, object] = {
     # ZeRO-1 sharded weight update for pure-DP meshes (arXiv:2004.13336):
     # reduce_scatter(grads) -> each replica updates its 1/dp shard of params
     # + optimizer moments -> all_gather(params), with grads coalesced into
-    # reverse-backward-order buckets (fleet/grad_buckets.py). On by default;
-    # the engine falls back to the replicated GSPMD update for hybrid
-    # meshes, non-elementwise rules (LAMB/LARS) and grad accumulation.
+    # reverse-backward-order buckets (fleet/grad_buckets.py). Beside 'mp'
+    # the same step exchanges each leaf by ppermute and updates it whole
+    # (engine._build_dp_step). On by default; the engine falls back to the
+    # replicated GSPMD update for other axes ('sp', 'pp', 'sharding'),
+    # non-elementwise rules (LAMB/LARS) and grad accumulation.
     "FLAGS_shard_weight_update": True,
     # EQuARX-style blockwise int8 compression of the DP gradient collectives
     # (collective.py quantized_* prims). Off by default — lossy; enable with

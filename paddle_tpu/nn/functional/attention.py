@@ -59,18 +59,28 @@ def _flash(q, k, v):
     from ...distributed.collective import _axis_bound
     from ...distributed.mesh import shard_map_compat
 
+    # the axes no enclosing map took by hand
+    free = frozenset(a for a in mesh.axis_names if not _axis_bound(a))
+
     def axis(name, dim):
         n = mesh.shape.get(name, 1)
-        return name if n > 1 and dim % n == 0 and not _axis_bound(name) else None
+        return name if n > 1 and dim % n == 0 and name in free else None
 
     dp, mp = axis("dp", q.shape[0]), axis("mp", q.shape[2])
     if dp is None and mp is None:
         return flash_attention_tpu(q, k, v, causal=True)
     spec = P(dp, None, mp, None)
     shard_map, check = shard_map_compat()
+    if len(free) < len(mesh.axis_names):
+        # inside a map that took some axes (the engine's step, manual over
+        # 'dp' and not 'mp'): map EVERY axis it left, over the mesh of that
+        # map (Mosaic refuses a call while any axis of the mesh is GSPMD's)
+        over = {"axis_names": free}
+    else:
+        over = {"mesh": mesh}
     fn = shard_map(
         lambda a, b, c: flash_attention_array(a, b, c, causal=True),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, **check,
+        in_specs=(spec, spec, spec), out_specs=spec, **over, **check,
     )
     return eager_call("flash_attention_spmd", fn, [q, k, v])
 

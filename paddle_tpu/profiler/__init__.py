@@ -112,7 +112,11 @@ def counters() -> Dict[str, int]:
     FLAGS_quantized_allreduce is on), ``dp_gather_bytes`` (ZeRO-1
     updated-param all-gather, full precision), ``dp_buckets`` /
     ``dp_reduce_scatters`` / ``dp_all_reduces`` (collective launches), and
-    ``wus_enabled`` (1 when the engine runs the sharded weight update).
+    ``wus_enabled`` (1 when the engine runs the sharded weight update),
+    ``dp_reduce_leaves`` / ``dp_reduce_async`` (states, not sums: the
+    gradient arrays the engine's compiled dp step reduces over 'dp', and
+    those whose reduce is a start/done pair with compute scheduled between,
+    read once from the executable's scheduled text).
 
     Serving engine (paddle_tpu/serving/): ``serve_requests`` /
     ``serve_admitted`` / ``serve_retired`` / ``serve_cancelled`` /
@@ -278,6 +282,7 @@ KNOWN_COUNTERS = frozenset({
     "compile_trace_ns",
     "dispatch_fastkey_hits",
     "dp_all_reduces", "dp_buckets", "dp_gather_bytes",
+    "dp_reduce_async", "dp_reduce_leaves",
     "dp_reduce_scatters", "dp_sync_bytes",
     "flight_dumps",
     "hbm_admission_checks", "hbm_admission_rejects", "hbm_cache_evicted",

@@ -374,14 +374,24 @@ class TestCountersAndFallbacks:
         assert e._wus is None
         assert np.isfinite(float(loss.item()))
 
-    def test_hybrid_mesh_falls_back(self):
+    def test_hybrid_mesh_takes_the_dp_step_leaf_by_leaf(self):
+        """Beside 'mp' the same step runs, its buckets the leaves as they
+        lie (PR 30; tests/test_dp_exchange_step.py holds it against the
+        replicated step); an axis the step does not know is GSPMD's."""
         _flags()
         x, y = _data()
         m, o = _make_model()
         mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2), ("dp", "mp"))
         e = HybridParallelEngine(m, o, _loss, mesh=mesh)
         loss = e.train_step(paddle.to_tensor(x), paddle.to_tensor(y))
-        assert e._wus is None  # GSPMD owns hybrid meshes
+        assert e._wus is not None and not e._wus.flat
+        assert e._dp_state is None  # the optimizer's own per-leaf state
+        assert np.isfinite(float(loss.item()))
+        m, o = _make_model()
+        mesh = Mesh(np.asarray(jax.devices()[:8]).reshape(4, 2), ("dp", "sp"))
+        e = HybridParallelEngine(m, o, _loss, mesh=mesh)
+        loss = e.train_step(paddle.to_tensor(x), paddle.to_tensor(y))
+        assert e._wus is None
         assert np.isfinite(float(loss.item()))
 
     def test_grad_accumulate_falls_back(self):

@@ -409,12 +409,20 @@ class TestProgramNames:
             step = HybridParallelEngine(model, opt, loss, mesh=mesh)
             step.train_step(x, y)
         assert step._jit.__name__ == "step_fn"
-        # and the scopes XProf groups the step's operations by
+        # and the scopes XProf groups the step's operations by; the engine's
+        # step on a mesh with a 'dp' axis is one shard_map over that axis
+        # (PR 30), its gradient reduces under a scope of their own
         text = step.lower(x, y).as_text(debug_info=True)
-        for scope in ("jit(step_fn)/jvp(loss)/jit(linear)",  # ops by name
-                      "jit(step_fn)/transpose(jvp(loss))/",
-                      "jit(step_fn)/optimizer_update/"):
+        # (the lowered text names the body of a shard_map from its own root:
+        # "jit(step_fn)/shard_map" + these, joined in the compiled module)
+        top = '"jit(step_fn)/' if entry == "compile_train_step" else '"'
+        for scope in (top + "jvp(loss)/jit(linear)",  # ops by name
+                      top + "transpose(jvp(loss))/",
+                      top + "optimizer_update/"):
             assert scope in text, scope
+        if entry == "hybrid_engine":
+            assert '"jit(step_fn)/shard_map"' in text
+            assert '"optimizer_update/dp_reduce/ppermute"' in text
         assert "jit(<lambda>)" not in text
 
 

@@ -367,3 +367,104 @@ def test_xing4_prefill_program_compiles(v5e, monkeypatch):
         _on(s, (4, 128), jnp.int32), pool).lower(lowering_platforms=("tpu",)))
     assert "%moe_experts_t256" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
+
+
+# -- the hybrid step's data-parallel gradient reduce (PR 30) -----------------
+def _groups(attr):
+    """``replica_groups`` of an HLO collective as a set of frozensets, from
+    either spelling: ``{{0,2},{1,3}}`` or the iota form
+    ``[2,2]<=[2,2]T(1,0)``."""
+    import re
+
+    if attr.startswith("{"):
+        return {frozenset(int(i) for i in g.split(","))
+                for g in re.findall(r"\{([0-9,]+)\}", attr)}
+    m = re.match(r"\[([0-9,]+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?", attr)
+    shape, dims, perm = ([int(i) for i in g.split(",")] if g else None
+                         for g in m.groups())
+    ids = np.arange(int(np.prod(dims))).reshape(dims)
+    if perm:
+        ids = ids.transpose(perm)
+    return {frozenset(int(i) for i in row) for row in ids.reshape(shape)}
+
+
+def test_hybrid_step_reduces_its_gradients_beside_compute(v5e, monkeypatch):
+    """The four-chip cell's step at two layers and its width of 4096,
+    compiled for ``v5e:2x2`` under dp2 x mp2: every gradient leaf is reduced
+    over the 'dp' pairs as a start/done pair with compute scheduled between
+    (``dp_reduce_async == dp_reduce_leaves``), and no synchronous
+    weight-shaped reduce over those pairs is left on the chip's line. XLA:TPU
+    makes an all-reduce synchronous (and no compile option of this libtpu
+    frees it), a collective-permute asynchronous: a later jax or libtpu that
+    undoes either shows here, not in a ledger row."""
+    import re
+
+    import paddle_tpu.ops.pallas as pallas_mod
+    from paddle_tpu.distributed import fleet
+    from paddle_tpu.distributed.engine import (
+        HybridParallelEngine, _sharding, dp_reduce_counts,
+    )
+    from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
+    from paddle_tpu.nn.functional import attention as attn_mod
+
+    for mod in (pallas_mod, flash_mod, attn_mod):  # the chip's attention
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    strategy = fleet.DistributedStrategy()
+    strategy.hybrid_configs = {"dp_degree": 2, "mp_degree": 2, "pp_degree": 1,
+                               "sharding_degree": 1, "sp_degree": 1}
+    fleet.init(is_collective=True, strategy=strategy)
+    host = fleet.get_hybrid_communicate_group().mesh
+    mesh = Mesh(np.asarray(v5e).reshape(host.devices.shape), host.axis_names)
+    before = paddle.get_default_dtype()
+    paddle.set_default_dtype("bfloat16")
+    try:
+        model = GPTForPretraining(GPTConfig(
+            vocab_size=8192, hidden_size=4096, num_layers=2, num_heads=32,
+            max_position_embeddings=2048, hidden_dropout=0.0,
+            attention_dropout=0.0))
+    finally:
+        paddle.set_default_dtype(before)
+    opt = paddle.optimizer.AdamW(learning_rate=1e-4, weight_decay=0.1,
+                                 parameters=model.parameters())
+    eng = HybridParallelEngine(model, opt, lambda m, i, l: m.loss(i, l), mesh=mesh)
+    eng._placed = True  # shapes only: nothing can be put on a described chip
+    eng._build()
+    assert eng._wus is not None and not eng._wus.flat
+
+    def like(a, sharding):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+    whole = NamedSharding(mesh, P())
+    state = opt._functional_state(eng.params)
+    ids = jax.ShapeDtypeStruct((4, 2048), jnp.int64)
+    lowered = eng._jit.trace(
+        [like(p._data, _sharding(mesh, getattr(p, "pspec", None)))
+         for p in eng.params],
+        {"t": like(state["t"], whole),
+         "accums": [{k: like(v, eng._opt_sharding(p)) for k, v in st.items()}
+                    for p, st in zip(eng.params, state["accums"])]},
+        tuple(like(ids, eng._batch_sharding(i, ids)) for i in range(2)),
+        like(jnp.zeros((), jnp.float32), whole),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole),
+    ).lower(lowering_platforms=("tpu",))
+    text = _compile_uncached(lowered).as_text()
+
+    counts = dp_reduce_counts(text)
+    # the eight weight matrices and the embedding travel alone, the biases,
+    # norms and positions stacked by shape and layout
+    assert 9 < counts["dp_reduce_leaves"] < len(eng.params)
+    assert counts["dp_reduce_async"] == counts["dp_reduce_leaves"]
+    # mesh (pp1, dp2, sharding1, sp1, mp2) over devices 0..3: chip i is
+    # replica i // 2, so the 'dp' pairs are {0,2} and {1,3}
+    dp_pairs = {frozenset({0, 2}), frozenset({1, 3})}
+    left = []
+    entry = text[text.find("\nENTRY "):]
+    for line in entry.split("\n"):
+        m = re.search(r" = (\(.*?\)|\S+) (all-reduce|reduce-scatter)\(", line)
+        if not m or _groups(re.search(r"replica_groups=(\S+?),? ", line)
+                            .group(1)) != dp_pairs:
+            continue
+        # a matrix, not the loss's scalar mean
+        if re.search(r"\[[0-9]+,[0-9]+", m.group(1)):
+            left.append(line.strip()[:160])
+    assert not left, left
