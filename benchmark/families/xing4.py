@@ -10,11 +10,8 @@ routing (the experts its live rows hit), which no static count can: the step's
 share of the HBM peak is ``moe_decode_hbm_roofline``: what every step reads
 whatever its routing (``dense_bytes_per_step``) + the touched experts x
 ``expert_bytes`` + the live context x ``latent_bytes_per_token``.
-``decode_hbm_roofline`` lists no cells in ``BENCHMARK.json``, so every cell
-that reports the median gap has to report it: ``weight_bytes`` is the part of
-the weights that is static, ``dense_bytes_per_step``, and the dense model's
-share reads, in this family's cells, the FLOOR of the step's share (no expert
-counted), never over what the step read.
+The family gives no ``weight_bytes``: ``decode_hbm_roofline``, the dense
+model's share, lists its own cell in ``BENCHMARK.json`` and is silent here.
 
 Hand-worked values at the published widths are in
 tests/benchmark/test_benchmark_xing4.py.
@@ -206,9 +203,6 @@ def dense_bytes_per_step(cfg: dict, itemsize: int = 2) -> float:
     dense = 3 * d * cfg["intermediate_size"]
     head = d + d * cfg["vocab_size"]
     return float(itemsize * (L * per_layer + n_moe * moe + (L - n_moe) * dense + head))
-
-
-weight_bytes = dense_bytes_per_step
 
 
 # -- what the three device-trace readers share: the decode steps of the traced

@@ -8,6 +8,13 @@ the token ids. The ramp before the window and the window itself are built
 apart, each with its own multisets, so the number of requests, prompt tokens
 and output tokens due INSIDE the window is the same for every seed.
 
+A traffic file may PIN the order with ``order_seed`` (an integer): rounds,
+requests inside a round and gaps are then permuted by that number, for the
+ramp and the window, and ``--seed`` draws the token ids alone, so every run of
+the mix offers the same request of the same lengths at the same due time.
+For a cell whose step follows the live rows (a routed model below its knee),
+where the order of the arrivals would otherwise be the run-to-run spread.
+
 Open loop: ``OpenLoop`` submits each request at its due time whether or not
 earlier ones finished, times it from the due time, and reports how late it
 ran. One consumer thread per live stream stamps tokens as the client's
@@ -87,11 +94,14 @@ def _phase(traffic: dict, n: int, span: float, rng) -> tuple:
 def build_schedule(traffic: dict, seconds: float, seed: int, vocab: int) -> Schedule:
     rate, ramp_s = float(traffic["rate_per_s"]), float(traffic["ramp_s"])
     rng = np.random.default_rng([int(seed), 0xA221])
+    # a mix that pins its order draws it from the file's number, not the run's
+    order = rng if "order_seed" not in traffic else np.random.default_rng(
+        [int(traffic["order_seed"]), 0xA221])
     # the ramp may offer more than the window does, to fill the engine fast
     n_ramp = round(float(traffic.get("ramp_rate_per_s", rate)) * ramp_s)
     n_win = round(rate * seconds)
-    rd, rp, ro = _phase(traffic, n_ramp, ramp_s, rng)
-    wd, wp, wo = _phase(traffic, n_win, seconds, rng)
+    rd, rp, ro = _phase(traffic, n_ramp, ramp_s, order)
+    wd, wp, wo = _phase(traffic, n_win, seconds, order)
     due = np.concatenate([rd - ramp_s, wd])
     plen, olen = np.concatenate([rp, wp]), np.concatenate([ro, wo])
     inw = np.concatenate([np.zeros(n_ramp, bool), np.ones(n_win, bool)])

@@ -6,7 +6,8 @@ weights every step reads whatever its routing, the family's
 its real context length, ``latent_bytes_per_token`` a layer) over the HBM
 peak, over the device time of the ``jit_step`` programs in the window. It
 is for a routed model what ``decode_hbm_roofline`` is for a dense one, whose
-static ``weight_bytes`` cannot follow a routing (there it reads the floor)."""
+static ``weight_bytes`` cannot follow a routing (so that share lists the dense
+cell alone)."""
 from benchmark import flops
 
 

@@ -21,6 +21,7 @@ T0 = time.monotonic()  # process start, for setup_s
 import argparse
 import importlib
 import json
+import math
 import os
 import sys
 
@@ -185,7 +186,18 @@ def main(argv=None):
     numbers["compiles_in_window"] = (float(len(in_window)), "backend compile events")
     numbers["failed"] = (float(res["failed"]), f"of {res['attempted']} attempted")
     limits = {**ctx.cell["limits"], "compiles_in_window": 0.0, "failed": 0.0}
-    correct = check.judge(numbers, limits, out=log)
+    checked = []  # the check's lines: in the log here, last on standard error at the end
+    correct = check.judge(numbers, limits, out=checked.append)
+    log("\n".join(checked))
+    compared = {n: {"value": v if math.isfinite(v) else repr(v), "limit": limits[n]}
+                for n, (v, _) in sorted(numbers.items()) if n in limits}
+
+    def result(line):
+        """The run's last words: each number compared beside its limit on
+        standard error, then the result's line, which carries them last."""
+        print("\n".join(checked), file=sys.stderr, flush=True)
+        print(json.dumps({**line, "check": compared}))
+
     for k, v in sorted(ctx.notes.items()):
         if isinstance(v, float):
             log(f"part: {k} = {v:.3f}")
@@ -210,9 +222,9 @@ def main(argv=None):
             for m in manifest.metrics_of(args.workload, "per_layer"):
                 log(f"reader: {m['name']} " + ("read something" if m["name"] in read
                                                 else "found nothing to read"))
-        print(json.dumps({"rehearsal": True, "correct": correct,
-                          "attempted": res["attempted"], "failed": res["failed"],
-                          "counts": res["counts"], "device": device}))
+        result({"rehearsal": True, "correct": correct,
+                "attempted": res["attempted"], "failed": res["failed"],
+                "counts": res["counts"], "device": device})
         return 0
 
     dev_out = dict(device, memory_peak_bytes=int(res["memory_peak_bytes"]))
@@ -233,7 +245,7 @@ def main(argv=None):
             f"{sum(len(d['ops']) for d in ctx.reduced['devices'].values())} device "
             f"operations and {len(ctx.spans.rows)} host spans")
     log(f"part: run_s = {time.monotonic() - T0:.3f}")
-    print(json.dumps(line))
+    result(line)
     return 0
 
 

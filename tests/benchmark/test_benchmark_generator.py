@@ -125,3 +125,69 @@ def test_failed_requests_are_counted():
     loop.start(t_open)
     st = G.window_stats(loop.drain(5.0), sched, t_open)
     assert st["attempted"] == 10 and st["failed"] == 2
+
+
+# -- a pinned order (``order_seed`` in the traffic file, PR 32)
+PINNED = {**CHAT, "order_seed": 32}
+
+
+def _digest(s):
+    import hashlib
+
+    h = hashlib.sha256()
+    for a in (s.due, s.prompt_len, s.out_len, s.in_window, np.concatenate(s.prompts)):
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()[:16]
+
+
+@pytest.mark.parametrize("seconds", [10, 51])
+def test_a_pinned_order_leaves_the_seed_the_token_ids_alone(seconds):
+    a, b = (G.build_schedule(PINNED, seconds, s, 50304) for s in SEEDS[:2])
+    for field_ in ("due", "prompt_len", "out_len", "in_window"):
+        assert np.array_equal(getattr(a, field_), getattr(b, field_)), field_
+    assert [len(p) for p in a.prompts] == list(a.prompt_len)
+    assert not any(np.array_equal(p, q) for p, q in zip(a.prompts, b.prompts) if len(p) > 4)
+    again = G.build_schedule(PINNED, seconds, SEEDS[0], 50304)
+    assert all(np.array_equal(p, q) for p, q in zip(a.prompts, again.prompts))
+
+
+def test_another_order_seed_offers_the_same_multisets_in_another_order():
+    a = G.build_schedule(PINNED, 30, 5, 50304)
+    b = G.build_schedule({**PINNED, "order_seed": 33}, 30, 5, 50304)
+    free = G.build_schedule(CHAT, 30, 5, 50304)
+    for s in (b, free):
+        for w, end in ((a.in_window, 30.0), (~a.in_window, 0.0)):
+            assert np.array_equal(s.in_window, a.in_window)
+            assert Counter(zip(s.prompt_len[w], s.out_len[w])) == \
+                Counter(zip(a.prompt_len[w], a.out_len[w]))
+            gaps = lambda x: sorted(np.diff(np.concatenate([x.due[w], [end]])))
+            assert np.allclose(gaps(s), gaps(a))
+        assert not np.array_equal(s.prompt_len, a.prompt_len)
+        assert not np.array_equal(s.due, a.due)
+
+
+@pytest.mark.parametrize("seed,seconds,want", [
+    (2_147_483_659, 51, "9be7bfc34dd328ab"), (7, 10, "65685084ae37300d")])
+def test_without_the_key_the_schedule_is_the_parent_s(seed, seconds, want):
+    """Digests of ``chat-sat``'s schedule (due times, lengths, token ids)
+    recorded from the parent commit of PR 32 (c1947d8): the dense serving
+    cell's traffic did not move when the key came."""
+    assert "order_seed" not in CHAT
+    assert _digest(G.build_schedule(CHAT, seconds, seed, 50304)) == want
+
+
+def test_the_manifest_finds_the_pinned_mix_by_name():
+    from benchmark.manifest import Manifest
+
+    m = Manifest()
+    assert m.validate() == []
+    mine = [w for w in m.data["workloads"] if w["traffic"] == "longanswer-pinned"]
+    assert [w["name"] for w in mine] == ["serve-xing4-longanswer-pinned"]
+    traffic = m.traffic("longanswer-pinned")
+    assert isinstance(traffic["order_seed"], int)
+    a, b = (G.build_schedule(traffic, 51, s, 131072) for s in SEEDS[:2])
+    assert np.array_equal(a.due, b.due) and np.array_equal(a.out_len, b.out_len)
+    assert a.in_window.sum() == round(traffic["rate_per_s"] * 51)
+    # no file or entry of the cell it replaces is left
+    assert not (m.root / "traffic" / "longanswer.json").exists()
+    assert "serve-xing4-longanswer\"" not in m.path.read_text()

@@ -53,8 +53,8 @@ def test_manifest_is_sound_with_the_new_entries(manifest):
         mine = [m["name"] for m in manifest.metrics_of(cell, "per_layer")]
         assert "setup_trace_s" in mine
         assert not any(n.startswith(("decode_host_ms", "schedule_self")) for n in mine)
-    # appended, in the issue's order, after what PR 23 had
-    assert [m["name"] for m in manifest.data["per_layer"]][-6:] == list(NEW)
+    # there, in the issue's order (later PRs append behind them)
+    assert [m["name"] for m in manifest.data["per_layer"] if m["name"] in NEW] == list(NEW)
 
 
 @pytest.mark.parametrize("metric,want", [

@@ -52,12 +52,18 @@ def test_four_chip_cell_rehearses_on_four_virtual_devices():
 def test_rehearsal_prints_counts_only(cell, counts, capsys):
     rc = run.main(["--workload", cell, "--seed", "2147483659", "--seconds", "1.5",
                    "--trace", "1", "--rehearse"])
-    out = capsys.readouterr().out
+    out, err = capsys.readouterr()
     line = _last_json(out)
     assert rc == 0 and line["rehearsal"] is True and line["correct"] is True
     assert line["failed"] == 0 and line["attempted"] > 0
     assert set(line["counts"]) == set(counts) and "metrics" not in line
     assert "check: compiles_in_window = 0" in out
+    # each number compared beside its limit: last in the line, last on standard error
+    assert list(line)[-1] == "check" and set(line["check"]) > {"compiles_in_window", "failed"}
+    assert line["check"]["compiles_in_window"] == {"value": 0.0, "limit": 0.0}
+    said = err.strip().splitlines()[-len(line["check"]):]
+    assert [s.split()[1] for s in said] == sorted(line["check"]) and all(
+        s.startswith("check: ") and s.endswith(" ok") for s in said)
 
 
 class _FakeCapture:

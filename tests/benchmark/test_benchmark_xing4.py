@@ -13,7 +13,7 @@ import pytest
 from benchmark import run
 from benchmark.manifest import Manifest
 
-CELL = "serve-xing4-longanswer"
+CELL = "serve-xing4-longanswer-pinned"
 
 
 @pytest.fixture(scope="module")
@@ -52,19 +52,21 @@ def test_manifest_is_sound_and_states_the_cut(M, CFG, FAM):
     assert {"experts_touched_per_layer", "expert_load_max_over_mean",
             "moe_decode_hbm_roofline", "expert_ffn_roofline",
             "latent_attention_roofline"} <= mine
-    # the dense model's share lists no cells, so it is this cell's too: the
-    # family's weight_bytes is what EVERY step reads, whatever its routing
-    assert "decode_hbm_roofline" in mine
-    assert FAM.weight_bytes(CFG) == FAM.dense_bytes_per_step(CFG)
+    # the dense model's share lists its own cell since PR 32: a static count
+    # cannot follow a routing, and moe_decode_hbm_roofline is the reading here
+    assert "decode_hbm_roofline" not in mine and not hasattr(FAM, "weight_bytes")
     # appended: what the manifest held before comes first, in its order
     assert [c["name"] for c in M.data["configs"]][-1] == "xing4-29b-a4b-8l"
     assert [w["name"] for w in M.data["workloads"]][-1] == CELL
     assert [m["name"] for m in M.data["per_layer"]][-5:] == [
         "experts_touched_per_layer", "expert_load_max_over_mean",
         "moe_decode_hbm_roofline", "expert_ffn_roofline", "latent_attention_roofline"]
-    # the load is ISSUE 27's: 0.7 x the knee, ramp at 1.5 x that
+    # the load is ISSUE 32's: four fifths of the knee the file states as a
+    # number, ramp at 1.5 x that, the order of the arrivals pinned
     traffic = M.traffic(cell["traffic"])
-    assert traffic["rate_per_s"] == 2.2 and traffic["ramp_rate_per_s"] == pytest.approx(3.3)
+    assert traffic["rate_per_s"] == round(0.8 * traffic["knee_per_s"], 1) == 3.8
+    assert traffic["ramp_rate_per_s"] == pytest.approx(1.5 * traffic["rate_per_s"])
+    assert isinstance(traffic["order_seed"], int)
     assert (traffic["prompt_len"]["hi"], traffic["output_len"]["hi"]) == (768, 1024)
 
 
@@ -177,7 +179,7 @@ def test_the_cell_rehearses(M, capsys):
     for name in ("moe_decode_hbm_roofline", "expert_ffn_roofline",
                  "latent_attention_roofline"):
         assert f"reader: {name} found nothing to read" in out, name
-    assert "reader: decode_hbm_roofline found nothing to read" in out  # no device trace here
+    assert "reader: decode_hbm_roofline" not in out   # the dense cell's alone
     assert "reference: a verdict at " in out
 
 
