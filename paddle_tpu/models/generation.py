@@ -17,10 +17,14 @@ four reads of the attention context that the programs put between ``qkv`` and
 ``finish`` (causal, gathered context, block table, contiguous cache) are
 written once beside ``_grouped_attention``, for both plugs and for their
 tensor-parallel shards. A third plug (``_mla_moe_arch``) brings the layers of
-``models/mla_moe.py``, whose cache is its own.
+``models/mla_moe.py``, whose cache is its own; a fourth (``_phi4flash_arch``)
+the five kinds of layer of ``models/phi4flash.py``, whose caches are of three
+kinds (:func:`cache_pools`) and whose values cross layers, so it brings its
+whole stack (``prompt_stack`` / ``decode_stack``) and not a layer.
 """
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import numpy as np
@@ -669,25 +673,31 @@ def mla_moe_params(cfg, sd):
     return params
 
 
+def _keyed_by_config(name, cfg, kernels):
+    """``(kernels, arch_key)`` of an arch built from a config dataclass:
+    whether its layers take their Pallas kernels (None: wherever Mosaic
+    compiles) and the key of its compiled programs, which every number they
+    bake in is part of."""
+    import dataclasses
+
+    from ..ops.pallas import interpret_default
+
+    kernels = (not interpret_default()) if kernels is None else bool(kernels)
+    return kernels, (name, kernels) + tuple(
+        (f.name, repr(getattr(cfg, f.name))) for f in dataclasses.fields(cfg))
+
+
 def mla_moe_decode_state(model, kernels=None):
     """(arch_key, arch, params, max_positions) for ``MLAMoEForCausalLM``: the
     weight tree the serving programs take and the arch plug around
     ``models/mla_moe.py``'s layer functions. ``kernels``: whether the layer
     takes its Pallas kernels (default: wherever Mosaic compiles, as the flash
     kernel is chosen) or their plain forms."""
-    import dataclasses
-
-    from ..ops.pallas import interpret_default
-
     cfg = model.config
-    if kernels is None:
-        kernels = not interpret_default()
+    kernels, arch_key = _keyed_by_config("mla_moe", cfg, kernels)
     params = mla_moe_params(
         cfg, {k: v._data for k, v in model.state_dict().items()})
-    # every number the compiled programs bake in keys the cache
-    arch_key = ("mla_moe", bool(kernels)) + tuple(
-        (f.name, repr(getattr(cfg, f.name))) for f in dataclasses.fields(cfg))
-    return arch_key, _mla_moe_arch(cfg, bool(kernels)), params, \
+    return arch_key, _mla_moe_arch(cfg, kernels), params, \
         cfg.max_position_embeddings
 
 
@@ -751,13 +761,207 @@ def _mla_moe_arch(cfg, kernels):
             "experts": cfg.n_routed_experts}
 
 
+def phi4flash_decode_state(model, kernels=None):
+    """(arch_key, arch, params, max_positions) for ``PhiFlashForCausalLM``:
+    the weight tree (the layers of a kind stacked, as the model holds them)
+    and the arch plug around ``models/phi4flash.py``'s layer functions.
+    ``kernels``: whether the scans and the cache reads take their Pallas
+    kernels (default: wherever Mosaic compiles) or their plain forms."""
+    from . import phi4flash as P
+
+    cfg = model.config
+    kernels, arch_key = _keyed_by_config("phi4flash", cfg, kernels)
+    params = P.params_tree(
+        cfg, {k: v._data for k, v in model.state_dict().items()})
+    return arch_key, _phi4flash_arch(cfg, kernels), params, \
+        cfg.max_position_embeddings
+
+
+def window_block(tokens: int, block_size: int) -> int:
+    """The block a window cache is cut into, so that the block-table read
+    serves it too: the largest size that divides both the window and the
+    engine's block."""
+    return math.gcd(int(tokens), int(block_size))
+
+
+def _phi4flash_arch(cfg, kernels):
+    """The arch plug of the Mamba / differential-attention hybrid. A layer
+    caches one of three things or nothing (``cache["layers"]``: the kind, and
+    the layer whose cache a layer READS):
+
+    - ``paged``: K and V rows a token by block table, as every arch's, for
+      the ONE full-attention layer; the cross layers behind it read the same
+      pool through the same table;
+    - ``window``: the last ``sliding_window`` K and V rows of a row, a RING a
+      slot (position ``p`` at entry ``p % W``; with no positional encoding
+      the order of the entries says nothing). The ring is stored as
+      ``W / window_block`` blocks of the slot, so the block-table read serves
+      it with a table made from the slot and a position held at ``W - 1``;
+    - ``state``: fixed shapes a slot, the scan's state (float32) and the
+      convolution's last ``K - 1`` inputs.
+
+    Its layers are ``models/phi4flash.py``'s, for prompts and decode rows
+    alike; here are only the reads and writes of the pools. It has the plain
+    prefill and decode programs and no other yet (``plain_paths_only``)."""
+    from . import phi4flash as P
+
+    W, L = cfg.sliding_window, cfg.num_hidden_layers
+    H, h, pairs = cfg.num_attention_heads, cfg.head_dim, cfg.kv_pairs
+    caches = {"mamba": "state", "window": "window", "full": "paged"}
+    kinds = [cfg.layer_kind(i) for i in range(L)]
+    layers = tuple((caches.get(k), L // 2 + 1 if k == "cross" else None)
+                   for k in kinds)
+
+    def embed(params, ids, posm):
+        return params["wte"][ids]
+
+    def head(params, x):
+        return _head_mm(params, P.layer_norm(x, params["lnf_g"], params["lnf_b"],
+                                             cfg.layer_norm_eps), "wte", True)
+
+    def window_table(slots, block_size):
+        wb = window_block(W, block_size)
+        return wb, slots[:, None] * (W // wb) + jnp.arange(W // wb)[None]
+
+    def prompt_stack(params, x, pools, lens, live, tb, slots, block_size):
+        B = x.shape[0]
+        wb, wrows = window_table(slots, block_size)
+
+        def keep(pools, kind, i, a, b):
+            kp, vp, wk, wv, S, tails = pools
+            if kind == "state":
+                return (kp, vp, wk, wv, S.at[i, slots].set(a),
+                        tails.at[i, slots].set(b.astype(tails.dtype)))
+            # (B, T, pairs, 2 h) rows as whole blocks of (token, pair) lines
+            cut = lambda r, size: r.reshape(B, -1, size * pairs, 2 * h)
+            if kind == "window":
+                return (kp, vp, wk.at[i, wrows].set(cut(a, wb)),
+                        wv.at[i, wrows].set(cut(b, wb)), S, tails)
+            return (kp.at[i, tb].set(cut(a, block_size)),
+                    vp.at[i, tb].set(cut(b, block_size)), wk, wv, S, tails)
+
+        return P.prompt_stack(cfg, params, x, pools, lens, live, kernels, keep)
+
+    def decode_stack(params, x, pools, tables, pos, bids, offs, slots,
+                     block_size):
+        from ..ops.kernels import paged_attention_rows
+        from ..ops.kernels.selective_scan import state_update, state_update_plain
+
+        B = x.shape[0]
+        wb, wtables = window_table(slots, block_size)
+        ring = pos % W
+        wbids, woffs = wtables[:, 0] + ring // wb, ring % wb
+        wpos = jnp.minimum(pos, W - 1)  # a full ring: every entry is live
+        # a token's lines inside its block
+        lines = lambda offs: offs[:, None] * pairs + jnp.arange(pairs)[None]
+
+        def read(kpool, vpool, li, q, tables, pos):
+            """The fresh rows are in the pool: every live position by table."""
+            if kernels:
+                with jax.named_scope("attention"):
+                    o = paged_attention_rows(q[:, 0], kpool, vpool, li, tables,
+                                             pos, scale=h ** -0.5, kv_heads=pairs)
+                return o.reshape(B, 1, H, 2 * h)
+            T_pad = tables.shape[1] * kpool.shape[2] // pairs
+            kc = kpool[li, tables].reshape((B, T_pad) + cfg.kv_row)
+            vc = vpool[li, tables].reshape((B, T_pad) + cfg.kv_row)
+            live = jnp.arange(T_pad)[None, None, :] <= pos[:, None, None]
+            return P.attend_dense(cfg, q, kc, vc, live)
+
+        def paged_read(pools, q, k, v):
+            kp, vp, *rest = pools
+            if k is not None:
+                kp = kp.at[0, bids[:, None], lines(offs)].set(k[:, 0])
+                vp = vp.at[0, bids[:, None], lines(offs)].set(v[:, 0])
+            return (kp, vp, *rest), read(kp, vp, 0, q, tables, pos)
+
+        def window_read(pools, i, q, k, v):
+            kp, vp, wk, wv, S, tails = pools
+            wk = wk.at[i, wbids[:, None], lines(woffs)].set(k[:, 0])
+            wv = wv.at[i, wbids[:, None], lines(woffs)].set(v[:, 0])
+            return (kp, vp, wk, wv, S, tails), read(wk, wv, i, q, wtables, wpos)
+
+        def state_step(pools, i, a):
+            kp, vp, wk, wv, S, tails = pools
+            taps = jnp.concatenate([tails[i, slots], a.astype(tails.dtype)], 1)
+            tails = tails.at[i, slots].set(taps[:, 1:])
+
+            def recur(pools, dt, c, Bm, Cm, A, D):
+                kp, vp, wk, wv, S, tails = pools
+                with jax.named_scope("state_update"):
+                    S, y = (state_update if kernels else state_update_plain)(
+                        S, i, slots, dt[:, 0], c[:, 0], Bm[:, 0], Cm[:, 0], A, D)
+                return y[:, None], (kp, vp, wk, wv, S, tails)
+
+            return (kp, vp, wk, wv, S, tails), taps[:, None], recur
+
+        return P.decode_stack(cfg, params, x, pools, state_step, window_read,
+                              paged_read)
+
+    return {"name": "phi4flash", "embed": embed, "head": head,
+            "prompt_stack": prompt_stack, "decode_stack": decode_stack,
+            "plain_paths_only": True,
+            "cache": {"layers": layers, "window_tokens": W,
+                      "paged": (cfg.kv_row,) * 2, "window": (cfg.kv_row,) * 2,
+                      "state": (((cfg.mamba_d_state, cfg.d_inner), "float32"),
+                                ((cfg.mamba_d_conv - 1, cfg.d_inner), None))}}
+
+
 def cache_row_shapes(arch):
-    """What the arch caches of one token in one layer, a trailing shape for
-    each pool: the engine's pools are ``(layers, blocks, block_size) +
-    shape``. An arch that declares none caches K and V per KV head."""
+    """What an arch whose layers all cache the same thing caches of one token
+    in one layer, a trailing shape for each pool: the engine's pools are
+    ``(layers, blocks, block_size) + shape``. An arch that declares none
+    caches K and V per KV head."""
     if "cache" in arch:
         return tuple(tuple(s) for s in arch["cache"])
     return ((arch["kv_heads"], arch["head_dim"]),) * 2
+
+
+def cache_slots(arch) -> bool:
+    """Whether a request of this arch holds a ROW SLOT for its life beside
+    its blocks: it has layers whose cache is a fixed size a row (``window``,
+    ``state``)."""
+    cache = arch.get("cache")
+    return isinstance(cache, dict) and any(
+        kind in ("window", "state") for kind, _ in cache["layers"])
+
+
+def cache_pools(arch, n_layers, num_blocks, block_size, max_batch):
+    """Every pool the engine holds for ``arch``: ``[(kind, shape, dtype)]``
+    (``dtype`` None: the model's). An arch may say, a layer, what the layer
+    caches (``arch["cache"]["layers"]``: ``(kind, layer it reads)`` a layer,
+    kind ``paged``, ``window``, ``state`` or None); each kind then gets its
+    pools over the layers of THAT kind alone:
+
+    - ``paged``: ``(layers, blocks, block_size x row[0]) + row[1:]``, by
+      block table. A block is ONE slab of ``(token, head)`` lines, as the
+      block-table kernel copies it (ten heads of bfloat16 as an axis of
+      their own are padded to a sublane tile of sixteen, and the kernel's
+      view of the pool is then a copy of the pool);
+    - ``window``: ``(layers, (max_batch + 1) x blocks a window, window_block
+      x row[0]) + row[1:]``: a ring of ``window_tokens`` rows a slot, cut
+      into blocks so that the block-table read serves it;
+    - ``state``: ``(layers, max_batch + 1) + row``, a fixed shape a slot.
+
+    Slot 0 is the trash slot, as block 0 is the trash block: the rows that
+    pad a bucket write there. An arch that says nothing a layer (GPT, Llama,
+    the MLA arch) caches the same rows in every layer: one paged kind over
+    all ``n_layers`` (:func:`cache_row_shapes`)."""
+    cache = arch.get("cache")
+    if not isinstance(cache, dict):
+        return [("paged", (n_layers, num_blocks, block_size) + row, None)
+                for row in cache_row_shapes(arch)]
+    count = lambda kind: sum(k == kind for k, _ in cache["layers"])
+    slots = max_batch + 1
+    pools = [("paged", (count("paged"), num_blocks, block_size * row[0])
+              + tuple(row[1:]), None) for row in cache.get("paged", ())]
+    if cache.get("window"):
+        wb = window_block(cache["window_tokens"], block_size)
+        pools += [("window", (count("window"), slots * (cache["window_tokens"] // wb),
+                              wb * row[0]) + tuple(row[1:]), None)
+                  for row in cache["window"]]
+    return pools + [("state", (count("state"), slots) + tuple(row), dtype)
+                    for row, dtype in cache.get("state", ())]
 
 
 def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
@@ -767,8 +971,11 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
     The returned pure fn ``prefill(params, ids, lens, tables, *pools)``
     (``pools``: what the arch declares, :func:`cache_row_shapes`; K and V for
     GPT and Llama, one latent pool for the MLA arch) runs the dense causal
-    forward over ``ids`` (B, T_bucket) — causality makes the cached rows of
-    every REAL position exact regardless of the padding behind it — reshapes
+    forward over ``ids`` (B, T_bucket) — what is cached of a REAL position
+    is exact regardless of the padding behind it: a cached row a token by
+    causality, a recurrent state because it is taken at ``lens`` and not at
+    the bucket's end (a padded position leaves it as it was), a window
+    because its ring holds the last positions under ``lens`` — reshapes
     each layer's (B, T_bucket, ...) rows into ``T_bucket // block_size``
     blocks and scatters them at ``tables[:, :nb]`` (rows shorter than the
     bucket point their tail entries at the reserved trash block 0), and
@@ -776,7 +983,13 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
     prompt token (``lens - 1``). The layer is the arch's ``prompt_layer``,
     told which positions are real (``live``: inside ``lens``, of a row whose
     table is mapped); where it routes experts the program returns after the
-    logits the tokens each expert took, ``(expert layers, experts)``."""
+    logits the tokens each expert took, ``(expert layers, experts)``.
+
+    An arch whose layers are of several kinds, with values that cross them,
+    brings its whole stack (``prompt_stack(params, x, pools, lens, live,
+    tables[:, :nb], slots, block_size) -> (x, pools)``) and writes its pools
+    itself; its program takes each row's slot after the tables:
+    ``prefill(params, ids, lens, tables, slots, *pools)``."""
     if T_bucket % block_size:
         raise ValueError(
             f"prefill bucket {T_bucket} must be a multiple of block_size "
@@ -786,13 +999,28 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
     if nb > max_blocks:
         raise ValueError("prefill bucket exceeds max sequence blocks")
 
+    def live_positions(lens, tables):
+        return ((jnp.arange(T_bucket)[None, :] < lens[:, None])
+                & (tables[:, :1] != 0))
+
+    if "prompt_stack" in arch:
+        def prefill(params, ids, lens, tables, slots, *pools):
+            x = arch["embed"](params, ids, jnp.arange(T_bucket)[None])
+            x, pools = arch["prompt_stack"](
+                params, x, tuple(pools), lens, live_positions(lens, tables),
+                tables[:, :nb], slots, block_size)
+            with jax.named_scope("head"):
+                logits = arch["head"](params, _last_rows(x, lens))
+            return (*pools, logits)
+
+        return prefill
+
     def prefill(params, ids, lens, tables, *pools):
         layer_ws = params["layers"]
         x = arch["embed"](params, ids, jnp.arange(T_bucket)[None])
         tb = tables[:, :nb]
         counts = []
-        live = ((jnp.arange(T_bucket)[None, :] < lens[:, None])
-                & (tables[:, :1] != 0))
+        live = live_positions(lens, tables)
         for li, w in enumerate(layer_ws):
             x, rows, c = arch["prompt_layer"](w, x, live)
             if c is not None:
@@ -895,13 +1123,34 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     the tokens the rows each expert took, ``(expert layers, experts)``, for
     the engine's one read-back. The engine jits it inside
     :func:`feed_tokens_back` (operands ``(params, *pools, ints, prev,
-    key)``), as it does the gather step."""
+    key)``), as it does the gather step.
+
+    An arch that brings its whole stack (``decode_stack(params, x, pools,
+    tables, pos, bids, offs, slots, block_size) -> (x, pools)``) gets the
+    same step around it, with each row's slot after ``pos``:
+    ``step(params, *pools, tables, pos, slots, toks, temps, key)``."""
+    def write_slots(tables, pos):
+        bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
+        return bids, pos % block_size
+
+    if "decode_stack" in arch:
+        def step(params, *args):
+            *pools, tables, pos, slots, toks, temps, key = args
+            x = arch["embed"](params, toks, pos)[:, None]
+            x, pools = arch["decode_stack"](
+                params, x, tuple(pools), tables, pos, *write_slots(tables, pos),
+                slots, block_size)
+            with jax.named_scope("head"):
+                logits = arch["head"](params, x[:, -1])
+            return (*pools, _next_tokens(logits, temps, key))
+
+        return step
+
     def step(params, *args):
         *pools, tables, pos, toks, temps, key = args
         layer_ws = params["layers"]
         x = arch["embed"](params, toks, pos)[:, None]
-        bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
-        offs = pos % block_size
+        bids, offs = write_slots(tables, pos)
         live = tables[:, 0] != 0  # a row whose table is unmapped pads the bucket
         counts = []
         for li, w in enumerate(layer_ws):
@@ -923,7 +1172,7 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
 STEP_COLS = 4  # position, src, host token, temperature (float32 bits)
 
 
-def feed_tokens_back(inner, B, max_batch, max_blocks, n_pools):
+def feed_tokens_back(inner, B, max_batch, max_blocks, n_pools, slots=False):
     """Wrap a decode step (``build_paged_decode``, ``build_paged_decode_kernel``
     or ``build_tp_paged_decode``; their bodies stay as they are) so that the
     tokens it feeds may come from the step before it WITHOUT a trip to the
@@ -934,7 +1183,9 @@ def feed_tokens_back(inner, B, max_batch, max_blocks, n_pools):
 
     - ``ints`` (B, max_blocks + ``STEP_COLS``) int32 packs what the host
       builds: the block table, then a column each of ``pos``, ``src``, the
-      host's token and the temperature (float32, bit for bit);
+      host's token and the temperature (float32, bit for bit); with
+      ``slots`` (an arch whose rows hold a slot, :func:`cache_slots`) one
+      column more, the row's slot, which ``inner`` takes after ``pos``;
     - ``prev`` is the previous step's ``next_tokens`` as the device array it
       is, ``max_batch`` long whatever bucket produced it; ``src`` is a row's
       index in that step, or -1 for a row that was not in it (one a prefill
@@ -957,7 +1208,8 @@ def feed_tokens_back(inner, B, max_batch, max_blocks, n_pools):
         pos, src, host_toks, temps = (
             ints[:, max_blocks + c] for c in range(STEP_COLS))
         toks = jnp.where(src >= 0, prev[jnp.maximum(src, 0)], host_toks)
-        out = inner(params, *pools, tables, pos, toks,
+        where = (pos, ints[:, max_blocks + STEP_COLS]) if slots else (pos,)
+        out = inner(params, *pools, tables, *where, toks,
                     lax.bitcast_convert_type(temps, jnp.float32), key)
         nxt = jnp.zeros((max_batch,), jnp.int32).at[:B].set(out[n_pools])
         return (*out[:n_pools], nxt, *out[n_pools + 1:])

@@ -485,6 +485,33 @@ class _Leaves(nn.Layer):
     """A node of the parameter tree: children by name, parameters by name."""
 
 
+def hold_parameters(model, specs, weights, initializer_range):
+    """Give ``model`` the tree of parameters that ``specs`` (``[(state_dict
+    key, shape, kind)]``) lists. ``weights``, a ``{key: array}`` of every one
+    of them, is held as given (no second copy on the device); without it each
+    leaf is drawn ``normal(0, initializer_range)``, a ``gain`` around 1."""
+    who = type(model).__name__
+    if weights is not None and set(weights) != {k for k, _, _ in specs}:
+        odd = sorted(set(weights) ^ {k for k, _, _ in specs})
+        raise ValueError(f"{who}: weights and parameters differ at {odd[:6]}")
+    rng = np.random.default_rng(0)
+    for key, shape, kind in specs:
+        if weights is not None:
+            data = jnp.asarray(weights[key], dtype=model._dtype)
+            if tuple(data.shape) != tuple(shape):
+                raise ValueError(f"{who}: {key} has shape "
+                                 f"{tuple(data.shape)}, the config says {shape}")
+        else:
+            x = rng.standard_normal(shape, np.float32) * initializer_range
+            data = jnp.asarray(x + (kind == "gain"), dtype=model._dtype)
+        node, names = model, key.split(".")
+        for name in names[:-1]:
+            if name not in node._sub_layers:
+                node.add_sublayer(name, _Leaves())
+            node = node._sub_layers[name]
+        node.add_parameter(names[-1], Parameter(data))
+
+
 class MLAMoEForCausalLM(nn.Layer):
     """The decoder as a tree of parameters (``state_dict`` keys as
     ``parameter_specs`` lists them; matrices are (in, out), experts stacked
@@ -496,26 +523,7 @@ class MLAMoEForCausalLM(nn.Layer):
     def __init__(self, config: MLAMoEConfig, weights: Optional[dict] = None):
         super().__init__()
         self.config = config
-        specs = _leaf_kinds(config)
-        if weights is not None and set(weights) != {k for k, _, _ in specs}:
-            odd = sorted(set(weights) ^ {k for k, _, _ in specs})
-            raise ValueError(f"MLAMoEForCausalLM: weights and parameters differ at {odd[:6]}")
-        rng = np.random.default_rng(0)
-        for key, shape, kind in specs:
-            if weights is not None:
-                data = jnp.asarray(weights[key], dtype=self._dtype)
-                if tuple(data.shape) != tuple(shape):
-                    raise ValueError(f"MLAMoEForCausalLM: {key} has shape "
-                                     f"{tuple(data.shape)}, the config says {shape}")
-            else:
-                x = rng.standard_normal(shape, np.float32) * config.initializer_range
-                data = jnp.asarray(x + (kind == "gain"), dtype=self._dtype)
-            node, names = self, key.split(".")
-            for name in names[:-1]:
-                if name not in node._sub_layers:
-                    node.add_sublayer(name, _Leaves())
-                node = node._sub_layers[name]
-            node.add_parameter(names[-1], Parameter(data))
+        hold_parameters(self, _leaf_kinds(config), weights, config.initializer_range)
 
     @staticmethod
     def parameter_specs(config: MLAMoEConfig):

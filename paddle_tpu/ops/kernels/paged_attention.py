@@ -194,17 +194,24 @@ def _group_tokens(Hp, H, KV, BS, C):
     return np.where(own, n[None, :] // KV, _NO_TOKEN).astype(np.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("C", "interpret"))
-def _paged_call(q, kpool, vpool, layer, tables, pos, *, C, interpret):
+@functools.partial(jax.jit, static_argnames=("C", "interpret", "scale",
+                                             "kv_heads"))
+def _paged_call(q, kpool, vpool, layer, tables, pos, *, C, interpret,
+                scale=None, kv_heads=None):
     B, H, D = q.shape
-    L, NB, BS, KV, _ = kpool.shape
+    if kv_heads is None:
+        L, NB, BS, KV, _ = kpool.shape
+    else:  # the pool as the kernel reads it: a block is one slab of lines
+        (L, NB, lines, _), KV = kpool.shape, kv_heads
+        BS = lines // KV
     MB = tables.shape[1]
     # queries padded to whole sublane tiles of the widest dtype Mosaic packs
     Hp = -(-H // 16) * 16
     qp = jnp.pad(q, ((0, 0), (0, Hp - H), (0, 0))) if Hp != H else q
     tok = jnp.asarray(_group_tokens(Hp, H, KV, BS, C))
     kern = functools.partial(_paged_kernel, B=B, MB=MB, BS=BS, C=C,
-                             scale=float(1.0 / np.sqrt(D)))
+                             scale=float(1.0 / np.sqrt(D)) if scale is None
+                             else scale)
     with kernel_x64_off(interpret):
         out = pl.pallas_call(
             kern,
@@ -237,7 +244,7 @@ def _paged_call(q, kpool, vpool, layer, tables, pos, *, C, interpret):
 
 
 def paged_attention_rows(q, kpool, vpool, layer, tables, pos, config=None,
-                         interpret=None):
+                         interpret=None, scale=None, kv_heads=None):
     """One decode step's attention read of layer ``layer`` over the paged
     pool.
 
@@ -247,13 +254,23 @@ def paged_attention_rows(q, kpool, vpool, layer, tables, pos, config=None,
     (B, MB) int32 per-row block tables (dead columns at the trash block);
     pos: (B,) int32 per-row write positions. Returns (B, H*D),
     ``_grouped_attention``'s reshaped output within the module's tolerance.
+    ``scale``: what the scores are multiplied by, ``D ** -0.5`` unless given
+    (differential attention pads its queries to twice the width their scores
+    are scaled by). ``kv_heads``: the pools are 4-D, ``(L, NB, BS * KV,
+    D)``, a block one slab of ``(token, kv head)`` lines as the kernel copies
+    it. (A 5-D pool is viewed so; where ``KV`` is not whole sublane tiles,
+    10 heads of bfloat16, that view is a copy of the pool a call, so such an
+    arch holds its pools 4-D.)
     """
     if not _HAS_PALLAS:
         raise RuntimeError("pallas unavailable")
     if interpret is None:
         interpret = interpret_default()
     B, H, D = q.shape
-    BS, KV = kpool.shape[2], kpool.shape[3]
+    if kv_heads is None:
+        BS, KV = kpool.shape[2], kpool.shape[3]
+    else:
+        BS, KV = kpool.shape[2] // int(kv_heads), int(kv_heads)
     MB = tables.shape[1]
     if config is None:
         config = resolve_config(
@@ -268,7 +285,9 @@ def paged_attention_rows(q, kpool, vpool, layer, tables, pos, config=None,
     return _paged_call(
         q, kpool, vpool, jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
-        C=C, interpret=bool(interpret))
+        C=C, interpret=bool(interpret),
+        scale=None if scale is None else float(scale),
+        kv_heads=None if kv_heads is None else KV)
 
 
 # -- registry ----------------------------------------------------------------

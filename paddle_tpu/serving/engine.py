@@ -488,10 +488,13 @@ class _Seq:
     path, where the whole prompt lands in one prefill pass). ``slot`` is the
     row's index in the decode step in flight (-1 when it has none): that
     step writes ``pos`` and its token is still on the device, so the step
-    enqueued behind it feeds ``prev[slot]`` at ``pos + 1``."""
+    enqueued behind it feeds ``prev[slot]`` at ``pos + 1``. ``row_slot`` is
+    the sequence's slot in the caches that hold a fixed size a row (a window,
+    a recurrent state): granted with its blocks, held until it retires or is
+    evicted, 0 (the trash slot) for an arch that has none."""
 
     __slots__ = ("req", "tokens", "blocks", "prompt_len", "cached_blocks",
-                 "chunk_pos", "slot")
+                 "chunk_pos", "slot", "row_slot")
 
     def __init__(self, req: _Request, tokens: List[int]):
         self.req = req
@@ -501,6 +504,7 @@ class _Seq:
         self.cached_blocks = 0
         self.chunk_pos = 0
         self.slot = -1
+        self.row_slot = 0
 
     @property
     def pos(self) -> int:
@@ -604,15 +608,20 @@ class Engine:
 
     ``model`` says what serves it: ``model.decode_state()`` returns its arch
     plug and weight tree (``GPTForPretraining``, ``LlamaForCausalLM``,
-    ``MLAMoEForCausalLM``; full logical weights). The engine
-    thread owns all scheduler state; only the submission queue and stop flag
-    cross threads (guarded below).
+    ``MLAMoEForCausalLM``, ``PhiFlashForCausalLM``; full logical weights).
+    The engine thread owns all scheduler state; only the submission queue and
+    stop flag cross threads (guarded below).
 
-    The cache is what the arch declares (``generation.cache_row_shapes``): a
+    The cache is what the arch declares (``generation.cache_pools``): a
     K pool and a V pool of ``(layers, blocks, block_size, kv_heads,
     head_dim)`` for GPT and Llama, ONE pool of padded latent rows for the MLA
     arch. ``PagePool``, block tables, growth, eviction and copy-on-write
-    count blocks and never look inside one, so they serve either.
+    count blocks and never look inside one, so they serve either. An arch
+    whose layers cache different KINDS of thing (``PhiFlashForCausalLM``: one
+    layer paged K/V, eight a window a row, nine a recurrent state a row,
+    fourteen nothing) gets a pool a kind over the layers of that kind, and
+    each request a row slot beside its blocks, held for its life and freed
+    with them (retire, evict); a re-prefill rebuilds what the slot held.
     """
 
     def __init__(self, model, config: Optional[EngineConfig] = None, **overrides):
@@ -626,8 +635,9 @@ class Engine:
         if not callable(getattr(model, "decode_state", None)):
             raise TypeError(
                 f"serving.Engine: unsupported model {type(model).__name__} "
-                "(expected GPTForPretraining, LlamaForCausalLM or "
-                "MLAMoEForCausalLM)"
+                "(expected GPTForPretraining, LlamaForCausalLM, "
+                "MLAMoEForCausalLM or PhiFlashForCausalLM: a model with "
+                "decode_state(), whose arch says what each layer caches)"
             )
         arch_key, arch, params, max_pos = model.decode_state()
         if config is not None and overrides:
@@ -697,22 +707,33 @@ class Engine:
                 "shard": jax.device_put(packed["shard"], shard_s),
             }
             self._dequant = None
-        self._n_layers = len(params["layers"])
+        self._n_layers = len(params.get("layers", ()))
         self._spec_k = int(cfg.spec_k)
         # speculative verify writes reach pos + spec_k: widen the block
         # tables so a real write can never clamp into the trash block
         self._max_blocks = -(-(cfg.max_seq_len + self._spec_k)
                              // cfg.block_size)
-        lead = (self._n_layers, cfg.num_blocks, cfg.block_size)
         # under tp the KV pool is sharded on the kv-heads axis: every device
         # owns heads/tp of EVERY block, so the replicated host-side block
         # tables / PagePool bookkeeping index all shards identically. The
         # zeros are CREATED sharded: built on one device and then spread, a
         # pool sized for the mesh does not fit the chip it starts on.
         pool_s = G.tp_pool_sharding(self._tp_mesh) if self._tp else None
-        self._cache = tuple(jnp.zeros(lead + row, self._dtype, device=pool_s)
-                            for row in G.cache_row_shapes(arch))
+        # a pool a kind of cache, over the layers of that kind: one paged
+        # kind over every layer unless the arch says what each layer caches
+        pools = G.cache_pools(arch, self._n_layers, cfg.num_blocks,
+                              cfg.block_size, cfg.max_batch)
+        self._cache = tuple(jnp.zeros(shape, dtype or self._dtype, device=pool_s)
+                            for _, shape, dtype in pools)
+        self._cache_kinds = tuple(kind for kind, _, _ in pools)
         self._pool = PagePool(cfg.num_blocks)
+        # row slots of the caches that hold a fixed size a row (None: the
+        # arch has none); slot 0 is the trash slot of a bucket's padding
+        self._row_slots = (list(range(cfg.max_batch, 0, -1))
+                           if G.cache_slots(arch) else None)
+        self._window = (arch["cache"].get("window_tokens", 0)
+                        if self._row_slots is not None else 0)
+        self._state_rebuilds = 0
         # routed experts: live tokens each expert took, by expert layer, as
         # the programs report them beside their tokens
         self._expert_tokens = (
@@ -994,7 +1015,39 @@ class Engine:
             "decode_wasted_rows": self._wasted_rows,
             **({"expert_tokens": self._expert_tokens.tolist()}
                if self._expert_tokens is not None else {}),
+            **(self._slot_stats() if self._row_slots is not None else {}),
         }
+
+    def _slot_stats(self) -> dict:
+        """Of an arch whose caches are of several kinds: the row slots, the
+        evictions that cost a re-prefill of state, the bytes held by kind."""
+        held: Dict[str, int] = {}
+        for kind, pool in zip(self._cache_kinds, self._cache):
+            held[kind] = held.get(kind, 0) + int(pool.nbytes)
+        total = self.config.max_batch
+        return {"state_slots_total": total,
+                "state_slots_used": total - len(self._row_slots),
+                "state_rebuilds": self._state_rebuilds,
+                "cache_bytes": held}
+
+    def _take_slot(self, seq: "_Seq"):
+        """A row slot with the blocks admission just granted: there is one
+        for every row the batch has room for."""
+        if self._row_slots is not None:
+            seq.row_slot = self._row_slots.pop()
+            counter_inc("serve_state_slots_taken")
+
+    def _release(self, seq: "_Seq"):
+        """Give back what a sequence holds of the caches: its blocks and its
+        row slot. What the slot held is dead from here: whoever takes it next
+        is prefilled into it, which overwrites every layer's window and
+        state."""
+        if seq.blocks:
+            self._pool.free(seq.blocks)
+            seq.blocks = []
+        if seq.row_slot:
+            self._row_slots.append(seq.row_slot)
+            seq.row_slot = 0
 
     def _refuse(self, path: str):
         """Raise for a path the arch has no program for (an arch that says
@@ -1753,8 +1806,7 @@ class Engine:
         # tokens once headroom allows, exactly like an evicted peer
         for seq in self._admitting:
             try:
-                if seq.blocks:
-                    self._pool.free(seq.blocks)
+                self._release(seq)
             except Exception:  # lint: ok(oom-handler) — pool itself may be what broke; the sweep must reach every seq
                 pass
             seq.blocks = []
@@ -1950,6 +2002,7 @@ class Engine:
                     continue
                 seq.blocks = matched + blocks
                 seq.cached_blocks = len(matched)
+                self._take_slot(seq)
                 admitted.append(seq)
                 if self._obs is not None and matched:
                     self._obs.on_prefix_match(
@@ -2000,6 +2053,7 @@ class Engine:
                 seq = _Seq(req, list(req.prompt))
                 seq.blocks = matched + blocks
                 seq.cached_blocks = len(matched)
+                self._take_slot(seq)
                 admitted.append(seq)
                 if self._obs is not None:
                     self._obs.on_admit(req)
@@ -2051,9 +2105,13 @@ class Engine:
                         ids[r, :len(s.tokens)] = s.tokens
                         lens[r] = len(s.tokens)
                         tables[r, :len(s.blocks)] = s.blocks
+                    if self._row_slots is not None:
+                        # real tokens through the scans, padding left out
+                        sp.set(scan_tokens=sum(len(s.tokens) for s in chunk))
                     logits, *extras = self._run(
                         fn, self._compute_params, jnp.asarray(ids),
-                        jnp.asarray(lens), jnp.asarray(tables))
+                        jnp.asarray(lens), jnp.asarray(tables),
+                        *self._slot_operand(chunk, bw))
                     counter_inc("serve_prefills")
                     rows, *extras = self._prefill_readback(logits, *extras)
                     if extras:
@@ -2090,6 +2148,15 @@ class Engine:
                     counter_inc("serve_tail_prefills")
                     rows, = self._prefill_readback(logits)
                     self._land_prefill(chunk, rows)
+
+    def _slot_operand(self, rows: List[_Seq], width: int) -> tuple:
+        """What a prefill program of an arch with row slots takes after the
+        tables: each row's slot, the trash slot for the bucket's padding."""
+        if self._row_slots is None:
+            return ()
+        slots = np.zeros((width,), np.int32)
+        slots[:len(rows)] = [s.row_slot for s in rows]
+        return (self._jnp.asarray(slots),)
 
     def _run(self, fn, params, *args, pools_first=False):
         """Call a compiled program with the cache pools in their slot (last,
@@ -2273,8 +2340,12 @@ class Engine:
         with span("evict", request=seq.req.id, generated=seq.generated) as sp:
             if self._obs is not None:
                 sp.set(traces=(seq.req.trace,))
-            self._pool.free(seq.blocks)
-            seq.blocks = []
+            if seq.row_slot:
+                # the window and the state go with the slot: the re-prefill
+                # pays for them again, not for the paged rows alone
+                self._state_rebuilds += 1
+                counter_inc("serve_state_rebuilds")
+            self._release(seq)
             self._running.remove(seq)
             self._resume.append(seq)
             counter_inc("serve_preempted")
@@ -2383,7 +2454,9 @@ class Engine:
             else:
                 # the plain step takes ONE operand from the host, its
                 # arrays side by side (generation.feed_tokens_back)
-                ints = np.zeros((bb, mb + self._G.STEP_COLS), np.int32)
+                # (an arch with row slots: one column more, the row's slot)
+                ints = np.zeros((bb, mb + self._G.STEP_COLS
+                                 + (self._row_slots is not None)), np.int32)
                 tables, (pos, src, toks, temps) = ints[:, :mb], (
                     ints[:, mb + c] for c in range(self._G.STEP_COLS))
                 temps = temps.view(np.float32)
@@ -2398,6 +2471,8 @@ class Engine:
                 else:
                     toks[r] = s.tokens[-1]
                     src[r] = s.slot
+                    if s.row_slot:
+                        ints[r, -1] = s.row_slot
             if k:
                 toks[:n, 1:] = drafts[:n]
         return rows, bb, mb, drafts, tables, pos, toks, temps, ints
@@ -2491,7 +2566,8 @@ class Engine:
         warm = ("decode", bb, mb) in self._fns
         with (self._landing_span(prev, ahead=1) if prev is not None else
               span("decode_step", bucket=bb, rows=len(rows),
-                   step=self._step_i, ahead=0)) as sp:
+                   step=self._step_i, ahead=0,
+                   **self._context_attrs(pos, len(rows)))) as sp:
             self._beat = time.monotonic()  # staleness clock covers this op
             if not warm:
                 # the compile stages this span will carry are of the program
@@ -2531,11 +2607,25 @@ class Engine:
             counter_inc("serve_decode_ahead")
             self._land(prev, sp)
 
+    def _context_attrs(self, pos: np.ndarray, n: int) -> dict:
+        """Of an arch whose caches are of several kinds, what a step of ``n``
+        live rows writing ``pos`` reads of each: the context of the one
+        paged layer (every reader sees the same tokens), the tokens inside
+        the windows, the rows whose state is updated. The rows that pad the
+        bucket are not counted."""
+        if self._row_slots is None:
+            return {}
+        ctx = pos[:n].astype(np.int64) + 1
+        return {"shared_kv_tokens": int(ctx.sum()),
+                "window_tokens": int(np.minimum(ctx, self._window).sum()),
+                "state_rows": n}
+
     def _landing_span(self, fl: _Flight, ahead: int = 0, **attrs):
         """The ``decode_step`` span a step lands in, with the attributes
         that describe it."""
         sp = span("decode_step", bucket=fl.bucket, rows=len(fl.rows),
-                  step=self._step_i, ahead=ahead, **attrs)
+                  step=self._step_i, ahead=ahead, **attrs,
+                  **self._context_attrs(fl.pos, len(fl.rows)))
         if self._obs is not None:
             sp.set(traces=tuple(s.req.trace for s in fl.rows))
         return sp
@@ -2709,8 +2799,7 @@ class Engine:
             self._retire(seq)
 
     def _retire(self, seq: _Seq, error: Optional[BaseException] = None):
-        self._pool.free(seq.blocks)
-        seq.blocks = []
+        self._release(seq)
         if seq in self._running:
             self._running.remove(seq)
         self._finish_request(seq.req, tokens=seq.tokens, error=error)
@@ -2780,8 +2869,7 @@ class Engine:
         for seq in list(self._running) + list(self._resume) \
                 + list(self._admitting) + list(self._prefilling):
             try:
-                if seq.blocks:
-                    self._pool.free(seq.blocks)
+                self._release(seq)
             except Exception:  # lint: ok(oom-handler) — corrupt-pool containment sweep, crash already classified in _step
                 pass
             seq.blocks = []
@@ -2841,7 +2929,8 @@ class Engine:
                 raw = G.build_paged_prefill(
                     self._arch, bw, t_bucket, self.config.block_size,
                     self._max_blocks)
-                donate = tuple(range(4, 4 + len(self._cache)))
+                first = 4 + (self._row_slots is not None)  # after the slots
+                donate = tuple(range(first, first + len(self._cache)))
             elif kind == "prefill_tail":
                 bw, t_bucket = bucket
                 raw = G.build_paged_tail_prefill(
@@ -2868,7 +2957,8 @@ class Engine:
                          else G.build_paged_decode)
                 raw = G.feed_tokens_back(
                     build(self._arch, bb, self.config.block_size, mb), bb,
-                    self.config.max_batch, mb, len(self._cache))
+                    self.config.max_batch, mb, len(self._cache),
+                    slots=self._row_slots is not None)
                 donate = tuple(range(1, 1 + len(self._cache)))
             if self._dequant is not None:
                 dq, inner = self._dequant, raw
@@ -2938,7 +3028,7 @@ class Engine:
         tables = np.full((bw, self._max_blocks), TRASH_BLOCK, np.int32)
         logits, *_ = self._run(
             fn, self._compute_params, jnp.asarray(ids), jnp.asarray(lens),
-            jnp.asarray(tables))
+            jnp.asarray(tables), *self._slot_operand([], bw))
         return np.asarray(logits[0])
 
 

@@ -352,6 +352,61 @@ def test_xing4_decode_step_beside_a_full_pool(v5e, monkeypatch):
     assert mem.temp_size_in_bytes < 256e6, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
 
 
+# -- the Mamba / differential-attention hybrid's kernels at the published sizes
+# of its configuration (d_i 5120, N 16; 10 K/V pairs of 128; 64 rows + the
+# trash slot; nine scan and eight window layers)
+@pytest.mark.parametrize("T", [64, 1024])
+def test_selective_scan_compiles(v5e, T):
+    from paddle_tpu.ops.kernels.selective_scan import selective_scan
+
+    s, f32 = SingleDeviceSharding(v5e[0]), jnp.float32
+    B, di, N = 4, 5120, 16
+    n = _compile_for_tpu(
+        lambda dt, c, Bm, Cm, A, D: selective_scan(dt, c, Bm, Cm, A, D,
+                                                   interpret=False),
+        _on(s, (B, T, di), f32), _on(s, (B, T, di), f32), _on(s, (B, T, N), f32),
+        _on(s, (B, T, N), f32), _on(s, (N, di), f32), _on(s, (di,), f32))
+    assert n == 1
+
+
+def test_state_update_compiles_over_the_pool_in_place(v5e):
+    from paddle_tpu.ops.kernels.selective_scan import state_update
+
+    s, f32 = SingleDeviceSharding(v5e[0]), jnp.float32
+    B, di, N = 64, 5120, 16
+    lowered = jax.jit(
+        lambda pool, layer, slots, dt, c, Bm, Cm, A, D: state_update(
+            pool, layer, slots, dt, c, Bm, Cm, A, D, interpret=False),
+        donate_argnums=(0,)).trace(
+        _on(s, (9, 65, N, di), f32), _on(s, (), jnp.int32), _on(s, (B,), jnp.int32),
+        _on(s, (B, di), f32), _on(s, (B, di), f32), _on(s, (B, N), f32),
+        _on(s, (B, N), f32), _on(s, (N, di), f32), _on(s, (di,), f32)
+    ).lower(lowering_platforms=("tpu",))
+    compiled = _compile_uncached(lowered)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    # in place: no second pool (192 MB) among the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 1024 * 1024
+
+
+@pytest.mark.parametrize("L,NB", [pytest.param(1, 8256, id="paged_one_layer"),
+                                  pytest.param(8, 65 * 32, id="window_rings")])
+def test_paged_attention_over_a_pool_of_lines_compiles(v5e, L, NB):
+    """The block-table read as the hybrid arch calls it: a 4-D pool whose
+    block is one slab of (token, K/V pair) lines (16 x 10), 40 padded queries
+    of 128 scored at 1 / sqrt(64), the layer a traced scalar."""
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    B, MB, H, KV, D, BS = 64, 128 if L == 1 else 32, 40, 10, 128, 16
+    pool = _on(s, (L, NB, BS * KV, D), bf)
+    n = _compile_for_tpu(
+        lambda q, k, v, layer, tables, pos: paged_attention_rows(
+            q, k, v, layer, tables, pos, interpret=False, scale=64 ** -0.5,
+            kv_heads=KV),
+        _on(s, (B, H, D), bf), pool, pool, _on(s, (), jnp.int32),
+        _on(s, (B, MB), jnp.int32), _on(s, (B,), jnp.int32))
+    assert n == 1
+
+
+
 def test_xing4_prefill_program_compiles(v5e, monkeypatch):
     """The widest prefill program of the cell (4 prompts x 1,024 positions):
     expanded attention, experts in 256-row tiles, temporaries inside the 2.5
