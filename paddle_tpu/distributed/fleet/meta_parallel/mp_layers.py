@@ -14,9 +14,15 @@ TPU-native: two composable modes —
 """
 from __future__ import annotations
 
+import math
+from functools import partial
+from typing import NamedTuple, Optional
+
 import numpy as np
 import jax
-from jax.sharding import PartitionSpec
+import jax.numpy as jnp
+from jax import lax
+from jax.sharding import Mesh, PartitionSpec
 
 from ....core.tensor import Tensor
 from ....nn import functional as F
@@ -84,6 +90,35 @@ class ColumnParallelLinear(Layer):
             out = _c_concat(out, self.group)
         return out
 
+    def fused_heads(self, x, groups, head_dim):
+        """``forward`` for a weight that fuses ``groups`` projections onto
+        heads of ``head_dim`` (a QKV), unbound: ``groups`` tensors (B, T,
+        heads, head_dim). Under GSPMD shapes are GLOBAL whatever the mesh:
+        the product is (B, T, groups x H) and ``heads`` every head; only
+        inside a map that holds 'mp' by hand (Megatron's per-rank view) is it
+        groups x H / mp and the rank's own heads.
+
+        The weight's contiguous column split is no split on head boundaries
+        (with mp 2 the first chip holds Q and half of K), so where the step is
+        compiled over a mesh that splits the heads (:func:`groups_axis`), the
+        reshape below would gather the activation across the axis in every
+        layer; there the product is taken against the weight exchanged onto
+        head boundaries (:func:`linear_on_groups`) and leaves the matmul as
+        (B, T, groups, H) with H on the axis. The parameters stay as they
+        lie."""
+        axis = None
+        if self.bias is not None:  # the exchange sends bias and weight as one
+            axis = groups_axis(self.weight.shape[1] // groups // head_dim, groups)
+        if axis is None:
+            out = self(x)
+        else:
+            from ....core.dispatch import eager_call
+
+            out = eager_call(
+                "linear_on_groups", linear_on_groups, [x, self.weight, self.bias],
+                {"groups": groups, "axis": axis})
+        return out.reshape([x.shape[0], x.shape[1], groups, -1, head_dim]).unbind(axis=2)
+
 
 class RowParallelLinear(Layer):
     def __init__(self, in_features, out_features, weight_attr=None, has_bias=True, input_is_parallel=False, fuse_matmul_bias=False, mp_group=None, name=None):
@@ -120,3 +155,180 @@ class ParallelCrossEntropy(Layer):
 
     def forward(self, input, label):
         return _c_softmax_with_cross_entropy(input, label, self.group, self.ignore_index)
+
+
+# -- a fused projection split on group boundaries (PR 36) ---------------------
+# A fused QKV weight (d, 3h) lies P(None, 'mp'): a CONTIGUOUS split of its 3h
+# columns, so with mp 2 chip 0 holds Q and half of K. Attention wants heads on
+# 'mp': (d, 3, h) lying P(None, None, 'mp'). GSPMD reaches that layout only by
+# gathering the activation (or the whole weight) in every layer; these
+# functions move a third of the weight by hand instead, which depends on no
+# activation and so runs under whatever the chip computes meanwhile.
+MP_EXCHANGE_SCOPE = "mp_exchange"
+
+
+def groups_axis(heads, groups=3, axis="mp"):
+    """``axis`` where the step being traced is compiled over a mesh
+    (``distributed.mesh.partitioned_over``) that splits ``heads`` heads over
+    more than one chip along it and :func:`split_on_groups` can serve
+    ``groups`` fused projections there, else None: no such axis, an enclosing
+    map holds it by hand (Megatron's per-rank view: shapes are local there),
+    it does not divide the heads, or rank -> (groups x rank + i) % n is no
+    permutation of its ranks (n and ``groups`` share a factor)."""
+    from ...collective import _axis_bound
+    from ...mesh import partitioned_mesh
+
+    mesh = partitioned_mesh()
+    n = mesh.shape.get(axis, 1) if mesh is not None else 1
+    if n == 1 or heads % n or math.gcd(groups, n) != 1 or _axis_bound(axis):
+        return None
+    return axis
+
+
+def _exchange(arrays, rank, groups, n, axis, join):
+    """One rank's part of :func:`split_on_groups` (``join``: of its inverse),
+    inside a map over ``axis``; ``rank`` is this rank's index as a (1,) array.
+    Splitting, the rank holds ``groups`` chunks of c columns; chunk i of rank
+    r is chunk m = groups x r + i of the whole, which belongs to rank m % n in
+    slot m // n. For each i that is ONE permutation of the ranks, and where it
+    is the identity nothing is sent (two of three at n 2). The arrays' chunks
+    travel stacked by rows in one transfer: a small transfer sent behind a
+    large one waits for it, and XLA schedules its wait as if it were instant
+    (PERF.md, PR 30). Rank d then finds slot s in what chunk (s x n + d) %
+    groups brought, so it lays the slots by a select on its own index: no
+    zero-filled buffer, no scatter."""
+    d = rank[0]
+    c = arrays[0].shape[-1] // (1 if join else groups)
+    rows = [int(np.prod(a.shape[:-2 if join else -1], dtype=np.int64)) for a in arrays]
+    inv = pow(n, -1, groups)  # slot s = (i - d) / n  (mod groups)
+
+    def part(a, i):
+        if join:
+            s = ((i - d) * inv) % groups
+            return lax.select_n(s, *(a[..., j, :] for j in range(groups)))
+        return a[..., i * c:(i + 1) * c]
+
+    def send(blocks, i):
+        perm = [(r, (groups * r + i) % n) for r in range(n)]
+        if all(a == b for a, b in perm):
+            return blocks
+        if join:
+            perm = [(b, a) for a, b in perm]
+        flat = jnp.concatenate([b.reshape(-1, c) for b in blocks], axis=0)
+        flat = lax.ppermute(flat, axis, perm)
+        out, at = [], 0
+        for b, k in zip(blocks, rows):
+            out.append(flat[at:at + k].reshape(b.shape))
+            at += k
+        return out
+
+    got = [send([part(a, i) for a in arrays], i) for i in range(groups)]
+    if join:
+        return tuple(jnp.concatenate([g[k] for g in got], axis=-1)
+                     for k in range(len(arrays)))
+    return tuple(
+        jnp.stack([lax.select_n((s * n + d) % groups, *(g[k] for g in got))
+                   for s in range(groups)], axis=-2)
+        for k in range(len(arrays)))
+
+
+class _Where(NamedTuple):
+    """Where an exchange runs, read while the forward pass is traced (its
+    transpose is traced later, outside ``partitioned_over``)."""
+    axis: str
+    n: int
+    mesh: Optional[Mesh]  # None inside a map that took some axes by hand
+
+
+def _where(axis) -> _Where:
+    from ...collective import _axis_bound
+    from ...mesh import partitioned_mesh
+
+    mesh = partitioned_mesh()
+    # inside a map that took some axes by hand (the engine's step, manual over
+    # 'dp'), the mesh is that map's and may not be named again
+    nested = any(_axis_bound(a) for a in mesh.axis_names)
+    return _Where(axis, mesh.shape[axis], None if nested else mesh)
+
+
+def _over_axis(arrays, groups, where, join):
+    from ...mesh import shard_map_compat
+
+    axis, n, mesh = where
+    k = 2 if join else 1
+    contiguous = tuple(PartitionSpec(*(None,) * (a.ndim - k), axis) for a in arrays)
+    on_groups = tuple(PartitionSpec(*(None,) * (a.ndim - k), None, axis) for a in arrays)
+    shard_map, check = shard_map_compat()
+    fn = shard_map(
+        lambda xs, rank: _exchange(xs, rank, groups, n, axis, join),
+        in_specs=(on_groups if join else contiguous, PartitionSpec(axis)),
+        out_specs=contiguous if join else on_groups,
+        axis_names=frozenset({axis}), **({} if mesh is None else {"mesh": mesh}), **check)
+    with jax.named_scope(MP_EXCHANGE_SCOPE):
+        # ``lax.axis_index`` of an inner axis does not lower in a nested map
+        return fn(tuple(arrays), jnp.arange(n, dtype=jnp.int32))
+
+
+def split_on_groups(x, groups, axis="mp"):
+    """``x`` (an array, or a tuple of arrays that travel together) whose LAST
+    dimension lies ``P(..., axis)`` contiguously and holds ``groups`` equal
+    blocks -> ``(..., groups, last / groups)`` lying ``P(..., None, axis)``:
+    every block split over the axis by itself, so that block g's part on a
+    chip is that chip's heads of projection g. A collective-permute over
+    ``axis`` inside the step being traced (:func:`groups_axis` says where it
+    serves); its transpose is the same exchange backwards."""
+    return _split(x, groups, _where(axis))
+
+
+@partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _split(x, groups, where):
+    one = not isinstance(x, (tuple, list))
+    out = _over_axis((x,) if one else tuple(x), groups, where, join=False)
+    return out[0] if one else out
+
+
+def _split_fwd(x, groups, where):
+    return _split(x, groups, where), None
+
+
+def _split_bwd(groups, where, _, ct):
+    one = not isinstance(ct, (tuple, list))
+    out = _over_axis((ct,) if one else tuple(ct), groups, where, join=True)
+    return (out[0] if one else out,)
+
+
+_split.defvjp(_split_fwd, _split_bwd)
+
+
+def linear_on_groups(x, w, b, groups=3, axis="mp"):
+    """``x @ w + b`` for a fused column-parallel ``w`` (d, groups x h) and
+    ``b`` as they lie, ``P(None, axis)`` and ``P(axis)``, as ``(..., groups,
+    h)`` with h on ``axis``: the product is taken against
+    :func:`split_on_groups` of the two, and no activation crosses the axis.
+    The backward pass keeps no split copy: its residuals are the operands
+    themselves, and it exchanges the weight again."""
+    return _linear(x, w, b, groups, _where(axis))
+
+
+def _product(x, w, b, groups, where):
+    ws, bs = _split((w, b), groups, where)
+    return jnp.einsum("...d,dgh->...gh", x, ws) + bs
+
+
+_linear = jax.custom_vjp(_product, nondiff_argnums=(3, 4))
+
+
+def _linear_bwd(groups, where, res, ct):
+    # tied to the cotangent, the second exchange cannot be merged with the
+    # forward pass's (XLA does merge a plain ``jax.checkpoint``'s), whose
+    # result would then be held from there to here: 16 split copies. Not x:
+    # through the barrier it has to exist as it was, and XLA then keeps the
+    # norm's output from the forward pass where it would have recomputed it
+    x, w, b = res
+    w, b, ct = lax.optimization_barrier((w, b, ct))
+    _, vjp = jax.vjp(lambda *a: _product(*a, groups, where), x, w, b)
+    return vjp(ct)
+
+
+_linear.defvjp(lambda x, w, b, groups, where: (_product(x, w, b, groups, where), (x, w, b)),
+               _linear_bwd)

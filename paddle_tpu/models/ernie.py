@@ -40,14 +40,12 @@ class ErnieSelfAttention(nn.Layer):
 
     def forward(self, x, attn_mask=None):
         B, T = x.shape[0], x.shape[1]
-        qkv = self.qkv(x)
-        local_h = qkv.shape[-1] // 3
-        qkv = qkv.reshape([B, T, 3, local_h // self.head_dim, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        # each (B, T, heads, D), GLOBAL shapes under GSPMD whatever the mesh
+        q, k, v = self.qkv.fused_heads(x, 3, self.head_dim)
         o = F.scaled_dot_product_attention(
             q, k, v, attn_mask=attn_mask, is_causal=False, dropout_p=self.dropout, training=self.training
         )
-        return self.out(o.reshape([B, T, local_h]))
+        return self.out(o.reshape([B, T, q.shape[2] * self.head_dim]))
 
 
 class ErnieLayer(nn.Layer):

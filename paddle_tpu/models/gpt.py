@@ -138,11 +138,8 @@ class GPTAttention(nn.Layer):
 
     def forward(self, x, attn_mask=None):
         B, T = x.shape[0], x.shape[1]
-        qkv = self.qkv(x)  # (B, T, 3H/mp)
-        local_h = qkv.shape[-1] // 3
-        local_heads = local_h // self.head_dim
-        qkv = qkv.reshape([B, T, 3, local_heads, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        # each (B, T, heads, D), GLOBAL shapes under GSPMD whatever the mesh
+        q, k, v = self.qkv.fused_heads(x, 3, self.head_dim)
         ring_mesh = self._ring_mesh() if attn_mask is None else None
         if ring_mesh is not None:
             out = self._ring_attention(q, k, v, ring_mesh)
@@ -153,7 +150,7 @@ class GPTAttention(nn.Layer):
                 dropout_p=self.attn_dropout, training=self.training,
                 impl=impl if impl in ("exact", "flash") else None,
             )
-        out = out.reshape([B, T, local_h])
+        out = out.reshape([B, T, q.shape[2] * self.head_dim])
         return self.proj(out)
 
 

@@ -116,7 +116,10 @@ def counters() -> Dict[str, int]:
     ``dp_reduce_leaves`` / ``dp_reduce_async`` (states, not sums: the
     gradient arrays the engine's compiled dp step reduces over 'dp', and
     those whose reduce is a start/done pair with compute scheduled between,
-    read once from the executable's scheduled text).
+    read once from the executable's scheduled text), and beside 'mp'
+    ``mp_weight_exchanges`` / ``mp_activation_gathers`` from the same text
+    (the fused QKV weight's transfers over 'mp' that run beside compute, and
+    the all-gathers of token-shaped data over 'mp' they are there to remove).
 
     Serving engine (paddle_tpu/serving/): ``serve_requests`` /
     ``serve_admitted`` / ``serve_retired`` / ``serve_cancelled`` /
@@ -307,6 +310,7 @@ KNOWN_COUNTERS = frozenset({
     "lazy_deferred_checks", "lazy_donated_buffers",
     "lazy_donation_fallbacks", "lazy_eager_replay_fallbacks",
     "lazy_flushes", "lazy_verify_passes",
+    "mp_activation_gathers", "mp_weight_exchanges",
     "naninf_donation_suppressed", "naninf_trips",
     "preemption_drains", "retry_attempts",
     "serve_admitted", "serve_adoptions", "serve_backpressure",

@@ -34,14 +34,14 @@ def v5e():
         platform="tpu", topology_name="v5e:2x2").devices
 
 
-def _compile_uncached(lowered):
+def _compile_uncached(lowered, options=None):
     # an executable for a chip that is not here cannot be loaded back from
     # the persistent cache ("DeserializeLoadedExecutable not implemented"):
     # keep these out of it, or every later run warns and recompiles anyway
     threshold = jax.config.jax_persistent_cache_min_compile_time_secs
     jax.config.update("jax_persistent_cache_min_compile_time_secs", float("inf"))
     try:
-        return lowered.compile()
+        return lowered.compile(options)
     finally:
         jax.config.update("jax_persistent_cache_min_compile_time_secs", threshold)
 
@@ -424,41 +424,14 @@ def test_xing4_prefill_program_compiles(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
-# -- the hybrid step's data-parallel gradient reduce (PR 30) -----------------
-def _groups(attr):
-    """``replica_groups`` of an HLO collective as a set of frozensets, from
-    either spelling: ``{{0,2},{1,3}}`` or the iota form
-    ``[2,2]<=[2,2]T(1,0)``."""
-    import re
-
-    if attr.startswith("{"):
-        return {frozenset(int(i) for i in g.split(","))
-                for g in re.findall(r"\{([0-9,]+)\}", attr)}
-    m = re.match(r"\[([0-9,]+)\]<=\[([0-9,]+)\](?:T\(([0-9,]+)\))?", attr)
-    shape, dims, perm = ([int(i) for i in g.split(",")] if g else None
-                         for g in m.groups())
-    ids = np.arange(int(np.prod(dims))).reshape(dims)
-    if perm:
-        ids = ids.transpose(perm)
-    return {frozenset(int(i) for i in row) for row in ids.reshape(shape)}
-
-
-def test_hybrid_step_reduces_its_gradients_beside_compute(v5e, monkeypatch):
-    """The four-chip cell's step at two layers and its width of 4096,
-    compiled for ``v5e:2x2`` under dp2 x mp2: every gradient leaf is reduced
-    over the 'dp' pairs as a start/done pair with compute scheduled between
-    (``dp_reduce_async == dp_reduce_leaves``), and no synchronous
-    weight-shaped reduce over those pairs is left on the chip's line. XLA:TPU
-    makes an all-reduce synchronous (and no compile option of this libtpu
-    frees it), a collective-permute asynchronous: a later jax or libtpu that
-    undoes either shows here, not in a ledger row."""
-    import re
-
+# -- the hybrid step's collectives (PR 30, PR 36) ----------------------------
+def _hybrid_step(v5e, monkeypatch, layers=2):
+    """The four-chip cell's step at ``layers`` layers and its width of 4096,
+    lowered for ``v5e:2x2`` under dp2 x mp2: the engine, and its step compiled
+    with the options the engine compiles it with."""
     import paddle_tpu.ops.pallas as pallas_mod
     from paddle_tpu.distributed import fleet
-    from paddle_tpu.distributed.engine import (
-        HybridParallelEngine, _sharding, dp_reduce_counts,
-    )
+    from paddle_tpu.distributed.engine import HybridParallelEngine, _sharding
     from paddle_tpu.models.gpt import GPTConfig, GPTForPretraining
     from paddle_tpu.nn.functional import attention as attn_mod
 
@@ -474,7 +447,7 @@ def test_hybrid_step_reduces_its_gradients_beside_compute(v5e, monkeypatch):
     paddle.set_default_dtype("bfloat16")
     try:
         model = GPTForPretraining(GPTConfig(
-            vocab_size=8192, hidden_size=4096, num_layers=2, num_heads=32,
+            vocab_size=8192, hidden_size=4096, num_layers=layers, num_heads=32,
             max_position_embeddings=2048, hidden_dropout=0.0,
             attention_dropout=0.0))
     finally:
@@ -502,24 +475,78 @@ def test_hybrid_step_reduces_its_gradients_beside_compute(v5e, monkeypatch):
         like(jnp.zeros((), jnp.float32), whole),
         jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=whole),
     ).lower(lowering_platforms=("tpu",))
-    text = _compile_uncached(lowered).as_text()
+    return eng, _compile_uncached(lowered, eng.step_compiler_options())
 
+
+# mesh (pp1, dp2, sharding1, sp1, mp2) over devices 0..3: chip i is replica
+# i // 2, so the 'dp' pairs are {0,2} and {1,3}, the 'mp' pairs {0,1} and {2,3}
+DP_PAIRS = {frozenset({0, 2}), frozenset({1, 3})}
+MP_PAIRS = {frozenset({0, 1}), frozenset({2, 3})}
+
+
+def test_hybrid_step_reduces_its_gradients_beside_compute(v5e, monkeypatch):
+    """The four-chip cell's step at two layers and its width of 4096,
+    compiled for ``v5e:2x2`` under dp2 x mp2: every gradient leaf is reduced
+    over the 'dp' pairs as a start/done pair with compute scheduled between
+    (``dp_reduce_async == dp_reduce_leaves``), and no synchronous
+    weight-shaped reduce over those pairs is left on the chip's line. XLA:TPU
+    makes an all-reduce synchronous (and no compile option of this libtpu
+    frees it), a collective-permute asynchronous: a later jax or libtpu that
+    undoes either shows here, not in a ledger row."""
+    import re
+
+    from paddle_tpu.distributed.engine import collectives, dp_reduce_counts
+
+    eng, compiled = _hybrid_step(v5e, monkeypatch)
+    text = compiled.as_text()
     counts = dp_reduce_counts(text)
     # the eight weight matrices and the embedding travel alone, the biases,
     # norms and positions stacked by shape and layout
     assert 9 < counts["dp_reduce_leaves"] < len(eng.params)
     assert counts["dp_reduce_async"] == counts["dp_reduce_leaves"]
-    # mesh (pp1, dp2, sharding1, sp1, mp2) over devices 0..3: chip i is
-    # replica i // 2, so the 'dp' pairs are {0,2} and {1,3}
-    dp_pairs = {frozenset({0, 2}), frozenset({1, 3})}
-    left = []
-    entry = text[text.find("\nENTRY "):]
-    for line in entry.split("\n"):
-        m = re.search(r" = (\(.*?\)|\S+) (all-reduce|reduce-scatter)\(", line)
-        if not m or _groups(re.search(r"replica_groups=(\S+?),? ", line)
-                            .group(1)) != dp_pairs:
-            continue
-        # a matrix, not the loss's scalar mean
-        if re.search(r"\[[0-9]+,[0-9]+", m.group(1)):
-            left.append(line.strip()[:160])
+    # a matrix, not the loss's scalar mean
+    left = [c for c in collectives(text) if c.op in ("all-reduce", "reduce-scatter")
+            and c.over() == DP_PAIRS and re.search(r"\[[0-9]+,[0-9]+", c.shape)]
     assert not left, left
+
+
+def test_hybrid_step_exchanges_the_qkv_weight_and_gathers_no_activation(v5e, monkeypatch):
+    """The same step (PR 36): Q, K and V leave the fused product split on head
+    boundaries, so no all-gather over the 'mp' pairs holds the batch's tokens
+    (the parent gathered ``[2,2048,12288]`` forward and its cotangent
+    backward, every layer); instead a third of the weight crosses 'mp' three
+    times a layer (forward, again for the backward pass, which keeps no split
+    copy, and the weight's cotangent), each a start/done pair with compute
+    between; the gradient reduces stay beside compute; and the step takes no
+    more memory than the step without the exchange."""
+    from paddle_tpu.distributed.engine import (
+        collectives, dp_reduce_counts, mp_exchange_counts,
+    )
+    from paddle_tpu.distributed.fleet.meta_parallel import mp_layers
+
+    def total(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes
+                + m.generated_code_size_in_bytes)
+
+    eng, compiled = _hybrid_step(v5e, monkeypatch)
+    text = compiled.as_text()
+    found = collectives(text)
+    gathered = [c for c in found if c.op == "all-gather" and c.over() == MP_PAIRS
+                and "[2,2048," in c.shape]
+    assert not gathered, gathered
+    sent = [c for c in found if c.op == "collective-permute" and c.over() == MP_PAIRS]
+    # a third of a chip's columns and of its bias in one transfer
+    assert len(sent) == 2 * 3 and all(c.shape.startswith("(bf16[4097,2048]") for c in sent)
+    assert all(c.under(mp_layers.MP_EXCHANGE_SCOPE) and c.hidden for c in sent)
+    assert mp_exchange_counts(text, MP_PAIRS, (2, 2048)) == {
+        "mp_weight_exchanges": 2 * 3, "mp_activation_gathers": 0}
+    counts = dp_reduce_counts(text)
+    assert counts["dp_reduce_async"] == counts["dp_reduce_leaves"] > 9
+
+    monkeypatch.setattr(mp_layers, "groups_axis", lambda *a, **k: None)
+    _, without = _hybrid_step(v5e, monkeypatch)
+    assert mp_exchange_counts(without.as_text(), MP_PAIRS, (2, 2048)) == {
+        "mp_weight_exchanges": 0, "mp_activation_gathers": 3}
+    assert total(compiled) <= total(without) + 40e6
