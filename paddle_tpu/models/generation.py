@@ -902,9 +902,122 @@ def _phi4flash_arch(cfg, kernels):
             "prompt_stack": prompt_stack, "decode_stack": decode_stack,
             "plain_paths_only": True,
             "cache": {"layers": layers, "window_tokens": W,
+                      "span_attrs": {"shared_kv_tokens": "paged",
+                                     "window_tokens": "window",
+                                     "state_rows": "state"},
                       "paged": (cfg.kv_row,) * 2, "window": (cfg.kv_row,) * 2,
                       "state": (((cfg.mamba_d_state, cfg.d_inner), "float32"),
                                 ((cfg.mamba_d_conv - 1, cfg.d_inner), None))}}
+
+
+def lfm2_moe_decode_state(model, kernels=None):
+    """(arch_key, arch, params, max_positions) for ``Lfm2MoeForCausalLM``:
+    the weight tree (the repetitions of the layer pattern stacked, as the
+    model holds them) and the arch plug around ``models/lfm2_moe.py``'s layer
+    functions. ``kernels``: whether the experts and the cache read take their
+    Pallas kernels (default: wherever Mosaic compiles) or their plain forms."""
+    from . import lfm2_moe as L
+
+    cfg = model.config
+    kernels, arch_key = _keyed_by_config("lfm2_moe", cfg, kernels)
+    params = L.params_tree(
+        cfg, {k: v._data for k, v in model.state_dict().items()})
+    return arch_key, _lfm2_moe_arch(cfg, kernels), params, \
+        cfg.max_position_embeddings
+
+
+def _lfm2_moe_arch(cfg, kernels):
+    """The arch plug of the gated-convolution / grouped-query / routed-expert
+    hybrid: a whole-stack arch (row slots, a cache of kinds) that ALSO routes
+    experts, so its stacks hand the programs their counts beside the pools.
+
+    - ``paged``: K and V rows a token by block table for the attention
+      layers, NEIGHBOURING key/value heads two a 128-lane line (``kv_row``):
+      heads of 64 fill half a line, and the block-table kernel takes whole
+      ones. The queries are laid to match (``lfm2_moe.pair_queries``);
+    - ``state``: the convolution's last ``conv_L_cache - 1`` inputs a slot, in
+      the served dtype.
+
+    A prompt's rows are written by ONE scatter a pool after the stack (the
+    layers inside the scan stage them, ``lfm2_moe.prompt_reads``); a decode
+    step writes its fresh row a layer, before the read. It has the plain prefill and decode
+    programs and no other yet (``plain_paths_only``)."""
+    from . import lfm2_moe as L
+    from .phi4flash import attend_dense
+
+    H, G, D = cfg.num_attention_heads, cfg.num_key_value_heads, cfg.head_dim
+    pairs = G // 2
+    kinds = {"conv": "state", "full_attention": "paged"}
+    if set(cfg.layer_types) != set(kinds):
+        raise NotImplementedError(
+            f"lfm2_moe: layer_types {cfg.layer_types!r}; the arch holds a pool "
+            "a kind and expects layers of both")
+
+    def embed(params, ids, posm):
+        return params["wte"][ids]
+
+    def head(params, x):
+        return _head_mm(params, L.M.rms(x, params["norm"], cfg.norm_eps),
+                        "wte", True)
+
+    def prompt_stack(params, x, pools, lens, live, tb, slots, block_size):
+        B, T = x.shape[:2]
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        conv, attend = L.prompt_reads(cfg, lens, T)
+        x, (k, v, tails), counts = L.stack(
+            cfg, params, x, pos, live, L.prompt_staging(cfg, B, T, x.dtype),
+            conv, attend, kernels)
+        kp, vp, S = pools
+        # (layers, B, T, pairs, 2 D) rows as whole blocks of (token, pair) lines
+        cut = lambda r: r.reshape(r.shape[0], B, -1, block_size * pairs, 2 * D)
+        return x, (kp.at[:, tb].set(cut(k)), vp.at[:, tb].set(cut(v)),
+                   S.at[:, slots].set(tails.astype(S.dtype))), counts
+
+    def decode_stack(params, x, pools, tables, pos, bids, offs, slots,
+                     block_size):
+        from ..ops.kernels import paged_attention_rows
+
+        B = x.shape[0]
+        # a token's lines inside its block
+        lines = offs[:, None] * pairs + jnp.arange(pairs)[None]
+
+        def conv(pools, i, z):
+            kp, vp, S = pools
+            taps = jnp.concatenate([S[i, slots], z.astype(S.dtype)], 1)
+            return (kp, vp, S.at[i, slots].set(taps[:, 1:])), taps[:, None]
+
+        def attend(pools, i, q, k, v):
+            kp, vp, S = pools
+            # the fresh row goes into the pool BEFORE the read
+            kp = kp.at[i, bids[:, None], lines].set(L.pair_keys(cfg, k[:, 0]))
+            vp = vp.at[i, bids[:, None], lines].set(L.pair_keys(cfg, v[:, 0]))
+            if kernels:
+                o = paged_attention_rows(
+                    L.pair_queries(cfg, q[:, 0]), kp, vp, i, tables, pos,
+                    scale=D ** -0.5, kv_heads=pairs)
+                o = L.own_half(cfg, o.reshape(B, H, 2 * D))[:, None]
+            else:
+                T_pad = tables.shape[1] * block_size
+                kc = kp[i, tables].reshape(B, T_pad, G, D)
+                vc = vp[i, tables].reshape(B, T_pad, G, D)
+                seen = jnp.arange(T_pad)[None, None, :] <= pos[:, None, None]
+                o = attend_dense(cfg, q, kc, vc, seen)
+            return (kp, vp, S), o
+
+        live = tables[:, :1] != 0  # a row whose table is unmapped pads the bucket
+        return L.stack(cfg, params, x, pos[:, None], live, pools, conv, attend,
+                       kernels)
+
+    return {"name": "lfm2_moe", "embed": embed, "head": head,
+            "prompt_stack": prompt_stack, "decode_stack": decode_stack,
+            "plain_paths_only": True,
+            "expert_layers": cfg.num_hidden_layers - cfg.num_dense_layers,
+            "experts": cfg.num_experts,
+            "cache": {"layers": tuple((kinds[k], None) for k in cfg.layer_types),
+                      "span_attrs": {"paged_kv_tokens": "paged",
+                                     "state_rows": "state"},
+                      "paged": (cfg.kv_row,) * 2,
+                      "state": (((cfg.conv_L_cache - 1, cfg.hidden_size), None),)}}
 
 
 def cache_row_shapes(arch):
@@ -944,7 +1057,11 @@ def cache_pools(arch, n_layers, num_blocks, block_size, max_batch):
     - ``state``: ``(layers, max_batch + 1) + row``, a fixed shape a slot.
 
     Slot 0 is the trash slot, as block 0 is the trash block: the rows that
-    pad a bucket write there. An arch that says nothing a layer (GPT, Llama,
+    pad a bucket write there. ``arch["cache"]["span_attrs"]`` (attribute ->
+    kind) names what the engine's ``decode_step`` spans report of each kind
+    a step: the live context summed over the rows (``paged``), its part
+    inside the windows (``window``), the live rows (``state``). An arch that
+    says nothing a layer (GPT, Llama,
     the MLA arch) caches the same rows in every layer: one paged kind over
     all ``n_layers`` (:func:`cache_row_shapes`)."""
     cache = arch.get("cache")
@@ -987,9 +1104,10 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
 
     An arch whose layers are of several kinds, with values that cross them,
     brings its whole stack (``prompt_stack(params, x, pools, lens, live,
-    tables[:, :nb], slots, block_size) -> (x, pools)``) and writes its pools
-    itself; its program takes each row's slot after the tables:
-    ``prefill(params, ids, lens, tables, slots, *pools)``."""
+    tables[:, :nb], slots, block_size) -> (x, pools)``, or ``(x, pools,
+    counts)`` where it routes experts too) and writes its pools itself; its
+    program takes each row's slot after the tables: ``prefill(params, ids,
+    lens, tables, slots, *pools)``."""
     if T_bucket % block_size:
         raise ValueError(
             f"prefill bucket {T_bucket} must be a multiple of block_size "
@@ -1006,12 +1124,12 @@ def build_paged_prefill(arch, B, T_bucket, block_size, max_blocks):
     if "prompt_stack" in arch:
         def prefill(params, ids, lens, tables, slots, *pools):
             x = arch["embed"](params, ids, jnp.arange(T_bucket)[None])
-            x, pools = arch["prompt_stack"](
+            x, pools, *counts = arch["prompt_stack"](
                 params, x, tuple(pools), lens, live_positions(lens, tables),
                 tables[:, :nb], slots, block_size)
             with jax.named_scope("head"):
                 logits = arch["head"](params, _last_rows(x, lens))
-            return (*pools, logits)
+            return (*pools, logits, *counts)
 
         return prefill
 
@@ -1126,9 +1244,10 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
     key)``), as it does the gather step.
 
     An arch that brings its whole stack (``decode_stack(params, x, pools,
-    tables, pos, bids, offs, slots, block_size) -> (x, pools)``) gets the
-    same step around it, with each row's slot after ``pos``:
-    ``step(params, *pools, tables, pos, slots, toks, temps, key)``."""
+    tables, pos, bids, offs, slots, block_size) -> (x, pools)``, or ``(x,
+    pools, counts)``) gets the same step around it, with each row's slot
+    after ``pos``: ``step(params, *pools, tables, pos, slots, toks, temps,
+    key)``."""
     def write_slots(tables, pos):
         bids = jnp.take_along_axis(tables, (pos // block_size)[:, None], axis=1)[:, 0]
         return bids, pos % block_size
@@ -1137,12 +1256,12 @@ def build_paged_decode_kernel(arch, B, block_size, max_blocks):
         def step(params, *args):
             *pools, tables, pos, slots, toks, temps, key = args
             x = arch["embed"](params, toks, pos)[:, None]
-            x, pools = arch["decode_stack"](
+            x, pools, *counts = arch["decode_stack"](
                 params, x, tuple(pools), tables, pos, *write_slots(tables, pos),
                 slots, block_size)
             with jax.named_scope("head"):
                 logits = arch["head"](params, x[:, -1])
-            return (*pools, _next_tokens(logits, temps, key))
+            return (*pools, _next_tokens(logits, temps, key), *counts)
 
         return step
 

@@ -369,14 +369,18 @@ def moe_ffn(cfg: MLAMoEConfig, w, x, live, kernels=False):
         lookup[list(held)] = np.arange(len(held))
         slot = jnp.asarray(lookup)[choice]
         g = jnp.where(slot < len(held), g, 0.0)
+    stacks = [w["experts_gate"], w["experts_up"], w["experts_down"]]
+    # ``experts_layer``: the stacks hold several layers' experts, (layers,
+    # held, ...), and this scalar (traced inside a scan over layers) says
+    # whose; the kernel reads them where they lie, the plain form slices
+    layer = w.get("experts_layer")
     if kernels:
         from ..ops.kernels.moe_experts import moe_experts
 
-        y = moe_experts(x, slot, g, w["experts_gate"], w["experts_up"],
-                        w["experts_down"])
+        y = moe_experts(x, slot, g, *stacks, layer=layer)
     else:
-        y = experts_plain(x, slot, g, w["experts_gate"], w["experts_up"],
-                          w["experts_down"])
+        y = experts_plain(x, slot, g, *(stacks if layer is None
+                                        else [s[layer] for s in stacks]))
     if "shared_gate" in w:
         y = y + gated_mlp(x, w["shared_gate"], w["shared_up"], w["shared_down"])
     return y, counts

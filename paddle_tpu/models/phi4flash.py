@@ -265,12 +265,11 @@ def attend_dense(cfg: PhiFlashConfig, q, k, v, live):
 
 # -- a whole prompt ------------------------------------------------------------------
 
-def prompt_conv(cfg: PhiFlashConfig, lens):
-    """``conv`` of :func:`mamba_mixer` over whole prompts: the taps of
-    position ``t`` are the inputs at ``t - K + 1 .. t`` (zeros before the
-    prompt), and what it keeps is the last ``K - 1`` inputs of each row's
+def prompt_conv(K: int, lens):
+    """``conv`` of :func:`mamba_mixer` over whole prompts, ``K`` taps: the
+    taps of position ``t`` are the inputs at ``t - K + 1 .. t`` (zeros before
+    the prompt), and what it keeps is the last ``K - 1`` inputs of each row's
     TRUE length ``lens``, not of the bucket."""
-    K = cfg.mamba_d_conv
 
     def conv(a):
         T = a.shape[1]
@@ -340,7 +339,7 @@ def prompt_stack(cfg: PhiFlashConfig, params, x, pools, lens, live, kernels,
     t = jnp.arange(T)
     causal = (t[None, :] <= t[:, None])[None]
     window = causal & (t[:, None] - t[None, :] < cfg.sliding_window)[None]
-    conv, recur = prompt_conv(cfg, lens), prompt_recur(live, kernels)
+    conv, recur = prompt_conv(cfg.mamba_d_conv, lens), prompt_recur(live, kernels)
     dense = lambda mask: lambda q, k, v: attend_dense(cfg, q, k, v, mask)
 
     def mamba(w, x, i, pools):

@@ -102,14 +102,19 @@ def _experts_kernel(te_ref, nt_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc,
 
 
 @functools.partial(jax.jit, static_argnames=("tm", "tf", "interpret"))
-def _experts_call(x, slot, gates, wg, wu, wd, *, tm, tf, interpret):
+def _experts_call(x, slot, gates, wg, wu, wd, layer=None, *, tm, tf, interpret):
     N, d = x.shape
     k = slot.shape[1]
-    E, _, f = wg.shape
+    E, _, f = wg.shape[-3:]
     nf = f // tf
     A = N * k
     flat = slot.reshape(A).astype(I32)
     dest, te, nt = tile_layout(flat, E, tm)
+    if layer is not None:
+        # the stacks of several layers, viewed as one run of experts: a
+        # tile's expert is counted from its layer's first
+        te = te + layer.astype(I32) * E
+        wg, wu, wd = (w.reshape((-1,) + w.shape[-2:]) for w in (wg, wu, wd))
     NT = te.shape[0]
     # the rows, tile by tile: each row of the layout GATHERS its pair's token
     # (a scatter of whole rows compiles and runs slower); a row no pair went
@@ -157,19 +162,23 @@ def _experts_call(x, slot, gates, wg, wu, wd, *, tm, tf, interpret):
 
 
 def moe_experts(x, slot, gates, gate_w, up_w, down_w, config=None,
-                interpret=None):
+                interpret=None, layer=None):
     """``sum_k gates[n, k] * Expert_{slot[n, k]}(x[n])`` over the held experts.
 
     x: (N, d); slot: (N, k) int32, an index into the held experts or their
     count ``E`` for "none" (a padding token, an expert held elsewhere);
     gates: (N, k) float32; gate_w / up_w: (E, d, f); down_w: (E, f, d).
+    ``layer``: the weights are the stacks of several layers, (L, E, d, f) /
+    (L, E, f, d), and this int32 scalar (traced inside a scan over layers)
+    says whose experts to take: the stacks go to the kernel whole and no
+    ``gate_w[layer]`` is formed (XLA:TPU would copy it for the call).
     Returns (N, d) in ``x``'s dtype."""
     if not _HAS_PALLAS:
         raise RuntimeError("pallas unavailable")
     if interpret is None:
         interpret = interpret_default()
     N, d = x.shape
-    E, _, f = gate_w.shape
+    E, _, f = gate_w.shape[-3:]
     pairs = N * slot.shape[1]
     if config is None:
         config = resolve_config("moe_experts",
@@ -177,8 +186,9 @@ def moe_experts(x, slot, gates, gate_w, up_w, down_w, config=None,
     tm = int(config.get("rows_per_tile") or (16 if pairs < 512 else 256))
     tf = int(config.get("f_slice") or 512)
     tf = tf if f % tf == 0 else f
-    return _experts_call(x, slot, gates, gate_w, up_w, down_w, tm=tm, tf=tf,
-                         interpret=bool(interpret))
+    return _experts_call(x, slot, gates, gate_w, up_w, down_w,
+                         None if layer is None else jnp.asarray(layer, I32),
+                         tm=tm, tf=tf, interpret=bool(interpret))
 
 
 def _runner(key):

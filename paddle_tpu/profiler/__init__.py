@@ -136,9 +136,11 @@ def counters() -> Dict[str, int]:
     summed over its expert layers, and the (token, choice) pairs it routed;
     padding counts in neither; the table by layer and expert is
     ``Engine.stats()["expert_tokens"]``),
-    ``serve_state_slots_taken`` / ``serve_state_rebuilds`` (an arch whose
-    layers keep a window or a recurrent state a row: row slots granted at
-    admission, and evictions that cost a re-prefill of that state),
+    ``serve_state_slots_taken`` / ``serve_state_rebuilds`` /
+    ``serve_state_rows`` (an arch whose layers keep a window, a recurrent
+    state or a convolution's last inputs a row: row slots granted at
+    admission, evictions that cost a re-prefill of that state, and the live
+    rows whose state the decode steps updated),
     ``serve_decode_ahead`` (decode steps enqueued while the step before was
     still unread on the device: over ``serve_decode_steps``, how often the
     loop ran one step ahead of the host), ``serve_decode_drains`` (landings
@@ -334,7 +336,8 @@ KNOWN_COUNTERS = frozenset({
     "serve_requests", "serve_requeued", "serve_restart_mttr_ms",
     "serve_restarts", "serve_retired", "serve_shed",
     "serve_snapshot_failed", "serve_snapshot_rejected",
-    "serve_snapshots", "serve_state_rebuilds", "serve_state_slots_taken",
+    "serve_snapshots", "serve_state_rebuilds", "serve_state_rows",
+    "serve_state_slots_taken",
     "serve_tail_prefills", "serve_tokens",
     "serve_trace_evicted", "serve_wedge_detected", "serve_wedged_close",
     "stability_barrier_timeouts", "stability_coordinated_trips",

@@ -733,7 +733,7 @@ class Engine:
                            if G.cache_slots(arch) else None)
         self._window = (arch["cache"].get("window_tokens", 0)
                         if self._row_slots is not None else 0)
-        self._state_rebuilds = 0
+        self._state_rebuilds = self._state_rows = 0
         # routed experts: live tokens each expert took, by expert layer, as
         # the programs report them beside their tokens
         self._expert_tokens = (
@@ -1020,7 +1020,8 @@ class Engine:
 
     def _slot_stats(self) -> dict:
         """Of an arch whose caches are of several kinds: the row slots, the
-        evictions that cost a re-prefill of state, the bytes held by kind."""
+        evictions that cost a re-prefill of state, the rows whose state the
+        decode steps updated, the bytes held by kind."""
         held: Dict[str, int] = {}
         for kind, pool in zip(self._cache_kinds, self._cache):
             held[kind] = held.get(kind, 0) + int(pool.nbytes)
@@ -1028,6 +1029,7 @@ class Engine:
         return {"state_slots_total": total,
                 "state_slots_used": total - len(self._row_slots),
                 "state_rebuilds": self._state_rebuilds,
+                "state_rows": self._state_rows,
                 "cache_bytes": held}
 
     def _take_slot(self, seq: "_Seq"):
@@ -2105,9 +2107,12 @@ class Engine:
                         ids[r, :len(s.tokens)] = s.tokens
                         lens[r] = len(s.tokens)
                         tables[r, :len(s.blocks)] = s.blocks
+                    # real tokens, the bucket's padding left out (an arch
+                    # with row slots runs them through its scans)
+                    real = sum(len(s.tokens) for s in chunk)
+                    sp.set(prompt_tokens=real)
                     if self._row_slots is not None:
-                        # real tokens through the scans, padding left out
-                        sp.set(scan_tokens=sum(len(s.tokens) for s in chunk))
+                        sp.set(scan_tokens=real)
                     logits, *extras = self._run(
                         fn, self._compute_params, jnp.asarray(ids),
                         jnp.asarray(lens), jnp.asarray(tables),
@@ -2526,6 +2531,9 @@ class Engine:
         counter_inc("serve_decode_steps")
         counter_inc("serve_occupancy_live", n)
         counter_inc("serve_occupancy_slots", bb)
+        if self._row_slots is not None:
+            self._state_rows += n
+            counter_inc("serve_state_rows", n)
 
     def _decode(self):
         """One plain decode iteration, one step AHEAD of the host: build and
@@ -2609,16 +2617,19 @@ class Engine:
 
     def _context_attrs(self, pos: np.ndarray, n: int) -> dict:
         """Of an arch whose caches are of several kinds, what a step of ``n``
-        live rows writing ``pos`` reads of each: the context of the one
-        paged layer (every reader sees the same tokens), the tokens inside
+        live rows writing ``pos`` reads of each, under the names the arch
+        declares (``cache["span_attrs"]``, attribute -> kind): the context of
+        a paged layer (every reader sees the same tokens), the tokens inside
         the windows, the rows whose state is updated. The rows that pad the
         bucket are not counted."""
         if self._row_slots is None:
             return {}
         ctx = pos[:n].astype(np.int64) + 1
-        return {"shared_kv_tokens": int(ctx.sum()),
-                "window_tokens": int(np.minimum(ctx, self._window).sum()),
-                "state_rows": n}
+        of_kind = {"paged": int(ctx.sum()),
+                   "window": int(np.minimum(ctx, self._window).sum()),
+                   "state": n}
+        return {name: of_kind[kind] for name, kind in
+                self._arch["cache"].get("span_attrs", {}).items()}
 
     def _landing_span(self, fl: _Flight, ahead: int = 0, **attrs):
         """The ``decode_step`` span a step lands in, with the attributes

@@ -424,6 +424,129 @@ def test_xing4_prefill_program_compiles(v5e, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 2.0e9
 
 
+# -- the gated-convolution / routed-expert hybrid (PR 38) at its published widths:
+# d 2048, 32 query heads on 8 K/V heads of 64 laid two a 128-lane line (4
+# lines a token), 64 experts of width 1536 stacked over the two scanned turns
+def test_packed_kv_read_compiles(v5e):
+    """The block-table read over the new pool layout: a block is one slab of
+    (token, PAIR of K/V heads) lines (16 x 4 of 128), 32 queries padded to
+    128 and scored at 1 / sqrt(64), the layer a traced scalar."""
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    B, MB, H, pairs, D, BS, NB = 64, 128, 32, 4, 128, 16, 56000
+    pool = _on(s, (2, NB, BS * pairs, D), bf)
+    n = _compile_for_tpu(
+        lambda q, k, v, layer, tables, pos: paged_attention_rows(
+            q, k, v, layer, tables, pos, interpret=False, scale=64 ** -0.5,
+            kv_heads=pairs),
+        _on(s, (B, H, D), bf), pool, pool, _on(s, (), jnp.int32),
+        _on(s, (B, MB), jnp.int32), _on(s, (B,), jnp.int32))
+    assert n == 1
+
+
+@pytest.mark.parametrize("N,room", [pytest.param(64, 16e6, id="decode_64_rows"),
+                                    pytest.param(8192, 420e6, id="prefill_4x2048")])
+def test_moe_experts_reads_its_layer_out_of_the_stack(v5e, N, room):
+    """``moe_experts(layer=...)``: the stacks of two layers' experts go to the
+    kernel whole and the layer is a traced scalar; no slice of a stack (403
+    MB a matrix, three of them) is among the temporaries: a decode call has
+    4 MB, a prefill call its own tiled rows in and out (2 x 192 tiles x 256
+    rows x 2048 in bfloat16 = 403 MB, whatever the weights)."""
+    from paddle_tpu.ops.kernels.moe_experts import moe_experts
+
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    fn = jax.jit(lambda x, slot, g, wg, wu, wd, layer: moe_experts(
+        x, slot, g, wg, wu, wd, interpret=False, layer=layer))
+    lowered = fn.trace(
+        _on(s, (N, 2048), bf), _on(s, (N, 4), jnp.int32), _on(s, (N, 4), jnp.float32),
+        _on(s, (2, 64, 2048, 1536), bf), _on(s, (2, 64, 2048, 1536), bf),
+        _on(s, (2, 64, 1536, 2048), bf), _on(s, (), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))
+    compiled = _compile_uncached(lowered)
+    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert compiled.memory_analysis().temp_size_in_bytes < room
+
+
+def _lfm2_operands(s, nb):
+    """(arch, params as shapes, the pools) of the benchmark's configuration."""
+    import json
+    import pathlib
+
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.models import lfm2_moe as L
+
+    path = pathlib.Path(__file__).parent.parent / "benchmark/configs/lfm2-24b-a2b-10l.json"
+    cfg = L.Lfm2MoeConfig.from_dict(json.loads(path.read_text()))
+    sd = {k: _on(s, shape, jnp.bfloat16)
+          for k, shape, _ in L.Lfm2MoeForCausalLM.parameter_specs(cfg)}
+    params = jax.tree_util.tree_map(
+        lambda x: _on(s, x.shape, x.dtype),
+        jax.eval_shape(lambda sd: L.params_tree(cfg, sd), sd))
+    arch = G._lfm2_moe_arch(cfg, True)
+    pools = [_on(s, shape, dtype or jnp.bfloat16)
+             for _, shape, dtype in G.cache_pools(arch, 0, nb, 16, 64)]
+    return arch, params, pools
+
+
+def _lfm2_kernels(monkeypatch):
+    from paddle_tpu.ops.kernels import moe_experts, paged_attention
+
+    for mod in (moe_experts, paged_attention):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+
+
+def test_lfm2_decode_step_beside_a_full_pool(v5e, monkeypatch):
+    """The 64-row decode program of the cell's configuration, whole (10.5 GB
+    of weights) beside the pool that fills what they leave, as the engine
+    jits it: ONE period of the layer pattern in the scan (4 expert calls and
+    1 block-table read, whatever the depth), the three donated pools updated
+    in place, and temporaries that follow neither the pool nor the experts
+    (4.9 MB when written)."""
+    import paddle_tpu.models.generation as G
+
+    _lfm2_kernels(monkeypatch)
+    s, B, MB, NB = SingleDeviceSharding(v5e[0]), 64, 128, 56000
+    arch, params, pools = _lfm2_operands(s, NB)
+    assert [p.shape for p in pools] == [(2, NB, 64, 128), (2, NB, 64, 128),
+                                        (8, 65, 2, 2048)]
+    assert G.paged_kernel_default(arch, mosaic=True) and G.cache_slots(arch)
+    step = jax.jit(G.feed_tokens_back(G.build_paged_decode_kernel(arch, B, 16, MB),
+                                      B, 64, MB, 3, slots=True),
+                   donate_argnums=(1, 2, 3))
+    compiled = _compile_uncached(step.trace(
+        params, *pools, _on(s, (B, MB + G.STEP_COLS + 1), jnp.int32),
+        _on(s, (64,), jnp.int32), _on(s, (2,), jnp.uint32),
+    ).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 4 + 1
+    for name in ("moe_experts_t16", "paged_attention"):
+        assert f"%{name}" in text, name
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * NB * 64 * 128 * 2
+    assert mem.temp_size_in_bytes < 64e6, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
+def test_lfm2_prefill_program_compiles(v5e, monkeypatch):
+    """The widest prefill program of the cell (4 prompts x 2,048 positions):
+    attention a K/V group at a time, experts in 256-row tiles, each pool
+    written by ONE scatter after the scan (aliased, no copy of a pool), and
+    temporaries inside the 2.5 GiB the pool's sizing leaves (636 MB when
+    written)."""
+    import paddle_tpu.models.generation as G
+
+    _lfm2_kernels(monkeypatch)
+    s, NB = SingleDeviceSharding(v5e[0]), 56000
+    arch, params, pools = _lfm2_operands(s, NB)
+    pre = jax.jit(G.build_paged_prefill(arch, 4, 2048, 16, 128), donate_argnums=(5, 6, 7))
+    compiled = _compile_uncached(pre.trace(
+        params, _on(s, (4, 2048), jnp.int32), _on(s, (4,), jnp.int32),
+        _on(s, (4, 128), jnp.int32), _on(s, (4,), jnp.int32), *pools,
+    ).lower(lowering_platforms=("tpu",)))
+    assert "%moe_experts_t256" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * 2 * NB * 64 * 128 * 2
+    assert mem.temp_size_in_bytes < 1.5e9, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
 # -- the hybrid step's collectives (PR 30, PR 36) ----------------------------
 def _hybrid_step(v5e, monkeypatch, layers=2):
     """The four-chip cell's step at ``layers`` layers and its width of 4096,
