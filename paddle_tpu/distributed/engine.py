@@ -28,6 +28,14 @@ formulation: ONE compiled XLA program per train step —
    the product comes out split on head boundaries and no activation is
    gathered); ``mp_weight_exchanges`` and ``mp_activation_gathers``
    (``mp_exchange_counts``) say from the same text whether it engaged;
+ * and where 'mp' joins two chips the sums of partial products over it (a
+   row-parallel product forward, a column-parallel product's input cotangent
+   backward: four a layer) cross by ``ppermute`` in blocks of tokens under
+   the products that make them (``mp_layers.product_summed``), where GSPMD's
+   all-reduce stood alone on the chip's line: ``mp_reduce_exchanges``,
+   ``mp_reduce_async`` and ``mp_activation_reduces`` (the same function, the
+   same pass over the text) say how many blocks cross, how many of them
+   beside compute, and how many token-shaped all-reduces are left;
  * any other mesh ('sp', 'pp', a ZeRO 'sharding' axis), gradient
    accumulation and non-elementwise optimizers keep the replicated GSPMD
    step; optimizer state sharded over the ZeRO axis makes its weight update
@@ -55,7 +63,7 @@ from ..core.tensor import Tensor
 from .fleet.meta_optimizers.hybrid_parallel_optimizer import (
     DP_REDUCE_SCOPE, ShardedWeightUpdate,
 )
-from .fleet.meta_parallel.mp_layers import MP_EXCHANGE_SCOPE
+from .fleet.meta_parallel.mp_layers import MP_EXCHANGE_SCOPE, MP_REDUCE_SCOPE
 from .mesh import global_mesh, partitioned_over
 
 
@@ -495,21 +503,22 @@ class HybridParallelEngine:
             from .. import profiler
 
             have, said = profiler.counters(), self._dp_reduce
-            # states, not sums: what the step that runs is, not how often
+
+            def _counter(name):
+                # states, not sums: what the step that runs is, not how
+                # often (named one by one: ``paddle_tpu.analysis`` reads the
+                # literals against ``KNOWN_COUNTERS``)
+                if name in said:
+                    profiler.counter_inc(name, said[name] - have.get(name, 0))
+
             profiler.counter_inc("wus_enabled", 1 - have.get("wus_enabled", 0))
-            profiler.counter_inc(
-                "dp_reduce_leaves",
-                said["dp_reduce_leaves"] - have.get("dp_reduce_leaves", 0))
-            profiler.counter_inc(
-                "dp_reduce_async",
-                said["dp_reduce_async"] - have.get("dp_reduce_async", 0))
-            if "mp_weight_exchanges" in said:
-                profiler.counter_inc(
-                    "mp_weight_exchanges",
-                    said["mp_weight_exchanges"] - have.get("mp_weight_exchanges", 0))
-                profiler.counter_inc(
-                    "mp_activation_gathers",
-                    said["mp_activation_gathers"] - have.get("mp_activation_gathers", 0))
+            _counter("dp_reduce_leaves")
+            _counter("dp_reduce_async")
+            _counter("mp_weight_exchanges")
+            _counter("mp_activation_gathers")
+            _counter("mp_reduce_exchanges")
+            _counter("mp_reduce_async")
+            _counter("mp_activation_reduces")
             for k, v in self._wus.step_counters().items():
                 profiler.counter_inc(k, v)
             sp.set(**said)
@@ -814,21 +823,35 @@ def dp_reduce_counts(text: str) -> dict:
 
 
 def mp_exchange_counts(text: str, mp_groups: set, tokens: tuple) -> dict:
-    """What the same text says of the fused projections under 'mp'
-    (``mp_layers.linear_on_groups``): ``mp_weight_exchanges``, the
-    collective-permutes traced under ``MP_EXCHANGE_SCOPE`` that run beside
-    compute (three a layer where the exchange engages: forward, again for
-    the backward pass, and the weight's cotangent), and
+    """What the same text says of the products under 'mp'. Of the fused
+    projections (``mp_layers.linear_on_groups``): ``mp_weight_exchanges``,
+    the collective-permutes traced under ``MP_EXCHANGE_SCOPE`` that run
+    beside compute (three a layer where the exchange engages: forward, again
+    for the backward pass, and the weight's cotangent), and
     ``mp_activation_gathers``, the all-gathers over ``mp_groups`` (the sets
     of chips that differ along 'mp' alone) whose result leads with
     ``tokens``, a replica's batch and sequence: the reshard of Q, K and V
-    that the exchange is there to remove."""
+    that the exchange is there to remove. Of the sums of partial products
+    (``mp_layers.product_summed``): ``mp_reduce_exchanges``, the
+    collective-permutes traced under ``MP_REDUCE_SCOPE`` (a block of a
+    partial product each: four sites a layer, times the blocks),
+    ``mp_reduce_async``, those of them that run beside compute, and
+    ``mp_activation_reduces``, the all-reduces over ``mp_groups`` whose result
+    leads with ``tokens``, which they are there to remove (four a layer
+    without them; the embedding's one a step stays)."""
     lead = "[" + ",".join(str(n) for n in tokens) + ","
     found = collectives(text)
+    summed = [c for c in found if c.op == "collective-permute"
+              and c.under(MP_REDUCE_SCOPE)]
     return {
         "mp_weight_exchanges": sum(
             1 for c in found if c.op == "collective-permute" and c.hidden
             and c.under(MP_EXCHANGE_SCOPE)),
         "mp_activation_gathers": sum(
             1 for c in found if c.op == "all-gather" and lead in c.shape
+            and c.over() == mp_groups),
+        "mp_reduce_exchanges": len(summed),
+        "mp_reduce_async": sum(1 for c in summed if c.hidden),
+        "mp_activation_reduces": sum(
+            1 for c in found if c.op == "all-reduce" and lead in c.shape
             and c.over() == mp_groups)}

@@ -19,7 +19,7 @@ from ..core.dispatch import as_tensor, eager_call
 from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..distributed.fleet.meta_parallel.mp_layers import (
-    ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
+    ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding, products_of,
 )
 
 
@@ -103,9 +103,9 @@ class LlamaAttention(nn.Layer):
 
     def forward(self, x, attn_mask=None):
         B, T = x.shape[0], x.shape[1]
-        q = self.q_proj(x)
-        k = self.k_proj(x)
-        v = self.v_proj(x)
+        # one input, three products: under 'mp' their input cotangents are
+        # added before they cross the axis once
+        q, k, v = products_of(x, self.q_proj, self.k_proj, self.v_proj)
         lh = q.shape[-1] // self.head_dim
         lkv = k.shape[-1] // self.head_dim
         q = q.reshape([B, T, lh, self.head_dim])
@@ -130,7 +130,8 @@ class LlamaMLP(nn.Layer):
         self.down_proj = RowParallelLinear(f, h, has_bias=False, input_is_parallel=True)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        gate, up = products_of(x, self.gate_proj, self.up_proj)
+        return self.down_proj(F.silu(gate) * up)
 
 
 class LlamaDecoderLayer(nn.Layer):

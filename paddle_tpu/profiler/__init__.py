@@ -119,7 +119,11 @@ def counters() -> Dict[str, int]:
     read once from the executable's scheduled text), and beside 'mp'
     ``mp_weight_exchanges`` / ``mp_activation_gathers`` from the same text
     (the fused QKV weight's transfers over 'mp' that run beside compute, and
-    the all-gathers of token-shaped data over 'mp' they are there to remove).
+    the all-gathers of token-shaped data over 'mp' they are there to remove)
+    and ``mp_reduce_exchanges`` / ``mp_reduce_async`` /
+    ``mp_activation_reduces`` (the blocks of partial products exchanged over
+    'mp' by ``ppermute``, those of them that run beside compute, and the
+    token-shaped all-reduces over 'mp' they are there to remove).
 
     Serving engine (paddle_tpu/serving/): ``serve_requests`` /
     ``serve_admitted`` / ``serve_retired`` / ``serve_cancelled`` /
@@ -312,7 +316,8 @@ KNOWN_COUNTERS = frozenset({
     "lazy_deferred_checks", "lazy_donated_buffers",
     "lazy_donation_fallbacks", "lazy_eager_replay_fallbacks",
     "lazy_flushes", "lazy_verify_passes",
-    "mp_activation_gathers", "mp_weight_exchanges",
+    "mp_activation_gathers", "mp_activation_reduces", "mp_reduce_async",
+    "mp_reduce_exchanges", "mp_weight_exchanges",
     "naninf_donation_suppressed", "naninf_trips",
     "preemption_drains", "retry_attempts",
     "serve_admitted", "serve_adoptions", "serve_backpressure",

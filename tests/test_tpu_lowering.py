@@ -659,17 +659,77 @@ def test_hybrid_step_exchanges_the_qkv_weight_and_gathers_no_activation(v5e, mon
     gathered = [c for c in found if c.op == "all-gather" and c.over() == MP_PAIRS
                 and "[2,2048," in c.shape]
     assert not gathered, gathered
-    sent = [c for c in found if c.op == "collective-permute" and c.over() == MP_PAIRS]
+    sent = [c for c in found if c.op == "collective-permute" and c.over() == MP_PAIRS
+            and c.under(mp_layers.MP_EXCHANGE_SCOPE)]
     # a third of a chip's columns and of its bias in one transfer
     assert len(sent) == 2 * 3 and all(c.shape.startswith("(bf16[4097,2048]") for c in sent)
     assert all(c.under(mp_layers.MP_EXCHANGE_SCOPE) and c.hidden for c in sent)
-    assert mp_exchange_counts(text, MP_PAIRS, (2, 2048)) == {
-        "mp_weight_exchanges": 2 * 3, "mp_activation_gathers": 0}
+    said = mp_exchange_counts(text, MP_PAIRS, (2, 2048))
+    assert (said["mp_weight_exchanges"], said["mp_activation_gathers"]) == (2 * 3, 0)
     counts = dp_reduce_counts(text)
     assert counts["dp_reduce_async"] == counts["dp_reduce_leaves"] > 9
 
+    # the step before PR 36: neither exchange (how many gathers GSPMD emits
+    # moves with the program around them; with PR 39's sums it reads 4)
     monkeypatch.setattr(mp_layers, "groups_axis", lambda *a, **k: None)
+    monkeypatch.setattr(mp_layers, "reduce_axis", lambda *a, **k: None)
     _, without = _hybrid_step(v5e, monkeypatch)
-    assert mp_exchange_counts(without.as_text(), MP_PAIRS, (2, 2048)) == {
-        "mp_weight_exchanges": 0, "mp_activation_gathers": 3}
+    said = mp_exchange_counts(without.as_text(), MP_PAIRS, (2, 2048))
+    assert (said["mp_weight_exchanges"], said["mp_activation_gathers"]) == (0, 3)
+    assert total(compiled) <= total(without) + 40e6
+
+
+def test_hybrid_step_sums_its_partial_products_by_exchange(v5e, monkeypatch):
+    """The same step (PR 39): the sum over the 'mp' pair of a row-parallel
+    product (``attn.proj``, ``mlp.down``) and of a column-parallel product's
+    input cotangent (``attn.qkv``, ``mlp.up``) is no all-reduce of
+    ``[2,2048,4096]`` alone on the chip's line (the parent held four a layer)
+    but an exchange of the two partials in four blocks of tokens, each a
+    collective-permute start/done pair. The blocks are tied so that block
+    k's transfer lies under block k + 1's product, and at every site at most
+    ONE block is left with no compute between its start and done: the last,
+    which has what independent work the scheduler finds (the next layer's
+    weight exchange, the weight's cotangent), or the third where a late pass
+    has moved the last block's product to the front (3 of 32 here, 49 of 256
+    at the cell's 16 layers). The one token-shaped all-reduce left is the
+    embedding's, once a step. The gradient reduces and the QKV weight's exchange stay beside
+    compute, and the step takes no more memory than with the mechanism
+    declined."""
+    from paddle_tpu.distributed.engine import (
+        collectives, dp_reduce_counts, mp_exchange_counts,
+    )
+    from paddle_tpu.distributed.fleet.meta_parallel import mp_layers
+
+    def total(compiled):
+        m = compiled.memory_analysis()
+        return (m.argument_size_in_bytes + m.output_size_in_bytes
+                - m.alias_size_in_bytes + m.temp_size_in_bytes
+                + m.generated_code_size_in_bytes)
+
+    layers, sites, blocks = 2, 4, mp_layers.MP_REDUCE_CHUNKS
+    eng, compiled = _hybrid_step(v5e, monkeypatch, layers)
+    text = compiled.as_text()
+    found = collectives(text)
+    left = [c for c in found if c.op == "all-reduce" and c.over() == MP_PAIRS
+            and "[2,2048," in c.shape]
+    assert len(left) == 1 and left[0].under("jit(embedding)"), left
+    sent = [c for c in found if c.op == "collective-permute"
+            and c.under(mp_layers.MP_REDUCE_SCOPE)]
+    assert len(sent) == sites * layers * blocks
+    assert all(c.over() == MP_PAIRS and c.shape.startswith("(bf16[1,1024,4096]")
+               for c in sent)
+    said = mp_exchange_counts(text, MP_PAIRS, (2, 2048))
+    assert said["mp_reduce_exchanges"] == len(sent)
+    assert said["mp_activation_reduces"] == 1
+    # every block but, at most, one of a site
+    assert said["mp_reduce_async"] >= sites * layers * (blocks - 1)
+    assert (said["mp_weight_exchanges"], said["mp_activation_gathers"]) == (2 * 3, 0)
+    counts = dp_reduce_counts(text)
+    assert counts["dp_reduce_async"] == counts["dp_reduce_leaves"] > 9
+
+    monkeypatch.setattr(mp_layers, "reduce_axis", lambda *a, **k: None)
+    _, without = _hybrid_step(v5e, monkeypatch, layers)
+    said = mp_exchange_counts(without.as_text(), MP_PAIRS, (2, 2048))
+    assert said["mp_reduce_exchanges"] == 0
+    assert said["mp_activation_reduces"] == sites * layers + 1
     assert total(compiled) <= total(without) + 40e6
