@@ -11,6 +11,10 @@ Top-level namespace mirrors `import paddle`.
 """
 from __future__ import annotations
 
+import time as _time
+
+_t_import = _time.perf_counter_ns()  # ``setup_import_ns``: to the last line
+
 # Paddle semantics: int64 indices/labels, explicit float management. JAX's
 # x64-off mode silently truncates to int32, so enable it; every float path in
 # this package passes dtypes explicitly (default float32 / bf16 on MXU).
@@ -183,3 +187,5 @@ from .core.compat import enable_persistent_compilation_cache as _enable_pcc  # n
 _enable_pcc()
 
 __version__ = "0.1.0"
+
+profiler.counter_inc("setup_import_ns", _time.perf_counter_ns() - _t_import)

@@ -95,8 +95,6 @@ def _fast_fn_key(fn):
                     if len(_code_key_cache) > _JIT_CACHE_MAX:
                         _code_key_cache.clear()  # exec/notebook-generated code objects
                     _code_key_cache[code] = k
-                elif _profiler is not None and _profiler._enabled:
-                    _profiler.counter_inc("dispatch_fastkey_hits")
                 return k
             # Call-site memo, scalar-closure shape (the common op lambda
             # `lambda *xs: fn(*xs, attr=v)` closing over attr values): build

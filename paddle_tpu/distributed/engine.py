@@ -253,6 +253,13 @@ class HybridParallelEngine:
         return accum_step_fn
 
     def _build(self):
+        from ..profiler import spans as _spans
+
+        with _spans.kept_span("program_build", kind="train_step") as sp:
+            self._build_step()
+            sp.set(wus=self._wus is not None)
+
+    def _build_step(self):
         opt, params = self.optimizer, self.params
         make_loss_of = self._make_loss_of()
 
@@ -543,9 +550,7 @@ class HybridParallelEngine:
         sig = tuple((a.shape, str(a.dtype)) for a in args[2])
         exe = self._compiled.get(sig)
         if exe is None:
-            exe = self._compiled[sig] = self._jit.lower(*args).compile(
-                self.step_compiler_options())
-            self._dp_reduce = self._step_counts(exe.as_text(), args[2][0].shape)
+            exe = self._compiled[sig] = self._compile_step(args)
         try:
             return exe(*args)
         except ValueError:
@@ -553,6 +558,26 @@ class HybridParallelEngine:
             # parameter restored from a checkpoint onto one device): where
             # jit would compile a second program, put it where it belongs
             return exe(*jax.device_put(args, exe.input_shardings[0]))
+
+    def _compile_step(self, args):
+        """The dp step compiled ahead of time for one batch signature, each
+        stage under a span of its own so that the set-up account tells them
+        apart: ``step_lower`` (trace and lowering), ``step_compile`` (the
+        backend, or the load from the persistent cache) and ``step_text``
+        (kept whether or not anything compiles under it: the scheduled text
+        written out and read for the collectives' counts, with its size and
+        what it yielded)."""
+        from ..profiler import spans as _spans
+
+        with _spans.span("step_lower"):
+            lowered = self._jit.lower(*args)
+        with _spans.span("step_compile"):
+            exe = lowered.compile(self.step_compiler_options())
+        with _spans.kept_span("step_text") as sp:
+            text = exe.as_text()
+            self._dp_reduce = self._step_counts(text, args[2][0].shape)
+            sp.set(text_bytes=len(text), **self._dp_reduce)
+        return exe
 
     def _step_counts(self, text, batch_shape) -> dict:
         """What the compiled dp step's scheduled text says of its collectives

@@ -221,8 +221,10 @@ def attributions(top: int = 16) -> List[dict]:
 
 
 def last_prediction() -> Dict[str, int]:
-    """Most recent preflight numbers (predicted/live/budget bytes) — folded
-    into every BENCH JSON line."""
+    """Most recent preflight numbers (predicted/live/budget bytes): the
+    serving engine's cost-drift gauge reads the predicted peak against the
+    census after a step (``Engine._hbm_drift``), and a flight-recorder
+    dump carries them whole (this module's context provider)."""
     return dict(_last)
 
 

@@ -345,7 +345,8 @@ _last_signals: Dict[str, float] = {}  # most recent judged signals (any sentinel
 
 def last_signals() -> Dict[str, float]:
     """The most recently judged signal values across all sentinels (plus
-    ``loss_ema``) — folded into every BENCH JSON line."""
+    ``loss_ema``): what the ``stability`` context of a flight-recorder dump
+    carries as ``last_signals``."""
     return dict(_last_signals)
 
 

@@ -276,6 +276,12 @@ class CompiledTrainStep:
         self._donate = donate
 
     def _build(self):
+        from ..profiler import spans as _spans
+
+        with _spans.kept_span("program_build", kind="train_step"):
+            self._build_step()
+
+    def _build_step(self):
         model, loss_fn, optimizer = self.model, self.loss_fn, self.optimizer
         params, buffers = self.params, self.buffers
         opt = optimizer

@@ -7,12 +7,14 @@ names, train/eval mode, forward hooks, apply, to().
 from __future__ import annotations
 
 import collections
+import time
 from typing import Callable, Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
 from ...core import dtype as dtypes
 from ...core.tensor import Parameter, Tensor
+from ...profiler import counter_inc
 from .. import initializer as init_mod
 
 
@@ -68,7 +70,13 @@ class Layer:
             initializer = default_initializer or (
                 init_mod._default_bias_init if is_bias else init_mod._default_weight_init
             )
+        t0 = time.perf_counter_ns()
         data = initializer(shape, dtype)
+        # what the constructors draw, in seconds of host time and bytes: a
+        # model that is then handed its weights drew them to be thrown away
+        counter_inc("param_init_ns", time.perf_counter_ns() - t0)
+        counter_inc("param_init_bytes", int(getattr(data, "nbytes", 0)))
+        counter_inc("param_init_leaves")
         p = Parameter(data, name=name, trainable=trainable)
         p.optimize_attr["learning_rate"] = learning_rate
         return p

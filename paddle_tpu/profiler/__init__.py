@@ -17,6 +17,10 @@ Layers (each usable alone):
   (``train_step`` → ``lazy_flush`` → ``trace``/``donate``/``compile``/
   ``execute``; ``dp_sync`` → per-bucket; ``ckpt_save`` →
   ``serialize``/``commit``) recorded while a :class:`Profiler` runs;
+* **set-up account** (:func:`setup_account`) — always on: the spans that
+  compiled, with their stages, and the few that build a process
+  (``engine_init``, ``program_build``, ``step_text``), kept in one bounded
+  table and made into rows when read: what a cold start cost, by program;
 * **flight recorder** (:mod:`.flight`) — always-on bounded ring of the last
   N spans + a JSON post-mortem dump on NaN trips, preemption drains,
   checkpoint-save failure, or an uncaught training-loop exception;
@@ -82,9 +86,7 @@ def counters() -> Dict[str, int]:
     without donation after XLA refused it) and
     ``lazy_eager_replay_fallbacks`` (a flush whose executable failed and was
     replayed op by op, un-jitted — correct but unfused; zero on a healthy
-    run);
-    ``dispatch_fastkey_hits`` is per-op and only counted while the profiler
-    is running, to keep the dispatch hot path free of bookkeeping.
+    run).
 
     Async runtime (FLAGS_lazy_async): ``lazy_blocks`` / ``lazy_block_ns``
     (attributed host waits on the device: the dispatch gap a step),
@@ -135,16 +137,15 @@ def counters() -> Dict[str, int]:
     exhaustion), ``serve_preempted`` (sequences evicted for re-prefill),
     ``serve_occupancy_live`` / ``serve_occupancy_slots`` (live rows vs
     padded batch slots per decode step — their ratio is mean batch
-    occupancy), ``serve_experts_touched`` / ``serve_expert_assignments``
-    (a routed-expert arch: distinct experts the live tokens of a program hit,
-    summed over its expert layers, and the (token, choice) pairs it routed;
-    padding counts in neither; the table by layer and expert is
-    ``Engine.stats()["expert_tokens"]``),
-    ``serve_state_slots_taken`` / ``serve_state_rebuilds`` /
-    ``serve_state_rows`` (an arch whose layers keep a window, a recurrent
-    state or a convolution's last inputs a row: row slots granted at
-    admission, evictions that cost a re-prefill of that state, and the live
-    rows whose state the decode steps updated),
+    occupancy), ``serve_expert_assignments`` (a routed-expert arch: the
+    (token, choice) pairs the programs routed, padding not counted; the
+    distinct experts a step hit are its span's ``experts_touched``, the
+    table by layer and expert is ``Engine.stats()["expert_tokens"]``),
+    ``serve_state_rebuilds`` / ``serve_state_rows`` (an arch whose layers
+    keep a window, a recurrent state or a convolution's last inputs a row:
+    evictions that cost a re-prefill of that state, and the live rows whose
+    state the decode steps updated; the slots held are
+    ``Engine.stats()["state_slots_used"]``),
     ``serve_decode_ahead`` (decode steps enqueued while the step before was
     still unread on the device: over ``serve_decode_steps``, how often the
     loop ran one step ahead of the host), ``serve_decode_drains`` (landings
@@ -207,14 +208,11 @@ def counters() -> Dict[str, int]:
     / ``serve_prefix_misses`` (admissions that found / missed a cached
     prompt prefix), ``serve_prefix_blocks_shared`` (KV blocks adopted from
     the cache instead of re-prefilled), ``serve_prefix_evicted`` (cached
-    prefixes dropped by the LRU bound), ``serve_pages_shared`` (blocks
-    holding refcount > 1 at share time), and ``serve_cow_copies``
+    prefixes dropped by the LRU bound), and ``serve_cow_copies``
     (copy-on-write block duplications when a shared block is written).
 
     Chunked prefill (FLAGS_serve_prefill_chunk): ``serve_prefill_chunks``
-    (prompt chunks executed through the chunk bucket) and
-    ``serve_tail_prefills`` (final partial chunks landed through the
-    ordinary prefill path).
+    (prompt chunks executed through the chunk bucket).
 
     Speculative decoding (FLAGS_serve_spec_k): ``serve_draft_proposed``
     / ``serve_draft_accepted`` (draft tokens proposed vs accepted by the
@@ -234,8 +232,7 @@ def counters() -> Dict[str, int]:
     time).
 
     Serving observability (this round): ``serve_trace_evicted`` (completed
-    request timelines dropped from the bounded trace ring),
-    ``serve_http_requests`` (telemetry endpoint GETs served), and
+    request timelines dropped from the bounded trace ring) and
     ``serve_http_bind_failed`` (endpoint start-ups that lost the port —
     telemetry never takes serving down).
 
@@ -268,7 +265,19 @@ def counters() -> Dict[str, int]:
     persistent cache is a backend stage) and ``compile_cache_hits``
     (programs the persistent compilation cache served). The innermost open
     span carries the same as ``compile_trace_s`` / ``compile_lower_s`` /
-    ``compile_backend_s`` / ``compile_cache_hits`` attributes.
+    ``compile_backend_s`` / ``compile_cache_hits`` attributes, and
+    ``compile_cache_misses`` beside them (programs compiled and WRITTEN to
+    the persistent cache: 0 in a warm process; a checkout at a new path, a
+    new jax or a new shape reads otherwise).
+
+    Set-up (:func:`setup_account` keeps the spans themselves):
+    ``setup_import_ns`` (the first to the last line of
+    ``paddle_tpu/__init__.py``, ``import jax`` inside it when the package is
+    the first to import it), ``param_init_ns`` / ``param_init_bytes`` /
+    ``param_init_leaves`` (the initializer calls of
+    ``Layer.create_parameter``: host time, bytes and arrays drawn; a model
+    that is then given its weights drew them to be thrown away), and
+    ``setup_account_dropped`` (spans the bounded account did not keep).
 
     Telemetry: ``flight_dumps`` (flight-recorder post-mortems written by
     this process).
@@ -290,9 +299,8 @@ def counters() -> Dict[str, int]:
 KNOWN_COUNTERS = frozenset({
     "ckpt_coordinated_commits", "ckpt_resume_fallbacks",
     "ckpt_save_failures", "ckpt_saves",
-    "compile_backend_ns", "compile_cache_hits", "compile_lower_ns",
-    "compile_trace_ns",
-    "dispatch_fastkey_hits",
+    "compile_backend_ns", "compile_cache_hits", "compile_cache_misses",
+    "compile_lower_ns", "compile_trace_ns",
     "dp_all_reduces", "dp_buckets", "dp_gather_bytes",
     "dp_reduce_async", "dp_reduce_leaves",
     "dp_reduce_scatters", "dp_sync_bytes",
@@ -319,6 +327,7 @@ KNOWN_COUNTERS = frozenset({
     "mp_activation_gathers", "mp_activation_reduces", "mp_reduce_async",
     "mp_reduce_exchanges", "mp_weight_exchanges",
     "naninf_donation_suppressed", "naninf_trips",
+    "param_init_bytes", "param_init_leaves", "param_init_ns",
     "preemption_drains", "retry_attempts",
     "serve_admitted", "serve_adoptions", "serve_backpressure",
     "serve_cancelled", "serve_compiles", "serve_cow_copies",
@@ -327,11 +336,10 @@ KNOWN_COUNTERS = frozenset({
     "serve_decode_drains", "serve_decode_steps", "serve_decode_wasted_rows",
     "serve_draft_accepted", "serve_draft_proposed",
     "serve_engine_errors", "serve_expert_assignments",
-    "serve_experts_touched", "serve_failed", "serve_handoffs",
-    "serve_http_bind_failed", "serve_http_requests",
+    "serve_failed", "serve_handoffs", "serve_http_bind_failed",
     "serve_occupancy_live", "serve_occupancy_slots",
     "serve_pages_allocated", "serve_pages_freed", "serve_pages_parked",
-    "serve_pages_shared", "serve_pages_unparked", "serve_pool_damaged",
+    "serve_pages_unparked", "serve_pool_damaged",
     "serve_pool_restores", "serve_pool_shrunk", "serve_preempted",
     "serve_prefill_chunks", "serve_prefills",
     "serve_prefix_blocks_shared", "serve_prefix_evicted",
@@ -342,9 +350,9 @@ KNOWN_COUNTERS = frozenset({
     "serve_restarts", "serve_retired", "serve_shed",
     "serve_snapshot_failed", "serve_snapshot_rejected",
     "serve_snapshots", "serve_state_rebuilds", "serve_state_rows",
-    "serve_state_slots_taken",
-    "serve_tail_prefills", "serve_tokens",
+    "serve_tokens",
     "serve_trace_evicted", "serve_wedge_detected", "serve_wedged_close",
+    "setup_account_dropped", "setup_import_ns",
     "stability_barrier_timeouts", "stability_coordinated_trips",
     "stability_halts", "stability_observed", "stability_readbacks",
     "stability_rollbacks", "stability_skips", "stability_trips",
@@ -353,7 +361,12 @@ KNOWN_COUNTERS = frozenset({
 
 
 def reset_counters():
+    """Clear the counters. ``setup_import_ns`` stays: it is stamped once, as
+    the package is imported, and nothing can count it again."""
+    stamp = _counters.get("setup_import_ns")
     _counters.clear()
+    if stamp is not None:
+        _counters["setup_import_ns"] = stamp
 
 
 # -- memory accounting --------------------------------------------------------
@@ -700,7 +713,7 @@ def profiler_guard(**kwargs):
 # AFTER those definitions.
 from . import flight  # noqa: E402,F401
 from . import spans  # noqa: E402,F401
-from .spans import span  # noqa: E402,F401
+from .spans import kept_span, setup_account, span  # noqa: E402,F401
 
 # Compilation is charged to the program span it fired under (spans.py). The
 # listeners do work only when jax reports a compile stage.

@@ -29,6 +29,8 @@ host plane of the xplane file, on the clock of the device operations, with
 their scalar attributes as the event's stats. And compilation is charged to
 the span it fired under (:func:`_on_compile_duration`): a ``decode_step`` or
 ``train_step`` that carries ``compile_backend_s`` is a step that compiled.
+Those spans, and the few that build a process (:func:`kept_span`), are what
+:func:`setup_account` keeps: the cold start, by program.
 """
 from __future__ import annotations
 
@@ -38,8 +40,9 @@ import threading
 import time
 from typing import Dict, List, Optional
 
-__all__ = ["Span", "span", "current_span", "active_spans",
-           "add_span_observer", "remove_span_observer"]
+__all__ = ["Span", "span", "kept_span", "setup_account", "account_row",
+           "current_span", "active_spans", "add_span_observer",
+           "remove_span_observer"]
 
 _ids = itertools.count(1)  # GIL-atomic enough; 0 means "no parent"
 _tls = threading.local()
@@ -185,6 +188,23 @@ def span(name: str, **attrs) -> Span:
     return Span(name, **attrs)
 
 
+class _KeptSpan(Span):
+    """A span a set-up site asked the account to keep (:func:`kept_span`):
+    it is in the account already, so the compile listener, which keeps the
+    spans it charges, leaves it alone."""
+
+    __slots__ = ()
+
+
+def kept_span(name: str, **attrs) -> Span:
+    """A :func:`span` that the set-up account keeps whether or not anything
+    compiles under it (``engine_init``, ``program_build``, ``step_text``):
+    for sites that run once a process or once a program, never a step."""
+    sp = _KeptSpan(name, **attrs)
+    _keep(sp)
+    return sp
+
+
 def current_span() -> Optional[Span]:
     st = getattr(_tls, "stack", None)
     return st[-1] if st else None
@@ -206,6 +226,96 @@ _TRACE = "/jax/core/compile/jaxpr_trace_duration"
 _LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 _BACKEND = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_CACHE_LOAD = "/jax/compilation_cache/cache_retrieval_time_sec"
+_CACHE_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+# event -> the span attribute it is charged to; the counters are named one
+# by one where they are bumped (``paddle_tpu.analysis`` reads the literals)
+_STAGE = {_TRACE: "compile_trace_s", _LOWER: "compile_lower_s",
+          _BACKEND: "compile_backend_s", _CACHE_LOAD: "compile_cache_load_s",
+          _CACHE_SAVED: "compile_cache_saved_s"}
+_COUNT = {_CACHE_HIT: "compile_cache_hits", _CACHE_MISS: "compile_cache_misses"}
+_CHARGES = frozenset(_STAGE.values()) | frozenset(_COUNT.values())
+
+# -- the set-up account --------------------------------------------------------
+# The Span objects that compiled (noted by the listeners below at their first
+# charge) and the few a set-up site asked for by name (``kept_span``), in the
+# order they were noted. Rows are computed from them when the account is READ:
+# a span that neither compiles nor is such a site never comes near this list,
+# so a warm step pays nothing for it. Bounded: a server that compiles a new
+# bucket an hour must not grow.
+_KEPT_MAX = 256
+_kept: List["Span"] = []
+_kept_lock = threading.Lock()
+
+
+def _keep(sp: "Span") -> None:
+    with _kept_lock:
+        if len(_kept) < _KEPT_MAX:
+            _kept.append(sp)
+            return
+    sys.modules[__package__].counter_inc("setup_account_dropped")
+
+
+def _charged(sp: "Span") -> None:
+    """Called by the listeners BEFORE they charge ``sp``: its first charge
+    puts it into the account."""
+    if not isinstance(sp, _KeptSpan) and _CHARGES.isdisjoint(sp.attrs):
+        _keep(sp)
+
+
+def account_row(sp: "Span") -> dict:
+    """One row of the set-up account: the span's name, ``site`` (True for a
+    set-up site kept by name, False for a span kept because it compiled), its
+    scalar attributes, its thread and its ends on ``time.perf_counter_ns``,
+    the compile stages charged to it in seconds, the persistent cache's hits
+    and misses under it with what the loads took and saved, and
+    ``first_run_s``, its duration less the three stages (for a span that
+    compiled a program: the program's first run and the step's own work)."""
+    a = sp.attrs
+    stages = [a.get(k, 0.0) for k in
+              ("compile_trace_s", "compile_lower_s", "compile_backend_s")]
+    row = {"name": sp.name, "site": isinstance(sp, _KeptSpan)}
+    for k, v in a.items():
+        if k not in _CHARGES and type(v) in (int, float, str, bool):
+            row.setdefault(k, v)
+    row.update(
+        tid=sp.tid, t0_ns=sp.t0, t1_ns=sp.t1,
+        trace_s=stages[0], lower_s=stages[1], backend_s=stages[2],
+        cache_hits=a.get("compile_cache_hits", 0),
+        cache_misses=a.get("compile_cache_misses", 0),
+        cache_load_s=a.get("compile_cache_load_s", 0.0),
+        cache_saved_s=a.get("compile_cache_saved_s", 0.0),
+        first_run_s=max((sp.t1 - sp.t0) / 1e9 - sum(stages), 0.0))
+    return row
+
+
+def setup_account() -> List[dict]:
+    """What this process compiled and what built it, as rows in order of
+    time (:func:`account_row`): every span a compile stage was charged to,
+    once, and the set-up sites kept by name (``engine_init`` with
+    ``pool_alloc`` / ``pack_params`` / ``quantize_params``,
+    ``program_build``, ``step_text``). A row's ``first_run_s`` is its own:
+    what the kept spans inside it took is theirs, so the rows add up. Spans
+    still open are left out. At most 256 spans are kept; counter
+    ``setup_account_dropped`` counts the rest."""
+    with _kept_lock:
+        kept = sorted((sp for sp in _kept if sp.t1), key=lambda sp: sp.t0)
+    rows = [account_row(sp) for sp in kept]
+    for i, (sp, row) in enumerate(zip(kept, rows)):
+        inside, end = 0, sp.t0
+        for other in kept[i + 1:]:
+            if other.t0 >= sp.t1:
+                break
+            if other.tid == sp.tid and other.t0 >= end and other.t1 <= sp.t1:
+                inside, end = inside + other.t1 - other.t0, other.t1
+        row["first_run_s"] = max(row["first_run_s"] - inside / 1e9, 0.0)
+    return rows
+
+
+def _reset_account() -> None:
+    with _kept_lock:
+        _kept.clear()
 
 
 def _own_trace_ns(dur_ns: int, root_t0: int) -> int:
@@ -228,31 +338,39 @@ def _own_trace_ns(dur_ns: int, root_t0: int) -> int:
 
 
 def _on_compile_duration(event: str, duration: float, **_) -> None:
-    if event != _BACKEND and event != _LOWER and event != _TRACE:
+    key = _STAGE.get(event)
+    if key is None:
         return
     st = getattr(_tls, "stack", None)
     if not st:
         return
     pkg, ns = sys.modules[__package__], int(duration * 1e9)
     if event == _BACKEND:  # the compiler itself, or the load of a cached program
-        key = "compile_backend_s"
         pkg.counter_inc("compile_backend_ns", ns)
     elif event == _LOWER:
-        key = "compile_lower_s"
         pkg.counter_inc("compile_lower_ns", ns)
-    else:
-        key, ns = "compile_trace_s", _own_trace_ns(ns, st[0].t0)
+    elif event == _TRACE:
+        ns = _own_trace_ns(ns, st[0].t0)
         pkg.counter_inc("compile_trace_ns", ns)
+    # the cache's own two (what the load took, what it saved: either may be
+    # negative) have no counter: they lie inside the backend stage
+    _charged(st[-1])
     attrs = st[-1].attrs
     attrs[key] = attrs.get(key, 0.0) + ns / 1e9
 
 
 def _on_compile_event(event: str, **_) -> None:
+    key = _COUNT.get(event)
     st = getattr(_tls, "stack", None)
-    if event == _CACHE_HIT and st:
-        attrs = st[-1].attrs
-        attrs["compile_cache_hits"] = attrs.get("compile_cache_hits", 0) + 1
+    if key is None or not st:
+        return
+    _charged(st[-1])
+    attrs = st[-1].attrs
+    attrs[key] = attrs.get(key, 0) + 1
+    if event == _CACHE_HIT:
         sys.modules[__package__].counter_inc("compile_cache_hits")
+    else:  # the program was compiled and written to the cache
+        sys.modules[__package__].counter_inc("compile_cache_misses")
 
 
 # -- session sink ------------------------------------------------------------

@@ -123,7 +123,7 @@ import numpy as np
 from ..fault import inject as _inject
 from ..framework import flags
 from ..profiler import counter_inc, flight
-from ..profiler.spans import span
+from ..profiler.spans import account_row, current_span, kept_span, span
 from .pool import PagePool, SnapshotError, TRASH_BLOCK
 
 __all__ = [
@@ -625,6 +625,12 @@ class Engine:
     """
 
     def __init__(self, model, config: Optional[EngineConfig] = None, **overrides):
+        # ``engine_init``: what a process pays once an engine, in the set-up
+        # account whether or not anything under it compiles
+        with kept_span("engine_init") as sp:
+            self._init(sp, model, config, overrides)
+
+    def _init(self, sp, model, config, overrides):
         import jax
         import jax.numpy as jnp
 
@@ -681,7 +687,8 @@ class Engine:
             from .int8 import attach_int8_head, dequantize_tree, \
                 quantize_params
 
-            self._compute_params = quantize_params(params)
+            with kept_span("quantize_params"):
+                self._compute_params = quantize_params(params)
             if flags.flag("FLAGS_serve_int8_kernel", False):
                 # keep the head's int8 bytes visible to the compiled step so
                 # the decode head runs the weight-only int8_matmul kernel
@@ -699,13 +706,14 @@ class Engine:
             # slice-then-dequantize bitwise dequantize-then-slice), so the
             # engine-side wrapper is retired. FLAGS_serve_int8_kernel is a
             # single-chip head fusion and is ignored under tp.
-            packed, self._tp_vocab = G.tp_pack_params(
-                arch_key, self._compute_params, self._tp)
-            rep_s, shard_s = G.tp_param_shardings(self._tp_mesh)
-            self._compute_params = {
-                "rep": jax.device_put(packed["rep"], rep_s),
-                "shard": jax.device_put(packed["shard"], shard_s),
-            }
+            with kept_span("pack_params", tp=self._tp):
+                packed, self._tp_vocab = G.tp_pack_params(
+                    arch_key, self._compute_params, self._tp)
+                rep_s, shard_s = G.tp_param_shardings(self._tp_mesh)
+                self._compute_params = {
+                    "rep": jax.device_put(packed["rep"], rep_s),
+                    "shard": jax.device_put(packed["shard"], shard_s),
+                }
             self._dequant = None
         self._n_layers = len(params.get("layers", ()))
         self._spec_k = int(cfg.spec_k)
@@ -723,9 +731,15 @@ class Engine:
         # kind over every layer unless the arch says what each layer caches
         pools = G.cache_pools(arch, self._n_layers, cfg.num_blocks,
                               cfg.block_size, cfg.max_batch)
-        self._cache = tuple(jnp.zeros(shape, dtype or self._dtype, device=pool_s)
-                            for _, shape, dtype in pools)
+        with kept_span("pool_alloc", pools=len(pools)):
+            self._cache = tuple(
+                jnp.zeros(shape, dtype or self._dtype, device=pool_s)
+                for _, shape, dtype in pools)
         self._cache_kinds = tuple(kind for kind, _, _ in pools)
+        sp.set(pool_bytes=sum(int(c.nbytes) for c in self._cache),
+               pool_blocks=int(cfg.num_blocks),
+               params_bytes=sum(int(getattr(a, "nbytes", 0)) for a in
+                                jax.tree_util.tree_leaves(self._compute_params)))
         self._pool = PagePool(cfg.num_blocks)
         # row slots of the caches that hold a fixed size a row (None: the
         # arch has none); slot 0 is the trash slot of a bucket's padding
@@ -733,6 +747,7 @@ class Engine:
                            if G.cache_slots(arch) else None)
         self._window = (arch["cache"].get("window_tokens", 0)
                         if self._row_slots is not None else 0)
+        sp.set(row_slots=len(self._row_slots or ()))
         self._state_rebuilds = self._state_rows = 0
         # routed experts: live tokens each expert took, by expert layer, as
         # the programs report them beside their tokens
@@ -766,6 +781,9 @@ class Engine:
 
         # engine-thread-only scheduler state
         self._fns: Dict[tuple, object] = {}
+        # (kind, *bucket) -> (its ``program_build`` span, the span it was
+        # built under, whose first call compiles it): ``stats()["programs"]``
+        self._programs: Dict[tuple, tuple] = {}
         # per-decode-bucket gather width (blocks), high-water, pow2-rounded
         self._decode_mb: Dict[int, int] = {}
         # decode reads K/V through the block-table kernel wherever Mosaic
@@ -1009,6 +1027,7 @@ class Engine:
             "pages_cached": (self._prefix.blocks
                             if self._prefix is not None else 0),
             "compiles": len(self._fns),
+            "programs": self._program_rows(),
             "decode_steps": self._step_i,
             "decode_ahead": self._ahead,
             "decode_drains": self._drains,
@@ -1017,6 +1036,29 @@ class Engine:
                if self._expert_tokens is not None else {}),
             **(self._slot_stats() if self._row_slots is not None else {}),
         }
+
+    def _program_rows(self) -> List[dict]:
+        """The cold-start report: one row a program this engine built, in
+        the order they were built: ``kind`` and ``bucket``, ``build_s`` (the
+        builder's Python and ``jax.jit``), then what its first call cost
+        under the span that made it (``span``: a ``prefill``, a
+        ``decode_step``): ``trace_s`` / ``lower_s`` / ``backend_s``, whether
+        the persistent cache served it (``cache_hits`` / ``cache_misses``)
+        and ``first_run_s``, that span's time less the stages and the build.
+        The stages read 0 until that span has ended."""
+        rows = []
+        for key, (build, call) in list(self._programs.items()):
+            first = account_row(call) if call is not None and call.t1 else {}
+            build_s = build.dur_ns / 1e9
+            rows.append({
+                "kind": key[0], "bucket": list(key[1:]), "build_s": build_s,
+                "span": call.name if call is not None else None,
+                **{k: first.get(k, 0) for k in
+                   ("trace_s", "lower_s", "backend_s", "cache_hits",
+                    "cache_misses")},
+                "first_run_s": max(first.get("first_run_s", 0.0) - build_s, 0.0),
+            })
+        return rows
 
     def _slot_stats(self) -> dict:
         """Of an arch whose caches are of several kinds: the row slots, the
@@ -1037,7 +1079,6 @@ class Engine:
         for every row the batch has room for."""
         if self._row_slots is not None:
             seq.row_slot = self._row_slots.pop()
-            counter_inc("serve_state_slots_taken")
 
     def _release(self, seq: "_Seq"):
         """Give back what a sequence holds of the caches: its blocks and its
@@ -1061,13 +1102,12 @@ class Engine:
 
     def _note_experts(self, sp, counts: np.ndarray):
         """A program's ``(expert layers, experts)`` count of the live tokens
-        each expert took, onto its span, the counters and ``stats()``."""
+        each expert took, onto its span, the counter and ``stats()``."""
         self._expert_tokens += counts
         touched = int(np.count_nonzero(counts))
         assigned = int(counts.sum())
         sp.set(experts_touched=touched, expert_tokens_max=int(counts.max()),
                expert_assignments=assigned)
-        counter_inc("serve_experts_touched", touched)
         counter_inc("serve_expert_assignments", assigned)
 
     def debug_requests(self) -> List[dict]:
@@ -2150,7 +2190,6 @@ class Engine:
                         jnp.asarray(starts), jnp.asarray(lens),
                         jnp.asarray(tables))
                     counter_inc("serve_prefills")
-                    counter_inc("serve_tail_prefills")
                     rows, = self._prefill_readback(logits)
                     self._land_prefill(chunk, rows)
 
@@ -2898,97 +2937,101 @@ class Engine:
         key = (kind,) + bucket
         fn = self._fns.get(key)
         if fn is None:
-            jax, G = self._jax, self._G
-            if self._tp:
-                # tensor-parallel builders: packed param tree, shard_map
-                # body, dequantization inside the body — no outer dequant
-                # wrapper. Same call signatures, same donation slots.
-                tpkw = dict(mesh=self._tp_mesh, vocab=self._tp_vocab,
-                            dtype=self._dtype,
-                            int8_wire=bool(self.config.tp_int8))
-                if kind == "prefill":
-                    bw, t_bucket = bucket
-                    raw = G.build_tp_paged_prefill(
-                        self._arch_key, bw, t_bucket,
-                        self.config.block_size, self._max_blocks, **tpkw)
-                    donate = (4, 5)
-                elif kind == "prefill_tail":
-                    bw, t_bucket = bucket
-                    raw = G.build_tp_paged_tail_prefill(
-                        self._arch_key, bw, t_bucket,
-                        self.config.block_size, self._max_blocks, **tpkw)
-                    donate = (5, 6)
-                elif kind == "decode":
-                    bb, mb = bucket
-                    raw = G.build_tp_paged_decode(
-                        self._arch_key, bb, self.config.block_size, mb,
-                        use_kernel=self._paged_kernel, **tpkw)
-                    donate = (1, 2)
-                else:  # spec/draft excluded by EngineConfig validation
-                    raise RuntimeError(
-                        f"serving: program kind {kind!r} has no "
-                        "tensor-parallel build")
-                if kind == "decode":
-                    raw = G.feed_tokens_back(raw, bb, self.config.max_batch,
-                                             mb, len(self._cache))
-                fn = jax.jit(raw, donate_argnums=donate)
-                self._fns[key] = fn
-                counter_inc("serve_compiles")
-                return fn
-            if kind == "prefill":
-                bw, t_bucket = bucket
-                raw = G.build_paged_prefill(
-                    self._arch, bw, t_bucket, self.config.block_size,
-                    self._max_blocks)
-                first = 4 + (self._row_slots is not None)  # after the slots
-                donate = tuple(range(first, first + len(self._cache)))
-            elif kind == "prefill_tail":
-                bw, t_bucket = bucket
-                raw = G.build_paged_tail_prefill(
-                    self._arch, bw, t_bucket, self.config.block_size,
-                    self._max_blocks)
-                donate = (5, 6)
-            elif kind == "spec":
-                bb, mb = bucket
-                raw = G.build_paged_spec_decode(
-                    self._arch, bb, self._spec_k, self.config.block_size, mb)
-                donate = (1, 2)
-            elif kind == "draft":
-                # drafter weights, not the (possibly int8) target params —
-                # no dequant wrapper, nothing donated
-                (bb,) = bucket
-                darch, _, W = self._drafter
-                fn = jax.jit(G.build_window_draft(darch, bb, W, self._spec_k))
-                self._fns[key] = fn
-                counter_inc("serve_compiles")
-                return fn
-            else:
-                bb, mb = bucket
-                build = (G.build_paged_decode_kernel if self._paged_kernel
-                         else G.build_paged_decode)
-                raw = G.feed_tokens_back(
-                    build(self._arch, bb, self.config.block_size, mb), bb,
-                    self.config.max_batch, mb, len(self._cache),
-                    slots=self._row_slots is not None)
-                donate = tuple(range(1, 1 + len(self._cache)))
-            if self._dequant is not None:
-                dq, inner = self._dequant, raw
-
-                def raw(params, *args, _dq=dq, _inner=inner):
-                    return _inner(_dq(params), *args)
-
-                # the device line tells programs apart by name (``jit_step``
-                # / ``jit_prefill``): the int8 wrapper keeps the builder's
-                raw.__name__ = raw.__qualname__ = inner.__name__
-
-            # donation lets XLA update the pools in place — on every backend,
-            # the CPU tier included, so a host-side reference that outlives a
-            # step fails in tier-1 ("Array has been deleted") and not first
-            # on the chip
-            fn = jax.jit(raw, donate_argnums=donate)
-            self._fns[key] = fn
+            # ``program_build`` holds the builders' Python and ``jax.jit``;
+            # the stages of the compilation land on the span this is called
+            # under, at the program's first call
+            names = (("bucket_b", "bucket_t") if kind.startswith("prefill")
+                     else ("bucket", "width"))
+            with kept_span("program_build", kind=kind,
+                           **dict(zip(names, bucket))) as sp:
+                fn = self._fns[key] = self._build_fn(kind, bucket)
+            self._programs[key] = (sp, current_span())
             counter_inc("serve_compiles")
         return fn
+
+    def _build_fn(self, kind: str, bucket: tuple):
+        jax, G = self._jax, self._G
+        if self._tp:
+            # tensor-parallel builders: packed param tree, shard_map
+            # body, dequantization inside the body — no outer dequant
+            # wrapper. Same call signatures, same donation slots.
+            tpkw = dict(mesh=self._tp_mesh, vocab=self._tp_vocab,
+                        dtype=self._dtype,
+                        int8_wire=bool(self.config.tp_int8))
+            if kind == "prefill":
+                bw, t_bucket = bucket
+                raw = G.build_tp_paged_prefill(
+                    self._arch_key, bw, t_bucket,
+                    self.config.block_size, self._max_blocks, **tpkw)
+                donate = (4, 5)
+            elif kind == "prefill_tail":
+                bw, t_bucket = bucket
+                raw = G.build_tp_paged_tail_prefill(
+                    self._arch_key, bw, t_bucket,
+                    self.config.block_size, self._max_blocks, **tpkw)
+                donate = (5, 6)
+            elif kind == "decode":
+                bb, mb = bucket
+                raw = G.build_tp_paged_decode(
+                    self._arch_key, bb, self.config.block_size, mb,
+                    use_kernel=self._paged_kernel, **tpkw)
+                donate = (1, 2)
+            else:  # spec/draft excluded by EngineConfig validation
+                raise RuntimeError(
+                    f"serving: program kind {kind!r} has no "
+                    "tensor-parallel build")
+            if kind == "decode":
+                raw = G.feed_tokens_back(raw, bb, self.config.max_batch,
+                                         mb, len(self._cache))
+            return jax.jit(raw, donate_argnums=donate)
+        if kind == "prefill":
+            bw, t_bucket = bucket
+            raw = G.build_paged_prefill(
+                self._arch, bw, t_bucket, self.config.block_size,
+                self._max_blocks)
+            first = 4 + (self._row_slots is not None)  # after the slots
+            donate = tuple(range(first, first + len(self._cache)))
+        elif kind == "prefill_tail":
+            bw, t_bucket = bucket
+            raw = G.build_paged_tail_prefill(
+                self._arch, bw, t_bucket, self.config.block_size,
+                self._max_blocks)
+            donate = (5, 6)
+        elif kind == "spec":
+            bb, mb = bucket
+            raw = G.build_paged_spec_decode(
+                self._arch, bb, self._spec_k, self.config.block_size, mb)
+            donate = (1, 2)
+        elif kind == "draft":
+            # drafter weights, not the (possibly int8) target params —
+            # no dequant wrapper, nothing donated
+            (bb,) = bucket
+            darch, _, W = self._drafter
+            return jax.jit(G.build_window_draft(darch, bb, W, self._spec_k))
+        else:
+            bb, mb = bucket
+            build = (G.build_paged_decode_kernel if self._paged_kernel
+                     else G.build_paged_decode)
+            raw = G.feed_tokens_back(
+                build(self._arch, bb, self.config.block_size, mb), bb,
+                self.config.max_batch, mb, len(self._cache),
+                slots=self._row_slots is not None)
+            donate = tuple(range(1, 1 + len(self._cache)))
+        if self._dequant is not None:
+            dq, inner = self._dequant, raw
+
+            def raw(params, *args, _dq=dq, _inner=inner):
+                return _inner(_dq(params), *args)
+
+            # the device line tells programs apart by name (``jit_step``
+            # / ``jit_prefill``): the int8 wrapper keeps the builder's
+            raw.__name__ = raw.__qualname__ = inner.__name__
+
+        # donation lets XLA update the pools in place — on every backend,
+        # the CPU tier included, so a host-side reference that outlives a
+        # step fails in tier-1 ("Array has been deleted") and not first
+        # on the chip
+        return jax.jit(raw, donate_argnums=donate)
 
     # -- flight-recorder context ----------------------------------------------
     def _flight_context(self) -> dict:

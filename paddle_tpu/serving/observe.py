@@ -615,7 +615,6 @@ class MetricsEndpoint:
                 self.wfile.write(data)
 
             def do_GET(self):
-                profiler.counter_inc("serve_http_requests")
                 path = self.path.split("?", 1)[0]
                 target = outer._target_ref()
                 try:

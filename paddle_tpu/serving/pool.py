@@ -118,8 +118,6 @@ class PagePool:
                 raise RuntimeError(f"PagePool: share of unowned block id {b}")
         for b in ids:
             self._ref[b] += 1
-        if ids:
-            counter_inc("serve_pages_shared", len(ids))
 
     def refcount(self, bid: int) -> int:
         """Current reference count of a block (0 = not owned)."""

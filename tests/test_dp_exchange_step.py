@@ -219,3 +219,35 @@ def test_counts_read_start_done_pairs_from_scheduled_text():
         "}",
     ])
     assert dp_reduce_counts(text) == {"dp_reduce_leaves": 4, "dp_reduce_async": 1}
+
+
+def test_step_text_row_carries_the_text_and_its_counts():
+    """The ahead-of-time compile of the dp step is three spans the set-up
+    account tells apart, and ``step_text`` (kept by name) says what reading
+    the scheduled text cost and yielded: its size and the seven counts."""
+    from paddle_tpu import profiler
+
+    before = list(_spans._kept)
+    _spans._reset_account()
+    try:
+        *_, eng, steps = _run(_mesh(2, 2), "float32", 1, True)
+        rows = profiler.setup_account()
+    finally:
+        _spans._kept[:] = before
+    by_name = {}
+    for r in rows:
+        by_name.setdefault(r["name"], []).append(r)
+    (text,), (lower,), (compile_,) = (
+        by_name["step_text"], by_name["step_lower"], by_name["step_compile"])
+    counts = {"dp_reduce_leaves", "dp_reduce_async", "mp_weight_exchanges",
+              "mp_activation_gathers", "mp_reduce_exchanges", "mp_reduce_async",
+              "mp_activation_reduces"}
+    assert text["site"] and counts <= set(text)
+    assert {k: text[k] for k in counts} == eng._dp_reduce
+    assert text["dp_reduce_leaves"] > 0 and text["text_bytes"] > 10_000
+    assert lower["trace_s"] > 0 and lower["lower_s"] > 0 and lower["backend_s"] == 0
+    assert compile_["backend_s"] > 0 and compile_["trace_s"] == 0
+    assert lower["t1_ns"] <= compile_["t0_ns"] <= compile_["t1_ns"] <= text["t0_ns"]
+    # all three inside the first train_step, once: the later steps compile nothing
+    assert steps[0].t0 <= lower["t0_ns"] and text["t1_ns"] <= steps[0].t1
+    assert [r["kind"] for r in by_name["program_build"]] == ["train_step"]

@@ -274,7 +274,9 @@ class TestProgramTracing:
         with profiler.span("load") as sp:
             spans._on_compile_event("/jax/compilation_cache/cache_hits")
             spans._on_compile_event("/jax/compilation_cache/cache_misses")
-        assert sp.attrs == {"compile_cache_hits": 1}
+        # (a miss, a program compiled and written to the cache, is charged
+        # beside it since PR 40)
+        assert sp.attrs == {"compile_cache_hits": 1, "compile_cache_misses": 1}
         assert profiler.counters()["compile_cache_hits"] == c0 + 1
 
     def test_nested_trace_events_count_once(self):
@@ -361,6 +363,175 @@ class TestProgramTracing:
             pass
         assert sp2._ann is None
         assert flight.recent_spans()[-1].name == "after"
+
+
+class TestSetupAccount:
+    """``profiler.setup_account()``: the spans that compiled and the few that
+    build a process, kept where the listener charges them and made into rows
+    when read. A warm step never comes near it."""
+
+    TRACE = "/jax/core/compile/jaxpr_trace_duration"
+    LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+    BACKEND = "/jax/core/compile/backend_compile_duration"
+    HIT = "/jax/compilation_cache/cache_hits"
+    MISS = "/jax/compilation_cache/cache_misses"
+
+    @pytest.fixture(autouse=True)
+    def fresh_account(self):
+        from paddle_tpu.profiler import spans
+
+        before = list(spans._kept)
+        spans._reset_account()
+        yield spans
+        spans._kept[:] = before
+
+    def test_a_span_that_compiles_is_kept_once_with_its_stages(self):
+        fn, x = TestProgramTracing._fresh_jit(19)
+        with profiler.span("outer_step"):
+            with profiler.span("step", bucket=4, note=(1, 2)) as first:
+                fn(x).block_until_ready()
+        rows = profiler.setup_account()
+        assert [r["name"] for r in rows] == ["step"]  # once, innermost only
+        row = rows[0]
+        assert row["site"] is False and row["bucket"] == 4 and "note" not in row
+        assert (row["t0_ns"], row["t1_ns"]) == (first.t0, first.t1)
+        for stage in ("trace", "lower", "backend"):
+            assert row[stage + "_s"] == first.attrs[f"compile_{stage}_s"] > 0
+        # nested traces count once: the stages and the first run ARE the span
+        assert row["trace_s"] + row["lower_s"] + row["backend_s"] \
+            + row["first_run_s"] == pytest.approx(first.dur_ns / 1e9)
+        assert not any(k.startswith("compile_") for k in row)
+
+    def test_a_hit_and_a_miss_are_told_apart(self, fresh_account):
+        spans = fresh_account
+        c0 = profiler.counters()
+        with profiler.span("loaded") as a:
+            spans._on_compile_event(self.HIT)
+            spans._on_compile_duration(
+                "/jax/compilation_cache/compile_time_saved_sec", 2.5)
+            spans._on_compile_duration(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+            spans._on_compile_duration(self.BACKEND, 0.3)
+        with profiler.span("compiled") as b:
+            spans._on_compile_duration(self.BACKEND, 3.0)
+            spans._on_compile_event(self.MISS)
+        spans._on_compile_event(self.MISS)  # outside any span: nobody's
+        loaded, compiled = profiler.setup_account()
+        assert (loaded["cache_hits"], loaded["cache_misses"]) == (1, 0)
+        assert (loaded["cache_saved_s"], loaded["cache_load_s"]) == (2.5, 0.25)
+        assert (compiled["cache_hits"], compiled["cache_misses"]) == (0, 1)
+        assert compiled["backend_s"] == 3.0 and a.t1 <= b.t0
+        c1 = profiler.counters()
+        assert c1["compile_cache_misses"] - c0.get("compile_cache_misses", 0) == 1
+        assert c1["compile_cache_hits"] - c0.get("compile_cache_hits", 0) == 1
+
+    @pytest.mark.parametrize("site", ["engine_init", "program_build", "step_text"])
+    def test_a_kept_site_makes_one_row(self, fresh_account, site):
+        with profiler.kept_span(site, kind="decode", text_bytes=7) as sp:
+            # a site that also compiles is still ONE row
+            fresh_account._on_compile_duration(self.TRACE, 0.001)
+            fresh_account._on_compile_duration(self.BACKEND, 0.002)
+        with profiler.kept_span(site):
+            pass  # and one that compiles nothing is a row all the same
+        first, second = profiler.setup_account()
+        assert first["name"] == second["name"] == site
+        assert first["site"] and second["site"]
+        assert first["kind"] == "decode" and first["text_bytes"] == 7
+        assert first["backend_s"] == 0.002 and second["backend_s"] == 0.0
+        assert first["t1_ns"] == sp.t1
+
+    def test_warm_steps_add_no_row_and_touch_no_list(self, fresh_account):
+        """The guard that the hot path pays nothing: after warm-up the kept
+        list is the same list of the same spans, whatever runs."""
+        model = nn.Linear(6, 3)
+        step = paddle.jit.compile_train_step(
+            model, lambda m, x, y: nn.MSELoss()(m(x), y),
+            paddle.optimizer.SGD(learning_rate=0.1,
+                                 parameters=model.parameters()))
+        x = paddle.to_tensor(np.ones((2, 6), np.float32))
+        y = paddle.to_tensor(np.zeros((2, 3), np.float32))
+        for _ in range(2):
+            step(x, y)
+        kept = list(fresh_account._kept)
+        rows = profiler.setup_account()
+        assert {"program_build", "train_step"} <= {r["name"] for r in rows}
+        dropped = profiler.counters().get("setup_account_dropped", 0)
+        for _ in range(25):
+            step(x, y)
+            with profiler.span("decode_step", bucket=2):
+                with profiler.span("decode_readback"):
+                    pass
+        assert len(fresh_account._kept) == len(kept)
+        assert all(a is b for a, b in zip(fresh_account._kept, kept))
+        assert profiler.setup_account() == rows
+        assert profiler.counters().get("setup_account_dropped", 0) == dropped
+
+    def test_the_hot_path_never_names_the_account(self):
+        """``Span.__enter__`` / ``__exit__`` / ``_emit`` and ``span()`` are
+        the code a warm step runs: none of them knows the account exists."""
+        import inspect
+
+        from paddle_tpu.profiler import spans
+
+        for fn in (spans.Span.__init__, spans.Span.__enter__,
+                   spans.Span.__exit__, spans._emit, spans.span):
+            src = inspect.getsource(fn)
+            assert "_keep" not in src and "_kept" not in src, fn
+
+    def test_the_bound_counts_what_it_drops(self, fresh_account):
+        spans = fresh_account
+        c0 = profiler.counters().get("setup_account_dropped", 0)
+        for i in range(spans._KEPT_MAX + 3):
+            with profiler.span("flush", i=i):
+                spans._on_compile_duration(self.TRACE, 0.001)
+                spans._on_compile_duration(self.LOWER, 0.001)
+                spans._on_compile_duration(self.BACKEND, 0.001)
+        with profiler.kept_span("program_build"):
+            spans._on_compile_duration(self.TRACE, 0.001)
+        rows = profiler.setup_account()
+        assert len(rows) == len(spans._kept) == spans._KEPT_MAX == 256
+        assert rows[-1]["i"] == 255  # the first 256, nothing after them
+        # each span past the bound is counted once, whatever it was charged
+        assert profiler.counters()["setup_account_dropped"] - c0 == 4
+
+    def test_rows_add_up_where_kept_spans_nest(self, fresh_account):
+        spans = fresh_account
+        with profiler.span("train_step") as outer:
+            spans._on_compile_duration(self.BACKEND, 0.001)
+            with profiler.kept_span("program_build") as build:
+                time.sleep(0.003)
+            with profiler.span("step_compile") as inner:
+                time.sleep(0.002)
+                spans._on_compile_duration(self.BACKEND, 0.002)
+                with profiler.kept_span("nested_deeper"):
+                    pass
+            time.sleep(0.001)
+        rows = {r["name"]: r for r in profiler.setup_account()}
+        assert list(rows) == ["train_step", "program_build", "step_compile",
+                              "nested_deeper"]
+        own = rows["train_step"]["first_run_s"]
+        assert own == pytest.approx(
+            (outer.dur_ns - build.dur_ns - inner.dur_ns) / 1e9 - 0.001)
+        assert 0 < own < 0.05 and rows["program_build"]["first_run_s"] >= 0.003
+
+    def test_import_and_parameter_init_are_counted(self):
+        c0 = profiler.counters()
+        assert c0["setup_import_ns"] > 0
+        layer = nn.Linear(16, 8)  # a weight and a bias
+        c1 = profiler.counters()
+        assert c1["param_init_leaves"] - c0.get("param_init_leaves", 0) == 2
+        assert c1["param_init_bytes"] - c0.get("param_init_bytes", 0) \
+            == sum(int(p._data.nbytes) for p in layer.parameters())
+        assert c1["param_init_ns"] > c0.get("param_init_ns", 0)
+        assert c1["setup_import_ns"] == c0["setup_import_ns"]  # once a process
+        # ... so a reset of the counters, which cannot count it again, keeps it
+        try:
+            profiler.reset_counters()
+            assert profiler.counters() == {"setup_import_ns": c0["setup_import_ns"]}
+        finally:
+            for k, v in c1.items():
+                if k != "setup_import_ns":
+                    profiler.counter_inc(k, v)
 
 
 class TestProgramNames:

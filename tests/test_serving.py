@@ -345,7 +345,9 @@ class TestServingTelemetry:
         assert steps >= 10
         assert sorted(rest)[len(rest) // 2] < 0.25, sorted(rest)
         for step in (sp for sp in rows if sp.name == "decode_step"):
-            inner = kids.get(step.span_id, [])
+            # (a step that builds a program also holds its ``program_build``)
+            inner = [k for k in kids.get(step.span_id, [])
+                     if k.name != "program_build"]
             if not inner:  # nothing was in flight: the step is only enqueued
                 assert step.attrs["ahead"] == 0
                 continue
@@ -378,6 +380,58 @@ class TestServingTelemetry:
         for sp in rows:
             if sp.name in ("decode_land", "prefill_land", "admit"):
                 assert not any(a in sp.attrs for a in stages), sp
+
+    def test_stats_programs_is_the_cold_start_report(self, model):
+        """``stats()["programs"]``: one row a (kind, bucket) this engine
+        built, with its build, the stages of its first call and whether the
+        persistent cache served it; the set-up account holds ``engine_init``
+        with the pool it made and a ``program_build`` a program."""
+        from paddle_tpu import profiler
+        from paddle_tpu.profiler import spans
+
+        before = list(spans._kept)
+        spans._reset_account()
+        rng = np.random.RandomState(14)
+        try:
+            with Engine(model, **_ENGINE_KW) as eng:
+                for p in _prompts(3, rng):
+                    eng.submit(p, max_new_tokens=6).result(timeout=300)
+                st = eng.stats()
+                compiles = profiler.counters()["serve_compiles"]
+                for p in _prompts(3, rng):  # a warm wave: nothing new
+                    eng.submit(p, max_new_tokens=6).result(timeout=300)
+                assert eng.stats()["programs"] == st["programs"]
+                assert profiler.counters()["serve_compiles"] == compiles
+            account = profiler.setup_account()
+        finally:
+            spans._kept[:] = before
+        rows = st["programs"]
+        keys = [(r["kind"], *r["bucket"]) for r in rows]
+        # (a gather-width upgrade REPLACES a bucket's program in the engine;
+        # the report keeps the one it replaced: it was paid for)
+        assert len(set(keys)) == len(keys) >= st["compiles"] >= 2
+        assert set(eng._fns) <= set(keys)
+        assert {"prefill", "decode"} == {r["kind"] for r in rows}
+        for r in rows:
+            assert r["span"] == ("prefill" if r["kind"] == "prefill"
+                                 else "decode_step")
+            assert r["trace_s"] > 0 and r["lower_s"] > 0 and r["backend_s"] > 0
+            assert r["build_s"] >= 0 and r["first_run_s"] >= 0
+            assert r["cache_hits"] + r["cache_misses"] <= 1
+        # the account: the engine's own row first, then a build a program
+        # under the step that compiled it
+        init = next(r for r in account if r["name"] == "engine_init")
+        assert init["site"] and init["pool_blocks"] == _ENGINE_KW["num_blocks"]
+        assert init["pool_bytes"] > 0 and init["params_bytes"] > 0
+        assert init["row_slots"] == 0
+        assert any(r["name"] == "pool_alloc" and r["t0_ns"] >= init["t0_ns"]
+                   and r["t1_ns"] <= init["t1_ns"] for r in account)
+        builds = [r for r in account if r["name"] == "program_build"]
+        assert sorted((b["kind"], b.get("bucket_b", b.get("bucket")),
+                       b.get("bucket_t", b.get("width"))) for b in builds) \
+            == sorted(keys)
+        compiled = [r for r in account if r["backend_s"] > 0 and not r["site"]]
+        assert len(compiled) == len(rows)
 
     def test_flight_context_provider_carries_request_table(self, model):
         from paddle_tpu.profiler import flight
