@@ -707,7 +707,10 @@ def _mla_moe_arch(cfg, kernels):
     ``mla_moe.decoder_layer`` for prompts (``prompt_layer``) and for decode
     rows (``decode_layer``) alike, so the plug has no per-builder copy of it.
     It has the plain prefill and decode programs and no other yet
-    (``plain_paths_only``: the engine refuses the rest by name)."""
+    (``plain_paths_only``: the engine refuses the rest by name). Where its
+    decode reads the pool through the kernel, ``step_attrs`` says what the
+    kernel's copy schedule does with a step's positions (blocks copied a
+    layer, chunks, chunks copied whole), for the ``decode_step`` span."""
     from . import mla_moe as M
 
     tabs = M.rope_tables(cfg)
@@ -753,12 +756,24 @@ def _mla_moe_arch(cfg, kernels):
         X, counts = M.decoder_layer(cfg, w, X, attend, live, kernels)
         return X[:, None], (pool,), counts
 
-    return {"name": "mla_moe", "embed": embed, "head": head,
+    arch = {"name": "mla_moe", "embed": embed, "head": head,
             "prompt_layer": prompt_layer, "decode_layer": decode_layer,
             "cache": ((cfg.cache_row,),), "plain_paths_only": True,
             "expert_layers": sum(cfg.is_expert_layer(i)
                                  for i in range(cfg.num_hidden_layers)),
             "experts": cfg.n_routed_experts}
+    if kernels:
+        from ..ops.kernels import mla_paged_attention as K
+
+        def step_attrs(pos, bucket, block_size, max_blocks, dtype):
+            # the chunk the bucket's program resolved when it was traced
+            C = K.blocks_per_chunk(K.mla_paged_attention_key(
+                bucket, max_blocks, block_size, H, cfg.cache_row,
+                cfg.kv_lora_rank, dtype))
+            return K.chunk_counts(pos, block_size, C)
+
+        arch["step_attrs"] = step_attrs
+    return arch
 
 
 def phi4flash_decode_state(model, kernels=None):

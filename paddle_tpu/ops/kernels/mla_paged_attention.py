@@ -18,6 +18,28 @@ step. One pool instead of two and no KV groups: every column of a chunk is a
 token of every head. A dead row (``pos = 0``, table at the trash block) costs
 one block.
 
+The copy schedule of a chunk (PR 43). A copy is one block, 16 tokens: 20 KB,
+25 ns of the chip's HBM. Timed alone at the serving cell's shape (PERF.md
+section 6), the copies themselves hide behind the dots (a chunk's copies land
+0.2 us + 24 ns a block after their start, whoever issues them); what stands
+BESIDE the dots, on the one instruction stream, is the scalar work of
+starting a copy (a table entry, the address arithmetic, two bounds checks)
+and of waiting for it, a block at a time. So a FULL chunk (all ``C`` blocks
+live: every chunk of a row but its last) is started as straight-line code
+(the loop's body unrolled ``C`` times when the kernel is lowered, so that the
+scheduler packs the ``C`` table entries, addresses and descriptors as it
+likes) and is waited for ONCE: a DMA semaphore counts bytes, so one wait on
+a descriptor whose destination is the whole ``buf.at[slot]`` takes the ``C``
+blocks' bytes off ``sems.at[slot]``. One chunk at most is in flight a slot,
+so the count is that chunk's alone, also across the grid step that hands the
+slot to the next row. A PARTIAL chunk (a row's last, ``live < C``) keeps the
+two loops of dynamic length, one start and one wait a live block; what it
+leaves unwritten of the buffer holds an earlier chunk's rows or the zeros of
+the first grid step, finite either way, and is masked. The copies, their
+order and the arithmetic are the same in both: at one ``blocks_per_chunk``
+the outputs are bit for bit those of the schedule with a loop a block
+(``tests/test_mla_paged_attention.py`` keeps that body).
+
 Contract with the plain form (``models/mla_moe.attend_absorbed_plain``, the
 gather of the row's table): the caller scatters the step's fresh row into the
 pool BEFORE the call; probabilities are rounded to the pool's dtype before
@@ -25,7 +47,10 @@ the second dot, as the gather's einsum rounds them; the online softmax sums
 in chunk order, so agreement is within ``2e-5 * max|plain| + 2e-6`` for
 float32 inputs (tests), not bit for bit.
 
-Tunable: ``blocks_per_chunk`` (C).
+Tunable: ``blocks_per_chunk`` (C). The dots of a chunk are one dependent
+chain (scores, row maximum, exponentials, the second dot, the carry), about
+0.3 us however many tokens it holds, so a wider chunk is cheaper a block: 16
+since PR 43.
 """
 from __future__ import annotations
 
@@ -46,7 +71,8 @@ try:
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
-__all__ = ["mla_paged_attention", "mla_paged_attention_key"]
+__all__ = ["mla_paged_attention", "mla_paged_attention_key",
+           "blocks_per_chunk", "chunk_counts"]
 
 _MASK = -0.7 * float(np.finfo(np.float32).max)
 
@@ -56,29 +82,74 @@ def mla_paged_attention_key(B, MB, BS, H, W, R, dtype) -> tuple:
             str(jnp.dtype(dtype)))
 
 
+def blocks_per_chunk(key, config=None) -> int:
+    """The ``C`` a call of shape ``key`` (``mla_paged_attention_key``) copies
+    and multiplies at a time: the registry's, at most a row's table."""
+    if config is None:
+        config = resolve_config("mla_paged_attention", key)
+    return max(1, min(int(config.get("blocks_per_chunk", 16)), key[1]))
+
+
+def chunk_counts(pos, BS, C) -> dict:
+    """What the kernel's copy schedule does, a layer, with rows that write
+    positions ``pos`` (the live rows of a step: a row that pads the bucket
+    costs one block and is the caller's to leave out), on the host:
+    ``latent_blocks`` copied, ``latent_chunks`` they come in and
+    ``latent_full_chunks``, those of them started as straight-line code and
+    waited for once."""
+    blocks = np.asarray(pos, np.int64) // BS + 1
+    return {"latent_blocks": int(blocks.sum()),
+            "latent_chunks": int((-(-blocks // C)).sum()),
+            "latent_full_chunks": int((blocks // C).sum())}
+
+
 def _mla_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref, buf,
                 sems, slot_ref, *, B, MB, BS, C, R, scale):
     b = pl.program_id(0)
     layer = layer_ref[0]
 
-    def for_live_blocks(b, c, slot, do):
-        """``do(copy)`` for each LIVE block of chunk ``c`` of row ``b`` into
-        buffer ``slot``; the same descriptors start and wait."""
-        live = jnp.clip(pos_ref[b] // BS + 1 - c * C, 0, C)
+    def live_blocks(b, c):
+        return jnp.clip(pos_ref[b] // BS + 1 - c * C, 0, C)
 
+    def block_copy(b, c, slot, j):
+        bid = tables_ref[b * MB + c * C + j]
+        return pltpu.make_async_copy(pool_ref.at[layer, bid], buf.at[slot, j],
+                                     sems.at[slot])
+
+    def for_blocks(n, do, unroll=False):
         def body(j, carry):
-            bid = tables_ref[b * MB + c * C + j]
-            do(pltpu.make_async_copy(pool_ref.at[layer, bid], buf.at[slot, j],
-                                     sems.at[slot]))
+            do(j)
             return carry
 
-        jax.lax.fori_loop(0, live, body, 0)
+        jax.lax.fori_loop(0, n, body, 0, unroll=unroll)
 
     def start(b, c, slot):
-        for_live_blocks(b, c, slot, lambda cp: cp.start())
+        live = live_blocks(b, c)
+        issue = lambda j: block_copy(b, c, slot, j).start()
+
+        @pl.when(live == C)
+        def _():
+            # a full chunk: straight-line code. ONE traced body, unrolled C
+            # times when it is lowered: a decode program's set-up is tracing
+            for_blocks(C, issue, unroll=True)
+
+        @pl.when(live < C)
+        def _():
+            for_blocks(live, issue)
 
     def wait(b, c, slot):
-        for_live_blocks(b, c, slot, lambda cp: cp.wait())
+        live = live_blocks(b, c)
+
+        @pl.when(live == C)
+        def _():
+            # the semaphore counts bytes: ONE wait for the chunk's C blocks
+            # (the descriptor is only its destination's size; nothing starts it)
+            pltpu.make_async_copy(buf.at[slot], buf.at[slot],
+                                  sems.at[slot]).wait()
+
+        @pl.when(live < C)
+        def _():
+            for_blocks(live, lambda j: block_copy(b, c, slot, j).wait())
 
     @pl.when(b == 0)
     def _():
@@ -99,13 +170,13 @@ def _mla_kernel(layer_ref, tables_ref, pos_ref, q_ref, pool_ref, o_ref, buf,
         slot, m, l, acc = carry
         nxt = 1 - slot
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start(b, c + 1, nxt)
+        # what is multiplied next: this row's next chunk, or the next row's
+        # first (ONE site: the kernel is lowered for every decode bucket)
+        last = c + 1 >= n_chunks
 
-        @pl.when(jnp.logical_and(c + 1 >= n_chunks, b + 1 < B))
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < B))
         def _():
-            start(b + 1, 0, nxt)
+            start(jnp.where(last, b + 1, b), jnp.where(last, 0, c + 1), nxt)
 
         wait(b, c, slot)
         kv = buf[slot].reshape(N, W)
@@ -187,11 +258,8 @@ def mla_paged_attention(q, pool, layer, tables, pos, latent_width, scale,
         raise ValueError(
             f"mla_paged_attention: Mosaic takes rows and values in whole "
             f"128-lane tiles, not {W} and {latent_width}")
-    if config is None:
-        config = resolve_config(
-            "mla_paged_attention",
-            mla_paged_attention_key(B, MB, BS, H, W, latent_width, q.dtype))
-    C = max(1, min(int(config.get("blocks_per_chunk", 8)), MB))
+    C = blocks_per_chunk(
+        mla_paged_attention_key(B, MB, BS, H, W, latent_width, q.dtype), config)
     return _mla_call(
         q, pool, jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
@@ -225,7 +293,9 @@ def _runner(key):
 
 register_kernel(
     "mla_paged_attention",
-    defaults={"blocks_per_chunk": 8},
+    # 16 since PR 43 (8 until then): measured on the chip at the serving
+    # cell's shape, PERF.md section 6
+    defaults={"blocks_per_chunk": 16},
     space={"blocks_per_chunk": (4, 8, 16)},
     runner=_runner,
 )

@@ -2614,7 +2614,7 @@ class Engine:
         with (self._landing_span(prev, ahead=1) if prev is not None else
               span("decode_step", bucket=bb, rows=len(rows),
                    step=self._step_i, ahead=0,
-                   **self._context_attrs(pos, len(rows)))) as sp:
+                   **self._context_attrs(pos, len(rows), bb))) as sp:
             self._beat = time.monotonic()  # staleness clock covers this op
             if not warm:
                 # the compile stages this span will carry are of the program
@@ -2654,13 +2654,20 @@ class Engine:
             counter_inc("serve_decode_ahead")
             self._land(prev, sp)
 
-    def _context_attrs(self, pos: np.ndarray, n: int) -> dict:
-        """Of an arch whose caches are of several kinds, what a step of ``n``
-        live rows writing ``pos`` reads of each, under the names the arch
-        declares (``cache["span_attrs"]``, attribute -> kind): the context of
-        a paged layer (every reader sees the same tokens), the tokens inside
-        the windows, the rows whose state is updated. The rows that pad the
-        bucket are not counted."""
+    def _context_attrs(self, pos: np.ndarray, n: int, bucket: int) -> dict:
+        """What a step of ``n`` live rows writing ``pos`` reads of the
+        caches, as the arch says it. An arch whose block-table read has a
+        copy schedule says what the schedule does with these positions in
+        the ``bucket``'s program (``step_attrs``). Of an arch whose caches
+        are of several kinds, what the step reads of each, under the names
+        the arch declares (``cache["span_attrs"]``, attribute -> kind): the
+        context of a paged layer (every reader sees the same tokens), the
+        tokens inside the windows, the rows whose state is updated. The rows
+        that pad the bucket are not counted."""
+        step_attrs = self._arch.get("step_attrs")
+        if step_attrs is not None:
+            return step_attrs(pos[:n], bucket, self.config.block_size,
+                              self._max_blocks, self._dtype)
         if self._row_slots is None:
             return {}
         ctx = pos[:n].astype(np.int64) + 1
@@ -2675,7 +2682,7 @@ class Engine:
         that describe it."""
         sp = span("decode_step", bucket=fl.bucket, rows=len(fl.rows),
                   step=self._step_i, ahead=ahead, **attrs,
-                  **self._context_attrs(fl.pos, len(fl.rows)))
+                  **self._context_attrs(fl.pos, len(fl.rows), fl.bucket))
         if self._obs is not None:
             sp.set(traces=tuple(s.req.trace for s in fl.rows))
         return sp

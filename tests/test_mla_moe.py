@@ -289,6 +289,47 @@ def test_padded_bucket_rows_are_not_counted(tiny):
     assert not any(k.startswith("serve_expert_tokens") for k in counters())
 
 
+@pytest.mark.parametrize("length,by_hand", [
+    # the three decode steps write positions length, length + 1, length + 2;
+    # blocks of 4 tokens, chunks of 16 blocks: (blocks, chunks, full chunks)
+    pytest.param(5, [(2, 1, 0)] * 3, id="under_one_chunk"),
+    pytest.param(62, [(16, 1, 1), (16, 1, 1), (17, 2, 1)], id="over_a_chunks_edge"),
+    pytest.param(70, [(18, 2, 1), (18, 2, 1), (19, 2, 1)], id="two_chunks"),
+    pytest.param(70, None, id="plain_form_has_no_schedule")])
+def test_decode_spans_carry_the_latent_reads_copy_schedule(tiny, monkeypatch,
+                                                           length, by_hand):
+    """``latent_blocks`` / ``latent_chunks`` / ``latent_full_chunks`` of a
+    ``decode_step`` span are what the landing step's positions give by hand
+    for the chunk the kernel resolved (the registry's 16 blocks, of a table
+    of 32); the three rows that pad the bucket of 4 (a block each in the
+    kernel) are not counted; the plain gather has no chunks and says
+    nothing."""
+    from paddle_tpu.profiler import spans
+
+    net, _ = tiny
+    real = G.mla_moe_decode_state
+    monkeypatch.setattr(G, "mla_moe_decode_state",
+                        lambda m, k=None: real(m, by_hand is not None))
+    prompt = np.arange(length, dtype=np.int32) % TINY["vocab_size"]
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with Engine(net, block_size=4, num_blocks=64, max_batch=8,
+                    max_seq_len=128, decode_buckets=(4, 8)) as eng:
+            eng.submit(prompt, max_new_tokens=4).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    landed = [sp.attrs for sp in seen if sp.name == "decode_step"
+              and "expert_assignments" in sp.attrs]
+    assert len(landed) == 3 and all(a["rows"] == 1 and a["bucket"] == 4
+                                    for a in landed)
+    if by_hand is None:
+        assert not any(k.startswith("latent_") for a in landed for k in a)
+        return
+    assert [(a["latent_blocks"], a["latent_chunks"], a["latent_full_chunks"])
+            for a in landed] == by_hand
+
+
 # -- (e) the keys switch the mechanisms ---------------------------------------------
 @pytest.mark.parametrize("over", [
     pytest.param({"hc_mult": 1}, id="hc_mult_1"),

@@ -259,6 +259,76 @@ def test_mla_paged_attention_compiles(v5e, B):
     assert n == 1
 
 
+def _mosaic_ops(lowered, names):
+    """``[(operation name, shapes of its array operands (source, then
+    destination, of a copy), loops around it)]`` of the ops of
+    the lowered program's ONE Mosaic kernel whose name ends in one of
+    ``names``: the kernel's serialized module parsed back (its dialects are
+    not registered here, so by the generic form) and walked."""
+    import base64
+    import re
+
+    from jax._src.interpreters import mlir as jmlir
+    from jax._src.lib.mlir import ir
+
+    body, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                       lowered.as_text())
+    ctx = jmlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    found = []
+
+    def walk(op, loops):
+        name = op.operation.name
+        if name.endswith(tuple(names)):
+            found.append((name.rsplit(".", 1)[-1],
+                          [tuple(v.type.shape) for v in op.operands
+                           if isinstance(v.type, ir.MemRefType)
+                           and v.type.shape], loops))
+        inner = loops + name.endswith(("scf.for", "scf.while"))
+        for region in op.regions:
+            for block in region:
+                for child in block:
+                    walk(child, inner)
+
+    with ctx:
+        walk(ir.Module.parse(base64.b64decode(body)).operation, 0)
+    return found
+
+
+def test_mla_paged_attention_waits_once_for_a_full_chunk(v5e):
+    """The kernel's Mosaic text keeps the copy schedule of PR 43. A full
+    chunk: ONE wait whose destination is a whole buffer slot, in the chunk
+    loop and in no loop inside it, and its ``C`` starts as straight-line code
+    (two sites: the first grid step's, and the one in the chunk loop that
+    starts the row's next chunk or the next row's first). A block-sized wait
+    or start in a loop of its own exists only as the partial chunk's, once a
+    site."""
+    from paddle_tpu.ops.kernels.mla_paged_attention import mla_paged_attention
+
+    s, C, block = SingleDeviceSharding(v5e[0]), 8, (16, 640)
+    lowered = jax.jit(lambda q, pool, tables, pos: mla_paged_attention(
+        q, pool, 7, tables, pos, 512, 0.1, config={"blocks_per_chunk": C},
+        interpret=False)).trace(
+            _on(s, (64, 32, 640), jnp.bfloat16),
+            _on(s, (8, 10600, 16, 640), jnp.bfloat16),
+            _on(s, (64, 128), jnp.int32), _on(s, (64,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))
+    ops = _mosaic_ops(lowered, ("tpu.wait_dma2", "tpu.enqueue_dma"))
+    waits = [(shapes[-1], loops) for name, shapes, loops in ops
+             if name == "wait_dma2"]
+    starts = [(shapes[-1], loops) for name, shapes, loops in ops
+              if name == "enqueue_dma"]
+    # every copy that is STARTED is one block's
+    assert {dst for dst, _ in starts} == {block}
+    # loops around an op: 1 = the chunk loop alone, 2 = a loop a block in it
+    assert sorted(waits) == sorted([((C,) + block, 1), (block, 2)])
+    # the first grid step starts row 0's first chunk outside the chunk loop
+    # (C starts in no loop, or one in a loop a block); inside the chunk loop
+    # ONE site starts what is multiplied next, either way
+    depths = [loops for _, loops in starts]
+    assert sorted(depths) == sorted([0] * C + [1] + [1] * C + [2])
+
+
 def test_mla_paged_attention_row_off_the_lane_tile_is_refused(v5e):
     """Mosaic's verdict on the UNPADDED latent row, kept as a test: 576 is
     not a multiple of its 128-lane tile (PR 25 found the same of 64-wide K/V
