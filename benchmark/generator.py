@@ -26,6 +26,7 @@ import math
 import threading
 import time
 from dataclasses import dataclass, field
+from fractions import Fraction
 from statistics import NormalDist
 
 import numpy as np
@@ -33,12 +34,39 @@ import numpy as np
 _PAIRING = 0x5EED  # pairs prompt with output lengths; never the run's seed
 
 
+def _shares(weights: list, n: int) -> list:
+    """``n`` split in proportion to ``weights`` by largest remainder (a tie
+    goes to the earlier part). The weights are read as the decimals the file
+    states, so that 0.1 + 0.2 + 0.7 is 1 and 0.29 x 100 is 29."""
+    exact = [Fraction(str(w)) for w in weights]
+    if sum(exact) != 1 or min(exact) < 0:
+        raise ValueError(f"mixture: the weights {weights} are not shares that sum to 1")
+    whole = [int(w * n) for w in exact]
+    by_rest = sorted(range(len(exact)), key=lambda i: whole[i] - exact[i] * n)
+    for i in by_rest[:n - sum(whole)]:
+        whole[i] += 1
+    return whole
+
+
 def quantiles(dist: dict, n: int) -> np.ndarray:
     """``n`` evenly spaced quantiles ((i+0.5)/n) of a distribution file entry:
     lognormal {median, sigma, lo, hi}, exponential {mean}, linspace {lo, hi},
-    const {value}. Lengths (lognormal, linspace, const) come back as ints."""
-    u = (np.arange(n) + 0.5) / n
+    const {value}. Lengths (lognormal, linspace, const) come back as ints.
+
+    mixture {parts: [{weight, dist, ...}, ...]} is lengths of several modes:
+    part i gets its share of the ``n`` by largest remainder and gives that
+    many quantiles OF ITS OWN, part after part; stratified like the others, so
+    the multiset depends on ``n`` alone."""
     kind = dist["dist"]
+    if kind == "mixture":
+        parts = dist["parts"]
+        for p in parts:
+            if p["dist"] in ("mixture", "exponential"):
+                raise ValueError(f"mixture: a part may not be {p['dist']!r} "
+                                 "(lengths of one mode each: lognormal, linspace, const)")
+        counts = _shares([p["weight"] for p in parts], n)
+        return np.concatenate([quantiles(p, k) for p, k in zip(parts, counts)])
+    u = (np.arange(n) + 0.5) / n
     if kind == "exponential":
         return -np.log1p(-u) * dist["mean"]
     if kind == "lognormal":
@@ -63,6 +91,32 @@ class Schedule:
     ramp_s: float
 
 
+def _pairs(traffic: dict, n: int) -> tuple:
+    """The fixed (prompt, output) lengths of a phase of ``n`` requests, in
+    order of prompt quantile: no seed has a part in them."""
+    plen = quantiles(traffic["prompt_len"], n)
+    olen = quantiles(traffic["output_len"], n)[
+        np.random.default_rng(_PAIRING).permutation(n)]
+    return plen, olen
+
+
+def _counts(traffic: dict, seconds: float) -> tuple:
+    """Requests of the ramp and of the window; the ramp may offer more than
+    the window does, to fill the engine fast."""
+    rate = float(traffic["rate_per_s"])
+    return (round(float(traffic.get("ramp_rate_per_s", rate)) * float(traffic["ramp_s"])),
+            round(rate * seconds))
+
+
+def longest(traffic: dict, seconds: float) -> tuple:
+    """(longest prompt, longest context = prompt + answer) a run of
+    ``seconds`` offers, ramp and window: what the engine's context has to
+    hold, known from the file alone."""
+    pairs = [_pairs(traffic, n) for n in _counts(traffic, seconds) if n]
+    return (max(int(p.max()) for p, _ in pairs),
+            max(int((p + o).max()) for p, o in pairs))
+
+
 def _phase(traffic: dict, n: int, span: float, rng) -> tuple:
     """``n`` requests over ``span`` seconds: fixed multisets, seeded order.
     Gaps are scaled so that they sum to ``span`` exactly.
@@ -76,9 +130,7 @@ def _phase(traffic: dict, n: int, span: float, rng) -> tuple:
     if n == 0:
         z = np.zeros(0, np.int64)
         return np.zeros(0), z, z
-    plen = quantiles(traffic["prompt_len"], n)
-    olen = quantiles(traffic["output_len"], n)[
-        np.random.default_rng(_PAIRING).permutation(n)]
+    plen, olen = _pairs(traffic, n)
     by_prompt = np.argsort(plen, kind="stable")
     rounds = max(1, n // int(traffic.get("round", n)))
     dealt = [by_prompt[r::rounds] for r in range(rounds)]
@@ -92,14 +144,12 @@ def _phase(traffic: dict, n: int, span: float, rng) -> tuple:
 
 
 def build_schedule(traffic: dict, seconds: float, seed: int, vocab: int) -> Schedule:
-    rate, ramp_s = float(traffic["rate_per_s"]), float(traffic["ramp_s"])
+    ramp_s = float(traffic["ramp_s"])
     rng = np.random.default_rng([int(seed), 0xA221])
     # a mix that pins its order draws it from the file's number, not the run's
     order = rng if "order_seed" not in traffic else np.random.default_rng(
         [int(traffic["order_seed"]), 0xA221])
-    # the ramp may offer more than the window does, to fill the engine fast
-    n_ramp = round(float(traffic.get("ramp_rate_per_s", rate)) * ramp_s)
-    n_win = round(rate * seconds)
+    n_ramp, n_win = _counts(traffic, seconds)
     rd, rp, ro = _phase(traffic, n_ramp, ramp_s, order)
     wd, wp, wo = _phase(traffic, n_win, seconds, order)
     due = np.concatenate([rd - ramp_s, wd])

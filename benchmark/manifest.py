@@ -101,6 +101,22 @@ class Manifest:
                 path, "benchmark_family_" + re.sub(r"\W", "_", name))
         return self._families[name]
 
+    def _deployment_faults(self, w: dict, traffic: dict, seconds: float) -> list:
+        """What ``serve_job.setup`` would refuse of a serving cell before it
+        builds anything: a key of the traffic file's ``engine`` entry that is
+        not the deployment's to state, a mix whose longest context, with the
+        warm-up's tail, passes the engine's context."""
+        from . import generator, serve_job
+
+        try:
+            serve_job.fit(traffic, self.config(w["config"]),
+                          *generator.longest(traffic, seconds))
+        except KeyError as e:
+            return [f"cell {w['name']}: traffic/{w['traffic']}.json: lacks the key {e}"]
+        except ValueError as e:
+            return [f"cell {w['name']}: traffic/{w['traffic']}.json: {e}"]
+        return []
+
     def validate(self) -> list:
         """Faults against the parts of the contract that can be checked
         without a chip; empty when sound."""
@@ -155,10 +171,13 @@ class Manifest:
             bad += [f"cell {w['name']}: no {kind}/{f.name}"
                     for kind, f in files.items() if not f.is_file()]
             if w["config"] in families and files["traffic"].is_file():
-                kind, fam = self.traffic(w["traffic"])["kind"], families[w["config"]]
+                traffic, fam = self.traffic(w["traffic"]), families[w["config"]]
+                kind = traffic["kind"]
                 bad += [f"cell {w['name']}: a {kind} cell of a family without {n} "
                         f"({Path(fam.__file__).name})"
                         for n in KIND_NEEDS.get(kind, ()) if not hasattr(fam, n)]
+                if kind == "open_loop":
+                    bad += self._deployment_faults(w, traffic, d["run_seconds"])
             mine = names(self.metrics_of(w["name"], "end_to_end"))
             if "setup_s" not in mine or len(mine) < 2:
                 bad.append(f"cell {w['name']}: needs setup_s and one more metric")

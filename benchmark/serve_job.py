@@ -1,13 +1,16 @@
-"""A serving cell: ``serving.Engine`` at default flags behind an open-loop
-generator. Set-up builds the model from the seed, sizes the cache pool to what
-the weights leave, warms exactly the prefill and decode shapes the traffic
-file reaches, and ramps; the window then counts tokens and requests at the
+"""A serving cell: ``serving.Engine`` behind an open-loop generator, at
+default flags but for what the traffic file's ``engine`` entry states of the
+deployment (context, rows, prefill batch). Set-up checks that the mix fits
+the engine's context, builds the model from the seed, sizes the cache pool to
+what the weights leave, warms exactly the prefill and decode shapes the
+traffic file reaches, and ramps; the window then counts tokens and requests at the
 client's side of the stream; afterwards arrivals stop, in-flight requests
 drain, the engine is freed and the plain reference is run over a seeded
 sample of what was served."""
 from __future__ import annotations
 
 import gc
+import inspect
 import time
 
 import numpy as np
@@ -40,6 +43,62 @@ def prefill_buckets(lengths, block_size):
     return sorted(out)
 
 
+def engine_config(traffic, cfg):
+    """The ``serving.EngineConfig`` this cell's engine resolves to, but for
+    the pool: the flags' defaults under the traffic file's ``engine`` entry,
+    whose keys are keyword arguments of that class (the harness keeps no list
+    of its own), the context held to the configuration's positions. Raises
+    what the entry may not hold, by key."""
+    from paddle_tpu.serving import EngineConfig
+
+    entry = traffic.get("engine", {})
+    takes = set(inspect.signature(EngineConfig.__init__).parameters) - {"self"}
+    for key in entry:
+        if key in ("num_blocks", "block_size"):
+            raise ValueError(
+                f"engine: {key!r} is the harness's own: it sizes the pool to what the "
+                "weights leave less headroom_bytes, and the prefill buckets by the block")
+        if key not in takes:
+            raise ValueError(f"engine: {key!r} is no keyword of serving.EngineConfig "
+                             f"(it takes {sorted(takes)})")
+    return EngineConfig(**entry).resolve(cfg.get("max_position_embeddings", 2 ** 62))
+
+
+def warm_rows(traffic, resolved):
+    """Rows the warm-up's staircase walks down from: the engine's."""
+    return int(traffic.get("warm_rows", resolved.max_batch))  # the rehearsal walks fewer
+
+
+def warm_tail(longest_prompt, longest_ctx, rows):
+    """Tokens the warm-up's long request asks for: it holds the mix's longest
+    context while ``rows`` short ones retire under it."""
+    return max(longest_ctx - longest_prompt, 1) + 2 * rows + 8
+
+
+def longest_of(schedule):
+    """(longest prompt, longest context) of a schedule, as
+    ``generator.longest`` reads them from the file."""
+    return (int(schedule.prompt_len.max()),
+            int((schedule.prompt_len + schedule.out_len).max()))
+
+
+def fit(traffic, cfg, longest_prompt, longest_ctx):
+    """The resolved engine sizes of ``engine_config``, once the mix is known
+    to fit them: before anything is built. The warm-up's long request, not the
+    mix's longest context alone, is what the engine's context has to hold."""
+    resolved = engine_config(traffic, cfg)
+    need = longest_prompt + warm_tail(longest_prompt, longest_ctx,
+                                      warm_rows(traffic, resolved))
+    if need > resolved.max_seq_len:
+        raise ValueError(
+            f"the mix does not fit the engine's context: its longest context of "
+            f"{longest_ctx} tokens and the warm-up's tail make a request of {need}, "
+            f"over max_seq_len {resolved.max_seq_len} (the least of the traffic file's "
+            "engine.max_seq_len, else FLAGS_serve_max_seq_len, and the "
+            "configuration's max_position_embeddings)")
+    return resolved
+
+
 def warm(eng, schedule, traffic, block_size, vocab, log):
     """Reach every shape the traffic reaches, through ``submit`` alone.
 
@@ -58,10 +117,9 @@ def warm(eng, schedule, traffic, block_size, vocab, log):
         n = max(int(x) for x in schedule.prompt_len if x <= b)
         eng.submit(mk(n), max_new_tokens=1).result(timeout=1200)
     log(f"warm: prefill buckets {buckets}")
-    rows = int(traffic.get("warm_rows", eng.config.max_batch))  # the rehearsal walks fewer
-    longest_ctx = int((schedule.prompt_len + schedule.out_len).max())
-    longest_prompt = int(schedule.prompt_len.max())
-    tail = max(longest_ctx - longest_prompt, 1) + 2 * rows + 8
+    rows = warm_rows(traffic, eng.config)
+    longest_prompt, longest_ctx = longest_of(schedule)
+    tail = warm_tail(longest_prompt, longest_ctx, rows)
     long_h = eng.submit(mk(longest_prompt), max_new_tokens=tail, stream=True)
     it = iter(long_h)
     next(it)
@@ -74,6 +132,14 @@ def warm(eng, schedule, traffic, block_size, vocab, log):
         f"{eng.stats()['compiles']} programs")
 
 
+def padded_len(n, pad_to=256):
+    """The length the reference is run at for ``n`` positions: a multiple of
+    ``pad_to`` up to 2,048 and of 1,024 beyond, so that a check of contexts up
+    to 8,192 compiles six float32 programs past 2,048 and not twenty-four."""
+    step = pad_to if n <= 2048 else max(pad_to, 1024)
+    return -(-n // step) * step
+
+
 def served_gap(family, cfg, weights, prompt, tokens, mode="f32", pad_to=256):
     """Run the family's reference once over prompt + served tokens. Returns, for each
     served token, how far its reference logit lies below the reference's
@@ -83,7 +149,7 @@ def served_gap(family, cfg, weights, prompt, tokens, mode="f32", pad_to=256):
 
     ids = np.concatenate([np.asarray(prompt, np.int64), np.asarray(tokens, np.int64)])
     n = len(ids) - 1  # the last served token is never fed back
-    padded = -(-n // pad_to) * pad_to
+    padded = padded_len(n, pad_to)
     x = np.zeros((1, padded), np.int64)
     x[0, :n] = ids[:n]
     ref = family.forward_logits(cfg, weights, x, "f32")[0, len(prompt) - 1:n]
@@ -111,12 +177,21 @@ def sample_finished(served, schedule, seed, k):
     return [longest] + [rest[i] for i in pick]
 
 
+def settling(gaps_ms, gap_at, seconds):
+    """The median gap over the window's first quarter, half, three quarters
+    and whole: how it settles as the window grows (and the queue with it).
+    None where no gap has fallen yet."""
+    so_far = [gaps_ms[gap_at <= f * seconds] for f in (0.25, 0.5, 0.75, 1.0)]
+    return [float(np.percentile(g, 50)) if len(g) else None for g in so_far]
+
+
 def setup(ctx, schedule):
     """Model from the seed, the engine with its pool, every shape warm."""
     from paddle_tpu.framework import flags
     from paddle_tpu.serving import Engine
 
     cfg, traffic, family = ctx.config, ctx.traffic, ctx.family
+    fit(traffic, cfg, *longest_of(schedule))
     t = time.monotonic()
     weights = W.make_weights(cfg, ctx.seed, family.leaf_specs(cfg))
     ctx.note("setup_weights_s", time.monotonic() - t)
@@ -130,7 +205,10 @@ def setup(ctx, schedule):
     blocks = traffic.get("pool_blocks") or pool_blocks(
         family, cfg, block_size, int(traffic["headroom_bytes"]))
     ctx.note("pool_blocks", blocks)
-    eng = Engine(model, num_blocks=blocks)  # default flags; the pool's size is ours
+    # default flags under the traffic file's entry; the pool's size is ours
+    eng = Engine(model, num_blocks=blocks, **traffic.get("engine", {}))
+    for key in ("max_seq_len", "max_batch", "prefill_batch"):
+        ctx.note(f"engine_{key}", getattr(eng.config, key))
     t = time.monotonic()
     warm(eng, schedule, traffic, block_size, cfg["vocab_size"], print)
     ctx.note("setup_warm_s", time.monotonic() - t)
@@ -210,10 +288,9 @@ def run(ctx) -> dict:
     if len(gaps_ms):
         log("gaps ms p5/25/50/75/95/99: " + " ".join(
             f"{np.percentile(gaps_ms, q):.2f}" for q in (5, 25, 50, 75, 95, 99)))
-        # how the median settles as the window grows (and the queue with it)
         log("gap p50 ms over the window's first quarter/half/three quarters/whole: "
-            + " ".join(f"{np.percentile(gaps_ms[st['gap_at'] <= f * schedule.seconds], 50):.3f}"
-                       for f in (0.25, 0.5, 0.75, 1.0)))
+            + " ".join("none" if x is None else f"{x:.3f}"
+                       for x in settling(gaps_ms, st["gap_at"], schedule.seconds)))
         log("ttft ms p10/50/90: " + " ".join(
             f"{np.percentile(ttft_ms, q):.1f}" for q in (10, 50, 90))
             + f"; generator late p99 {np.percentile(st['late'], 99) * 1e3:.2f} ms")

@@ -73,6 +73,79 @@ def test_quantiles_kinds():
         G.quantiles({"dist": "zipf"}, 3)
 
 
+@pytest.mark.parametrize("dist,n,want", [
+    ({"dist": "lognormal", "median": 640, "sigma": 0.7, "lo": 128, "hi": 1408}, 7,
+     [229, 368, 495, 640, 827, 1114, 1408]),
+    ({"dist": "linspace", "lo": 3, "hi": 50}, 5, [3, 15, 26, 38, 50]),
+    ({"dist": "const", "value": 9}, 2, [9, 9]),
+    ({"dist": "exponential", "mean": 1.5}, 4,
+     [0.20029708893678394, 0.7050054438686033, 1.4712438795175893, 3.1191623125197534]),
+])
+def test_the_four_plain_kinds_return_what_they_did(dist, n, want):
+    """Values taken at the parent commit of PR 44 (ae673ab), which brought
+    ``mixture``: bit for bit, the gaps too."""
+    assert G.quantiles(dist, n).tolist() == want
+
+
+SHORT = {"dist": "lognormal", "median": 384, "sigma": 0.6, "lo": 128, "hi": 1024}
+LONG = {"dist": "lognormal", "median": 2560, "sigma": 0.1, "lo": 2048, "hi": 3072}
+TWO_MODES = {"dist": "mixture", "parts": [{"weight": 0.75, **SHORT}, {"weight": 0.25, **LONG}]}
+
+
+@pytest.mark.parametrize("weights,n,want", [
+    ([0.75, 0.25], 8, [6, 2]),
+    ([0.75, 0.25], 10, [8, 2]),          # 7.5 and 2.5: the tie goes to the earlier part
+    ([0.75, 0.25], 1, [1, 0]),
+    ([0.25, 0.75], 2, [1, 1]),           # 0.5 and 1.5: the earlier part again
+    ([0.1, 0.2, 0.7], 10, [1, 2, 7]),    # decimals as the file states them, not as floats
+    ([0.29, 0.71], 100, [29, 71]),
+    ([0.5, 0.3, 0.2], 7, [4, 2, 1]),     # 3.5 2.1 1.4: one left over, to the largest remainder
+    ([1.0], 5, [5]),
+    ([0.75, 0.25], 0, [0, 0]),
+])
+def test_a_mixture_shares_its_requests_by_largest_remainder(weights, n, want):
+    parts = [{"weight": w, "dist": "const", "value": 10 * i} for i, w in enumerate(weights)]
+    got = G.quantiles({"dist": "mixture", "parts": parts}, n)
+    assert [int((got == 10 * i).sum()) for i in range(len(weights))] == want
+    assert got.dtype == np.int64 and len(got) == n
+
+
+@pytest.mark.parametrize("dist,says", [
+    ({"dist": "mixture", "parts": [{"weight": 0.5, **SHORT}, {"weight": 0.4, **LONG}]},
+     "not shares that sum to 1"),
+    ({"dist": "mixture", "parts": [{"weight": 1.5, **SHORT}, {"weight": -0.5, **LONG}]},
+     "not shares that sum to 1"),
+    ({"dist": "mixture", "parts": [{"weight": 1.0, **TWO_MODES}]}, "may not be 'mixture'"),
+    ({"dist": "mixture", "parts": [{"weight": 0.5, **SHORT},
+                                   {"weight": 0.5, "dist": "exponential", "mean": 9.0}]},
+     "may not be 'exponential'"),
+])
+def test_a_mixture_that_is_none_is_refused_by_name(dist, says):
+    with pytest.raises(ValueError, match=says):
+        G.quantiles(dist, 8)
+
+
+def test_two_modes_of_length_in_one_queue():
+    """A mix of short and long prompts: the same multiset for any seed, both
+    modes in every round of 16 in the mix's proportions, and the longest
+    context known from the file alone."""
+    # part after part, each the quantiles of its own share
+    assert G.quantiles(TWO_MODES, 10).tolist() == (G.quantiles(SHORT, 8).tolist()
+                                                   + G.quantiles(LONG, 2).tolist())
+    traffic = {**CHAT, "rate_per_s": 4.0, "ramp_rate_per_s": 4.0, "prompt_len": TWO_MODES}
+    a, b = (G.build_schedule(traffic, 16, s, 50304) for s in SEEDS[:2])
+    for w in (a.in_window, ~a.in_window):
+        assert Counter(zip(a.prompt_len[w], a.out_len[w])) == \
+            Counter(zip(b.prompt_len[w], b.out_len[w]))
+    assert not np.array_equal(a.prompt_len, b.prompt_len)
+    p = a.prompt_len[a.in_window]
+    assert len(p) == 64 and int((p >= 2048).sum()) == 16 and p.max() <= 3072
+    assert [int((p[i:i + 16] >= 2048).sum()) for i in range(0, 64, 16)] == [4, 4, 4, 4]
+    longest_prompt, longest_ctx = G.longest(traffic, 16)
+    assert longest_prompt == max(a.prompt_len) and \
+        longest_ctx == max(a.prompt_len + a.out_len) > 2048
+
+
 def test_open_loop_times_from_due_and_reports_lateness():
     traffic = {**CHAT, "rate_per_s": 40.0, "ramp_rate_per_s": 40.0, "ramp_s": 0.25,
                "prompt_len": {"dist": "const", "value": 4},
@@ -164,6 +237,25 @@ def test_another_order_seed_offers_the_same_multisets_in_another_order():
             assert np.allclose(gaps(s), gaps(a))
         assert not np.array_equal(s.prompt_len, a.prompt_len)
         assert not np.array_equal(s.due, a.due)
+
+
+@pytest.mark.parametrize("mix,vocab,seed,seconds,want", [
+    ("chat-sat", 50304, 4_400_000_021, 51, "c99b001b2e158512"),
+    ("chat-sat", 50304, 7, 10, "65685084ae37300d"),
+    ("longanswer-pinned", 131072, 4_400_000_021, 51, "567f8da601818d88"),
+    ("longanswer-pinned", 131072, 7, 10, "78bcb2957c22f1fd"),
+    ("reasoning-pinned", 200064, 4_400_000_021, 51, "173c91a47a2e7137"),
+    ("reasoning-pinned", 200064, 7, 10, "54ce59f1503f0c29"),
+    ("toolturn-pinned", 65536, 4_400_000_021, 51, "59b4de9309872081"),
+    ("toolturn-pinned", 65536, 7, 10, "81974e82cbe5499c"),
+])
+def test_every_serving_mix_offers_the_parent_s_schedule(mix, vocab, seed, seconds, want):
+    """Digests of each serving mix's schedule (due times, lengths, window,
+    token ids) taken at the parent commit of PR 44 (ae673ab): ``mixture``, the
+    ``engine`` entry and the fit check moved no cell's traffic."""
+    traffic = json.loads((Path(G.__file__).parent / "traffic" / f"{mix}.json").read_text())
+    assert "engine" not in traffic
+    assert _digest(G.build_schedule(traffic, seconds, seed, vocab)) == want
 
 
 @pytest.mark.parametrize("seed,seconds,want", [

@@ -55,10 +55,15 @@ def test_manifest_is_sound_and_states_the_cut(M, CFG, FAM):
     # the dense model's share lists its own cell since PR 32: a static count
     # cannot follow a routing, and moe_decode_hbm_roofline is the reading here
     assert "decode_hbm_roofline" not in mine and not hasattr(FAM, "weight_bytes")
-    # appended: what the manifest held before comes first, in its order
-    assert [c["name"] for c in M.data["configs"]][-1] == "xing4-29b-a4b-8l"
-    assert [w["name"] for w in M.data["workloads"]][-1] == CELL
-    assert [m["name"] for m in M.data["per_layer"]][-5:] == [
+    # appended behind what the manifest held at PR 27 (later PRs append behind
+    # these in turn, so nothing here says "last")
+    after = lambda names, mine, before: names.index(mine) == names.index(before) + 1
+    assert after([c["name"] for c in M.data["configs"]], "xing4-29b-a4b-8l",
+                 "gpt3-6p7b-4chip")
+    assert after([w["name"] for w in M.data["workloads"]], CELL, "train-hybrid-4chip")
+    metrics = [m["name"] for m in M.data["per_layer"]]
+    at = metrics.index("experts_touched_per_layer")
+    assert metrics[at:at + 5] == [
         "experts_touched_per_layer", "expert_load_max_over_mean",
         "moe_decode_hbm_roofline", "expert_ffn_roofline", "latent_attention_roofline"]
     # the load is ISSUE 32's: four fifths of the knee the file states as a
