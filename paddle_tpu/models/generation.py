@@ -799,6 +799,14 @@ def window_block(tokens: int, block_size: int) -> int:
     return math.gcd(int(tokens), int(block_size))
 
 
+def window_table(W: int, slots, block_size: int):
+    """``(window_block, tables (B, W / window_block))``: the blocks of each
+    row's ring, as a block table the block-table read takes: slot ``s`` holds
+    blocks ``s W / wb .. (s + 1) W / wb - 1`` of the window pools."""
+    wb = window_block(W, block_size)
+    return wb, slots[:, None] * (W // wb) + jnp.arange(W // wb)[None]
+
+
 def _phi4flash_arch(cfg, kernels):
     """The arch plug of the Mamba / differential-attention hybrid. A layer
     caches one of three things or nothing (``cache["layers"]``: the kind, and
@@ -834,13 +842,9 @@ def _phi4flash_arch(cfg, kernels):
         return _head_mm(params, P.layer_norm(x, params["lnf_g"], params["lnf_b"],
                                              cfg.layer_norm_eps), "wte", True)
 
-    def window_table(slots, block_size):
-        wb = window_block(W, block_size)
-        return wb, slots[:, None] * (W // wb) + jnp.arange(W // wb)[None]
-
     def prompt_stack(params, x, pools, lens, live, tb, slots, block_size):
         B = x.shape[0]
-        wb, wrows = window_table(slots, block_size)
+        wb, wrows = window_table(W, slots, block_size)
 
         def keep(pools, kind, i, a, b):
             kp, vp, wk, wv, S, tails = pools
@@ -863,7 +867,7 @@ def _phi4flash_arch(cfg, kernels):
         from ..ops.kernels.selective_scan import state_update, state_update_plain
 
         B = x.shape[0]
-        wb, wtables = window_table(slots, block_size)
+        wb, wtables = window_table(W, slots, block_size)
         ring = pos % W
         wbids, woffs = wtables[:, 0] + ring // wb, ring % wb
         wpos = jnp.minimum(pos, W - 1)  # a full ring: every entry is live
@@ -1033,6 +1037,134 @@ def _lfm2_moe_arch(cfg, kernels):
                                      "state_rows": "state"},
                       "paged": (cfg.kv_row,) * 2,
                       "state": (((cfg.conv_L_cache - 1, cfg.hidden_size), None),)}}
+
+
+def afmoe_decode_state(model, kernels=None):
+    """(arch_key, arch, params, max_positions) for ``AfmoeForCausalLM``: the
+    weight tree (the repetitions of the layer pattern stacked, as the model
+    holds them) and the arch plug around ``models/afmoe.py``'s layer
+    functions. ``kernels``: whether the prompt attention, the experts and the
+    cache reads take their Pallas kernels (default: wherever Mosaic compiles)
+    or their plain forms."""
+    from . import afmoe as A
+
+    cfg = model.config
+    kernels, arch_key = _keyed_by_config("afmoe", cfg, kernels)
+    params = A.params_tree(
+        cfg, {k: v._data for k, v in model.state_dict().items()})
+    return arch_key, _afmoe_arch(cfg, kernels), params, \
+        cfg.max_position_embeddings
+
+
+def _afmoe_arch(cfg, kernels):
+    """The arch plug of the window-and-full / gated-attention / routed-expert
+    lineage: a whole-stack arch (row slots, a cache of two kinds) that routes
+    experts, so its stacks hand the programs their counts beside the pools.
+    Both kinds hold K (after its norm and, in a window layer, its rotation)
+    and V as they lie, ``(G, D)`` a token:
+
+    - ``paged``: a row a token by block table, for the full layers;
+    - ``window``: the last ``sliding_window`` rows of a row slot, a RING
+      (position ``p`` at entry ``p % W``: a key is rotated before it is
+      cached, so its place in the ring says nothing), stored as blocks so
+      that the block-table read serves it (``window_table``).
+
+    A prompt's rows and rings are written by ONE scatter a pool after the
+    stack (the layers stage them, ``afmoe.prompt_reads``); a decode step
+    writes its fresh row a layer, before the read. ``prefill_attrs`` says
+    what a prefill's attention must score (``band_tokens_*``, one layer of the
+    kind). It has the plain prefill and decode programs and no other yet
+    (``plain_paths_only``)."""
+    from . import afmoe as A
+    from .phi4flash import attend_dense
+
+    W = cfg.sliding_window
+    H, (G, D) = cfg.num_attention_heads, cfg.kv_row
+    kinds = {"sliding_attention": "window", "full_attention": "paged"}
+    if set(cfg.layer_types) != set(kinds):
+        raise NotImplementedError(
+            f"afmoe: layer_types {cfg.layer_types!r}; the arch holds a pool a "
+            "kind and expects layers of both")
+
+    def embed(params, ids, posm):
+        return A.embed(cfg, params, ids)
+
+    def head(params, x):
+        return _head_mm(params, A.M.rms(x, params["norm"], cfg.rms_norm_eps),
+                        "head_w", False)
+
+    def prompt_stack(params, x, pools, lens, live, tb, slots, block_size):
+        B, T = x.shape[:2]
+        pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+        x, (k, v, rk, rv), counts = A.stack(
+            cfg, params, x, pos, live, A.prompt_staging(cfg, B, T, x.dtype),
+            A.prompt_reads(cfg, lens, kernels), kernels)
+        kp, vp, wk, wv = pools
+        wb, wrows = window_table(W, slots, block_size)
+        # (layers, B, tokens, G, D) rows as whole blocks of (token, head) lines
+        cut = lambda r, size: r.reshape(r.shape[0], B, -1, size * G, D)
+        return x, (kp.at[:, tb].set(cut(k, block_size)),
+                   vp.at[:, tb].set(cut(v, block_size)),
+                   wk.at[:, wrows].set(cut(rk, wb)),
+                   wv.at[:, wrows].set(cut(rv, wb))), counts
+
+    def decode_stack(params, x, pools, tables, pos, bids, offs, slots,
+                     block_size):
+        from ..ops.kernels import paged_attention_rows
+
+        B = x.shape[0]
+        wb, wtables = window_table(W, slots, block_size)
+        ring = pos % W
+        wbids, woffs = wtables[:, 0] + ring // wb, ring % wb
+        wpos = jnp.minimum(pos, W - 1)  # a full ring: every entry is live
+        # a token's lines inside its block
+        lines = lambda offs: offs[:, None] * G + jnp.arange(G)[None]
+
+        def read(kpool, vpool, i, q, k, v, bids, offs, tables, pos):
+            """The fresh row goes into the pool BEFORE the read: every live
+            position by table."""
+            kpool = kpool.at[i, bids[:, None], lines(offs)].set(k[:, 0])
+            vpool = vpool.at[i, bids[:, None], lines(offs)].set(v[:, 0])
+            if kernels:
+                o = paged_attention_rows(q[:, 0], kpool, vpool, i, tables, pos,
+                                         kv_heads=G).reshape(B, 1, H, D)
+            else:
+                T_pad = tables.shape[1] * kpool.shape[2] // G
+                kc = kpool[i, tables].reshape(B, T_pad, G, D)
+                vc = vpool[i, tables].reshape(B, T_pad, G, D)
+                seen = jnp.arange(T_pad)[None, None, :] <= pos[:, None, None]
+                o = attend_dense(cfg, q, kc, vc, seen)
+            return kpool, vpool, o
+
+        def full(pools, i, q, k, v):
+            kp, vp, wk, wv = pools
+            kp, vp, o = read(kp, vp, i, q, k, v, bids, offs, tables, pos)
+            return (kp, vp, wk, wv), o
+
+        def sliding(pools, i, q, k, v):
+            kp, vp, wk, wv = pools
+            wk, wv, o = read(wk, wv, i, q, k, v, wbids, woffs, wtables, wpos)
+            return (kp, vp, wk, wv), o
+
+        live = tables[:, :1] != 0  # a row whose table is unmapped pads the bucket
+        return A.stack(cfg, params, x, pos[:, None], live, pools,
+                       {"full_attention": full, "sliding_attention": sliding},
+                       kernels)
+
+    def prefill_attrs(lens):
+        return {"band_tokens_window": A.band_tokens(lens, W),
+                "band_tokens_full": A.band_tokens(lens)}
+
+    return {"name": "afmoe", "embed": embed, "head": head,
+            "prompt_stack": prompt_stack, "decode_stack": decode_stack,
+            "plain_paths_only": True, "prefill_attrs": prefill_attrs,
+            "expert_layers": cfg.num_hidden_layers - cfg.num_dense_layers,
+            "experts": len(cfg.experts_held),
+            "cache": {"layers": tuple((kinds[k], None) for k in cfg.layer_types),
+                      "window_tokens": W,
+                      "span_attrs": {"paged_kv_tokens": "paged",
+                                     "window_tokens": "window"},
+                      "paged": (cfg.kv_row,) * 2, "window": (cfg.kv_row,) * 2}}
 
 
 def cache_row_shapes(arch):

@@ -52,6 +52,7 @@ from jax import lax
 from .. import nn
 from ..core.tensor import Tensor
 from . import mla_moe as M
+from .periodic_stack import PeriodicLayers, layer_trees, scan_stack
 from .phi4flash import attend_dense, prompt_conv
 
 F32 = jnp.float32
@@ -59,7 +60,7 @@ KINDS = ("conv", "full_attention")
 
 
 @dataclass
-class Lfm2MoeConfig:
+class Lfm2MoeConfig(PeriodicLayers):
     vocab_size: int = 65536
     hidden_size: int = 2048
     intermediate_size: int = 7168
@@ -133,30 +134,6 @@ class Lfm2MoeConfig:
         """What the cache holds of a token in an attention layer, for K and
         for V: neighbouring heads side by side, two a line."""
         return (self.num_key_value_heads // 2, 2 * self.head_dim)
-
-    @property
-    def period(self) -> Tuple[str, ...]:
-        """The shortest pattern that the layers behind the dense ones repeat
-        (the last repetition may be cut short)."""
-        body = self.layer_types[self.num_dense_layers:]
-        for p in range(1, len(body) + 1):
-            if all(body[i] == body[i % p] for i in range(len(body))):
-                return body[:p]
-        return ()
-
-    @property
-    def periods(self) -> int:
-        """Whole repetitions of ``period``: what the programs scan over."""
-        p = len(self.period)
-        return (self.num_hidden_layers - self.num_dense_layers) // p if p else 0
-
-    @property
-    def tail_start(self) -> int:
-        """The first layer behind the last whole period."""
-        return self.num_dense_layers + self.periods * len(self.period)
-
-    def is_expert_layer(self, i: int) -> bool:
-        return i >= self.num_dense_layers
 
 
 # -- the layer equations -----------------------------------------------------------
@@ -247,52 +224,9 @@ def stack(cfg: Lfm2MoeConfig, params, x, pos, live, pools, conv, attend, kernels
     -> (pools, taps (B, T, K, d))`` and ``attend(pools, i, q, k, v) -> (pools,
     o)``. Returns ``(x, pools, counts (expert layers, experts) or None)``."""
     freqs = rope_freqs(cfg)
-    kinds, period, P = cfg.layer_types, cfg.period, cfg.periods
-    seen = dict.fromkeys(KINDS, 0)   # layers of each kind so far
-    counts = []
-
-    def one(w, x, pools, i):
-        box = {"pools": pools}
-
-        def read(fn):
-            def call(*a):
-                box["pools"], out = fn(box["pools"], i, *a)
-                return out
-            return call
-
-        x, c = layer(cfg, freqs, w, x, pos, live, read(conv), read(attend), kernels)
-        return x, box["pools"], c
-
-    def unrolled(ws, first, x, pools):
-        for l, w in enumerate(ws, first):
-            x, pools, c = one(w, x, pools, seen[kinds[l]])
-            seen[kinds[l]] += 1
-            if c is not None:
-                counts.append(c[None])
-        return x, pools
-
-    x, pools = unrolled(params["lead"], 0, x, pools)
-    if P:
-        def turn(carry, xs):
-            x, pools = carry
-            ws, p = xs
-            rank, cs = dict(seen), []
-            for j, kind in enumerate(period):
-                # the experts' stacks whole, the turn as a scalar: the grouped
-                # kernel reads its layer's experts where they lie
-                w = {**ws[j], **params["body_experts"][j], "experts_layer": p}
-                x, pools, c = one(w, x, pools, rank[kind] + p * period.count(kind))
-                rank[kind] += 1
-                cs.append(c)
-            return (x, pools), jnp.stack(cs)
-
-        (x, pools), cs = lax.scan(
-            turn, (x, pools), (params["body"], jnp.arange(P, dtype=jnp.int32)))
-        for kind in KINDS:
-            seen[kind] += P * period.count(kind)
-        counts.append(cs.reshape((-1, cs.shape[-1])))
-    x, pools = unrolled(params["tail"], cfg.tail_start, x, pools)
-    return x, pools, jnp.concatenate(counts) if counts else None
+    return scan_stack(
+        cfg, params, x, pools, lambda kind, w, x, read: layer(
+            cfg, freqs, w, x, pos, live, read(conv), read(attend), kernels))
 
 
 def prompt_reads(cfg: Lfm2MoeConfig, lens, T):
@@ -412,21 +346,8 @@ def params_tree(cfg: Lfm2MoeConfig, sd):
     ``body`` a dict a position of the period with the repetitions stacked,
     which the scan slices, and beside it ``body_experts``, the experts' stacks
     of the same positions, which it does not."""
-    def short(name):
-        return _SHORT[name[:-len(".weight")] if name.endswith(".weight") else name]
-
-    layers, body = {}, {}
-    for key, _, _ in _leaf_kinds(cfg):
-        parts = key.split(".")
-        if parts[1] in ("layers", "body"):
-            into = layers if parts[1] == "layers" else body
-            into.setdefault(int(parts[2]), {})[short(".".join(parts[3:]))] = sd[key]
-    split = lambda w, mine: {k: v for k, v in w.items() if (k in _EXPERTS) == mine}
     return {"wte": sd["model.embed_tokens.weight"], "norm": sd["model.norm.weight"],
-            "lead": [layers[i] for i in sorted(layers) if i < cfg.num_dense_layers],
-            "tail": [layers[i] for i in sorted(layers) if i >= cfg.tail_start],
-            "body": [split(body[j], False) for j in sorted(body)],
-            "body_experts": [split(body[j], True) for j in sorted(body)]}
+            **layer_trees(cfg, _leaf_kinds(cfg), _SHORT.__getitem__, _EXPERTS, sd)}
 
 
 class Lfm2MoeForCausalLM(nn.Layer):

@@ -2153,6 +2153,11 @@ class Engine:
                     sp.set(prompt_tokens=real)
                     if self._row_slots is not None:
                         sp.set(scan_tokens=real)
+                    if "prefill_attrs" in self._arch:
+                        # what the arch says of these lengths (the keys its
+                        # prompt attention must score, by kind of layer)
+                        sp.set(**self._arch["prefill_attrs"](
+                            [len(s.tokens) for s in chunk]))
                     logits, *extras = self._run(
                         fn, self._compute_params, jnp.asarray(ids),
                         jnp.asarray(lens), jnp.asarray(tables),

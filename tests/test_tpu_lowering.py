@@ -617,6 +617,105 @@ def test_lfm2_prefill_program_compiles(v5e, monkeypatch):
     assert mem.temp_size_in_bytes < 1.5e9, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
 
 
+# -- window and full layers of grouped 128-wide heads (PR 45) ------------------
+@pytest.mark.parametrize("T", [16, 128, 8192])
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_window_flash_compiles(v5e, T, window):
+    """The prompt kernel at the cell's head counts (32 query heads on 4 K/V
+    heads of 128), from the warm-up's 16-token bucket to the long prompts'
+    8,192: Mosaic takes the lane slices of the grouped query block and K/V of
+    one head resident in VMEM."""
+    from paddle_tpu.ops.kernels.window_flash import window_flash
+
+    s = SingleDeviceSharding(v5e[0])
+    calls = _compile_for_tpu(
+        lambda q, k, v, lens: window_flash(q, k, v, lens, heads=32, window=window,
+                                           interpret=False),
+        _on(s, (1, T, 4096), jnp.bfloat16), _on(s, (1, T, 512), jnp.bfloat16),
+        _on(s, (1, T, 512), jnp.bfloat16), _on(s, (1,), jnp.int32))
+    assert calls == 1
+
+
+def _afmoe_operands(s, nb, monkeypatch):
+    """(arch, params as shapes, the pools) of the benchmark's configuration,
+    its kernels Mosaic's."""
+    import json
+    import pathlib
+
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.models import afmoe as A
+    from paddle_tpu.ops.kernels import moe_experts, paged_attention, window_flash
+
+    for mod in (moe_experts, paged_attention, window_flash):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    path = pathlib.Path(__file__).parent.parent / "benchmark/configs/trinity-mini-16l-ep8.json"
+    file = json.loads(path.read_text())
+    cfg = A.AfmoeConfig.from_dict({**file, "num_experts": file["published"]["num_experts"],
+                                   "held_experts": range(file["num_experts"])})
+    sd = {k: _on(s, shape, jnp.bfloat16)
+          for k, shape, _ in A.AfmoeForCausalLM.parameter_specs(cfg)}
+    params = jax.tree_util.tree_map(
+        lambda x: _on(s, x.shape, x.dtype),
+        jax.eval_shape(lambda sd: A.params_tree(cfg, sd), sd))
+    arch = G._afmoe_arch(cfg, True)
+    pools = [_on(s, shape, dtype or jnp.bfloat16)
+             for _, shape, dtype in G.cache_pools(arch, 0, nb, 16, 64)]
+    return arch, params, pools
+
+
+@pytest.mark.parametrize("B", [48, 64])
+def test_afmoe_decode_step_beside_a_full_pool(v5e, monkeypatch, B):
+    """The cell's two widest decode programs (its engine states the buckets
+    32, 48 and 64) of the cell's configuration at a context of
+    8,192 (a table of 512 blocks), as the engine jits it: ONE period of the
+    layer pattern in the scan and the two dense and two tail layers unrolled
+    (8 block-table reads, 6 expert calls, whatever the depth), the four
+    donated pools (pages and rings) updated in place, and temporaries that
+    follow neither (30 MB when written)."""
+    import paddle_tpu.models.generation as G
+
+    s, MB, NB = SingleDeviceSharding(v5e[0]), 512, 40000
+    arch, params, pools = _afmoe_operands(s, NB, monkeypatch)
+    assert [p.shape for p in pools] == [(4, NB, 64, 128)] * 2 + [(12, 65 * 128, 64, 128)] * 2
+    assert G.paged_kernel_default(arch, mosaic=True) and G.cache_slots(arch)
+    step = jax.jit(G.feed_tokens_back(G.build_paged_decode_kernel(arch, B, 16, MB),
+                                      B, 64, MB, 4, slots=True),
+                   donate_argnums=(1, 2, 3, 4))
+    compiled = _compile_uncached(step.trace(
+        params, *pools, _on(s, (B, MB + G.STEP_COLS + 1), jnp.int32),
+        _on(s, (64,), jnp.int32), _on(s, (2,), jnp.uint32),
+    ).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 8 + 6
+    for name in ("moe_experts_t", "paged_attention"):
+        assert f"%{name}" in text, name
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p.shape)) for p in pools)
+    assert mem.temp_size_in_bytes < 128e6, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
+def test_afmoe_long_prefill_program_compiles(v5e, monkeypatch):
+    """The cell's widest prefill program (ONE prompt in the bucket of 8,192):
+    the prompt kernel in every layer (no (T, T) scores: those alone would be
+    8.6 GB), experts in 256-row tiles, each pool written by ONE scatter after
+    the scan (aliased, no copy of a pool or of the rings), and temporaries
+    well inside what the pool's sizing leaves (0.83 GB when written)."""
+    import paddle_tpu.models.generation as G
+
+    s, NB = SingleDeviceSharding(v5e[0]), 40000
+    arch, params, pools = _afmoe_operands(s, NB, monkeypatch)
+    pre = jax.jit(G.build_paged_prefill(arch, 1, 8192, 16, 512), donate_argnums=(5, 6, 7, 8))
+    compiled = _compile_uncached(pre.trace(
+        params, _on(s, (1, 8192), jnp.int32), _on(s, (1,), jnp.int32),
+        _on(s, (1, 512), jnp.int32), _on(s, (1,), jnp.int32), *pools,
+    ).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert "%window_flash" in text and "%moe_experts_t256" in text
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= sum(2 * int(np.prod(p.shape)) for p in pools)
+    assert mem.temp_size_in_bytes < 1.5e9, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
 # -- the hybrid step's collectives (PR 30, PR 36) ----------------------------
 def _hybrid_step(v5e, monkeypatch, layers=2):
     """The four-chip cell's step at ``layers`` layers and its width of 4096,
