@@ -807,6 +807,43 @@ def window_table(W: int, slots, block_size: int):
     return wb, slots[:, None] * (W // wb) + jnp.arange(W // wb)[None]
 
 
+def paged_step_attrs(reads, pos, bucket, block_size, max_blocks, dtype):
+    """What the block-table kernel's copy schedule does in one decode step
+    with live rows that write positions ``pos``, summed over the step's
+    calls of ``paged_attention_rows``: ``paged_blocks`` copied from each
+    pool, the ``paged_chunks`` they come in and ``paged_full_chunks``, those
+    started as straight-line code and waited for once (``ops/kernels/
+    paged_attention.chunk_counts``), for the ``decode_step`` span. ``reads``:
+    ``(calls a step, KV heads, queries a KV head, head width, window)`` for
+    each kind of read, as the kernel's key has them; ``window`` 0: the rows'
+    block tables by ``pos``; else a ring of that many tokens, read through a
+    table of its own with ``pos`` held at ``window - 1``. The chunk is the
+    one the ``bucket``'s program resolved when it was traced."""
+    from ..ops.kernels import paged_attention as K
+
+    pos = np.asarray(pos, np.int64)
+    total = dict.fromkeys(("paged_blocks", "paged_chunks", "paged_full_chunks"), 0)
+    for calls, KV, rep, D, window in reads:
+        BS, MB, at = block_size, max_blocks, pos
+        if window:
+            BS = window_block(window, block_size)
+            MB, at = window // BS, np.minimum(pos, window - 1)
+        C = K.blocks_per_chunk(
+            K.paged_attention_key(bucket, MB, BS, KV, rep, D, dtype))
+        for name, n in K.chunk_counts(at, BS, C).items():
+            total[name] += calls * n
+    return total
+
+
+def _kernel_step_attrs(kernels, reads) -> dict:
+    """The ``step_attrs`` entry of a plug whose decode reads are ``reads``
+    (:func:`paged_step_attrs`) where they go through the kernel; the plain
+    gather has no chunks and says nothing."""
+    if not kernels:
+        return {}
+    return {"step_attrs": lambda *step: paged_step_attrs(reads, *step)}
+
+
 def _phi4flash_arch(cfg, kernels):
     """The arch plug of the Mamba / differential-attention hybrid. A layer
     caches one of three things or nothing (``cache["layers"]``: the kind, and
@@ -917,9 +954,12 @@ def _phi4flash_arch(cfg, kernels):
         return P.decode_stack(cfg, params, x, pools, state_step, window_read,
                               paged_read)
 
+    reads = ((sum(k in ("full", "cross") for k in kinds), pairs, H // pairs,
+              2 * h, 0),
+             (kinds.count("window"), pairs, H // pairs, 2 * h, W))
     return {"name": "phi4flash", "embed": embed, "head": head,
             "prompt_stack": prompt_stack, "decode_stack": decode_stack,
-            "plain_paths_only": True,
+            "plain_paths_only": True, **_kernel_step_attrs(kernels, reads),
             "cache": {"layers": layers, "window_tokens": W,
                       "span_attrs": {"shared_kv_tokens": "paged",
                                      "window_tokens": "window",
@@ -1027,9 +1067,11 @@ def _lfm2_moe_arch(cfg, kernels):
         return L.stack(cfg, params, x, pos[:, None], live, pools, conv, attend,
                        kernels)
 
+    reads = ((cfg.layer_types.count("full_attention"), pairs, H // pairs,
+              2 * D, 0),)
     return {"name": "lfm2_moe", "embed": embed, "head": head,
             "prompt_stack": prompt_stack, "decode_stack": decode_stack,
-            "plain_paths_only": True,
+            "plain_paths_only": True, **_kernel_step_attrs(kernels, reads),
             "expert_layers": cfg.num_hidden_layers - cfg.num_dense_layers,
             "experts": cfg.num_experts,
             "cache": {"layers": tuple((kinds[k], None) for k in cfg.layer_types),
@@ -1155,9 +1197,12 @@ def _afmoe_arch(cfg, kernels):
         return {"band_tokens_window": A.band_tokens(lens, W),
                 "band_tokens_full": A.band_tokens(lens)}
 
+    reads = ((cfg.layer_types.count("full_attention"), G, H // G, D, 0),
+             (cfg.layer_types.count("sliding_attention"), G, H // G, D, W))
     return {"name": "afmoe", "embed": embed, "head": head,
             "prompt_stack": prompt_stack, "decode_stack": decode_stack,
             "plain_paths_only": True, "prefill_attrs": prefill_attrs,
+            **_kernel_step_attrs(kernels, reads),
             "expert_layers": cfg.num_hidden_layers - cfg.num_dense_layers,
             "experts": len(cfg.experts_held),
             "cache": {"layers": tuple((kinds[k], None) for k in cfg.layer_types),

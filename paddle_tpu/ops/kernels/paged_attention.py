@@ -33,6 +33,33 @@ one replaces):
   head by head; the wasted columns cost MXU rows that a decode step has to
   spare. Running max, sum and accumulator are float32.
 
+The copy schedule of a chunk (PR 46; ``mla_paged_attention`` has had it for
+its one pool since PR 43, and its docstring has the reasons). A block is two
+copies, its K slab and its V slab. Timed alone at the shapes the serving
+cells run (PERF.md section 6, PR 46), half of a block's time at 4 K/V heads
+(98.6 ns at 8 blocks a chunk: 47 the dots, 52 the rest) was neither its
+bytes nor its dots but what stands BESIDE the dots on the one instruction
+stream: the scalar work of starting a copy (a table entry, the address
+arithmetic, two bounds checks) and of waiting for it, twice a
+block. So a FULL chunk (all ``C`` blocks live: every chunk of a row but its
+last) has its ``2 C`` copies started as straight-line code (ONE traced loop
+body, unrolled when the kernel is lowered, at ONE site in the chunk loop
+that starts either the row's next chunk or the next row's first, plus the
+first grid step's start) and is waited for ONCE a pool: a DMA semaphore
+counts bytes, so a wait on a descriptor whose destination is the whole
+``kbuf.at[slot]`` takes the ``C`` blocks' bytes off ``sems.at[slot, 0]``,
+and ``vbuf``'s off ``sems.at[slot, 1]``. One chunk at most is in flight a
+slot, so the counts are that chunk's alone, also across the grid step that
+hands the slot to the next row. A PARTIAL chunk (a row's last, ``live <
+C``) keeps the two loops of dynamic length, a K and a V start and a K and a
+V wait a live block. The copies, their order and the arithmetic are the
+same in both: at one ``blocks_per_chunk`` the outputs are bit for bit those
+of the schedule with a loop a block (``tests/test_paged_kernel.py`` keeps
+that body). Where a block is large (10 pairs: 2 x 40 KB) the copies
+themselves set the pace (scattered slabs move at 690 GB/s, 84% of the HBM
+peak) and the schedule changes nothing. :func:`chunk_counts` says on the
+host how many of a step's chunks came whole.
+
 Contract with the gather path (``models/generation.py build_paged_decode``,
 which stays as the plain reference): the caller scatters the step's fresh
 K/V into the pool BEFORE the call, every live position is attended, K/V and
@@ -44,10 +71,19 @@ in chunk order and divides once at the end. The tolerance the tests hold is
 tiny models. With bfloat16 inputs the probabilities are rounded to bfloat16
 before the second dot, as the gather path's einsum rounds them.
 
-Tunable (kernel registry): ``blocks_per_chunk`` (C). One batch row a grid
-step: copies are prefetched across rows whether they share a grid step or
-not, and on the chip 8 rows a step read the same time as 1 (PERF.md, PR 25),
-so there is no rows-per-program knob.
+Tunable (kernel registry): ``blocks_per_chunk`` (C). The dots of a chunk are
+one dependent chain (scores, row maximum, exponentials, the second dot, the
+carry) that costs a fixed part however many tokens the chunk holds, so a
+wider chunk is cheaper a block, until the columns of a row's last, partial
+chunk cost more than the chain saves: since PR 46 (8 until then) the
+registry's 0 leaves the choice to :func:`blocks_per_chunk`, the widest chunk
+of at most 640 KB a pool, which is what the chip measured as fastest at the
+serving cells' shapes: 32 blocks of 16 KB (4 K/V heads of 128 in bfloat16),
+16 of 40 KB (10 pairs), 8 of 64 KB (16 heads); VMEM holds four buffers of
+``C`` blocks, 2 to 2.5 MB. One
+batch row a grid step: copies are prefetched across rows whether they share
+a grid step or not, and on the chip 8 rows a step read the same time as 1
+(PERF.md, PR 25), so there is no rows-per-program knob.
 
 Head width: Mosaic takes a block's slab only when a K/V line fills whole
 128-lane rows (``D % 128 == 0``, :func:`mosaic_takes`). It sees a narrower
@@ -73,7 +109,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..pallas import interpret_default, kernel_x64_off
-from .registry import register_kernel, resolve_config
+from .registry import get_kernel, register_kernel, resolve_config
 
 try:
     from jax.experimental import pallas as pl
@@ -83,7 +119,8 @@ try:
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
-__all__ = ["paged_attention_rows", "paged_attention_key", "mosaic_takes"]
+__all__ = ["paged_attention_rows", "paged_attention_key", "mosaic_takes",
+           "blocks_per_chunk", "chunk_counts"]
 
 # a masked score: far below any real one, and finite, so a row of padding
 # queries (every column masked) still has a finite softmax
@@ -104,34 +141,109 @@ def mosaic_takes(head_dim) -> bool:
     return int(head_dim) % 128 == 0
 
 
+# what a chunk of one pool may hold where the registry leaves the choice to
+# the shape: measured on the chip (PERF.md section 6, PR 46), the kernel
+# alone is fastest at 32 blocks of 16 KB, 16 of 40 KB and 8 of 64 KB a chunk
+_CHUNK_BYTES = 640 * 1024
+
+
+def blocks_per_chunk(key, config=None) -> int:
+    """The ``C`` a call of shape ``key`` (``paged_attention_key``) copies and
+    multiplies at a time, at most a row's table: the registry's, and where
+    that is 0 the widest of its space whose chunk of one pool is at most
+    ``_CHUNK_BYTES``. A chunk's dots are one dependent chain with a fixed
+    cost, so a wider chunk is cheaper a block until its lines (``C * BS *
+    KV``, every one a column of both dots whether its block is live or not)
+    cost more than the chain saves and the last, partial chunk of a row
+    pays for the whole width."""
+    if config is None:
+        config = resolve_config("paged_attention", key)
+    C = int(config.get("blocks_per_chunk", 0))
+    if not C:
+        _, _, BS, KV, _, D, dtype = key
+        block = BS * KV * D * jnp.dtype(dtype).itemsize
+        C = max([c for c in get_kernel("paged_attention").space["blocks_per_chunk"]
+                 if c * block <= _CHUNK_BYTES], default=1)
+    return max(1, min(C, key[1]))
+
+
+def chunk_counts(pos, BS, C) -> dict:
+    """What the kernel's copy schedule does in ONE call with rows that write
+    positions ``pos`` (the live rows of a step: a row that pads the bucket
+    costs one block and is the caller's to leave out), on the host:
+    ``paged_blocks`` copied from each pool, ``paged_chunks`` they come in and
+    ``paged_full_chunks``, those of them started as straight-line code and
+    waited for once a pool."""
+    blocks = np.asarray(pos, np.int64) // BS + 1
+    return {"paged_blocks": int(blocks.sum()),
+            "paged_chunks": int((-(-blocks // C)).sum()),
+            "paged_full_chunks": int((blocks // C).sum())}
+
+
 def _paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, tok_ref, kpool_ref,
                   vpool_ref, o_ref, kbuf, vbuf, sems, slot_ref, *, B, MB, BS,
                   C, scale):
     b = pl.program_id(0)
     layer = layer_ref[0]
 
-    def for_live_blocks(b, c, slot, do):
-        """``do(K copy, V copy)`` for each LIVE block of chunk ``c`` of row
-        ``b`` into buffer ``slot`` (a loop, not ``C`` unrolled branches: the
-        kernel is lowered for every decode bucket, and its size is set-up
-        time); the same descriptors start and wait."""
-        live = jnp.clip(pos_ref[b] // BS + 1 - c * C, 0, C)
+    def live_blocks(b, c):
+        return jnp.clip(pos_ref[b] // BS + 1 - c * C, 0, C)
 
+    def block_copies(b, c, slot, j):
+        """The K and the V copy of block ``j`` of chunk ``c`` of row ``b``
+        into buffer ``slot``; the same descriptors start and wait."""
+        bid = tables_ref[b * MB + c * C + j]
+        return (pltpu.make_async_copy(kpool_ref.at[layer, bid],
+                                      kbuf.at[slot, j], sems.at[slot, 0]),
+                pltpu.make_async_copy(vpool_ref.at[layer, bid],
+                                      vbuf.at[slot, j], sems.at[slot, 1]))
+
+    def for_blocks(n, do, unroll=False):
         def body(j, carry):
-            bid = tables_ref[b * MB + c * C + j]
-            do(pltpu.make_async_copy(kpool_ref.at[layer, bid],
-                                     kbuf.at[slot, j], sems.at[slot, 0]),
-               pltpu.make_async_copy(vpool_ref.at[layer, bid],
-                                     vbuf.at[slot, j], sems.at[slot, 1]))
+            do(j)
             return carry
 
-        jax.lax.fori_loop(0, live, body, 0)
+        jax.lax.fori_loop(0, n, body, 0, unroll=unroll)
 
     def start(b, c, slot):
-        for_live_blocks(b, c, slot, lambda kc, vc: (kc.start(), vc.start()))
+        live = live_blocks(b, c)
+
+        def issue(j):
+            kc, vc = block_copies(b, c, slot, j)
+            kc.start()
+            vc.start()
+
+        @pl.when(live == C)
+        def _():
+            # a full chunk: straight-line code. ONE traced body, unrolled C
+            # times when it is lowered: a decode program's set-up is tracing
+            for_blocks(C, issue, unroll=True)
+
+        @pl.when(live < C)
+        def _():
+            for_blocks(live, issue)
 
     def wait(b, c, slot):
-        for_live_blocks(b, c, slot, lambda kc, vc: (kc.wait(), vc.wait()))
+        live = live_blocks(b, c)
+
+        @pl.when(live == C)
+        def _():
+            # a semaphore counts bytes: ONE wait a pool for the chunk's C
+            # blocks (the descriptor is only its destination's size; nothing
+            # starts it)
+            pltpu.make_async_copy(kbuf.at[slot], kbuf.at[slot],
+                                  sems.at[slot, 0]).wait()
+            pltpu.make_async_copy(vbuf.at[slot], vbuf.at[slot],
+                                  sems.at[slot, 1]).wait()
+
+        @pl.when(live < C)
+        def _():
+            def await_block(j):
+                kc, vc = block_copies(b, c, slot, j)
+                kc.wait()
+                vc.wait()
+
+            for_blocks(live, await_block)
 
     @pl.when(b == 0)
     def _():
@@ -151,13 +263,13 @@ def _paged_kernel(layer_ref, tables_ref, pos_ref, q_ref, tok_ref, kpool_ref,
         slot, m, l, acc = carry
         nxt = 1 - slot
 
-        @pl.when(c + 1 < n_chunks)
-        def _():
-            start(b, c + 1, nxt)
+        # what is multiplied next: this row's next chunk, or the next row's
+        # first (ONE site: the kernel is lowered for every decode bucket)
+        last = c + 1 >= n_chunks
 
-        @pl.when(jnp.logical_and(c + 1 >= n_chunks, b + 1 < B))
+        @pl.when(jnp.logical_or(jnp.logical_not(last), b + 1 < B))
         def _():
-            start(b + 1, 0, nxt)
+            start(jnp.where(last, b + 1, b), jnp.where(last, 0, c + 1), nxt)
 
         wait(b, c, slot)
         k = kbuf[slot].reshape(N, D)
@@ -272,16 +384,13 @@ def paged_attention_rows(q, kpool, vpool, layer, tables, pos, config=None,
     else:
         BS, KV = kpool.shape[2] // int(kv_heads), int(kv_heads)
     MB = tables.shape[1]
-    if config is None:
-        config = resolve_config(
-            "paged_attention",
-            paged_attention_key(B, MB, BS, KV, H // KV, D, q.dtype))
     if not interpret and not mosaic_takes(D):
         raise ValueError(
             f"paged_attention: Mosaic takes head widths that are multiples "
             f"of 128, not {D}; build the gather step "
             f"(generation.paged_kernel_default chooses)")
-    C = max(1, min(int(config.get("blocks_per_chunk", 8)), MB))
+    C = blocks_per_chunk(
+        paged_attention_key(B, MB, BS, KV, H // KV, D, q.dtype), config)
     return _paged_call(
         q, kpool, vpool, jnp.asarray(layer, jnp.int32).reshape(1),
         jnp.asarray(tables, jnp.int32), jnp.asarray(pos, jnp.int32),
@@ -318,7 +427,9 @@ def _runner(key):
 
 register_kernel(
     "paged_attention",
-    defaults={"blocks_per_chunk": 8},
-    space={"blocks_per_chunk": (4, 8, 16)},
+    # 0 since PR 46 (8 until then): by the bytes of a block
+    # (``blocks_per_chunk``)
+    defaults={"blocks_per_chunk": 0},
+    space={"blocks_per_chunk": (4, 8, 16, 32)},
     runner=_runner,
 )

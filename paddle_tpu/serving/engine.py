@@ -790,6 +790,15 @@ class Engine:
         # compiles it for this arch (backend and head width); the
         # speculative and tail-prefill programs gather
         self._paged_kernel = bool(self._G.paged_kernel_default(arch))
+        # what the kernel's copy schedule does with a step's positions, for
+        # the ``decode_step`` span: the arch's own account, or a call a layer
+        # of an arch that caches K and V per head
+        self._step_attrs = arch.get("step_attrs")
+        if self._paged_kernel and "cache" not in arch:
+            count, reads = self._G.paged_step_attrs, ((
+                self._n_layers, arch["kv_heads"] // (self._tp or 1),
+                arch["rep"], arch["head_dim"], 0),)
+            self._step_attrs = lambda *step: count(reads, *step)
         self._running: List[_Seq] = []
         # the decode loop runs one step ahead of the host: the step whose
         # tokens are still on the device (None between bursts and wherever
@@ -2663,24 +2672,27 @@ class Engine:
         """What a step of ``n`` live rows writing ``pos`` reads of the
         caches, as the arch says it. An arch whose block-table read has a
         copy schedule says what the schedule does with these positions in
-        the ``bucket``'s program (``step_attrs``). Of an arch whose caches
-        are of several kinds, what the step reads of each, under the names
-        the arch declares (``cache["span_attrs"]``, attribute -> kind): the
+        the ``bucket``'s program (``step_attrs``; an arch that caches K and V
+        per head reads them through the kernel where the engine built the
+        kernel step, a call a layer). Of an arch whose caches are of several
+        kinds, beside that, what the step reads of each, under the names the
+        arch declares (``cache["span_attrs"]``, attribute -> kind): the
         context of a paged layer (every reader sees the same tokens), the
         tokens inside the windows, the rows whose state is updated. The rows
         that pad the bucket are not counted."""
-        step_attrs = self._arch.get("step_attrs")
-        if step_attrs is not None:
-            return step_attrs(pos[:n], bucket, self.config.block_size,
-                              self._max_blocks, self._dtype)
-        if self._row_slots is None:
-            return {}
-        ctx = pos[:n].astype(np.int64) + 1
-        of_kind = {"paged": int(ctx.sum()),
-                   "window": int(np.minimum(ctx, self._window).sum()),
-                   "state": n}
-        return {name: of_kind[kind] for name, kind in
-                self._arch["cache"].get("span_attrs", {}).items()}
+        arch, attrs = self._arch, {}
+        if self._step_attrs is not None:
+            attrs.update(self._step_attrs(
+                pos[:n], bucket, self.config.block_size, self._max_blocks,
+                self._dtype))
+        if self._row_slots is not None:
+            ctx = pos[:n].astype(np.int64) + 1
+            of_kind = {"paged": int(ctx.sum()),
+                       "window": int(np.minimum(ctx, self._window).sum()),
+                       "state": n}
+            attrs.update((name, of_kind[kind]) for name, kind in
+                         arch["cache"].get("span_attrs", {}).items())
+        return attrs
 
     def _landing_span(self, fl: _Flight, ahead: int = 0, **attrs):
         """The ``decode_step`` span a step lands in, with the attributes

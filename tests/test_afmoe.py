@@ -505,6 +505,34 @@ def test_spans_count_real_rows_and_tokens_alone(tiny):
     # one live row picks 4 of 16 experts a layer, of which 4 are held: at most 4 x 6
     assert all(0 <= a["experts_touched"] == a["expert_assignments"] <= 24 for a in steps)
     assert not any("shared_kv_tokens" in a or "state_rows" in a for a in steps)
+    # the plain gather has no chunks: none of the kernel's counts (PR 46)
+    assert not any(k in a for a in steps for k in ("paged_blocks", "paged_chunks", "paged_full_chunks"))
+
+
+def test_decode_spans_carry_the_block_table_reads_copy_schedule(tiny, monkeypatch):
+    """``paged_blocks`` / ``paged_chunks`` / ``paged_full_chunks`` of a
+    ``decode_step`` span are what the landing step's positions give by hand,
+    summed over the step's calls of the kernel, BESIDE what the span said of
+    the caches before (the plain gather has no chunks and says nothing: the
+    test of the spans above). A
+    table of 4 blocks of 8 tokens, so chunks of 4: a row that writes position
+    22, 23 reads 3 blocks a call (a partial chunk), 24, 25 reads 4 (a full
+    one). Two full layers by the position; six rings of one block (a chunk of
+    one: always full)."""
+    net, _ = tiny
+    _kernels(monkeypatch, True)
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with _engine(net, decode_buckets=(4,), max_seq_len=32) as eng:
+            eng.submit(np.arange(22, dtype=np.int32), max_new_tokens=6).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    steps = [sp.attrs for sp in seen if sp.name == "decode_step" and sp.attrs["ahead"]]
+    assert [a["paged_kv_tokens"] for a in steps] == [23, 24, 25, 26]
+    assert all(a["window_tokens"] == W for a in steps)
+    assert [(a["paged_blocks"], a["paged_chunks"], a["paged_full_chunks"])
+            for a in steps] == [(12, 8, 6), (12, 8, 6), (14, 8, 8), (14, 8, 8)]
 
 
 # -- (f) what is not built is refused by name ------------------------------------------
@@ -583,10 +611,13 @@ def test_no_first_call_searches_for_a_kernel_config():
     """The kernel registry answers the new shapes from its defaults with the
     autotuner off (the default flag)."""
     from paddle_tpu.ops.kernels import paged_attention_key
+    from paddle_tpu.ops.kernels.paged_attention import blocks_per_chunk
     from paddle_tpu.ops.kernels.registry import resolve_config
     from paddle_tpu.ops.kernels.window_flash import window_flash_key
 
     assert resolve_config("window_flash", window_flash_key(
         1, 8192, 32, 4, 128, 2048, jnp.bfloat16)) == {"block_q": 64, "block_k": 512}
-    assert resolve_config("paged_attention", paged_attention_key(
-        64, 512, 16, 4, 8, 128, jnp.bfloat16)) == {"blocks_per_chunk": 8}
+    # 0: the chunk follows a block's bytes (32 blocks of 16 KB), no search
+    key = paged_attention_key(64, 512, 16, 4, 8, 128, jnp.bfloat16)
+    assert resolve_config("paged_attention", key) == {"blocks_per_chunk": 0}
+    assert blocks_per_chunk(key) == 32

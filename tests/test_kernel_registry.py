@@ -35,7 +35,7 @@ from paddle_tpu.profiler import counters
 PINNED = {
     "flash_attention": {"block_q": 512, "block_k": 512},
     "fused_ce": {"block_rows": 2048},
-    "paged_attention": {"blocks_per_chunk": 8},
+    "paged_attention": {"blocks_per_chunk": 0},  # 8 until PR 46; 0: by a block's bytes
     "int8_matmul": {"block_n": 512},
 }
 

@@ -456,7 +456,34 @@ def test_spans_and_counters_count_real_rows_and_tokens_alone(tiny):
     # one live row takes 2 of 8 experts in each of 4 expert layers
     assert all(a["experts_touched"] == 8 and a["expert_tokens_max"] == 1 for a in steps)
     assert not any("shared_kv_tokens" in a or "window_tokens" in a for a in steps)
+    # the plain gather has no chunks: none of the kernel's counts (PR 46)
+    assert not any(k in a for a in steps for k in ("paged_blocks", "paged_chunks", "paged_full_chunks"))
     assert counters()["serve_state_rows"] - before == 11 == stats["state_rows"]
+
+
+def test_decode_spans_carry_the_block_table_reads_copy_schedule(tiny, monkeypatch):
+    """``paged_blocks`` / ``paged_chunks`` / ``paged_full_chunks`` of a
+    ``decode_step`` span are what the landing step's positions give by hand,
+    summed over the step's calls of the kernel, BESIDE what the span said of
+    the caches before (the plain gather has no chunks and says nothing: the
+    test of the spans above). A
+    table of 4 blocks of 8 tokens, so chunks of 4: a row that writes position
+    22, 23 reads 3 blocks a call (a partial chunk), 24, 25 reads 4 (a full
+    one). Two attention layers by the position."""
+    net, _ = tiny
+    _kernels(monkeypatch, True)
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with _engine(net, decode_buckets=(4,), max_seq_len=32) as eng:
+            eng.submit(np.arange(22, dtype=np.int32), max_new_tokens=6).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    steps = [sp.attrs for sp in seen if sp.name == "decode_step" and sp.attrs["ahead"]]
+    assert [a["paged_kv_tokens"] for a in steps] == [23, 24, 25, 26]
+    assert all(a["state_rows"] == 1 for a in steps)
+    assert [(a["paged_blocks"], a["paged_chunks"], a["paged_full_chunks"])
+            for a in steps] == [(6, 2, 0), (6, 2, 0), (8, 2, 2), (8, 2, 2)]
 
 
 # -- (e) what is not built is refused by name ------------------------------------------
@@ -520,13 +547,15 @@ def test_unsupported_calls_raise_at_the_call(tiny):
 def test_no_first_call_searches_for_a_kernel_config():
     """The kernel registry answers the new shapes from its defaults with the
     autotuner off (the default flag): the expert kernel at 2048 x 1536 in
-    three slices of 512, the packed read in chunks of eight blocks."""
+    three slices of 512, the packed read in chunks of 32 blocks (8 until PR 46)."""
     from paddle_tpu.ops.kernels import paged_attention_key
+    from paddle_tpu.ops.kernels.paged_attention import blocks_per_chunk
     from paddle_tpu.ops.kernels.moe_experts import moe_experts_key
     from paddle_tpu.ops.kernels.registry import resolve_config
 
     experts = resolve_config("moe_experts", moe_experts_key(256, 64, 2048, 1536, jnp.bfloat16))
     assert experts == {"rows_per_tile": 0, "f_slice": 512} and 1536 % 512 == 0
-    read = resolve_config("paged_attention",
-                          paged_attention_key(64, 128, 16, 4, 8, 128, jnp.bfloat16))
-    assert read == {"blocks_per_chunk": 8}
+    key = paged_attention_key(64, 128, 16, 4, 8, 128, jnp.bfloat16)
+    # 0: the chunk follows a block's bytes (32 blocks of 16 KB), no search
+    assert resolve_config("paged_attention", key) == {"blocks_per_chunk": 0}
+    assert blocks_per_chunk(key) == 32

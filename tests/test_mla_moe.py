@@ -323,6 +323,9 @@ def test_decode_spans_carry_the_latent_reads_copy_schedule(tiny, monkeypatch,
               and "expert_assignments" in sp.attrs]
     assert len(landed) == 3 and all(a["rows"] == 1 and a["bucket"] == 4
                                     for a in landed)
+    # the latent arch's span is as it was: the grouped-head read's counters
+    # (``paged_*``, PR 46) are of the archs that call that kernel
+    assert not any(k.startswith("paged_") for a in landed for k in a)
     if by_hand is None:
         assert not any(k.startswith("latent_") for a in landed for k in a)
         return

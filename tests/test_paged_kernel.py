@@ -168,6 +168,219 @@ class TestPagedKernelAgainstGather:
         assert np.array_equal(np.asarray(k[2]), np.asarray(r[2]))
 
 
+# -- the copy schedule (PR 46) ------------------------------------------------
+# The schedule the kernel had until PR 46 (one K and one V start a live block
+# from a loop, one wait each from a second loop, the next chunk started at two
+# sites), kept as the plain form of the copy schedule: the new one issues and
+# awaits the same copies, so the same sums in the same order.
+from jax.experimental import pallas as pl  # noqa: E402
+from jax.experimental.pallas import tpu as pltpu  # noqa: E402
+
+from paddle_tpu.ops.kernels import paged_attention as PA  # noqa: E402
+
+
+def _parent_kernel(layer_ref, tables_ref, pos_ref, q_ref, tok_ref, kpool_ref,
+                   vpool_ref, o_ref, kbuf, vbuf, sems, slot_ref, *, B, MB, BS,
+                   C, scale):
+    b = pl.program_id(0)
+    layer = layer_ref[0]
+
+    def for_live_blocks(b, c, slot, do):
+        live = jnp.clip(pos_ref[b] // BS + 1 - c * C, 0, C)
+
+        def body(j, carry):
+            bid = tables_ref[b * MB + c * C + j]
+            do(pltpu.make_async_copy(kpool_ref.at[layer, bid],
+                                     kbuf.at[slot, j], sems.at[slot, 0]),
+               pltpu.make_async_copy(vpool_ref.at[layer, bid],
+                                     vbuf.at[slot, j], sems.at[slot, 1]))
+            return carry
+
+        jax.lax.fori_loop(0, live, body, 0)
+
+    def start(b, c, slot):
+        for_live_blocks(b, c, slot, lambda kc, vc: (kc.start(), vc.start()))
+
+    def wait(b, c, slot):
+        for_live_blocks(b, c, slot, lambda kc, vc: (kc.wait(), vc.wait()))
+
+    @pl.when(b == 0)
+    def _():
+        vbuf[...] = jnp.zeros_like(vbuf)
+        slot_ref[0] = 0
+        start(0, 0, 0)
+
+    Hp, D = q_ref.shape[1], q_ref.shape[2]
+    N = kbuf.shape[1] * kbuf.shape[2]
+    pos = pos_ref[b]
+    n_chunks = (pos // BS + C) // C
+    q = q_ref[0]
+
+    def chunk_body(c, carry):
+        slot, m, l, acc = carry
+        nxt = 1 - slot
+
+        @pl.when(c + 1 < n_chunks)
+        def _():
+            start(b, c + 1, nxt)
+
+        @pl.when(jnp.logical_and(c + 1 >= n_chunks, b + 1 < B))
+        def _():
+            start(b + 1, 0, nxt)
+
+        wait(b, c, slot)
+        k = kbuf[slot].reshape(N, D)
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale
+        s = jnp.where(tok_ref[...] + c * (C * BS) <= pos, s, PA._MASK)
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m - m_new)
+        e = jnp.exp(s - m_new)
+        l = alpha * l + e.sum(axis=1, keepdims=True)
+        v = vbuf[slot].reshape(N, D)
+        acc = alpha * acc + jax.lax.dot_general(
+            e.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        return nxt, m_new, l, acc
+
+    slot, _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk_body,
+        (slot_ref[0], jnp.full((Hp, 1), PA._MASK, jnp.float32),
+         jnp.zeros((Hp, 1), jnp.float32), jnp.zeros((Hp, D), jnp.float32)))
+    slot_ref[0] = slot
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+_SC, _SBS, _SMB = 4, 8, 14  # blocks a chunk, tokens a block, table width
+_SN = _SC * _SBS            # tokens a chunk
+# contexts a row, in tokens (0: a dead row, pos 0 and its table at the trash)
+_SCHEDULE_ROWS = {
+    "a_row_of_whole_chunks": [3 * _SN],
+    "a_full_chunk_then_one_block": [_SN + 1, 2 * _SN + _SBS],
+    "partial_only": [_SBS + 1, 3, _SN - 1],
+    "dead_rows_between_live_ones": [_SN + 5, 0, 2 * _SN - 1, 0, 0, 3],
+    # the slot handed to the next row at a full chunk and at a partial one,
+    # the next row's first chunk full and partial
+    "prefetch_across_a_row_boundary": [_SN, 3, 2 * _SN, _SN, _SBS, _SN + 2],
+}
+
+
+def _schedule_case(ctx, dtype, pools, seed=0):
+    """Rows of ``ctx`` tokens, their blocks drawn without order from pools of
+    random lines; 2 KV heads x 2 queries each, layer 1 of two; ``pools``:
+    "5d" ``(L, NB, BS, KV, D)`` or "4d" ``(L, NB, BS * KV, D)`` with
+    ``kv_heads``."""
+    rng = np.random.default_rng(seed)
+    KV, rep, D, L = 2, 2, 16, 2
+    need = [-(-c // _SBS) for c in ctx]
+    NB, MB = 1 + sum(need) + 3, max(_SMB, max(need) + 1)
+    shape = (L, NB, _SBS, KV, D) if pools == "5d" else (L, NB, _SBS * KV, D)
+    kpool = jnp.asarray(rng.normal(size=shape), dtype)
+    vpool = jnp.asarray(rng.normal(size=shape), dtype)
+    free = list(1 + rng.permutation(NB - 1))
+    tables = np.full((len(ctx), MB), TRASH_BLOCK, np.int32)
+    for b, n in enumerate(need):
+        tables[b, :n] = [free.pop() for _ in range(n)]
+    pos = np.asarray([max(c - 1, 0) for c in ctx], np.int32)
+    q = jnp.asarray(rng.normal(size=(len(ctx), KV * rep, D)), dtype)
+    kw = {} if pools == "5d" else {"kv_heads": KV}
+    return (q, kpool, vpool, 1, jnp.asarray(tables), jnp.asarray(pos)), kw
+
+
+class TestCopySchedule:
+    """A full chunk's ``2 C`` copies are started as straight-line code and
+    waited for once a pool; a partial chunk keeps a loop a block. The copies
+    and the arithmetic are the parent's."""
+
+    @pytest.fixture(autouse=True, scope="class")
+    def _drop_the_executables(self):
+        """Every case compiles two interpreted kernels; a test process that
+        keeps some hundred XLA:CPU executables alive runs out of room for
+        code (PERF.md, PR 45 (6)), so they go when the class is done."""
+        yield
+        jax.clear_caches()
+
+    # every kind of row as 5-D float32 pools and as 4-D bfloat16 pools with
+    # ``kv_heads``; the other two pairings on the row that has every site
+    @pytest.mark.parametrize("rows,pools,dtype", [
+        *[(rows, pools, dtype) for rows in _SCHEDULE_ROWS for pools, dtype in
+          (("5d", "float32"), ("4d_kv_heads", "bfloat16"))],
+        ("prefetch_across_a_row_boundary", "5d", "bfloat16"),
+        ("prefetch_across_a_row_boundary", "4d_kv_heads", "float32")])
+    def test_equals_the_parents_schedule_bit_for_bit(self, monkeypatch, rows,
+                                                     pools, dtype):
+        args, kw = _schedule_case(_SCHEDULE_ROWS[rows], jnp.dtype(dtype),
+                                  pools[:2])
+        run = lambda: np.asarray(K.paged_attention_rows(
+            *args, config={"blocks_per_chunk": _SC}, interpret=True, **kw))
+        new = run()
+        PA._paged_call.clear_cache()
+        monkeypatch.setattr(PA, "_paged_kernel", _parent_kernel)
+        try:
+            old = run()
+        finally:
+            PA._paged_call.clear_cache()
+        assert new.dtype == old.dtype and np.array_equal(new, old)
+        assert np.isfinite(np.asarray(new, np.float32)).all()
+
+    @pytest.mark.parametrize("rows", list(_SCHEDULE_ROWS))
+    def test_within_tolerance_of_the_gather_at_the_default_chunk(self, rows):
+        """No ``config``: the registry's ``blocks_per_chunk``, the chunk the
+        engine's programs resolve, over the same kinds of row with every
+        length a multiple of the test chunk so that the default's chunks
+        fill."""
+        C = PA.blocks_per_chunk(PA.paged_attention_key(
+            8, 4096, _SBS, 2, 2, 16, jnp.float32))
+        ctx = [c * C // _SC for c in _SCHEDULE_ROWS[rows]]
+        args, _ = _schedule_case(ctx, jnp.float32, "5d")
+        out = K.paged_attention_rows(*args, interpret=True)
+        assert _close(out, _ref_paged(*args))
+
+
+# -- the chunk a shape gets (PR 46) ----------------------------------------------
+@pytest.mark.parametrize("key,C", [
+    # (B, table, BS, KV, queries a KV head, D, dtype): what the cells run, and
+    # what the chip measured as fastest for each (PERF.md section 6, PR 46)
+    pytest.param((32, 512, 16, 4, 8, 128, "bfloat16"), 32, id="trinity_full_16KB"),
+    pytest.param((32, 128, 16, 4, 8, 128, "bfloat16"), 32, id="trinity_rings"),
+    pytest.param((64, 128, 16, 4, 8, 128, "bfloat16"), 32, id="packed_16KB"),
+    pytest.param((64, 128, 16, 10, 4, 128, "bfloat16"), 16, id="hybrid_paged_40KB"),
+    pytest.param((64, 32, 16, 10, 4, 128, "bfloat16"), 16, id="hybrid_rings"),
+    pytest.param((4, 128, 16, 16, 1, 128, "bfloat16"), 8, id="dense_64KB"),
+    pytest.param((32, 512, 16, 4, 8, 128, "float32"), 16, id="float32_doubles_a_block"),
+    pytest.param((32, 512, 16, 16, 1, 128, "float32"), 4, id="the_spaces_narrowest"),
+    pytest.param((32, 512, 16, 64, 1, 256, "float32"), 1, id="a_block_over_the_limit"),
+    pytest.param((8, 5, 16, 4, 8, 128, "bfloat16"), 5, id="at_most_the_table")])
+def test_the_chunk_follows_a_blocks_bytes(key, C):
+    """No flag, no arch's name: the widest chunk of the registry's space that
+    holds at most 640 KB of one pool, at most the row's table; a ``config``
+    that names a width wins."""
+    assert PA.blocks_per_chunk(key) == C
+    assert PA.blocks_per_chunk(key, {"blocks_per_chunk": 2}) == 2
+    assert PA.blocks_per_chunk(key, {"blocks_per_chunk": 64}) == min(64, key[1])
+
+
+# -- the host's count of what the kernel copies --------------------------------
+@pytest.mark.parametrize("C", [4, 8, 16, 32])
+def test_chunk_counts_are_what_the_positions_give_by_hand(C):
+    BS = 16
+    N = C * BS
+    # context (tokens) -> (blocks, chunks, full chunks), by hand
+    rows = {1: (1, 1, 0), BS: (1, 1, 0), BS + 1: (2, 1, 0),
+            N - 1: (C, 1, 1), N: (C, 1, 1), N + 1: (C + 1, 2, 1),
+            3 * N: (3 * C, 3, 3), 3 * N + BS + 1: (3 * C + 2, 4, 3)}
+    names = ("paged_blocks", "paged_chunks", "paged_full_chunks")
+    for ctx, by_hand in rows.items():
+        got = PA.chunk_counts(np.asarray([ctx - 1]), BS, C)
+        assert got == dict(zip(names, by_hand)), ctx
+    total = PA.chunk_counts(np.asarray([c - 1 for c in rows]), BS, C)
+    assert total == {n: sum(v[i] for v in rows.values())
+                     for i, n in enumerate(names)}
+    # an empty step (no live row) copies nothing
+    assert PA.chunk_counts(np.zeros((0,), np.int32), BS, C) == dict.fromkeys(names, 0)
+
+
 def _run_engine(prompt_seed=3, n=4, max_new=8, kernel=False, **fl):
     """Token outputs of a fresh tiny-GPT engine under flag overrides, its
     decode program built with the kernel step or the gather step."""
@@ -262,6 +475,32 @@ class TestEnginePagedKernel:
         assert max(live) == -(-(10 + 100 - 1) // ENGINE_KW["block_size"])
         c1 = profiler.counters().get("serve_decode_blocks_read", 0)
         assert c1 - c0 == sum(held)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel_step", "gather_step"])
+def test_dense_archs_decode_spans_carry_the_copy_schedule(kernel):
+    """An arch that caches K and V per head reads them through the kernel
+    where the engine built the kernel step, a call a layer (two here): a
+    table of 4 blocks of 8 tokens, chunks of 4; the gather step has no
+    chunks and says nothing."""
+    from paddle_tpu.profiler import spans
+
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with paged_kernel(kernel), Engine(
+                tiny_gpt(seed=0), **{**ENGINE_KW, "max_seq_len": 32,
+                                     "decode_buckets": (4,)}) as eng:
+            eng.submit(list(range(22)), max_new_tokens=6).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    steps = [sp.attrs for sp in seen if sp.name == "decode_step" and sp.attrs["ahead"]]
+    assert len(steps) == 4 and all(a["rows"] == 1 for a in steps)
+    if not kernel:
+        assert not any(k.startswith("paged_") for a in steps for k in a)
+        return
+    assert [(a["paged_blocks"], a["paged_chunks"], a["paged_full_chunks"])
+            for a in steps] == [(6, 2, 0), (6, 2, 0), (8, 2, 2), (8, 2, 2)]
 
 
 def _gpt_of_width(head_dim, seed=0):

@@ -407,6 +407,34 @@ def test_spans_count_real_rows_and_tokens_alone(tiny):
     assert [a["shared_kv_tokens"] for a in steps] == list(range(6, 16))
     assert [a["window_tokens"] for a in steps] == [min(c, W) for c in range(6, 16)]
     assert all(a["state_rows"] == 1 and a["rows"] == 1 and a["bucket"] == 4 for a in steps)
+    # the plain gather has no chunks: none of the kernel's counts (PR 46)
+    assert not any(k in a for a in steps for k in ("paged_blocks", "paged_chunks", "paged_full_chunks"))
+
+
+def test_decode_spans_carry_the_block_table_reads_copy_schedule(tiny, monkeypatch):
+    """``paged_blocks`` / ``paged_chunks`` / ``paged_full_chunks`` of a
+    ``decode_step`` span are what the landing step's positions give by hand,
+    summed over the step's calls of the kernel, BESIDE what the span said of
+    the caches before (the plain gather has no chunks and says nothing: the
+    test of the spans above). A
+    table of 4 blocks of 8 tokens, so chunks of 4: a row that writes position
+    22, 23 reads 3 blocks a call (a partial chunk), 24, 25 reads 4 (a full
+    one). The full layer and the cross layer behind it by the position; two
+    rings of one block (a chunk of one: always full)."""
+    net, _ = tiny
+    _kernels(monkeypatch, True)
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with _engine(net, decode_buckets=(4,), max_seq_len=32) as eng:
+            eng.submit(np.arange(22, dtype=np.int32), max_new_tokens=6).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    steps = [sp.attrs for sp in seen if sp.name == "decode_step" and sp.attrs["ahead"]]
+    assert [a["shared_kv_tokens"] for a in steps] == [23, 24, 25, 26]
+    assert all(a["window_tokens"] == W and a["state_rows"] == 1 for a in steps)
+    assert [(a["paged_blocks"], a["paged_chunks"], a["paged_full_chunks"])
+            for a in steps] == [(8, 4, 2), (8, 4, 2), (10, 4, 4), (10, 4, 4)]
 
 
 def test_other_archs_carry_none_of_it():

@@ -295,6 +295,14 @@ def _mosaic_ops(lowered, names):
     return found
 
 
+def _dma_sites(lowered):
+    """``(waits, starts)`` of the lowered program's Mosaic kernel, each a list
+    of ``(the copy's destination shape, loops around the op)``."""
+    ops = _mosaic_ops(lowered, ("tpu.wait_dma2", "tpu.enqueue_dma"))
+    return tuple([(shapes[-1], loops) for name, shapes, loops in ops
+                  if name == which] for which in ("wait_dma2", "enqueue_dma"))
+
+
 def test_mla_paged_attention_waits_once_for_a_full_chunk(v5e):
     """The kernel's Mosaic text keeps the copy schedule of PR 43. A full
     chunk: ONE wait whose destination is a whole buffer slot, in the chunk
@@ -313,11 +321,7 @@ def test_mla_paged_attention_waits_once_for_a_full_chunk(v5e):
             _on(s, (8, 10600, 16, 640), jnp.bfloat16),
             _on(s, (64, 128), jnp.int32), _on(s, (64,), jnp.int32),
     ).lower(lowering_platforms=("tpu",))
-    ops = _mosaic_ops(lowered, ("tpu.wait_dma2", "tpu.enqueue_dma"))
-    waits = [(shapes[-1], loops) for name, shapes, loops in ops
-             if name == "wait_dma2"]
-    starts = [(shapes[-1], loops) for name, shapes, loops in ops
-              if name == "enqueue_dma"]
+    waits, starts = _dma_sites(lowered)
     # every copy that is STARTED is one block's
     assert {dst for dst, _ in starts} == {block}
     # loops around an op: 1 = the chunk loop alone, 2 = a loop a block in it
@@ -327,6 +331,50 @@ def test_mla_paged_attention_waits_once_for_a_full_chunk(v5e):
     # ONE site starts what is multiplied next, either way
     depths = [loops for _, loops in starts]
     assert sorted(depths) == sorted([0] * C + [1] + [1] * C + [2])
+
+
+# the grouped-head read at the shapes two cells run (PR 46): Trinity-Mini's full
+# layers and rings (32 queries over 4 K/V heads, a pool of lines) and the
+# hybrid's paged layer and rings (40 queries over 10 K/V pairs)
+@pytest.mark.parametrize("B,H,KV,MB,L,NB", [
+    pytest.param(32, 32, 4, 512, 4, 55000, id="trinity_full_layers"),
+    pytest.param(32, 32, 4, 128, 12, 65 * 128, id="trinity_rings"),
+    pytest.param(64, 40, 10, 128, 1, 8256, id="hybrid_paged_layer"),
+    pytest.param(64, 40, 10, 32, 8, 65 * 32, id="hybrid_rings")])
+def test_paged_attention_waits_once_a_pool_for_a_full_chunk(v5e, B, H, KV, MB,
+                                                            L, NB):
+    """The kernel's Mosaic text keeps the copy schedule of PR 46, PR 43's for
+    two pools. A full chunk: ONE wait a pool whose destination is a whole
+    buffer slot, in the chunk loop and in no loop inside it, and its ``C``
+    starts a pool as straight-line code (two sites: the first grid step's,
+    and the one in the chunk loop that starts the row's next chunk or the
+    next row's first). A block-sized wait or start in a loop of its own
+    exists only as the partial chunk's, once a pool a site."""
+    from paddle_tpu.ops.kernels.paged_attention import (
+        blocks_per_chunk, paged_attention_key)
+
+    s, D, BS = SingleDeviceSharding(v5e[0]), 128, 16
+    C = blocks_per_chunk(paged_attention_key(B, MB, BS, KV, H // KV, D,
+                                             jnp.bfloat16))
+    assert 1 < C <= MB
+    block, pool = (BS * KV, D), _on(s, (L, NB, BS * KV, D), jnp.bfloat16)
+    lowered = jax.jit(lambda q, k, v, layer, tables, pos: paged_attention_rows(
+        q, k, v, layer, tables, pos, kv_heads=KV, interpret=False)).trace(
+            _on(s, (B, H, D), jnp.bfloat16), pool, pool, _on(s, (), jnp.int32),
+            _on(s, (B, MB), jnp.int32), _on(s, (B,), jnp.int32),
+    ).lower(lowering_platforms=("tpu",))
+    waits, starts = _dma_sites(lowered)
+    # every copy that is STARTED is one block's
+    assert {dst for dst, _ in starts} == {block}
+    # loops around an op: 1 = the chunk loop alone, 2 = a loop a block in it;
+    # K and V each
+    assert sorted(waits) == sorted(2 * [((C,) + block, 1), (block, 2)])
+    # the first grid step starts row 0's first chunk outside the chunk loop
+    # (C starts a pool in no loop, or one in a loop a block); inside the
+    # chunk loop ONE site starts what is multiplied next, either way
+    depths = [loops for _, loops in starts]
+    assert sorted(depths) == sorted(2 * ([0] * C + [1] + [1] * C + [2]))
+    _compile_uncached(lowered)
 
 
 def test_mla_paged_attention_row_off_the_lane_tile_is_refused(v5e):
