@@ -24,6 +24,7 @@ whole stack (``prompt_stack`` / ``decode_stack``) and not a layer.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -704,13 +705,19 @@ def mla_moe_decode_state(model, kernels=None):
 def _mla_moe_arch(cfg, kernels):
     """The arch plug of the latent-attention / routed-expert lineage. Its
     cache is ONE pool a layer, a padded latent row a token; its layer is
-    ``mla_moe.decoder_layer`` for prompts (``prompt_layer``) and for decode
-    rows (``decode_layer``) alike, so the plug has no per-builder copy of it.
-    It has the plain prefill and decode programs and no other yet
-    (``plain_paths_only``: the engine refuses the rest by name). Where its
-    decode reads the pool through the kernel, ``step_attrs`` says what the
-    kernel's copy schedule does with a step's positions (blocks copied a
-    layer, chunks, chunks copied whole), for the ``decode_step`` span."""
+    ``mla_moe.decoder_layer`` for prompts (``prompt_layer``), for a call of
+    positions against what the pool already holds of a row (``tail_layer``:
+    chunked prefill) and for decode rows (``decode_layer``) alike, so the
+    plug has no per-builder copy of it. It has the plain prefill and decode
+    programs and the tail program, and no other yet (``plain_paths_only``:
+    the engine refuses tp, int8, speculative verify, the prefix index and
+    snapshots by name; an arch that brings ``tail_layer`` is served
+    ``prefill_chunk``; ``prompt_max`` is the longest prompt the whole-prompt
+    program takes, longer ones go through the tail program in calls). Where its decode reads the pool through the kernel,
+    ``step_attrs`` says what the kernel's copy schedule does with a step's
+    positions (blocks copied a layer, chunks, chunks copied whole), for the
+    ``decode_step`` span; ``call_attrs`` says what a tail call reads of the
+    pool, for the ``prefill`` span."""
     from . import mla_moe as M
 
     tabs = M.rope_tables(cfg)
@@ -756,8 +763,41 @@ def _mla_moe_arch(cfg, kernels):
         X, counts = M.decoder_layer(cfg, w, X, attend, live, kernels)
         return X[:, None], (pool,), counts
 
+    def tail_layer(w, X, pools, li, tables, starts, lens, bids, live, scratch):
+        # T fresh positions a row at ``starts + 0 .. T - 1``, (B,T,n,d); the
+        # context is expanded from the pool, the call's own rows included
+        pool = pools[0]
+        B, T = X.shape[:2]
+        posm = starts[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
+
+        def attend(u):
+            nonlocal pool, scratch
+            q_nope, q_rope, latent = M.latent_project(cfg, w, u, posm, tabs)
+            # the fresh rows go into the pool BEFORE the read, as in decode
+            pool = pool.at[li, bids].set(
+                latent.reshape((B, bids.shape[1], -1) + latent.shape[2:]))
+            scratch = M.expand_context(cfg, w, pool, li, tables, starts + lens,
+                                       scratch)
+            o = M.attend_call(cfg, q_nope, q_rope, scratch, starts, lens,
+                              tabs[2], kernels)
+            return o @ w["o"]
+
+        X, counts = M.decoder_layer(cfg, w, X, attend, live, kernels)
+        return X, (pool,), counts, scratch
+
+    def call_attrs(starts, feeds, block_size, max_blocks):
+        # blocks of the pool a call's expansion gathers a layer: whole turns
+        # up to the longest row's end, of every row of the call
+        turn = M.expand_turn(block_size, max_blocks)
+        ends = np.asarray(starts, np.int64) + np.asarray(feeds, np.int64)
+        return {"latent_blocks_read": int(
+            -(-int(ends.max()) // (turn * block_size)) * turn * len(ends))}
+
     arch = {"name": "mla_moe", "embed": embed, "head": head,
             "prompt_layer": prompt_layer, "decode_layer": decode_layer,
+            "tail_layer": tail_layer,
+            "tail_scratch": functools.partial(M.context_scratch, cfg),
+            "call_attrs": call_attrs, "prompt_max": M.whole_prompt_max(cfg),
             "cache": ((cfg.cache_row,),), "plain_paths_only": True,
             "expert_layers": sum(cfg.is_expert_layer(i)
                                  for i in range(cfg.num_hidden_layers)),
@@ -1577,8 +1617,9 @@ def kv_block_checksums(kpool, vpool, bids):
 
 
 def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
-    """Prefix-cache tail prefill: prompt heads already live in shared pool
-    blocks, only the TAIL tokens run the forward pass.
+    """Tail prefill (a prefix-cache hit, a call of chunked prefill): prompt
+    heads already live in pool blocks, only the TAIL tokens run the forward
+    pass.
 
     The returned pure fn
     ``prefill(params, ids, starts, lens, tables, kpool, vpool)`` feeds each
@@ -1593,8 +1634,17 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
     logits)`` at each row's true last tail token (``lens - 1``). Shared
     prefix blocks sit BELOW every written column, so a sharer's tail
     prefill can never touch a peer's mapped block. Rows whose tail bucket
-    overshoots the table (or padding rows) write to the trash block."""
-    KV, D = arch["kv_heads"], arch["head_dim"]
+    overshoots the table (or padding rows) write to the trash block.
+
+    An arch with a cache of its own brings the layer of such a call
+    (``tail_layer(w, x, pools, li, tables, starts, lens, bids, live, scratch)
+    -> (x, pools, counts, scratch)``: it writes the call's rows at ``bids``
+    and reads its context by block table itself; ``scratch``, from
+    ``tail_scratch``, is what the layers hand on, made once a program) and
+    gets the same program around it, over the pools it declares:
+    ``prefill(params, ids, starts, lens, tables, *pools)`` returns ``(*pools,
+    logits)`` and, where the layers route experts, the live tokens each
+    expert took."""
     if T_bucket % block_size:
         raise ValueError(
             f"tail-prefill bucket {T_bucket} must be a multiple of "
@@ -1603,15 +1653,43 @@ def build_paged_tail_prefill(arch, B, T_bucket, block_size, max_blocks):
     nb = T_bucket // block_size
     T_pad = block_size * max_blocks
 
+    def write_blocks(tables, starts):
+        cols = (starts // block_size)[:, None] + jnp.arange(nb)[None, :]
+        bids = jnp.take_along_axis(
+            tables, jnp.minimum(cols, max_blocks - 1), axis=1)
+        return jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
+
+    if "tail_layer" in arch:
+        def prefill(params, ids, starts, lens, tables, *pools):
+            posm = starts[:, None] + jnp.arange(T_bucket)[None, :]
+            x = arch["embed"](params, ids, posm)
+            bids = write_blocks(tables, starts)
+            live = ((jnp.arange(T_bucket)[None, :] < lens[:, None])
+                    & (tables[:, :1] != 0))
+            width, scratch = arch["tail_scratch"](B, block_size, max_blocks,
+                                                  pools[0].dtype)
+            wide = jnp.pad(tables, ((0, 0), (0, width - max_blocks)))
+            counts = []
+            for li, w in enumerate(params["layers"]):
+                x, pools, c, scratch = arch["tail_layer"](
+                    w, x, tuple(pools), li, wide, starts, lens, bids, live,
+                    scratch)
+                if c is not None:
+                    counts.append(c)
+            with jax.named_scope("head"):
+                logits = arch["head"](params, _last_rows(x, lens))
+            return (*pools, logits) + ((jnp.stack(counts),) if counts else ())
+
+        return prefill
+
+    KV, D = arch["kv_heads"], arch["head_dim"]
+
     def prefill(params, ids, starts, lens, tables, kpool, vpool):
         layer_ws = params["layers"]
         posm = starts[:, None] + jnp.arange(T_bucket)[None, :]  # (B, T)
         x = arch["embed"](params, ids, posm)
         live = jnp.arange(T_pad)[None, None, :] <= posm[:, :, None]  # (B,T,Tp)
-        cols = (starts // block_size)[:, None] + jnp.arange(nb)[None, :]
-        bids = jnp.take_along_axis(
-            tables, jnp.minimum(cols, max_blocks - 1), axis=1)
-        bids = jnp.where(cols < max_blocks, bids, 0)  # 0 = trash block
+        bids = write_blocks(tables, starts)
         # gathers hoisted above the scatter chain (see build_paged_decode):
         # avoids a whole-pool copy-on-write per layer
         with jax.named_scope("kv_gather"):

@@ -19,11 +19,19 @@ their siblings), built from a config whose KEYS switch the mechanisms:
   ``hc_sinkhorn_iters``). ``hc_mult 1`` is the plain pre-norm residual.
 
 The layer equations are the pure functions below; ONE set, which prefill,
-decode and ``forward`` all call (``models/generation.py`` builds the serving
-programs around ``decoder_layer``). Each of the three new device operations
-has a Pallas kernel that the layer takes where Mosaic compiles (``kernels``)
-and a plain ``jax.numpy`` form, its reference, everywhere else:
-``ops/kernels/mla_paged_attention.py``, ``moe_experts.py``, ``mhc_mix.py``.
+a prefill call against the cache, decode and ``forward`` all call
+(``models/generation.py`` builds the serving programs around
+``decoder_layer``). Each of the four device operations has a Pallas kernel
+that the layer takes where Mosaic compiles (``kernels``) and a plain
+``jax.numpy`` form, its reference, everywhere else:
+``ops/kernels/mla_paged_attention.py``, ``mla_prefill_attention.py``,
+``moe_experts.py``, ``mhc_mix.py``.
+
+Attention has three forms of one mathematics: EXPANDED over a whole short
+prompt (``attend_expanded``: a (T, T) product), expanded over a CALL of
+positions against the latent rows the cache holds and its own
+(``expand_context`` + ``attend_call``: blocked, no (queries x context)
+tensor), ABSORBED for one token a row (``absorb_queries``).
 
 Served only so far: no training step, and the multi-token-prediction module
 (``num_nextn_predict_layers``) is not held (its join with the residual
@@ -285,6 +293,125 @@ def attend_expanded(cfg: MLAMoEConfig, w, q_nope, q_rope, latent, tables):
     return o.reshape(B, T, H * cfg.v_head_dim)
 
 
+# rows of context a turn of ``expand_context`` up-projects (whole blocks)
+EXPAND_ROWS = 2048
+# the float32 scores (H, T, T) a whole prompt's ``attend_expanded`` may hold a
+# row; a longer prompt is served in calls (``whole_prompt_max``)
+WHOLE_SCORES_BYTES = 2 ** 30
+
+
+def whole_prompt_max(cfg: "MLAMoEConfig") -> int:
+    """The longest prompt ``prompt_layer`` takes whole: the power of two whose
+    (H, T, T) float32 scores still fit ``WHOLE_SCORES_BYTES`` (4,096 positions
+    at 16 heads)."""
+    t = math.isqrt(WHOLE_SCORES_BYTES // (4 * cfg.num_attention_heads))
+    return 1 << (t.bit_length() - 1)
+
+
+def expand_turn(block_size, max_blocks) -> int:
+    """Blocks of a row's table a turn of ``expand_context`` gathers."""
+    return min(max(EXPAND_ROWS // block_size, 1), max_blocks)
+
+
+def context_scratch(cfg: MLAMoEConfig, B, block_size, max_blocks, dtype):
+    """``(tables' padded width, (K, V))``: the expanded keys and values of a
+    call's context, ``(B, S, H (nope + pad))`` and ``(B, S, H v)`` over ``S``
+    = the table's positions in whole turns of :func:`expand_context`. Made
+    ONCE a program (zeros: what no layer writes has to be finite) and handed
+    from layer to layer, each overwriting the rows up to its call's end."""
+    turn = expand_turn(block_size, max_blocks)
+    MB = -(-max_blocks // turn) * turn
+    H, S = cfg.num_attention_heads, MB * block_size
+    Dk = cfg.qk_nope_head_dim + cfg.cache_row - cfg.kv_lora_rank
+    return MB, (jnp.zeros((B, S, H * Dk), dtype),
+                jnp.zeros((B, S, H * cfg.v_head_dim), dtype))
+
+
+def expand_rows(cfg: MLAMoEConfig, w, latent):
+    """Keys and values of every head from cached rows ``latent`` (B, T,
+    cache_row): ``(B, T, H (nope + pad))`` and ``(B, T, H v)``. A key is
+    ``[k_nope | the row's lanes behind the latent]``: the rotary key all heads
+    share and the row's zero padding, 256 lanes at the published widths, so
+    that one dot with ``[q_nope | q_rope | 0]`` is the score."""
+    B, T = latent.shape[:2]
+    H, r, nope = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    up = (latent[..., :r] @ w["kv_b"]).reshape(B, T, H, -1)
+    shared = jnp.broadcast_to(latent[:, :, None, r:], (B, T, H, latent.shape[-1] - r))
+    k = jnp.concatenate([up[..., :nope], shared], axis=-1)
+    return k.reshape(B, T, -1), up[..., nope:].reshape(B, T, -1)
+
+
+def expand_context(cfg: MLAMoEConfig, w, pool, layer, tables, ends, scratch):
+    """Keys and values of every head for the positions ``0 .. ends - 1`` of
+    each row, up-projected from the latent rows the pool holds (read by block
+    table), into ``scratch`` (:func:`context_scratch`; ``tables`` padded to
+    its width; ``expand_rows`` a turn). A ``fori_loop`` of ``EXPAND_ROWS``
+    positions a turn up to the longest row's end: positions no row has reached
+    cost nothing."""
+    K, V = scratch
+    B, S = K.shape[:2]
+    BS = pool.shape[2]
+    turn = expand_turn(BS, tables.shape[1])
+    rows = turn * BS
+
+    def body(c, kv):
+        tb = lax.dynamic_slice_in_dim(tables, c * turn, turn, axis=1)
+        k, v = expand_rows(cfg, w, pool[layer, tb].reshape(B, rows, pool.shape[-1]))
+        return (lax.dynamic_update_slice_in_dim(kv[0], k, c * rows, axis=1),
+                lax.dynamic_update_slice_in_dim(kv[1], v, c * rows, axis=1))
+
+    turns = jnp.minimum(-(-jnp.max(ends) // rows), S // rows)
+    return lax.fori_loop(0, turns, body, (K, V))
+
+
+def attend_call_plain(q, k, v, starts, heads, scale, block=512):
+    """Attention of a call's queries against a context in sequence order, in
+    blocks of query rows: the plain form of
+    ``ops/kernels/mla_prefill_attention`` (its docstring has the shapes).
+    ``lax.scan`` over the blocks, every key masked by position; float32
+    scores of ONE block at a time, never (queries x context) whole."""
+    B, T, S = q.shape[0], q.shape[1], k.shape[1]
+    bq = min(int(block), T)
+    Tp = -(-T // bq) * bq
+    if Tp != T:
+        q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0)))
+    kh, vh = k.reshape(B, S, heads, -1), v.reshape(B, S, heads, -1)
+
+    def rows(_, i):
+        qb = lax.dynamic_slice_in_dim(q, i * bq, bq, axis=1).reshape(B, bq, heads, -1)
+        qpos = starts[:, None] + i * bq + jnp.arange(bq)[None]
+        sees = jnp.arange(S)[None, None, :] <= qpos[:, :, None]
+        s = jnp.einsum("bqhd,bkhd->bhqk", qb, kh, preferred_element_type=F32) * scale
+        p = jax.nn.softmax(jnp.where(sees[:, None], s, -jnp.inf), axis=-1)
+        o = jnp.einsum("bhqk,bkhd->bqhd", p.astype(vh.dtype), vh)
+        return None, o.reshape(B, bq, -1)
+
+    with jax.named_scope("attention"):
+        _, o = lax.scan(rows, None, jnp.arange(Tp // bq))
+    return jnp.moveaxis(o, 0, 1).reshape(B, Tp, -1)[:, :T]
+
+
+def attend_call(cfg: MLAMoEConfig, q_nope, q_rope, context, starts, lens, scale,
+                kernels=False):
+    """Causal attention of a call (``q_nope`` / ``q_rope`` (B, T, H, ...) at
+    positions ``starts + 0 .. T - 1``) in the EXPANDED form, against the
+    ``context`` ``expand_context`` made: through the kernel where Mosaic takes
+    the widths, else the plain form. (B, T, H v)."""
+    B, T, H = q_nope.shape[:3]
+    K, V = context
+    Dk = K.shape[-1] // H
+    pad = jnp.zeros((B, T, H, Dk - cfg.qk_head_dim), q_nope.dtype)
+    q = jnp.concatenate([q_nope, q_rope, pad], axis=-1).reshape(B, T, H * Dk)
+    if kernels:
+        from ..ops.kernels import mla_prefill_attention as A
+
+        if A.mla_prefill_attention_takes(K.shape[1], Dk, cfg.v_head_dim, q.dtype):
+            with jax.named_scope("attention"):
+                return A.mla_prefill_attention(q, K, V, starts, lens, heads=H,
+                                               scale=scale)
+    return attend_call_plain(q, K, V, starts, H, scale)
+
+
 def absorb_queries(cfg: MLAMoEConfig, w, q_nope, q_rope):
     """The ABSORBED form's query of one token a row: ``q_nope`` through the
     key up-projection, beside ``q_rope``, zero over the row's padding, so
@@ -421,7 +548,9 @@ def final_hidden(cfg: MLAMoEConfig, params, X):
 
 
 def prompt_layer(cfg, tables, w, X, live, kernels=False):
-    """A layer over whole prompts ``X`` (B, T, n, d), attention expanded.
+    """A layer over whole prompts ``X`` (B, T, n, d), attention expanded as
+    one (T, T) product (prompts up to ``whole_prompt_max``: the engine serves
+    longer ones in calls, through the arch's ``tail_layer``).
     Returns ``(X, latent rows (B, T, cache_row), counts)``."""
     T = X.shape[1]
     pos = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), X.shape[:2])

@@ -212,7 +212,9 @@ def counters() -> Dict[str, int]:
     (copy-on-write block duplications when a shared block is written).
 
     Chunked prefill (FLAGS_serve_prefill_chunk): ``serve_prefill_chunks``
-    (prompt chunks executed through the chunk bucket).
+    (prompt chunks executed through the chunk bucket),
+    ``serve_prefill_context_tokens`` (the positions those calls attended
+    over: what was cached of their rows plus what they fed).
 
     Speculative decoding (FLAGS_serve_spec_k): ``serve_draft_proposed``
     / ``serve_draft_accepted`` (draft tokens proposed vs accepted by the
@@ -341,7 +343,7 @@ KNOWN_COUNTERS = frozenset({
     "serve_pages_allocated", "serve_pages_freed", "serve_pages_parked",
     "serve_pages_unparked", "serve_pool_damaged",
     "serve_pool_restores", "serve_pool_shrunk", "serve_preempted",
-    "serve_prefill_chunks", "serve_prefills",
+    "serve_prefill_chunks", "serve_prefill_context_tokens", "serve_prefills",
     "serve_prefix_blocks_shared", "serve_prefix_evicted",
     "serve_prefix_hits", "serve_prefix_misses",
     "serve_reattached", "serve_reattached_blocks", "serve_relayed",

@@ -662,12 +662,17 @@ class Engine:
         # leaves every code path below byte-for-byte the single-chip one.
         self._tp = int(cfg.tp) if int(cfg.tp) >= 2 else 0
         # an arch with the plain prefill and decode programs and no other
-        # yet (the MLA arch): every other path is refused by name, here or at
-        # its call, never served by GPT's code
+        # yet: every other path is refused by name, here or at its call,
+        # never served by GPT's code. One that brings the layer of a tail
+        # call (the MLA arch) has the tail program, so chunked prefill is
+        # served; what it still lacks of the prefix cache is the index
+        tail = "tail_layer" in arch
         for path, on in (("tp", self._tp), ("int8", cfg.int8),
                          ("speculative verify", int(cfg.spec_k)),
-                         ("prefix cache / tail prefill", cfg.prefix_cache),
-                         ("chunked prefill", int(cfg.prefill_chunk))):
+                         ("the prefix index" if tail
+                          else "prefix cache / tail prefill", cfg.prefix_cache),
+                         ("chunked prefill",
+                          int(cfg.prefill_chunk) and not tail)):
             if on:
                 self._refuse(path)
         self._tp_mesh = None
@@ -815,7 +820,15 @@ class Engine:
         # prefill. 0 = monolithic prefill, the exact prior path.
         self._chunk = int(cfg.prefill_chunk) if int(cfg.prefill_chunk) > 0 \
             else 0
+        # the longest un-cached prompt tail the whole-prompt program takes: a
+        # chunk, or what the arch says that program holds where that is less
+        # (``prompt_max``); a longer one is fed in calls, or refused at submit
+        # where the engine has no ``prefill_chunk``
+        self._prompt_max = arch.get("prompt_max")
+        self._whole_max = min(self._chunk, self._prompt_max or self._chunk)
         self._prefilling: List[_Seq] = []
+        self._chunk_counts: list = []  # expert counts of calls not read back yet
+        self._chunk_flight = None  # logits of a call that was not read back
         # analytic floor for the shed ETA while the decode EMA is cold: the
         # cost model's estimate of the per-step tp collective term (0.0 on
         # a single chip; a device the model holds no peaks for is an error)
@@ -969,6 +982,12 @@ class Engine:
                 f"serving: prompt + max_new_tokens = {total} exceeds "
                 f"max_seq_len {self.config.max_seq_len}"
             )
+        if self._prompt_max and not self._chunk \
+                and len(prompt) > self._prompt_max:
+            raise ValueError(
+                f"serving: the {self._arch['name']} arch prefills a whole "
+                f"prompt of at most {self._prompt_max} tokens, and this one "
+                f"has {len(prompt)}; set prefill_chunk to serve it in calls")
         # spec verify maps up to spec_k write slots past the last token
         if -(-(total + self._spec_k) // self.config.block_size) \
                 > self._pool.num_blocks - 1:
@@ -1103,7 +1122,9 @@ class Engine:
 
     def _refuse(self, path: str):
         """Raise for a path the arch has no program for (an arch that says
-        ``plain_paths_only``; GPT and Llama have every path)."""
+        ``plain_paths_only``: the plain prefill and decode programs, and the
+        tail program where it brings ``tail_layer``; GPT and Llama have every
+        path)."""
         if self._arch.get("plain_paths_only"):
             raise NotImplementedError(
                 f"serving: the {self._arch['name']} arch does not support "
@@ -1877,6 +1898,7 @@ class Engine:
             if not seq.req.done.is_set():
                 self._resume.append(seq)
         self._prefilling = []
+        self._chunk_flight, self._chunk_counts = None, []
         if self._prefix is not None and len(self._prefix):
             # cached-prefix KV is the most expendable resident state under
             # exhaustion — drop half before parking shrinks live headroom
@@ -2033,12 +2055,16 @@ class Engine:
 
     def _admit(self) -> List[_Seq]:
         admitted: List[_Seq] = []
+        # a sequence in mid-prefill (chunked) holds its batch slot from its
+        # admission: it lands among the running rows without asking again
+        taken = lambda: (len(self._running) + len(self._prefilling)
+                         + len(admitted))
         with span("admit") as sp:
             # preempted sequences first: they already hold tokens and their
             # latency clock is running
             still_resume = []
             for seq in self._resume:
-                if len(self._running) + len(admitted) >= self.config.max_batch:
+                if taken() >= self.config.max_batch:
                     still_resume.append(seq)
                     continue
                 matched = (self._match_prefix(seq.tokens)
@@ -2075,7 +2101,7 @@ class Engine:
                 else:
                     cand = list(self._waiting)
             for req in cand:
-                if len(self._running) + len(admitted) >= self.config.max_batch:
+                if taken() >= self.config.max_batch:
                     break
                 with self._cv:
                     if req.cancelled:
@@ -2263,14 +2289,15 @@ class Engine:
     # -- chunked prefill (PR 19) ---------------------------------------------
     def _chunk_divert(self, seqs: List[_Seq]) -> List[_Seq]:
         """Route admitted sequences whose un-cached prompt tail exceeds one
-        chunk into the incremental queue; the rest (short prompts gain
+        chunk (or what the arch's whole-prompt program holds, if that is
+        less) into the incremental queue; the rest (short prompts gain
         nothing from chunking) keep the monolithic path. The diverted
         sequence already owns ALL its prompt blocks — only the K/V writes
         are spread over steps."""
         keep: List[_Seq] = []
         bs = self.config.block_size
         for s in seqs:
-            if len(s.tokens) - s.cached_blocks * bs > self._chunk:
+            if len(s.tokens) - s.cached_blocks * bs > self._whole_max:
                 s.chunk_pos = s.cached_blocks * bs
                 self._prefilling.append(s)
             else:
@@ -2288,15 +2315,29 @@ class Engine:
         table, and the write goes through the existing paged scatter — so
         prefix-cached tails compose and the result is bit-identical to
         monolithic prefill. Intermediate chunk logits are discarded; the
-        final chunk lands the sequence exactly like a monolithic pass."""
+        final chunk lands the sequence exactly like a monolithic pass.
+
+        Every call is of ONE shape, the chunk's bucket, a prompt's last call
+        padded to it (``lens`` says what is real): an engine has one tail
+        program a ``prefill_chunk``, compiled by the first long prompt it is
+        shown, and no remainder reaches a bucket that nothing warmed."""
         jnp = self._jnp
         bw = self.config.prefill_batch
         batch = self._prefilling[:bw]
         feeds = [min(self._chunk, len(s.tokens) - s.chunk_pos)
                  for s in batch]
-        t_bucket = self._bucket_for(max(feeds))
+        t_bucket = self._bucket_for(self._chunk)
+        cached = [s.chunk_pos for s in batch]
         with span("prefill", bucket_t=t_bucket, bucket_b=bw,
-                  rows=len(batch), chunked=True) as sp:
+                  rows=len(batch), chunked=True, start=sum(cached),
+                  feed=sum(feeds), context_tokens=sum(cached) + sum(feeds),
+                  # live (query, key) pairs a layer and a head: a fed
+                  # position sees what is cached and the fed up to itself
+                  attended_pairs=sum(f * c + f * (f + 1) // 2
+                                     for c, f in zip(cached, feeds)),
+                  calls_left=sum(
+                      -(-(len(s.tokens) - s.chunk_pos - f) // self._chunk)
+                      for s, f in zip(batch, feeds))) as sp:
             if self._obs is not None:
                 sp.set(traces=tuple(s.req.trace for s in batch))
             self._beat = time.monotonic()
@@ -2313,15 +2354,41 @@ class Engine:
                 starts[r] = s.chunk_pos
                 lens[r] = feeds[r]
                 tables[r, :len(s.blocks)] = s.blocks
-            logits, = self._run(
+            if "call_attrs" in self._arch:
+                # what the arch says its call reads of the pool
+                sp.set(**self._arch["call_attrs"](
+                    cached, feeds, self.config.block_size, self._max_blocks))
+            if self._chunk_flight is not None:
+                # ONE call in flight for an arch whose tail program holds a
+                # scratch of its whole context (``tail_scratch``): the call
+                # before this one was not read back, and a second program's
+                # temporaries beside the first's need not fit beside a pool
+                # that fills the chip. The decode step enqueued between them
+                # keeps the device busy meanwhile. Other archs' calls overlap
+                with span("prefill_wait"):
+                    self._jax.block_until_ready(self._chunk_flight)
+                self._chunk_flight = None
+                self._beat = time.monotonic()
+            logits, *extras = self._run(
                 fn, self._compute_params, jnp.asarray(ids),
                 jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(tables))
             counter_inc("serve_prefill_chunks")
+            counter_inc("serve_prefill_context_tokens",
+                        sum(cached) + sum(feeds))
             done = [r for r, s in enumerate(batch)
                     if s.chunk_pos + feeds[r] >= len(s.tokens)]
+            # an arch that routes experts reports every call's counts; they
+            # stay on the device until a final chunk is read back anyway
+            self._chunk_counts += extras
             if done:  # only final chunks need the logits host-side
-                rows, = self._prefill_readback(logits)
+                rows, *counts = self._prefill_readback(
+                    logits, *self._chunk_counts)
+                self._chunk_counts = []
+                if counts:
+                    self._note_experts(sp, np.sum(counts, axis=0))
             else:
+                if "tail_scratch" in self._arch:
+                    self._chunk_flight = logits
                 self._beat = time.monotonic()
                 self._compiling = False
             for r, s in enumerate(batch):
@@ -2681,6 +2748,9 @@ class Engine:
         tokens inside the windows, the rows whose state is updated. The rows
         that pad the bucket are not counted."""
         arch, attrs = self._arch, {}
+        if self._row_slots is None:
+            # one kind of cache: every layer reads the rows' whole contexts
+            attrs["context_tokens"] = int(pos[:n].astype(np.int64).sum()) + n
         if self._step_attrs is not None:
             attrs.update(self._step_attrs(
                 pos[:n], bucket, self.config.block_size, self._max_blocks,
@@ -3020,7 +3090,7 @@ class Engine:
             raw = G.build_paged_tail_prefill(
                 self._arch, bw, t_bucket, self.config.block_size,
                 self._max_blocks)
-            donate = (5, 6)
+            donate = tuple(range(5, 5 + len(self._cache)))
         elif kind == "spec":
             bb, mb = bucket
             raw = G.build_paged_spec_decode(

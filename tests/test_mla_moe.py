@@ -368,8 +368,10 @@ def test_unknown_mechanisms_are_refused_by_name():
 @pytest.mark.parametrize("kw,path", [
     ({"tp": 2}, "tp"), ({"int8": True}, "int8"),
     ({"spec_k": 2}, "speculative verify"),
-    ({"prefix_cache": True}, "prefix cache / tail prefill"),
-    ({"prefill_chunk": 16}, "chunked prefill")])
+    # the tail program exists since PR 47 (``prefill_chunk`` is served:
+    # tests/test_mla_moe_chunked.py); of the prefix cache the index is owed
+    ({"prefix_cache": True}, "the prefix index"),
+    ({"prefix_cache": True, "prefill_chunk": 16}, "the prefix index")])
 def test_unsupported_engine_paths_raise_at_construction(tiny, kw, path):
     with pytest.raises(NotImplementedError) as e:
         Engine(tiny[0], block_size=8, num_blocks=16, max_batch=4, max_seq_len=64, **kw)

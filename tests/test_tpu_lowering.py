@@ -950,3 +950,113 @@ def test_hybrid_step_sums_its_partial_products_by_exchange(v5e, monkeypatch):
     assert said["mp_reduce_exchanges"] == 0
     assert said["mp_activation_reduces"] == sites * layers + 1
     assert total(compiled) <= total(without) + 40e6
+
+
+# -- prompts of the latent arch prefilled in calls against the cache (PR 47) ----
+# Kimi-VL-A3B's decoder at its published widths: d 2048, 16 heads, a cached row
+# of 576 numbers padded to 640, 64 experts of width 1408 at 6 a token
+@pytest.mark.parametrize("T,S", [
+    pytest.param(8192, 32768, id="the_cells_call_and_context"),
+    pytest.param(2048, 8192, id="a_shorter_call_and_context")])
+def test_mla_prefill_attention_compiles(v5e, T, S):
+    """The prefill-call kernel at the published widths (16 heads, keys of 256
+    lanes, values of 128): keys and values of one head resident in VMEM over
+    the engine's whole context."""
+    H, Dk, Dv = 16, 256, 128
+    from paddle_tpu.ops.kernels.mla_prefill_attention import mla_prefill_attention
+
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    calls = _compile_for_tpu(
+        lambda q, k, v, starts, lens: mla_prefill_attention(
+            q, k, v, starts, lens, heads=H, scale=192 ** -0.5, interpret=False),
+        _on(s, (1, T, H * Dk), bf), _on(s, (1, S, H * Dk), bf),
+        _on(s, (1, S, H * Dv), bf), _on(s, (1,), jnp.int32), _on(s, (1,), jnp.int32))
+    assert calls == 1
+
+
+def test_mla_prefill_attention_refuses_what_vmem_cannot_hold(v5e):
+    from paddle_tpu.ops.kernels.mla_prefill_attention import (
+        mla_prefill_attention, mla_prefill_attention_takes)
+
+    assert mla_prefill_attention_takes(32768, 256, 128, jnp.bfloat16, interpret=False)
+    assert not mla_prefill_attention_takes(32768, 640, 512, jnp.bfloat16, interpret=False)
+    assert not mla_prefill_attention_takes(1024, 192, 128, jnp.bfloat16, interpret=False)
+    s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
+    with pytest.raises(ValueError, match="mla_prefill_attention_takes"):
+        _compile_for_tpu(
+            lambda q, k, v, starts, lens: mla_prefill_attention(
+                q, k, v, starts, lens, heads=1, scale=0.1, interpret=False),
+            _on(s, (1, 8192, 640), bf), _on(s, (1, 32768, 640), bf),
+            _on(s, (1, 32768, 512), bf), _on(s, (1,), jnp.int32), _on(s, (1,), jnp.int32))
+
+
+def _kimivl_operands(s, nb, monkeypatch):
+    """(arch, params as shapes, the latent pool) of the benchmark's
+    configuration, its kernels Mosaic's."""
+    import json
+    import pathlib
+
+    import paddle_tpu.models.generation as G
+    from paddle_tpu.models.mla_moe import MLAMoEConfig, MLAMoEForCausalLM
+    from paddle_tpu.ops.kernels import (mla_paged_attention, mla_prefill_attention,
+                                        moe_experts)
+
+    for mod in (mla_paged_attention, mla_prefill_attention, moe_experts):
+        monkeypatch.setattr(mod, "interpret_default", lambda: False)
+    path = pathlib.Path(__file__).parent.parent / "benchmark/configs/kimi-vl-a3b-7l.json"
+    cfg = MLAMoEConfig.from_dict(json.loads(path.read_text()))
+    sd = {k: _on(s, shape, jnp.bfloat16)
+          for k, shape, _ in MLAMoEForCausalLM.parameter_specs(cfg)}
+    params = jax.tree_util.tree_map(
+        lambda x: _on(s, x.shape, x.dtype),
+        jax.eval_shape(lambda sd: G.mla_moe_params(cfg, sd), sd))
+    pool = _on(s, (cfg.num_hidden_layers, nb, 16, cfg.cache_row), jnp.bfloat16)
+    return G._mla_moe_arch(cfg, True), params, pool
+
+
+def test_kimivl_prefill_call_beside_a_full_pool(v5e, monkeypatch):
+    """The tail program of the cell's configuration, whole (1 dense + 6
+    expert layers, 8.5 GB of weights), a call of 8,192 positions against a
+    table of 32,768, beside a pool of 4 GB: seven calls of the prefill
+    kernel and six of the experts' wide tiles, the donated pool updated in
+    place, and temporaries (the expanded context, the dense layer's products)
+    inside the cell's ``headroom_bytes`` with no (queries x context) tensor
+    among them: float32 scores of 8,192 x 16 heads against 24,576 cached rows
+    alone would be 12.9 GB."""
+    import paddle_tpu.models.generation as G
+
+    s, T, MB, NB = SingleDeviceSharding(v5e[0]), 8192, 2048, 28000
+    arch, params, pool = _kimivl_operands(s, NB, monkeypatch)
+    fn = jax.jit(G.build_paged_tail_prefill(arch, 1, T, 16, MB), donate_argnums=(5,))
+    compiled = _compile_uncached(fn.trace(
+        params, _on(s, (1, T), jnp.int32), _on(s, (1,), jnp.int32),
+        _on(s, (1,), jnp.int32), _on(s, (1, MB), jnp.int32), pool
+    ).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 7 + 6
+    for name in ("mla_prefill_attention", "moe_experts_t256"):
+        assert f"%{name}" in text, name
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7 * NB * 16 * 640 * 2
+    assert mem.temp_size_in_bytes < 2.2e9, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
+def test_kimivl_decode_step_takes_a_table_of_32k_positions(v5e, monkeypatch):
+    """The 32-row decode program at the cell's context: the latent read takes
+    32 rows of a 2,048-block table by scalar prefetch (256 KB), which no run
+    had shown before PR 47."""
+    import paddle_tpu.models.generation as G
+
+    s, B, MB, NB = SingleDeviceSharding(v5e[0]), 32, 2048, 28000
+    arch, params, pool = _kimivl_operands(s, NB, monkeypatch)
+    step = jax.jit(G.feed_tokens_back(G.build_paged_decode_kernel(arch, B, 16, MB),
+                                      B, 32, MB, 1), donate_argnums=(1,))
+    compiled = _compile_uncached(step.trace(
+        params, pool, _on(s, (B, MB + G.STEP_COLS), jnp.int32),
+        _on(s, (32,), jnp.int32), _on(s, (2,), jnp.uint32)
+    ).lower(lowering_platforms=("tpu",)))
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 7 + 6
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7 * NB * 16 * 640 * 2
+    assert mem.temp_size_in_bytes < 256e6, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
