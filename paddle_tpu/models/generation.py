@@ -801,7 +801,9 @@ def _mla_moe_arch(cfg, kernels):
             "cache": ((cfg.cache_row,),), "plain_paths_only": True,
             "expert_layers": sum(cfg.is_expert_layer(i)
                                  for i in range(cfg.num_hidden_layers)),
-            "experts": cfg.n_routed_experts}
+            "experts": cfg.n_routed_experts,
+            **({} if len(cfg.experts_held) == cfg.n_routed_experts
+               else {"experts_held": cfg.experts_held})}
     if kernels:
         from ..ops.kernels import mla_paged_attention as K
 
@@ -1245,6 +1247,7 @@ def _afmoe_arch(cfg, kernels):
             **_kernel_step_attrs(kernels, reads),
             "expert_layers": cfg.num_hidden_layers - cfg.num_dense_layers,
             "experts": len(cfg.experts_held),
+            "experts_per_token": cfg.num_experts_per_tok,
             "cache": {"layers": tuple((kinds[k], None) for k in cfg.layer_types),
                       "window_tokens": W,
                       "span_attrs": {"paged_kv_tokens": "paged",

@@ -828,6 +828,7 @@ class Engine:
         self._whole_max = min(self._chunk, self._prompt_max or self._chunk)
         self._prefilling: List[_Seq] = []
         self._chunk_counts: list = []  # expert counts of calls not read back yet
+        self._chunk_tokens = 0  # the tokens those calls fed
         self._chunk_flight = None  # logits of a call that was not read back
         # analytic floor for the shed ETA while the decode EMA is cold: the
         # cost model's estimate of the per-step tp collective term (0.0 on
@@ -1130,14 +1131,26 @@ class Engine:
                 f"serving: the {self._arch['name']} arch does not support "
                 f"{path} yet")
 
-    def _note_experts(self, sp, counts: np.ndarray):
+    def _note_experts(self, sp, counts: np.ndarray, tokens: int):
         """A program's ``(expert layers, experts)`` count of the live tokens
-        each expert took, onto its span, the counter and ``stats()``."""
+        each expert took, onto its span, the counter and ``stats()``; of the
+        ``tokens`` live tokens' (token, choice) pairs (``expert_pairs``), how
+        many an expert this chip holds took (``expert_pairs_held``: the rows
+        the expert product's combine moves). An arch whose counts are of the
+        experts held alone says how many a token chooses
+        (``experts_per_token``); one that counts every expert and holds some
+        says which columns (``experts_held``)."""
         self._expert_tokens += counts
         touched = int(np.count_nonzero(counts))
         assigned = int(counts.sum())
+        per_token = self._arch.get("experts_per_token")
+        held = self._arch.get("experts_held")
         sp.set(experts_touched=touched, expert_tokens_max=int(counts.max()),
-               expert_assignments=assigned)
+               expert_assignments=assigned,
+               expert_pairs=(assigned if per_token is None
+                             else int(tokens) * per_token * counts.shape[0]),
+               expert_pairs_held=(assigned if held is None
+                                  else int(counts[:, list(held)].sum())))
         counter_inc("serve_expert_assignments", assigned)
 
     def debug_requests(self) -> List[dict]:
@@ -1899,6 +1912,7 @@ class Engine:
                 self._resume.append(seq)
         self._prefilling = []
         self._chunk_flight, self._chunk_counts = None, []
+        self._chunk_tokens = 0
         if self._prefix is not None and len(self._prefix):
             # cached-prefix KV is the most expendable resident state under
             # exhaustion — drop half before parking shrinks live headroom
@@ -2200,7 +2214,7 @@ class Engine:
                     counter_inc("serve_prefills")
                     rows, *extras = self._prefill_readback(logits, *extras)
                     if extras:
-                        self._note_experts(sp, extras[0])
+                        self._note_experts(sp, extras[0], real)
                     self._land_prefill(chunk, rows)
         for t_bucket in sorted(tail_groups):
             group = tail_groups[t_bucket]
@@ -2380,12 +2394,14 @@ class Engine:
             # an arch that routes experts reports every call's counts; they
             # stay on the device until a final chunk is read back anyway
             self._chunk_counts += extras
+            self._chunk_tokens += sum(feeds)
             if done:  # only final chunks need the logits host-side
                 rows, *counts = self._prefill_readback(
                     logits, *self._chunk_counts)
-                self._chunk_counts = []
                 if counts:
-                    self._note_experts(sp, np.sum(counts, axis=0))
+                    self._note_experts(sp, np.sum(counts, axis=0),
+                                       self._chunk_tokens)
+                self._chunk_counts, self._chunk_tokens = [], 0
             else:
                 if "tail_scratch" in self._arch:
                     self._chunk_flight = logits
@@ -2785,7 +2801,7 @@ class Engine:
         try:
             nxt, *extras = self._decode_readback(*fl.arrays)
             if extras:
-                self._note_experts(sp, extras[0])
+                self._note_experts(sp, extras[0], len(fl.rows))
             self._step_done(sp, fl.warm, fl.t0, len(fl.rows), fl.bucket)
             live = [(r, s) for r, s in enumerate(fl.rows)
                     if not s.req.done.is_set()]

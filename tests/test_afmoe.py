@@ -509,6 +509,34 @@ def test_spans_count_real_rows_and_tokens_alone(tiny):
     assert not any(k in a for a in steps for k in ("paged_blocks", "paged_chunks", "paged_full_chunks"))
 
 
+def test_spans_carry_the_pairs_and_those_a_held_expert_took(tiny):
+    """PR 48: a span that carries ``experts_touched`` also says how many
+    (token, choice) pairs its live tokens made over the expert layers
+    (``expert_pairs``) and how many of them an expert held here took
+    (``expert_pairs_held``: the rows the expert product's combine moves).
+    This chip holds 4 of the router's 16 experts and a token chooses 4."""
+    net, w = tiny
+    layers = TINY["num_hidden_layers"] - TINY["num_dense_layers"]
+    k = TINY["num_experts_per_tok"]
+    prompt = np.arange(11, dtype=np.int32)
+    seen = []
+    spans.add_span_observer(seen.append)
+    try:
+        with _engine(net, decode_buckets=(4,)) as eng:
+            eng.submit(prompt, max_new_tokens=4).result(timeout=600)
+    finally:
+        spans.remove_span_observer(seen.append)
+    fill, = [sp.attrs for sp in seen if sp.name == "prefill"]
+    assert fill["expert_pairs"] == 11 * k * layers
+    assert fill["expert_pairs_held"] == fill["expert_assignments"] \
+        == int(_reference_counts(TINY, w, prompt).sum())
+    assert 0 < fill["expert_pairs_held"] < fill["expert_pairs"]
+    steps = [sp.attrs for sp in seen if sp.name == "decode_step" and sp.attrs["ahead"]]
+    assert steps and all(a["expert_pairs"] == k * layers for a in steps)
+    assert all(a["expert_pairs_held"] == a["expert_assignments"] <= k * layers
+               for a in steps)
+
+
 def test_decode_spans_carry_the_block_table_reads_copy_schedule(tiny, monkeypatch):
     """``paged_blocks`` / ``paged_chunks`` / ``paged_full_chunks`` of a
     ``decode_step`` span are what the landing step's positions give by hand,

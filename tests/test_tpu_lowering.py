@@ -259,10 +259,11 @@ def test_mla_paged_attention_compiles(v5e, B):
     assert n == 1
 
 
-def _mosaic_ops(lowered, names):
+def _mosaic_ops(lowered, names, kernel=None):
     """``[(operation name, shapes of its array operands (source, then
     destination, of a copy), loops around it)]`` of the ops of
-    the lowered program's ONE Mosaic kernel whose name ends in one of
+    the lowered program's ONE Mosaic kernel (``kernel``: the how-manieth of
+    several) whose name ends in one of
     ``names``: the kernel's serialized module parsed back (its dialects are
     not registered here, so by the generic form) and walked."""
     import base64
@@ -271,8 +272,9 @@ def _mosaic_ops(lowered, names):
     from jax._src.interpreters import mlir as jmlir
     from jax._src.lib.mlir import ir
 
-    body, = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
-                       lowered.as_text())
+    bodies = re.findall(r'\\22body\\22: \\22([A-Za-z0-9+/=]+)\\22',
+                        lowered.as_text())
+    body, = bodies if kernel is None else [bodies[kernel]]
     ctx = jmlir.make_ir_context()
     ctx.allow_unregistered_dialects = True
     found = []
@@ -295,10 +297,10 @@ def _mosaic_ops(lowered, names):
     return found
 
 
-def _dma_sites(lowered):
+def _dma_sites(lowered, kernel=None):
     """``(waits, starts)`` of the lowered program's Mosaic kernel, each a list
     of ``(the copy's destination shape, loops around the op)``."""
-    ops = _mosaic_ops(lowered, ("tpu.wait_dma2", "tpu.enqueue_dma"))
+    ops = _mosaic_ops(lowered, ("tpu.wait_dma2", "tpu.enqueue_dma"), kernel)
     return tuple([(shapes[-1], loops) for name, shapes, loops in ops
                   if name == which] for which in ("wait_dma2", "enqueue_dma"))
 
@@ -397,10 +399,12 @@ def test_mla_paged_attention_row_off_the_lane_tile_is_refused(v5e):
             C=8, interpret=False), *args)
 
 
-@pytest.mark.parametrize("N", [pytest.param(8, id="decode_8_rows"),
-                               pytest.param(64, id="decode_64_rows"),
-                               pytest.param(4096, id="prefill_4x1024")])
-def test_moe_experts_compiles(v5e, N):
+@pytest.mark.parametrize("N,calls", [pytest.param(8, 1, id="decode_8_rows"),
+                                     pytest.param(64, 1, id="decode_64_rows"),
+                                     pytest.param(4096, 2, id="prefill_4x1024")])
+def test_moe_experts_compiles(v5e, N, calls):
+    """One kernel at 16-row tiles; at 256-row tiles ``moe_combine`` behind it
+    (PR 48), here with rows of 3,584: 14 lanes-rows of 128 words a row."""
     from paddle_tpu.ops.kernels.moe_experts import moe_experts
 
     s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
@@ -410,7 +414,69 @@ def test_moe_experts_compiles(v5e, N):
         _on(s, (N, 3584), bf), _on(s, (N, 4), jnp.int32),
         _on(s, (N, 4), jnp.float32), _on(s, (64, 3584, 1024), bf),
         _on(s, (64, 3584, 1024), bf), _on(s, (64, 1024, 3584), bf))
-    assert n == 1
+    assert n == calls
+
+
+@pytest.mark.parametrize("k,E,f,temp_mb", [
+    pytest.param(6, 64, 1408, 480, id="kimi_prefill_8192x6_of_64"),
+    pytest.param(8, 16, 1024, 560, id="trinity_prefill_8192x8_16_held")])
+def test_moe_experts_prefill_combine_writes_no_float32_pairs(v5e, k, E, f, temp_mb):
+    """PR 48: at 256-row tiles the pairs' rows leave the expert kernel for
+    their (choice, token) place as 32-bit words of two bfloat16 and
+    ``moe_combine`` reads them once: the optimised program holds no float32
+    array of ``N k d`` elements (the parent wrote ``f32[8192,6,2048]``, 537
+    MB with the 6 padded to 8), nor one of half or a quarter of that. Its
+    temporaries are the tiled rows into the kernel (268 / 285 MB) beside the
+    planes (201 / 268 MB): 471 and 554 MB where the parent's call at Kimi's
+    shape held 739. (ISSUE 48 asked for under 300 MB; the tiled rows alone
+    are nearly that, and they are the dispatch side, which PR 48 leaves.)"""
+    import re
+
+    from paddle_tpu.ops.kernels.moe_experts import moe_experts
+
+    s, bf, N, d = SingleDeviceSharding(v5e[0]), jnp.bfloat16, 8192, 2048
+    lowered = jax.jit(
+        lambda x, slot, g, wg, wu, wd: moe_experts(x, slot, g, wg, wu, wd,
+                                                   interpret=False)
+    ).trace(_on(s, (N, d), bf), _on(s, (N, k), jnp.int32),
+            _on(s, (N, k), jnp.float32), _on(s, (E, d, f), bf),
+            _on(s, (E, d, f), bf), _on(s, (E, f, d), bf)
+            ).lower(lowering_platforms=("tpu",))
+    compiled = _compile_uncached(lowered)
+    text = compiled.as_text()
+    assert "%moe_experts_t256" in text and "%moe_combine" in text
+    widest = max(int(np.prod([int(n) for n in dims.split(",")]))
+                 for dims in re.findall(r"f32\[([\d,]+)\]", text))
+    assert widest < N * k * d // 8, widest
+    assert compiled.memory_analysis().temp_size_in_bytes < temp_mb * 1e6
+
+
+@pytest.mark.parametrize("d,lanes", [pytest.param(2048, 8, id="rows_of_whole_tiles"),
+                                     pytest.param(3584, 14, id="rows_of_14_lane_rows")])
+def test_moe_experts_copy_schedule_at_wide_tiles(v5e, d, lanes):
+    """PR 48: a full tile's 256 row copies are started as straight-line code
+    (a partial tile's in a loop), every one a row of ``lanes`` x 128 words.
+    They are waited for at two sites (the next tile's epilogue, the last grid
+    step): ONCE, by a descriptor of the whole buffer, where a row is whole
+    (8, 128) tiles; where it is not (14 lane-rows lie in 16) the buffer's
+    descriptor would count the padding too and the wait would never end (the
+    chip hung so, once), so there every row is awaited in a loop."""
+    from paddle_tpu.ops.kernels.moe_experts import moe_experts
+
+    s, bf, N, k, E, f = SingleDeviceSharding(v5e[0]), jnp.bfloat16, 4096, 4, 64, 1024
+    lowered = jax.jit(
+        lambda x, slot, g, wg, wu, wd: moe_experts(x, slot, g, wg, wu, wd,
+                                                   interpret=False)
+    ).trace(_on(s, (N, d), bf), _on(s, (N, k), jnp.int32),
+            _on(s, (N, k), jnp.float32), _on(s, (E, d, f), bf),
+            _on(s, (E, d, f), bf), _on(s, (E, f, d), bf)
+            ).lower(lowering_platforms=("tpu",))
+    waits, starts = _dma_sites(lowered, kernel=0)
+    row, tile = (1, lanes, 128), (256, lanes, 128)
+    assert {dst for dst, _ in starts} == {row}
+    assert sorted(loops for _, loops in starts) == [0] * 256 + [1]
+    whole = lanes % 8 == 0
+    assert sorted(waits) == sorted(([(tile, 0)] if whole else []) * 2 + [(row, 1)] * 2)
 
 
 @pytest.mark.parametrize("T", [32, 4096])
@@ -561,14 +627,18 @@ def test_packed_kv_read_compiles(v5e):
     assert n == 1
 
 
-@pytest.mark.parametrize("N,room", [pytest.param(64, 16e6, id="decode_64_rows"),
-                                    pytest.param(8192, 420e6, id="prefill_4x2048")])
-def test_moe_experts_reads_its_layer_out_of_the_stack(v5e, N, room):
+@pytest.mark.parametrize("N,room,calls", [
+    pytest.param(64, 16e6, 1, id="decode_64_rows"),
+    pytest.param(8192, 420e6, 2, id="prefill_4x2048")])
+def test_moe_experts_reads_its_layer_out_of_the_stack(v5e, N, room, calls):
     """``moe_experts(layer=...)``: the stacks of two layers' experts go to the
     kernel whole and the layer is a traced scalar; no slice of a stack (403
     MB a matrix, three of them) is among the temporaries: a decode call has
-    4 MB, a prefill call its own tiled rows in and out (2 x 192 tiles x 256
-    rows x 2048 in bfloat16 = 403 MB, whatever the weights)."""
+    4 MB, a prefill call its tiled rows into the kernel (192 tiles x 256 rows
+    x 2048 in bfloat16 = 201 MB) beside, since PR 48, the pairs' rows out of
+    it (4 planes of 8,192 x 4 KB = 134 MB; until then the tiled rows out,
+    201 MB more), whatever the weights; ``moe_combine`` is the second
+    kernel of a prefill call."""
     from paddle_tpu.ops.kernels.moe_experts import moe_experts
 
     s, bf = SingleDeviceSharding(v5e[0]), jnp.bfloat16
@@ -580,7 +650,7 @@ def test_moe_experts_reads_its_layer_out_of_the_stack(v5e, N, room):
         _on(s, (2, 64, 1536, 2048), bf), _on(s, (), jnp.int32),
     ).lower(lowering_platforms=("tpu",))
     compiled = _compile_uncached(lowered)
-    assert lowered.as_text().count("tpu_custom_call") == 1
+    assert lowered.as_text().count("tpu_custom_call") == calls
     assert compiled.memory_analysis().temp_size_in_bytes < room
 
 
@@ -711,15 +781,17 @@ def _afmoe_operands(s, nb, monkeypatch):
     return arch, params, pools
 
 
-@pytest.mark.parametrize("B", [48, 64])
-def test_afmoe_decode_step_beside_a_full_pool(v5e, monkeypatch, B):
+@pytest.mark.parametrize("B,combines", [(48, 0), (64, 6)])
+def test_afmoe_decode_step_beside_a_full_pool(v5e, monkeypatch, B, combines):
     """The cell's two widest decode programs (its engine states the buckets
     32, 48 and 64) of the cell's configuration at a context of
     8,192 (a table of 512 blocks), as the engine jits it: ONE period of the
     layer pattern in the scan and the two dense and two tail layers unrolled
-    (8 block-table reads, 6 expert calls, whatever the depth), the four
-    donated pools (pages and rings) updated in place, and temporaries that
-    follow neither (30 MB when written)."""
+    (8 block-table reads, 6 expert calls, whatever the depth; the 64-row
+    bucket's 512 pairs pass the tile rule, so since PR 48 ``moe_combine``
+    stands behind each of its expert calls), the four donated pools (pages
+    and rings) updated in place, and temporaries that follow neither (30 MB
+    when written)."""
     import paddle_tpu.models.generation as G
 
     s, MB, NB = SingleDeviceSharding(v5e[0]), 512, 40000
@@ -734,7 +806,8 @@ def test_afmoe_decode_step_beside_a_full_pool(v5e, monkeypatch, B):
         _on(s, (64,), jnp.int32), _on(s, (2,), jnp.uint32),
     ).lower(lowering_platforms=("tpu",)))
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 8 + 6
+    assert text.count('custom_call_target="tpu_custom_call"') == 8 + 6 + combines
+    assert text.count("%moe_combine") >= bool(combines)
     for name in ("moe_experts_t", "paged_attention"):
         assert f"%{name}" in text, name
     mem = compiled.memory_analysis()
@@ -1018,7 +1091,8 @@ def test_kimivl_prefill_call_beside_a_full_pool(v5e, monkeypatch):
     """The tail program of the cell's configuration, whole (1 dense + 6
     expert layers, 8.5 GB of weights), a call of 8,192 positions against a
     table of 32,768, beside a pool of 4 GB: seven calls of the prefill
-    kernel and six of the experts' wide tiles, the donated pool updated in
+    kernel and six of the experts' wide tiles, ``moe_combine`` behind each
+    of the six (PR 48), the donated pool updated in
     place, and temporaries (the expanded context, the dense layer's products)
     inside the cell's ``headroom_bytes`` with no (queries x context) tensor
     among them: float32 scores of 8,192 x 16 heads against 24,576 cached rows
@@ -1033,8 +1107,8 @@ def test_kimivl_prefill_call_beside_a_full_pool(v5e, monkeypatch):
         _on(s, (1,), jnp.int32), _on(s, (1, MB), jnp.int32), pool
     ).lower(lowering_platforms=("tpu",)))
     text = compiled.as_text()
-    assert text.count('custom_call_target="tpu_custom_call"') == 7 + 6
-    for name in ("mla_prefill_attention", "moe_experts_t256"):
+    assert text.count('custom_call_target="tpu_custom_call"') == 7 + 6 + 6
+    for name in ("mla_prefill_attention", "moe_experts_t256", "moe_combine"):
         assert f"%{name}" in text, name
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes >= 7 * NB * 16 * 640 * 2
