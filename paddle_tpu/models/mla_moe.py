@@ -48,6 +48,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from .. import nn
 from ..core.tensor import Parameter, Tensor
@@ -295,6 +296,9 @@ def attend_expanded(cfg: MLAMoEConfig, w, q_nope, q_rope, latent, tables):
 
 # rows of context a turn of ``expand_context`` up-projects (whole blocks)
 EXPAND_ROWS = 2048
+# the layout ``mla_prefill_attention`` reads its context in: (B, S, H D),
+# features minor
+ROW_MAJOR = Layout(major_to_minor=(0, 1, 2))
 # the float32 scores (H, T, T) a whole prompt's ``attend_expanded`` may hold a
 # row; a longer prompt is served in calls (``whole_prompt_max``)
 WHOLE_SCORES_BYTES = 2 ** 30
@@ -332,13 +336,19 @@ def expand_rows(cfg: MLAMoEConfig, w, latent):
     cache_row): ``(B, T, H (nope + pad))`` and ``(B, T, H v)``. A key is
     ``[k_nope | the row's lanes behind the latent]``: the rotary key all heads
     share and the row's zero padding, 256 lanes at the published widths, so
-    that one dot with ``[q_nope | q_rope | 0]`` is the score."""
+    that one dot with ``[q_nope | q_rope | 0]`` is the score. Both results are
+    pinned ``ROW_MAJOR``: left to itself XLA:TPU lays the up-projection out
+    positions minor, ``expand_context``'s loop carries the whole scratch so,
+    and a transposing copy of the whole scratch stands before every kernel
+    call (PR 49); pinned, one turn's rows are relaid in VMEM. No arithmetic,
+    and nothing at all on the CPU."""
     B, T = latent.shape[:2]
     H, r, nope = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
     up = (latent[..., :r] @ w["kv_b"]).reshape(B, T, H, -1)
     shared = jnp.broadcast_to(latent[:, :, None, r:], (B, T, H, latent.shape[-1] - r))
     k = jnp.concatenate([up[..., :nope], shared], axis=-1)
-    return k.reshape(B, T, -1), up[..., nope:].reshape(B, T, -1)
+    return with_layout_constraint(
+        (k.reshape(B, T, -1), up[..., nope:].reshape(B, T, -1)), ROW_MAJOR)
 
 
 def expand_context(cfg: MLAMoEConfig, w, pool, layer, tables, ends, scratch):

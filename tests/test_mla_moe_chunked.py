@@ -134,6 +134,55 @@ def test_two_rows_of_one_call_keep_their_own_starts(tiny):
     assert int(np.asarray(c).sum()) == 16 * 2 * TINY["num_experts_per_tok"]
 
 
+def _expand_rows_plain(cfg, w, latent):
+    """``expand_rows`` as it stood until PR 49 pinned its results' layout:
+    the same arithmetic with no word about a layout, its plain form."""
+    B, T = latent.shape[:2]
+    H, r, nope = cfg.num_attention_heads, cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    up = (latent[..., :r] @ w["kv_b"]).reshape(B, T, H, -1)
+    shared = jnp.broadcast_to(latent[:, :, None, r:], (B, T, H, latent.shape[-1] - r))
+    k = jnp.concatenate([up[..., :nope], shared], axis=-1)
+    return k.reshape(B, T, -1), up[..., nope:].reshape(B, T, -1)
+
+
+def test_expand_context_fills_its_scratch_turn_by_turn(tiny, monkeypatch):
+    """Two rows whose longer one ends inside the SECOND of the scratch's three
+    turns: the two turns the loop runs hold, position for position,
+    ``expand_rows`` of the rows their tables name (the positions up to each
+    row's end among them), bit for bit what the function gave before its
+    results' layout was pinned, and the third turn is the scratch's zeros."""
+    monkeypatch.setattr(M, "EXPAND_ROWS", 16)
+    net, _ = tiny
+    cfg = net.config
+    _, _, params, _ = G.mla_moe_decode_state(net, False)
+    w, layer, bs, B = params["layers"][1], 1, 8, 2
+    rng = np.random.default_rng(5)
+    pool = jnp.asarray(rng.standard_normal((3, 16, bs, cfg.cache_row)), jnp.float32)
+    width, scratch = M.context_scratch(cfg, B, bs, 5, pool.dtype)
+    rows = M.expand_turn(bs, width) * bs
+    assert (width, rows, scratch[0].shape[1]) == (6, 16, 48)
+    tables = np.zeros((B, width), np.int32)
+    tables[0, :3], tables[1, :2] = [3, 9, 4], [7, 1]
+    ends = jnp.asarray([21, 12], jnp.int32)
+    K, V = jax.jit(lambda pool, tables, ends: M.expand_context(
+        cfg, w, pool, layer, tables, ends, scratch))(pool, jnp.asarray(tables), ends)
+    assert K.shape == (B, 48, 4 * (16 + 96)) and V.shape == (B, 48, 4 * 16)
+    plain = jax.jit(lambda latent: _expand_rows_plain(cfg, w, latent))
+    for turn in range(2):
+        latent = pool[layer][tables[:, 2 * turn:2 * turn + 2]].reshape(B, rows, -1)
+        k, v = plain(latent)
+        at = slice(turn * rows, (turn + 1) * rows)
+        np.testing.assert_array_equal(np.asarray(K[:, at]), np.asarray(k))
+        np.testing.assert_array_equal(np.asarray(V[:, at]), np.asarray(v))
+        for got, want in zip(M.expand_rows(cfg, w, latent), (k, v)):
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    # a key is [k_nope | the row's lanes behind the latent] a head
+    np.testing.assert_array_equal(
+        np.asarray(K[0, 20]).reshape(4, -1)[:, 16:],
+        np.broadcast_to(np.asarray(pool[layer, 4, 4, cfg.kv_lora_rank:]), (4, 96)))
+    assert not np.asarray(K[:, 2 * rows:]).any() and not np.asarray(V[:, 2 * rows:]).any()
+
+
 # -- (b) the kernel against its plain form ------------------------------------------
 @pytest.mark.parametrize("H,Dk,Dv", [
     pytest.param(4, 24, 16, id="four_heads"),

@@ -1087,7 +1087,28 @@ def _kimivl_operands(s, nb, monkeypatch):
     return G._mla_moe_arch(cfg, True), params, pool
 
 
-def test_kimivl_prefill_call_beside_a_full_pool(v5e, monkeypatch):
+KIMIVL_BLOCKS = 28000
+
+
+@pytest.fixture(scope="module")
+def kimivl_tail(v5e):
+    """The compiled tail program of the cell's configuration (1 dense + 6
+    expert layers): a call of 8,192 positions against a table of 32,768,
+    beside a pool of ``KIMIVL_BLOCKS`` blocks; compiled once for the tests
+    that read it."""
+    import paddle_tpu.models.generation as G
+
+    s, T, MB = SingleDeviceSharding(v5e[0]), 8192, 2048
+    with pytest.MonkeyPatch.context() as patch:
+        arch, params, pool = _kimivl_operands(s, KIMIVL_BLOCKS, patch)
+        fn = jax.jit(G.build_paged_tail_prefill(arch, 1, T, 16, MB), donate_argnums=(5,))
+        return _compile_uncached(fn.trace(
+            params, _on(s, (1, T), jnp.int32), _on(s, (1,), jnp.int32),
+            _on(s, (1,), jnp.int32), _on(s, (1, MB), jnp.int32), pool
+        ).lower(lowering_platforms=("tpu",)))
+
+
+def test_kimivl_prefill_call_beside_a_full_pool(kimivl_tail):
     """The tail program of the cell's configuration, whole (1 dense + 6
     expert layers, 8.5 GB of weights), a call of 8,192 positions against a
     table of 32,768, beside a pool of 4 GB: seven calls of the prefill
@@ -1097,22 +1118,53 @@ def test_kimivl_prefill_call_beside_a_full_pool(v5e, monkeypatch):
     inside the cell's ``headroom_bytes`` with no (queries x context) tensor
     among them: float32 scores of 8,192 x 16 heads against 24,576 cached rows
     alone would be 12.9 GB."""
-    import paddle_tpu.models.generation as G
-
-    s, T, MB, NB = SingleDeviceSharding(v5e[0]), 8192, 2048, 28000
-    arch, params, pool = _kimivl_operands(s, NB, monkeypatch)
-    fn = jax.jit(G.build_paged_tail_prefill(arch, 1, T, 16, MB), donate_argnums=(5,))
-    compiled = _compile_uncached(fn.trace(
-        params, _on(s, (1, T), jnp.int32), _on(s, (1,), jnp.int32),
-        _on(s, (1,), jnp.int32), _on(s, (1, MB), jnp.int32), pool
-    ).lower(lowering_platforms=("tpu",)))
-    text = compiled.as_text()
+    text = kimivl_tail.as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 7 + 6 + 6
     for name in ("mla_prefill_attention", "moe_experts_t256", "moe_combine"):
         assert f"%{name}" in text, name
-    mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 7 * NB * 16 * 640 * 2
+    mem = kimivl_tail.memory_analysis()
+    assert mem.alias_size_in_bytes >= 7 * KIMIVL_BLOCKS * 16 * 640 * 2
     assert mem.temp_size_in_bytes < 2.2e9, f"{mem.temp_size_in_bytes / 1e6:.0f} MB"
+
+
+def _hlo_instructions(text):
+    """{name: (result type, opcode, operand names)} of a compiled program's
+    text, over every computation of the module."""
+    import re
+
+    pattern = re.compile(
+        r"^\s*(?:ROOT )?%(\S+) = (\(.*?\)|\S+) ([\w-]+)\(([^)]*)\)", re.M)
+    return {name: (shape, opcode, re.findall(r"%([\w.-]+)", operands))
+            for name, shape, opcode, operands in pattern.findall(text)}
+
+
+def test_kimivl_prefill_call_builds_its_context_in_the_kernels_layout(kimivl_tail):
+    """The same program (PR 49): the expanded context is built, handed from
+    layer to layer and read in ONE layout, the prompt kernel's, features
+    minor. No ``copy`` whose result has the shape of the key or the value
+    scratch (until PR 49: fourteen, a transposing copy of 268 and of 134 MB
+    before each of the seven kernel calls, the loops carrying positions
+    minor), every ``while`` that carries the scratch carries it ``{2,1,0}``,
+    and each ``mla_prefill_attention`` call takes its layer's loop results
+    themselves."""
+    ins = _hlo_instructions(kimivl_tail.as_text())
+    scratch = ("bf16[1,32768,4096]", "bf16[1,32768,2048]")
+    copies = [name for name, (shape, opcode, _) in ins.items()
+              if opcode == "copy" and shape.startswith(scratch)]
+    assert not copies, copies
+    loops = [shape for shape, opcode, _ in ins.values()
+             if opcode == "while" and scratch[0] in shape]
+    assert len(loops) == 7
+    for shape in loops:
+        assert all(f"{one}{{2,1,0:" in shape for one in scratch), shape
+    calls = [operands for name, (_, opcode, operands) in ins.items()
+             if opcode == "custom-call" and name.startswith("mla_prefill_attention")]
+    assert len(calls) == 7
+    for operands in calls:
+        for one, index in zip(scratch, operands[-2:]):
+            shape, opcode, (source,) = ins[index]
+            assert shape.startswith(one + "{2,1,0:") and opcode == "get-tuple-element"
+            assert ins[source][1] == "while", (index, source, ins[source][1])
 
 
 def test_kimivl_decode_step_takes_a_table_of_32k_positions(v5e, monkeypatch):
